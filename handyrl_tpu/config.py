@@ -136,7 +136,7 @@ TRAIN_DEFAULTS: Dict[str, Any] = {
         'enabled': False,        # route worker inference through the host engine
         'batch_wait_ms': 2.0,    # coalescing deadline: how long the engine holds the oldest request while the batch fills (it dispatches early once every local worker has a request in flight)
         'max_batch': 64,         # request cap per dispatched forward batch
-        'engine_backend': 'cpu',  # 'cpu' pins the engine to host cores; 'device' lets the engine claim a worker-host-local accelerator (never set on hosts sharing the learner's chip)
+        'engine_backend': 'cpu',  # 'cpu' pins the engine to host cores; 'device' lets the engine claim a worker-host-local accelerator (a local --train on an accelerator refuses it at start-up: its gathers would claim the learner's chip)
         'vault_size': 3,         # materialized model snapshots cached (engine-side in engine mode, per worker otherwise — including a degraded worker's local fallback vault)
 
         # self-healing tier (inference.EngineSupervisor / EngineClient,

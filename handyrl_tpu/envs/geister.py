@@ -345,7 +345,7 @@ class Environment(BaseEnvironment):
     def net(self):
         from ..models.geister import GeisterNet
         # env_args: {'norm_kind': 'batch'} surfaces the round-4 norm
-        # investigation knob (BENCHMARKS.md Geister quality-gap section)
+        # investigation knob (ROADMAP D5)
         # without a source edit
         return GeisterNet(norm_kind=self.args.get('norm_kind', 'group'),
                           policy_head=self.args.get('policy_head', 'dense'),

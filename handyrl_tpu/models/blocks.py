@@ -81,8 +81,8 @@ class BatchStatsNorm(nn.Module):
     GroupNorm-for-BatchNorm substitution as THE cause of the quality gap
     vs the reference (its nn.BatchNorm2d stem/heads, reference
     geister.py:107,122 — swap them for GroupNorm and the reference drops
-    from 0.661 to 0.486 at ~1k episodes, exactly this repo's level; see
-    BENCHMARKS.md). Batch statistics in the training forward are the
+    from 0.661 to 0.486 at ~1k episodes, exactly this repo's level;
+    ROADMAP D5). Batch statistics in the training forward are the
     learning-dynamics ingredient; this block provides them without
     running-stats state.
 
@@ -181,7 +181,7 @@ class TorusConv(nn.Module):
       the wrap-pad materializes a padded copy of the full activation in
       HBM for every block — the round-5 per-op table showed these
       copies/slices as the largest single HBM consumers of the GeeseNet
-      update step (BENCHMARKS.md round-5 chip window).
+      update step (ROADMAP S1; not re-measured on today's code).
     * ``impl='halo'``: the conv runs with XLA window padding (zero-pad
       folded into the conv HLO — no materialized pad), and the missing
       wrapped contributions are added back exactly: kernel-row strips for

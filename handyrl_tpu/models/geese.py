@@ -59,34 +59,28 @@ class GeeseNet(nn.Module):
     layers: int = 12
     # 'batch' = the reference TorusConv2d's nn.BatchNorm2d in the stem +
     # all 12 blocks (reference hungry_geese.py:23-35,43-44) with full
-    # running-average semantics; default follows the measured A/B verdict
-    # in BENCHMARKS.md (the round-4 Geister forensics flipped the burden
-    # of proof onto GroupNorm for this net too).
+    # running-average semantics; default follows the measured A/B
+    # (PARITY.md "norm": 0.940 group vs 0.929 batch against random — the
+    # round-4 Geister forensics had flipped the burden of proof onto
+    # GroupNorm for this net too).
     norm_kind: str = 'group'
     # 'halo' computes the identical torus conv without materializing the
     # wrap-padded activation (blocks.TorusConv docstring / round-5 per-op
     # HBM table); parity pinned by tests/test_torus_halo.py.
-    # 'pallas' fuses the WHOLE trunk (stem + all blocks) into one kernel
-    # that keeps activations in VMEM (ops/pallas_geese.py); same param
-    # tree, parity pinned by tests/test_pallas_geese.py. GroupNorm only.
     torus_impl: str = 'pad'
-    pallas_tile: int = 64
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, obs, hidden=None, train: bool = False):
         x = to_nhwc(obs)                       # (..., 7, 11, 17)
-        if self.torus_impl == 'pallas':
-            h = self._pallas_trunk(x)
-        else:
-            h = nn.relu(TorusConv(self.filters, norm_kind=self.norm_kind,
-                                  impl=self.torus_impl,
-                                  dtype=self.dtype)(x, train))
-            for _ in range(self.layers):
-                h = nn.relu(h + TorusConv(self.filters,
-                                          norm_kind=self.norm_kind,
-                                          impl=self.torus_impl,
-                                          dtype=self.dtype)(h, train))
+        h = nn.relu(TorusConv(self.filters, norm_kind=self.norm_kind,
+                              impl=self.torus_impl,
+                              dtype=self.dtype)(x, train))
+        for _ in range(self.layers):
+            h = nn.relu(h + TorusConv(self.filters,
+                                      norm_kind=self.norm_kind,
+                                      impl=self.torus_impl,
+                                      dtype=self.dtype)(h, train))
 
         # pool features at the acting goose's head cell (channel 0 of obs)
         head_mask = x[..., :1]                 # (..., 7, 11, 1)
@@ -97,42 +91,3 @@ class GeeseNet(nn.Module):
         value = jnp.tanh(nn.Dense(1, use_bias=False, dtype=self.dtype)(
             jnp.concatenate([h_head, h_avg], axis=-1)))
         return {'policy': policy, 'value': value}
-
-    def _pallas_trunk(self, x):
-        """Route the trunk through the fused VMEM kernel. The Flax
-        TorusConv stack still OWNS the params (each submodule is touched
-        once on a dummy sample — dead code XLA eliminates — so the param
-        tree is identical to the other impls); the kernel reads them."""
-        from ..ops.pallas_geese import trunk_apply, trunk_params_from_geesenet
-        if self.norm_kind != 'group':
-            raise ValueError("torus_impl='pallas' implements GroupNorm "
-                             "only (norm_kind=%r)" % (self.norm_kind,))
-        convs = [TorusConv(self.filters, norm_kind=self.norm_kind,
-                           dtype=self.dtype)
-                 for _ in range(self.layers + 1)]
-        for i, conv in enumerate(convs):
-            cin = x.shape[-1] if i == 0 else self.filters
-            conv(jnp.zeros((1, 7, 11, cin), self.dtype))
-        kp = trunk_params_from_geesenet(
-            {'TorusConv_%d' % i: c.variables['params']
-             for i, c in enumerate(convs)}, layers=self.layers)
-        lead = x.shape[:-3]
-        xf = x.reshape((-1,) + x.shape[-3:]).astype(self.dtype)
-        n = xf.shape[0]
-        tile = min(self.pallas_tile, n)
-        pad = (-n) % tile
-        if pad:
-            xf = jnp.concatenate(
-                [xf, jnp.zeros((pad,) + xf.shape[1:], xf.dtype)], axis=0)
-        groups = min(8, self.filters)
-        # Mosaic lowering needs the TPU; everywhere else (CPU tests,
-        # virtual-device meshes) the kernel runs in interpret mode.
-        # HANDYRL_PALLAS_INTERPRET=1 forces interpret anywhere (e.g.
-        # CPU-placed execution on a TPU host, debugging a Mosaic crash).
-        import os
-
-        import jax
-        interpret = (jax.default_backend() != 'tpu'
-                     or os.environ.get('HANDYRL_PALLAS_INTERPRET') == '1')
-        h = trunk_apply(xf, *kp, groups, tile, interpret)
-        return h[:n].reshape(lead + h.shape[1:])

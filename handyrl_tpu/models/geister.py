@@ -29,7 +29,8 @@ class GeisterNet(nn.Module):
     # (reference model.py:54 — self.eval() before inference). The round-4
     # pure-statistics half-measure is kept as 'batchstats' for the record;
     # it measured tied with GroupNorm (0.452 vs 0.466 at ~1k episodes,
-    # BENCHMARKS.md). Default follows the measured verdict in BENCHMARKS.md.
+    # benchmarks.jsonl geister rows). The default is what ROADMAP D5 is
+    # about to change.
     norm_kind: str = 'group'
     # 'dense' = the measured r1-r4 baseline head (1x1 conv -> Dense over
     # the flattened map); 'spatial' = the reference Conv2dHead structure
@@ -38,14 +39,14 @@ class GeisterNet(nn.Module):
     # arms flat at ~0.45 vs the reference's 0.661 while its policy stays
     # near-uniform — the spatially-local head is the next suspect: per-
     # cell logits see their own 3x3 neighborhood instead of learning a
-    # global 288->144 dense map. Default follows BENCHMARKS.md verdicts.
+    # global 288->144 dense map. Default: see ROADMAP D5.
     policy_head: str = 'dense'
     # 'torch' reproduces the reference framework's default weight
     # distributions (kaiming-uniform kernels, uniform biases —
     # blocks.torch_default_inits); 'flax' is this repo's measured
     # baseline (lecun_normal, zero biases). Initialization is the
     # remaining dynamics suspect for the early-curve Geister gap after
-    # norm + head were measured (BENCHMARKS.md).
+    # norm + head were measured (benchmarks.jsonl geister-fused-sp* rows).
     init_kind: str = 'flax'
     dtype: jnp.dtype = jnp.float32
 

@@ -23,30 +23,6 @@ import os
 from typing import Optional
 
 
-def _enable_cpu_collectives():
-    """CPU backend: cross-process collectives need the gloo transport.
-
-    XLA:CPU's default collective implementation refuses multi-process
-    computations outright ("Multiprocess computations aren't implemented on
-    the CPU backend"); the gloo implementation shipped with jaxlib handles
-    them. Must be set BEFORE ``jax.distributed.initialize`` creates the
-    backend. A no-op on non-CPU platforms, older jaxlibs without the flag,
-    and when the operator already chose an implementation.
-    """
-    import jax
-
-    platform = (os.environ.get('JAX_PLATFORMS', '').strip().lower()
-                or str(getattr(jax.config, 'jax_platforms', None) or ''))
-    if 'cpu' not in platform:
-        return
-    if 'jax_cpu_collectives_implementation' not in jax.config.values:
-        return
-    current = jax.config.values.get('jax_cpu_collectives_implementation')
-    if current and current != 'none':
-        return   # operator already chose (gloo/mpi); leave it alone
-    jax.config.update('jax_cpu_collectives_implementation', 'gloo')
-
-
 def initialize(coordinator_address: Optional[str] = None,
                num_processes: Optional[int] = None,
                process_id: Optional[int] = None) -> bool:
@@ -74,7 +50,7 @@ def initialize(coordinator_address: Optional[str] = None,
     if process_id is None and os.environ.get('JAX_PROCESS_ID'):
         process_id = int(os.environ['JAX_PROCESS_ID'])
 
-    _enable_cpu_collectives()
+    # (XLA:CPU's cross-process collectives ride gloo, jax's default)
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -18,18 +18,14 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
 from functools import partial
 
+import jax
+import jax.numpy as jnp
 from jax import lax
-try:
-    # jax >= 0.8: jax.shard_map, replication check named check_vma
-    shard_map = partial(jax.shard_map, check_vma=False)
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-    shard_map = partial(shard_map, check_rep=False)
 from jax.sharding import Mesh, PartitionSpec as P
+
+shard_map = partial(jax.shard_map, check_vma=False)
 
 
 def _block_attention(q, k, v, m_prev, l_prev, o_prev, scale):

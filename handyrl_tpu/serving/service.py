@@ -620,8 +620,9 @@ def serve_main(args, argv=None):
     sargs['env'] = dict(args['env_args'])
     inf = dict(sargs.get('inference') or {})
     if str(inf.get('engine_backend', 'cpu')) == 'device':
-        from .. import setup_compile_cache
+        from .. import claim_devices, setup_compile_cache
         setup_compile_cache()
+        claim_devices('serve')
     else:
         from ..connection import force_cpu_backend
         force_cpu_backend()
@@ -631,6 +632,9 @@ def serve_main(args, argv=None):
     telemetry.adopt_config(sargs)
     telemetry.set_process_label('serve')
     telemetry.install_crash_dump()
+    if telemetry.enabled():
+        # XLA compile-event counters (cache hits, compile durations)
+        telemetry.install_jax_monitoring()
     guard = PreemptionGuard().install()
     service = InferenceService(sargs).start()
     print(json.dumps({'serving_ready': {

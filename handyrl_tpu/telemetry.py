@@ -1478,24 +1478,22 @@ class TelemetryExporter:
 # XLA compile-event counters (jax.monitoring listeners)
 
 _JAX_MONITORING_INSTALLED = False
+# jaxpr_trace_duration / jaxpr_to_mlir_module_duration / backend_compile_duration
+_COMPILE_EVENT_PREFIX = '/jax/core/compile/'
 
 
 def install_jax_monitoring() -> bool:
     """Subscribe to jax.monitoring and count XLA compile activity into the
     registry: ``xla_compile_events_total{event=...}`` (cache hits/misses,
-    compile requests) and the ``xla_compile_seconds`` duration histogram
-    (jaxpr trace / MLIR lowering / backend compile). Idempotent and
-    version-tolerant — a jax without the monitoring API simply reports
-    False. Catches unexpected recompiles (a new padded bucket shape, a
+    compile requests) and the ``xla_compile_seconds{event=...}`` duration
+    histograms (jaxpr_trace / jaxpr_to_mlir_module / backend_compile).
+    Idempotent. Catches unexpected recompiles (a new padded bucket shape, a
     donation-geometry change) that otherwise only show up as mystery
     latency spikes in the trace."""
     global _JAX_MONITORING_INSTALLED
     if _JAX_MONITORING_INSTALLED:
         return True
-    try:
-        import jax.monitoring as _jm
-    except Exception:
-        return False
+    import jax.monitoring as _jm
 
     def _on_event(event, *a, **kw):
         try:
@@ -1507,10 +1505,16 @@ def install_jax_monitoring() -> bool:
 
     def _on_duration(event, duration, *a, **kw):
         try:
-            if 'compil' in event:
-                REGISTRY.histogram('xla_compile_seconds',
-                                   buckets=COMPILE_SECOND_BUCKETS).observe(
-                                       float(duration))
+            # trace / lower / backend-compile only, one series each (traces
+            # nest, so their sum is not wall time; backend_compile is). The
+            # compilation cache's own durations stay out: its
+            # compile_time_saved_sec would book the time a cache hit SAVED
+            # as time spent compiling
+            if event.startswith(_COMPILE_EVENT_PREFIX):
+                REGISTRY.histogram(
+                    'xla_compile_seconds', buckets=COMPILE_SECOND_BUCKETS,
+                    event=event[len(_COMPILE_EVENT_PREFIX):-len('_duration')]
+                ).observe(float(duration))
         except Exception:
             pass
         # Retrace sentinel: after mark_steady_state() every lowering event
@@ -1520,11 +1524,8 @@ def install_jax_monitoring() -> bool:
         if _STEADY['on'] and event == _RETRACE_EVENT:
             _note_retrace(event)
 
-    try:
-        _jm.register_event_listener(_on_event)
-        _jm.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        return False
+    _jm.register_event_listener(_on_event)
+    _jm.register_event_duration_secs_listener(_on_duration)
     _JAX_MONITORING_INSTALLED = True
     return True
 

@@ -638,6 +638,15 @@ def resolve_generation_backend(args: Dict[str, Any]) -> str:
     return backend
 
 
+def gather_claims_device(args: Dict[str, Any]) -> bool:
+    """True when a gather host's rollout or inference engine runs on the
+    host's accelerator instead of pinning itself to the CPU."""
+    inf = args.get('inference') or {}
+    return (resolve_generation_backend(args) == 'device'
+            or bool(inf.get('enabled')
+                    and str(inf.get('engine_backend', 'cpu')) == 'device'))
+
+
 class DeviceActorGather(Gather):
     """A gather whose 'workers' are lanes of one fused device rollout.
 
@@ -743,14 +752,14 @@ def gather_loop(args, conn, gather_id, server_address=None):
     from .environment import make_jax_env
     backend = resolve_generation_backend(args)
     inf = args.get('inference') or {}
-    if (backend == 'device'
-            or (inf.get('enabled')
-                and str(inf.get('engine_backend', 'cpu')) == 'device')):
+    if gather_claims_device(args):
         # the rollout/inference engine is the ONE process on this host
-        # allowed to claim a local accelerator (hosts without one fall back
-        # to jax's default); workers stay CPU-pinned either way
-        from . import setup_compile_cache
+        # allowed to claim a local accelerator; it says which backend jax
+        # gave it (the CPU, on a host without one). Workers stay CPU-pinned
+        # either way.
+        from . import claim_devices, setup_compile_cache
         setup_compile_cache()
+        claim_devices('gather-%d' % gather_id)
     else:
         force_cpu_backend()
     reconnect = None
@@ -763,10 +772,10 @@ def gather_loop(args, conn, gather_id, server_address=None):
             DeviceActorGather(args, conn, gather_id,
                               reconnect=reconnect).run()
             return
-        _LOG.warning(
-            'gather %d: generation backend "device" requested but env %r '
-            'has no pure-JAX twin; falling back to the host path',
-            gather_id, (args.get('env') or {}).get('env'))
+        raise ValueError(
+            'gather %d: generation backend "device" needs an env with a '
+            'pure-JAX twin; %r has none'
+            % (gather_id, (args.get('env') or {}).get('env')))
     if backend == 'worker' and inf.get('enabled'):
         # per-host override demoted this gather to plain workers: they
         # must materialize their own params instead of dialing an engine
@@ -994,7 +1003,10 @@ class RemoteWorkerCluster:
 
 
 def worker_main(args, argv):
-    force_cpu_backend()   # worker hosts are CPU actors by design
+    # This host process only supervises: it creates no jax array, and it
+    # leaves JAX_PLATFORMS as the operator set it. Every gather pins itself
+    # to the CPU (gather_loop) except a 'device' gather, which must inherit
+    # the operator's platform to reach this host's accelerator.
     worker_args = args['worker_args']
     if len(argv) >= 1:
         worker_args['num_parallel'] = int(argv[0])

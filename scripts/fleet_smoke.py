@@ -50,8 +50,6 @@ def main() -> int:
     trace_dir = tempfile.mkdtemp(prefix='fleet_smoke_trace.')
     os.environ['HANDYRL_TPU_TRACE'] = trace_dir
     os.environ['HANDYRL_TPU_TRACE_RATE'] = '1'
-    import handyrl_tpu
-    handyrl_tpu.honor_platform_env()
     from handyrl_tpu.environment import make_env
     from handyrl_tpu.generation import sample_seed
     from handyrl_tpu.model import ModelWrapper

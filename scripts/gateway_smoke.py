@@ -56,8 +56,6 @@ def main() -> int:
     trace_dir = tempfile.mkdtemp(prefix='gateway_smoke_trace.')
     os.environ['HANDYRL_TPU_TRACE'] = trace_dir
     os.environ['HANDYRL_TPU_TRACE_RATE'] = '1'
-    import handyrl_tpu
-    handyrl_tpu.honor_platform_env()
     from handyrl_tpu.environment import make_env
     from handyrl_tpu.league import journal_path, make_rating_book
     from handyrl_tpu.model import ModelWrapper

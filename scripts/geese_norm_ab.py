@@ -1,4 +1,4 @@
-"""A/B: GroupNorm vs full BatchNorm in GeeseNet (VERDICT r4 #2).
+"""A/B: GroupNorm vs full BatchNorm in GeeseNet (round-4 review, item 2).
 
 The round-4 Geister forensics proved the GroupNorm-for-BatchNorm
 substitution causes that env's quality gap (reference drops 0.661 → 0.486
@@ -29,9 +29,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
 
 def run_arm(norm_kind: str, epochs: int):
-    import jax
-    if os.environ.get('JAX_PLATFORMS', '').strip() == 'cpu':
-        jax.config.update('jax_platforms', 'cpu')
     from handyrl_tpu.config import apply_defaults
     from handyrl_tpu.train import Learner
 

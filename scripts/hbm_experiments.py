@@ -1,4 +1,4 @@
-"""Close the gap to the HBM roofline floor (VERDICT r3 #4).
+"""Close the gap to the HBM roofline floor (round-3 review, item 4).
 
 The round-3 roofline: the headline update step (GeeseNet B=128 T=16,
 bf16 activations) moves 4.26 GB HBM/step, a 5.2 ms floor at the v5e's
@@ -173,8 +173,6 @@ def main():
             T = int(val or next(argv))
         else:
             raise SystemExit('unknown argument %r' % a)
-    import handyrl_tpu
-    handyrl_tpu.honor_platform_env()
     out = os.path.join(os.path.dirname(__file__), '..', 'benchmarks.jsonl')
     for name, kw in (('fp32', {}),
                      ('bf16-act', {'dtype': 'bf16'}),
@@ -184,12 +182,7 @@ def main():
                      # the wrap-pad HBM copies (models/blocks.py) — the
                      # round-5 per-op table's named target
                      ('bf16-act+halo', {'dtype': 'bf16',
-                                        'torus_impl': 'halo'}),
-                     # whole trunk fused into one VMEM-resident Pallas
-                     # kernel (ops/pallas_geese.py) — activations never
-                     # round-trip HBM between the 13 conv layers
-                     ('bf16-act+pallas', {'dtype': 'bf16',
-                                          'torus_impl': 'pallas'})):
+                                        'torus_impl': 'halo'})):
         row = variant(name, steps=steps, B=B, T=T, **kw)
         print(json.dumps(row), flush=True)
         with open(os.path.abspath(out), 'a') as f:

@@ -1,4 +1,4 @@
-"""Account for shard_map overhead on the virtual CPU mesh (VERDICT r3 #8).
+"""Account for shard_map overhead on the virtual CPU mesh (round-3 review, item 8).
 
 Times ONE fused-pipeline dispatch (rollout chunk + window ingest + K SGD
 steps, ops/fused_pipeline.py) at mesh sizes 1/2/4/8 with the GLOBAL

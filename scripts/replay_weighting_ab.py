@@ -1,4 +1,4 @@
-"""A/B: per-window vs per-episode replay weighting (VERDICT r3 #7).
+"""A/B: per-window vs per-episode replay weighting (round-3 review, item 7).
 
 The device windower ingests up to ``replay_windows_per_episode`` (W)
 uniformly-placed windows per finished episode; ring rows are then drawn
@@ -18,7 +18,7 @@ Run: JAX_PLATFORMS=cpu python scripts/replay_weighting_ab.py
      [--epochs N] [--arms 1,4] [--init CKPT]
 Appends one JSON row per arm to benchmarks.jsonl.
 
---init (VERDICT r4 #5 — the divergent regime): warm-start both arms from
+--init (round-4 review, item 5 — the divergent regime): warm-start both arms from
 a late-stage checkpoint (e.g. models_north_star_device/latest.ckpt) whose
 policy plays LONG episodes, so min(len//fs, W) actually spreads and the
 two weightings differ. Requires the full GeeseNet architecture (the
@@ -37,9 +37,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
 
 def run_arm(windows_cap: int, epochs: int, init_ckpt: str = ''):
-    import jax
-    if os.environ.get('JAX_PLATFORMS', '').strip() == 'cpu':
-        jax.config.update('jax_platforms', 'cpu')
     from handyrl_tpu.config import apply_defaults
     from handyrl_tpu.models import build
     from handyrl_tpu.train import Learner

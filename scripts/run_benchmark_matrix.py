@@ -97,7 +97,7 @@ ROWS = {
                        'gamma': 0.99,
                        'policy_target': 'VTRACE', 'value_target': 'VTRACE'},
     },
-    # VERDICT r1 #5: the fully device-resident Hungry Geese pipeline —
+    # round-1 review, item 5: the fully device-resident Hungry Geese pipeline —
     # rollouts, replay ring, and SGD all on the accelerator
     'geese-device': {
         'env_args': {'env': 'HungryGeese'},
@@ -155,8 +155,6 @@ for _twin, _extra in (('geister-fused-sp', {'policy_head': 'spatial'}),
 
 
 def run_row(name, epochs):
-    import handyrl_tpu
-    handyrl_tpu.honor_platform_env()
     from handyrl_tpu.config import apply_defaults
     from handyrl_tpu.train import Learner
 
@@ -197,9 +195,6 @@ def run_row(name, epochs):
 
 
 def main():
-    if os.environ.get('JAX_PLATFORMS', '').strip() == 'cpu':
-        import jax
-        jax.config.update('jax_platforms', 'cpu')
     epochs = 10
     rows = []
     argv = iter(sys.argv[1:])

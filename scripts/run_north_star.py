@@ -66,8 +66,6 @@ def latest_epoch(model_dir: str) -> int:
 
 
 def main():
-    import handyrl_tpu
-    handyrl_tpu.honor_platform_env()
     from handyrl_tpu.config import apply_defaults
     from handyrl_tpu.train import Learner
 

@@ -42,8 +42,7 @@ import os
 os.environ['JAX_PLATFORMS'] = 'cpu'
 
 def main():
-    import jax, json
-    jax.config.update('jax_platforms', 'cpu')
+    import json
     from handyrl_tpu import telemetry
     from handyrl_tpu.config import apply_defaults
     from handyrl_tpu.train import Learner

@@ -27,8 +27,6 @@ PORT = int(os.environ.get('TELEMETRY_SMOKE_PORT', '18917'))
 LEARNER = r'''
 import os
 os.environ['JAX_PLATFORMS'] = 'cpu'
-import jax
-jax.config.update('jax_platforms', 'cpu')
 from handyrl_tpu.config import apply_defaults
 from handyrl_tpu.train import Learner
 raw = {'env_args': {'env': 'TicTacToe'},

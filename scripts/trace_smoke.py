@@ -31,8 +31,6 @@ import os
 os.environ['JAX_PLATFORMS'] = 'cpu'
 
 def main():
-    import jax
-    jax.config.update('jax_platforms', 'cpu')
     from handyrl_tpu.config import apply_defaults
     from handyrl_tpu.train import Learner
     raw = {'env_args': {'env': 'TicTacToe'},

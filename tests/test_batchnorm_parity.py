@@ -1,4 +1,4 @@
-"""Full BatchNorm parity for norm_kind='batch' (VERDICT r4 #1).
+"""Full BatchNorm parity for norm_kind='batch' (round-4 review, item 1).
 
 The reference trains GeisterNet with nn.BatchNorm2d in the stem and both
 heads (reference geister.py:107,122) and serves actors/evaluators in eval

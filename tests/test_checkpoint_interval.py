@@ -43,11 +43,6 @@ def _ckpt_numbers(model_dir):
 
 
 def _learner_child(args, report_path):
-    # keep the child off the persistent XLA compile cache: jaxlib 0.4.x CPU
-    # corrupts the heap (malloc abort / SIGSEGV) deserializing the cached
-    # fused-pipeline executable on the resume run; these programs compile in
-    # seconds, so the child just recompiles
-    os.environ['HANDYRL_TPU_NO_COMPILE_CACHE'] = '1'
     from handyrl_tpu.train import Learner
     ln = Learner(args=args)
     steps_at_start = ln.trainer.steps

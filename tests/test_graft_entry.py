@@ -12,9 +12,11 @@ def test_entry_compiles_and_runs():
     assert out['value'].shape == (64, 1)
 
 
-def test_dryrun_multichip_two_devices():
+def test_dryrun_multichip_two_devices(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)   # the dry run checkpoints under its cwd
     graft.dryrun_multichip(2)
 
 
-def test_dryrun_multichip_eight_devices():
+def test_dryrun_multichip_eight_devices(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
     graft.dryrun_multichip(8)

@@ -243,11 +243,8 @@ def test_feed_episodes_drops_and_counts_poisoned_episodes(tmp_path):
 LEARNER_SCRIPT = r'''
 import os, sys
 os.environ['JAX_PLATFORMS'] = 'cpu'
-os.environ['HANDYRL_TPU_NO_COMPILE_CACHE'] = '1'
 
 def main():
-    import jax
-    jax.config.update('jax_platforms', 'cpu')
     from handyrl_tpu.config import apply_defaults
     from handyrl_tpu.train import train_main
     raw = {'env_args': {'env': 'TicTacToe'},
@@ -275,7 +272,6 @@ def _spawn_learner(tmp_path, tag, epochs=3, restart=0, guard_cfg=None,
         'guard': guard_cfg or {}})
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {**os.environ, 'JAX_PLATFORMS': 'cpu',
-           'HANDYRL_TPU_NO_COMPILE_CACHE': '1',
            'PYTHONPATH': repo + os.pathsep + os.environ.get('PYTHONPATH', '')}
     if chaos:
         env['HANDYRL_TPU_CHAOS'] = chaos
@@ -398,7 +394,6 @@ def test_bitflipped_checkpoint_resumes_from_previous_epoch(tmp_path):
 def _nan_learner_child(args, chaos, report_path):
     # spawned subprocess: an XLA-CPU crash fails one test instead of
     # killing the whole pytest run (same containment as test_resume)
-    os.environ['HANDYRL_TPU_NO_COMPILE_CACHE'] = '1'
     os.environ['HANDYRL_TPU_CHAOS'] = chaos
     import jax
     import numpy as _np

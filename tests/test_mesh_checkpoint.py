@@ -50,16 +50,13 @@ def _value_sha1(params):
 
 
 def _learner_child(args, device_count, report_path):
-    # the virtual-device count must be pinned BEFORE jax imports; spawned
-    # children also stay off the persistent compile cache (jaxlib 0.4.x CPU
-    # resume-deserialization corruption, see test_resume)
+    # the virtual-device count must be pinned BEFORE jax imports
     os.environ['XLA_FLAGS'] = \
         '--xla_force_host_platform_device_count=%d' % device_count
     os.environ['JAX_PLATFORMS'] = 'cpu'
-    os.environ['HANDYRL_TPU_NO_COMPILE_CACHE'] = '1'
     import contextlib
+
     import jax
-    jax.config.update('jax_platforms', 'cpu')
     from handyrl_tpu.train import Learner
 
     buf = io.StringIO()

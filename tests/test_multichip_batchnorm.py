@@ -41,11 +41,7 @@ def test_shard_map_batchnorm_stats_replicated(geister_batch_and_wrapper):
     rep8 = jax.tree_util.tree_map(
         lambda a: jnp.concatenate([a] * 8, axis=0), batch)
 
-    try:
-        shard_map = partial(jax.shard_map, mesh=mesh, check_vma=False)
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as _sm
-        shard_map = partial(_sm, mesh=mesh, check_rep=False)
+    shard_map = partial(jax.shard_map, mesh=mesh, check_vma=False)
 
     P = jax.sharding.PartitionSpec
 

@@ -16,7 +16,6 @@ _CHILD = r"""
 import os, sys
 os.environ['JAX_PLATFORMS'] = 'cpu'
 import jax
-jax.config.update('jax_platforms', 'cpu')
 sys.path.insert(0, %(repo)r)
 from handyrl_tpu.parallel import multihost
 
@@ -80,7 +79,6 @@ _TRAIN_CHILD = r"""
 import os, sys
 os.environ['JAX_PLATFORMS'] = 'cpu'
 import jax
-jax.config.update('jax_platforms', 'cpu')
 sys.path.insert(0, %(repo)r)
 from handyrl_tpu.parallel import multihost
 

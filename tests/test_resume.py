@@ -30,11 +30,6 @@ def _args(model_dir, epochs, restart=0):
 
 
 def _learner_child(args, report_path):
-    # keep the child off the persistent XLA compile cache: jaxlib 0.4.x CPU
-    # corrupts the heap (malloc abort / SIGSEGV) deserializing the cached
-    # fused-pipeline executable on the resume run; these programs compile in
-    # seconds, so the child just recompiles
-    os.environ['HANDYRL_TPU_NO_COMPILE_CACHE'] = '1'
     import numpy as np
     import jax
     from handyrl_tpu.train import Learner

@@ -2,7 +2,7 @@
 impl='pad' (the wrap-pad reference semantics of the torus conv,
 reference hungry_geese.py:23-35) — same param tree, same outputs, same
 gradients. The halo path exists purely to remove the wrap-pad's
-full-activation HBM copies (BENCHMARKS.md round-5 per-op table)."""
+full-activation HBM copies (the round-5 per-op table; ROADMAP S1)."""
 
 import jax
 import jax.numpy as jnp
