@@ -48,7 +48,7 @@ def _doc_tokens(doc: SourceFile) -> Set[str]:
 # registry entry points whose first positional string literal is a metric
 _METRIC_CALLS = {'counter', 'gauge', 'histogram'}
 # entry points whose first positional string literal is a stage name
-_STAGE_CALLS = {'observe_stage', 'trace_span', 'span'}
+_STAGE_CALLS = {'observe_stage', 'trace_span'}
 
 # package files whose literals are NOT part of the runtime vocabulary
 _EXCLUDED_PREFIXES = ('handyrl_tpu/analysis/',)

@@ -936,9 +936,15 @@ class InferenceEngine:
                 extra = {'trace_ids': tids, 'always': True}
         with telemetry.trace_span('engine_batch', rows=len(group), mid=mid,
                                   **extra):
-            self._serve_group(mid, group)
+            replies = self._serve_group(mid, group)
+        # fan out only after the span has closed: a submitter that reads
+        # the trace as soon as it holds its reply must find the span there
+        for ep, reply in replies:
+            self._safe_reply(ep, reply)
 
-    def _serve_group(self, mid: int, group: List[tuple]):
+    def _serve_group(self, mid: int, group: List[tuple]) -> List[tuple]:
+        """One coalesced forward batch; returns ``(endpoint, reply)`` for
+        every request of the group, in order."""
         self._ensure_vault()
         model = self.vault.model(mid)
         reqs = [req for _ep, req, _t in group]
@@ -985,6 +991,7 @@ class InferenceEngine:
                 [reqs[n].get('seed') or [0] for n in act_rows])
         act_index = {n: k for k, n in enumerate(act_rows)}
 
+        replies = []
         for n, (ep, req, _t) in enumerate(group):
             hidden_row = None
             if next_hidden is not None:
@@ -1004,7 +1011,8 @@ class InferenceEngine:
                 if hidden_row is not None:
                     row_out['hidden'] = hidden_row
                 reply = {'rid': req.get('rid'), 'outputs': row_out}
-            self._safe_reply(ep, reply)
+            replies.append((ep, reply))
+        return replies
 
 
 class EngineSupervisor:

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..device_generation import _init_rollout_engine, make_gen_body
 from ..parallel.mesh import batch_sharding, replicated_sharding, shard_batch
 from .losses import LossConfig
@@ -135,10 +136,15 @@ class FusedPipeline:
 
         def gen_ingest(actor_params, env_state, hidden, wstate, ring,
                        cursor, size, rng):
-            env_state, hidden, rng, records = rollout_chunk(
-                actor_params, env_state, hidden, rng, chunk_steps)
-            (wstate, ring, cursor, size, rng,
-             n_done, n_win) = ingest(records, wstate, ring, cursor, size, rng)
+            # the phases carry stable names into every operation's metadata
+            # (the device trace's ``tf_op``): time is attributed by scope,
+            # not by compiler numbering
+            with jax.named_scope('rollout'):
+                env_state, hidden, rng, records = rollout_chunk(
+                    actor_params, env_state, hidden, rng, chunk_steps)
+            with jax.named_scope('ingest'):
+                (wstate, ring, cursor, size, rng, n_done, n_win) = ingest(
+                    records, wstate, ring, cursor, size, rng)
             return (env_state, hidden, wstate, ring, cursor, size, rng,
                     records['done'], records['outcome'], n_win)
 
@@ -146,13 +152,15 @@ class FusedPipeline:
             # EVERYTHING the host reads per chunk rides ONE f32 array: every
             # distinct-array fetch is its own blocking transfer, so one sync
             # point per dispatch is the budget
-            parts = [done.astype(jnp.float32).reshape(-1),
-                     outcome.astype(jnp.float32).reshape(-1),
-                     size.astype(jnp.float32).reshape(1),
-                     size_min.astype(jnp.float32).reshape(1),
-                     n_win.astype(jnp.float32).reshape(1)]
-            parts += [v.astype(jnp.float32).reshape(1) for v in metric_vals]
-            return jnp.concatenate(parts)
+            with jax.named_scope('pack'):
+                parts = [done.astype(jnp.float32).reshape(-1),
+                         outcome.astype(jnp.float32).reshape(-1),
+                         size.astype(jnp.float32).reshape(1),
+                         size_min.astype(jnp.float32).reshape(1),
+                         n_win.astype(jnp.float32).reshape(1)]
+                parts += [v.astype(jnp.float32).reshape(1)
+                          for v in metric_vals]
+                return jnp.concatenate(parts)
 
         def sgd_tail(train_state, ring, cursor, size, rng, data_cnt_ema,
                      batch_rows):
@@ -166,19 +174,22 @@ class FusedPipeline:
                 # restore the (B, T, P, ...) window shape after the gather
                 # and rebuild the batch pytree (dotted keys -> nested obs)
                 from .device_windows import unflatten_window_keys
-                batch = unflatten_window_keys(
-                    {k: ring[k][slots].reshape(
-                        (batch_rows,) + windower.window_spec[k][0])
-                     for k in ring})
+                with jax.named_scope('sample'):
+                    batch = unflatten_window_keys(
+                        {k: ring[k][slots].reshape(
+                            (batch_rows,) + windower.window_spec[k][0])
+                         for k in ring})
                 lr = (default_lr * data_cnt_ema
                       / (1 + ts.steps.astype(jnp.float32) * 1e-5))
-                ts, metrics = update(ts, batch, lr)
+                with jax.named_scope('update'):
+                    ts, metrics = update(ts, batch, lr)
                 return (ts, key), metrics
 
-            (train_state, rng), stacked = jax.lax.scan(
-                body, (train_state, rng), None, length=sgd_steps)
-            metrics = jax.tree_util.tree_map(
-                lambda m: jnp.sum(m, axis=0), stacked)
+            with jax.named_scope('sgd'):
+                (train_state, rng), stacked = jax.lax.scan(
+                    body, (train_state, rng), None, length=sgd_steps)
+                metrics = jax.tree_util.tree_map(
+                    lambda m: jnp.sum(m, axis=0), stacked)
             return train_state, rng, [metrics[k]
                                       for k in self._metric_keys]
 
@@ -240,6 +251,15 @@ class FusedPipeline:
         self.ring_size_host = 0
         self.ring_min_host = 0          # min ring size across shards
         self.windows_ingested_host = 0  # cumulative windows ingested
+        # cumulative host counters of the fetched chunks (the ``host_block``
+        # span carries them): plies played, plies on which any lane ended a
+        # game (the ingest's window builder ran), the windows it built there
+        # (every lane's, on each shard where a game ended), games ended
+        self.plies_host = 0
+        self.builder_plies_host = 0
+        self.windows_built_host = 0
+        self.episodes_host = 0
+        self._windows_per_lane = windower.W
 
     # -- multi-chip construction -------------------------------------------
     def _shard_loop_state(self, mesh):
@@ -333,16 +353,32 @@ class FusedPipeline:
     # -- dispatch helpers --------------------------------------------------
     def _parse(self, pending):
         flat, has_metrics = pending
-        flat = np.asarray(flat)
         K, N, P = self.chunk_steps, self.n_envs, self.num_players
-        done = flat[:K * N].reshape(K, N) > 0.5
-        outcome = flat[K * N:K * N * (1 + P)].reshape(K, N, P)
-        rest = flat[K * N * (1 + P):]
-        self.ring_size_host = int(rest[0])
-        self.ring_min_host = int(rest[1])
-        # true cumulative ingest count (ring size saturates at capacity
-        # once the ring wraps, so it cannot stand in for this)
-        self.windows_ingested_host += int(rest[2])
+        with telemetry.trace_span('host_block') as span:
+            # the wait for the chunk's device work: everything else here
+            # is host arithmetic on the fetched array
+            flat = np.asarray(flat)
+            done = flat[:K * N].reshape(K, N) > 0.5
+            outcome = flat[K * N:K * N * (1 + P)].reshape(K, N, P)
+            rest = flat[K * N * (1 + P):]
+            self.ring_size_host = int(rest[0])
+            self.ring_min_host = int(rest[1])
+            # true cumulative ingest count (ring size saturates at capacity
+            # once the ring wraps, so it cannot stand in for this)
+            self.windows_ingested_host += int(rest[2])
+            self.plies_host += K * N
+            self.builder_plies_host += int(done.any(axis=1).sum())
+            self.windows_built_host += (
+                int(done.reshape(K, self.ndev, -1).any(axis=2).sum())
+                * (N // self.ndev) * self._windows_per_lane)
+            self.episodes_host += int(done.sum())
+            span.set(plies=self.plies_host,
+                     builder_plies=self.builder_plies_host,
+                     windows_built=self.windows_built_host,
+                     episodes=self.episodes_host,
+                     windows_ingested=self.windows_ingested_host,
+                     ring_size=self.ring_size_host,
+                     sgd_steps=self.sgd_steps if has_metrics else 0)
         metrics = None
         if has_metrics:
             metrics = {k: float(v)
@@ -360,21 +396,24 @@ class FusedPipeline:
     def warm_step(self, actor_params):
         """Generation+ingest only (pre-minimum_episodes). Returns the parsed
         accounting of the PREVIOUS chunk, or None on the first call."""
-        (self.state, self.hidden, self.wstate, self.ring, self.cursor,
-         self.size, self.rng, packed) = self._warmup(
-            actor_params, self.state, self.hidden, self.wstate, self.ring,
-            self.cursor, self.size, self.rng)
+        with telemetry.trace_span('dispatch'):
+            (self.state, self.hidden, self.wstate, self.ring, self.cursor,
+             self.size, self.rng, packed) = self._warmup(
+                actor_params, self.state, self.hidden, self.wstate,
+                self.ring, self.cursor, self.size, self.rng)
         return self._flip(packed, False)
 
     def train_step(self, actor_params, train_state: TrainState,
                    data_cnt_ema: float):
         """One fused chunk+ingest+K-SGD-steps dispatch. Returns
         (train_state, parsed_prev_chunk_or_None)."""
-        (train_state, self.state, self.hidden, self.wstate, self.ring,
-         self.cursor, self.size, self.rng, packed) = self._fused(
-            actor_params, train_state, self.state, self.hidden, self.wstate,
-            self.ring, self.cursor, self.size, self.rng,
-            jnp.asarray(data_cnt_ema, jnp.float32))
+        ema = jnp.asarray(data_cnt_ema, jnp.float32)
+        with telemetry.trace_span('dispatch'):
+            (train_state, self.state, self.hidden, self.wstate, self.ring,
+             self.cursor, self.size, self.rng, packed) = self._fused(
+                actor_params, train_state, self.state, self.hidden,
+                self.wstate, self.ring, self.cursor, self.size, self.rng,
+                ema)
         return train_state, self._flip(packed, True)
 
     def drain(self):

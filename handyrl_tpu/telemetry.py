@@ -13,11 +13,15 @@ throughput go across processes). Four pieces, all stdlib-only:
   existing heartbeat frames and the learner merges them fleet-wide
   (``merge_snapshots``: counters sum, gauges sum, histogram buckets add).
 
-* **Spans** — lightweight timed sections recorded as observations of the
-  ``stage_seconds{stage=...}`` histogram family, stamped with a run-scoped
-  ``run_id``. The stage vocabulary subsumes the ingest StageTimer's
-  canonical names (``INGEST_STAGES``): a bench row, a live epoch timing
-  line, and an exported histogram all speak the same stage language.
+* **Spans** — ``trace_span``, the one timed-section primitive: name, start
+  and end on ``time.perf_counter``, a span id and the id of the enclosing
+  span, flat attributes. Finished spans sit in a bounded in-memory ring
+  (``spans()``), feed the ``stage_seconds{stage=...}`` histogram family
+  and the trace file, and while open are ``handyrl:<name>`` annotations
+  in any jax profiler session. The stage vocabulary subsumes the ingest
+  StageTimer's canonical names (``INGEST_STAGES``): a bench row, a live
+  epoch timing line, and an exported histogram all speak the same stage
+  language.
 
 * **Leveled logger** — ``get_logger()``; verbosity from
   ``HANDYRL_TPU_LOG_LEVEL`` (debug/info/warning/error, default info).
@@ -55,11 +59,14 @@ from __future__ import annotations
 
 import atexit
 import bisect
+import itertools
 import json
 import logging
 import os
 import random
 import re
+import resource
+import statistics
 import sys
 import threading
 import time
@@ -308,20 +315,160 @@ def trace_event(name: str, ts: Optional[float] = None, dur: float = 0.0,
         _emit_locked(json.dumps(ev))
 
 
-@contextmanager
-def trace_span(name: str, trace_id=None, **args):
-    """Timed section: always folded into the ``stage_seconds{stage=...}``
-    histogram family; additionally written to the trace file when tracing
-    is on (and the id — or the rate, for id-less spans — samples it)."""
-    t_wall = time.time()
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        REGISTRY.observe_stage(name, dt)
-        if _TRACE.dir:
-            trace_event(name, ts=t_wall, dur=dt, trace_id=trace_id, **args)
+# -- spans: the one way the program times a section
+
+SPAN_RING_SIZE = 16384     # a 51 s window of 100 ms chunks at ten spans each
+SPAN_ANNOTATION_PREFIX = 'handyrl:'
+
+_SPAN_LOCK = threading.Lock()
+_SPAN_RING: deque = deque(maxlen=SPAN_RING_SIZE)   # guarded-by: _SPAN_LOCK
+_SPAN_IDS = itertools.count(1)
+_SPAN_LOCAL = threading.local()       # .stack: this thread's open spans
+_ANNOTATIONS: Optional[tuple] = None  # (TraceAnnotation, StepTraceAnnotation)
+
+
+def _annotation_classes() -> tuple:
+    """jax.profiler's host annotations, once jax is in the process (a
+    process that never imported jax has no profiler session to appear in,
+    and a span must not be what imports it)."""
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        if 'jax' not in sys.modules:
+            return ()
+        try:
+            from jax.profiler import StepTraceAnnotation, TraceAnnotation
+            _ANNOTATIONS = (TraceAnnotation, StepTraceAnnotation)
+        except ImportError:
+            _ANNOTATIONS = ()
+    return _ANNOTATIONS
+
+
+class Span:
+    """One timed section, open or finished: ``name``, ``t0`` / ``t1`` on
+    ``time.perf_counter``, ``span_id``, ``parent_id`` (the enclosing open
+    span of the same thread, None at the root) and flat ``attrs``. Made by
+    :func:`trace_span`; read back through :func:`spans`."""
+
+    __slots__ = ('name', 'span_id', 'parent_id', 't0', 't1', 'attrs',
+                 'children', '_parent', '_note', '_t_wall', '_trace_id')
+
+    def __init__(self, name, trace_id, step_num, attrs):
+        self.name = name
+        self.attrs = attrs
+        self.children: List['Span'] = []       # finished direct children
+        self.t0 = self.t1 = None
+        self._trace_id = trace_id
+        self._note = None
+        classes = _annotation_classes()
+        if classes:
+            self._note = (classes[0](SPAN_ANNOTATION_PREFIX + name)
+                          if step_num is None else
+                          classes[1](SPAN_ANNOTATION_PREFIX + name,
+                                     step_num=step_num))
+
+    def set(self, **attrs):
+        """Attributes read at the boundary, after the work (counters)."""
+        self.attrs.update(attrs)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def child_seconds(self, name: str) -> float:
+        """Seconds spent in the finished direct children called ``name``."""
+        return sum(c.t1 - c.t0 for c in self.children if c.name == name)
+
+    def __enter__(self):
+        stack = getattr(_SPAN_LOCAL, 'stack', None)
+        if stack is None:
+            stack = _SPAN_LOCAL.stack = []
+        self._parent = stack[-1] if stack else None
+        self.parent_id = self._parent.span_id if stack else None
+        self.span_id = next(_SPAN_IDS)
+        stack.append(self)
+        self._t_wall = time.time() if _TRACE.dir else None
+        if self._note is not None:
+            self._note.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        _SPAN_LOCAL.stack.pop()
+        dt = self.t1 - self.t0
+        if self._parent is not None:
+            self._parent.children.append(self)
+            self._parent = None     # a finished span keeps no open one alive
+        with _SPAN_LOCK:
+            _SPAN_RING.append(self)
+        REGISTRY.observe_stage(self.name, dt)
+        if self._t_wall is not None and _TRACE.dir:
+            trace_event(self.name, ts=self._t_wall, dur=dt,
+                        trace_id=self._trace_id, span_id=self.span_id,
+                        parent_id=self.parent_id, **self.attrs)
+        return False
+
+    def record(self) -> Dict[str, Any]:
+        return {'name': self.name, 't0': self.t0, 't1': self.t1,
+                'span_id': self.span_id, 'parent_id': self.parent_id,
+                'attrs': dict(self.attrs)}
+
+
+class _NullSpan:
+    """What :func:`trace_span` hands out with telemetry off."""
+
+    __slots__ = ()
+    children = ()
+    t0 = t1 = None
+
+    def set(self, **attrs):
+        pass
+
+    def child_seconds(self, name: str) -> float:
+        return 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def trace_span(name: str, trace_id=None, step_num: Optional[int] = None,
+               **attrs):
+    """Timed section (a context manager; ``as`` gives the :class:`Span`,
+    whose ``set(**attrs)`` takes what is only known after the work).
+
+    On close the span goes to the process's bounded in-memory ring
+    (:func:`spans`), its duration into the ``stage_seconds{stage=...}``
+    histogram family and, when tracing is on (and the id — or the rate, for
+    id-less spans — samples it), a Chrome-trace event with
+    ``args.span_id`` / ``args.parent_id`` into the trace file. While open
+    it is a ``jax.profiler.TraceAnnotation`` named ``handyrl:<name>`` (a
+    ``StepTraceAnnotation`` when ``step_num`` is given), so any profiler
+    session holds the program's spans on the device trace's timeline.
+    With telemetry off: one flag check."""
+    if not _ENABLED:
+        return _NULL_SPAN
+    return Span(name, trace_id, step_num, attrs)
+
+
+def spans(name: Optional[str] = None,
+          since: Optional[float] = None) -> List[Dict[str, Any]]:
+    """Finished spans still in the ring, oldest first, as plain records
+    (``name``, ``t0``, ``t1``, ``span_id``, ``parent_id``, ``attrs``);
+    ``name`` keeps one stage, ``since`` those that ended at or after that
+    ``time.perf_counter`` reading."""
+    with _SPAN_LOCK:
+        held = list(_SPAN_RING)
+    return [s.record() for s in held
+            if (name is None or s.name == name)
+            and (since is None or s.t1 >= since)]
 
 
 def trace_stage(stage: str, seconds: float, count: int = 1):
@@ -859,31 +1006,6 @@ class MetricRegistry:
                                            Histogram(self._lock, buckets))
         return h
 
-    @contextmanager
-    def span(self, stage: str, parent: Optional[str] = None):
-        """Timed section recorded under ``stage_seconds{stage=...}`` (plus a
-        DEBUG structured event carrying the run id and a monotonic stamp).
-        ``parent`` names the enclosing stage, keeping the select/decode/
-        assemble/ipc/h2d/compute/drain vocabulary hierarchical."""
-        if not _ENABLED:
-            yield
-            return
-        labels = {'stage': stage}
-        if parent:
-            labels['parent'] = parent
-        hist = self.histogram('stage_seconds', **labels)
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            hist.observe(dt)
-            _RECORDER.record('span', stage, seconds=round(dt, 6))
-            log = get_logger('span')
-            if log.isEnabledFor(logging.DEBUG):
-                log.debug('span %s run=%s t=%.6f dur=%.6f parent=%s',
-                          stage, _RUN_ID, time.monotonic(), dt, parent or '-')
-
     def observe_stage(self, stage: str, seconds: float, count: int = 1):
         """StageTimer mirror: fold an ingest-stage timing batch into the
         span histogram family (same canonical stage names)."""
@@ -924,7 +1046,6 @@ REGISTRY = MetricRegistry()
 counter = REGISTRY.counter
 gauge = REGISTRY.gauge
 histogram = REGISTRY.histogram
-span = REGISTRY.span
 snapshot = REGISTRY.snapshot
 
 
@@ -1830,6 +1951,151 @@ def set_utilization_proxy(value):
     gauge('device_utilization_proxy').set(value)
 
 
+# -- the fused loop's per-chunk record and stall event
+
+# a chunk's split: the loop's spans under the names the stall event and the
+# epoch record's ``fused`` block give them. The first two lie at the head of
+# the iteration that sees the chunk complete, the rest at the tail of the
+# iteration before. A boundary's ``state_fetch`` waits for the chunk in
+# flight, so its time is booked under ``wait`` and taken out of ``epoch``:
+# the pieces are disjoint parts of the interval and ``wait`` is ALL the time
+# the loop was blocked on device results in it
+CHUNK_HEAD = (('enqueue', 'dispatch'), ('wait', 'host_block'))
+CHUNK_TAIL = (('account', 'chunk_account'), ('eval', 'eval_share'),
+              ('epoch', 'epoch_boundary'))
+
+
+def _cpu_usage() -> Tuple[float, float, int]:
+    """Process CPU seconds, this thread's CPU seconds, and the process's
+    involuntary context switches so far."""
+    return (time.process_time(), time.thread_time(),
+            resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw)
+
+
+class ChunkMonitor:
+    """What the fused loop keeps of each chunk, and the stall detector.
+
+    A chunk is complete when the loop's blocking fetch of its packed result
+    returns (the end of a ``host_block`` span). The INTERVAL between two
+    completions is one chunk, whole: the tail of the iteration that saw the
+    first (accounting, eval share, an epoch boundary with its own wait for
+    the device) and the head of the next (enqueue, wait). ``fetched`` books
+    it from the open ``fused_iter`` span right after the step; ``closed``
+    keeps the finished iteration's tail for the next interval.
+
+    A running median over the last ``WINDOW`` intervals is kept; an interval
+    over ``RATIO`` times that median and at least ``FLOOR_S`` over it is a
+    stall. The stall's record carries the interval, the median, the split,
+    the process's and the thread's CPU seconds and the involuntary context
+    switches inside the interval and the load average, and is emitted ONE
+    completion later (flight-recorder event ``stall``, counter
+    ``fused_stalls_total``, one WARNING line), when ``next_host_block_s`` is
+    known (the next interval's ``wait``): near zero, the device had long
+    finished and the host was not woken; a whole chunk, the device itself
+    was late. ``epoch_block`` gives the epoch record's ``fused`` block and
+    starts the next epoch's."""
+
+    WINDOW, MIN_SAMPLES = 64, 8
+    RATIO, FLOOR_S = 2.0, 0.5
+
+    def __init__(self):
+        self._recent: deque = deque(maxlen=self.WINDOW)
+        self._chunks: List[Tuple[float, Dict[str, float]]] = []
+        self._stalls: List[Dict[str, Any]] = []
+        self._pending: Optional[Dict[str, Any]] = None
+        self._usage = _cpu_usage()
+        self._done: Optional[float] = None     # the last completion
+        self._tail: Dict[str, float] = {}      # the last iteration's tail
+
+    def fetched(self, dispatch: int, span) -> Optional[Dict[str, Any]]:
+        """The step of iteration ``span`` (still open) has returned: if it
+        fetched a chunk, book the interval that chunk's completion ends."""
+        blocks = [c for c in span.children if c.name == 'host_block']
+        if not blocks:
+            return None
+        done, before = blocks[-1].t1, self._done
+        self._done = done
+        if before is None:
+            return None
+        split = dict(self._tail)
+        for key, stage in CHUNK_HEAD:
+            split[key] = split.get(key, 0.0) + span.child_seconds(stage)
+        return self.observe(dispatch, done - before, split)
+
+    def closed(self, span):
+        """Iteration ``span`` has finished: its tail opens the next
+        interval. The boundary's packed state fetch waits for the chunk in
+        flight: that part of the boundary is the next interval's wait."""
+        tail = {key: span.child_seconds(stage) for key, stage in CHUNK_TAIL}
+        tail['wait'] = sum(
+            boundary.child_seconds('state_fetch')
+            for boundary in span.children if boundary.name == 'epoch_boundary')
+        tail['epoch'] -= tail['wait']
+        self._tail = tail
+
+    def observe(self, dispatch: int, interval_s: float,
+                split: Dict[str, float]) -> Optional[Dict[str, Any]]:
+        """Book one interval; returns the stall record this call emitted
+        (the PREVIOUS stall, now complete), if any."""
+        usage, before = _cpu_usage(), self._usage
+        self._usage = usage
+        emitted = self._emit(split.get('wait'))
+        median = (statistics.median(self._recent)
+                  if len(self._recent) >= self.MIN_SAMPLES else None)
+        if median is not None and interval_s > max(
+                self.RATIO * median, median + self.FLOOR_S):
+            self._pending = {
+                'dispatch': int(dispatch),
+                'interval_s': round(interval_s, 6),
+                'median_s': round(median, 6),
+                'split': {k: round(v, 6) for k, v in split.items()},
+                'process_cpu_s': round(usage[0] - before[0], 6),
+                'thread_cpu_s': round(usage[1] - before[1], 6),
+                'involuntary_switches': usage[2] - before[2],
+                'loadavg': [round(x, 2) for x in os.getloadavg()],
+                'next_host_block_s': None}
+        self._recent.append(interval_s)
+        self._chunks.append((interval_s, split))
+        return emitted
+
+    def _emit(self, next_wait_s: Optional[float]):
+        stall, self._pending = self._pending, None
+        if stall is None:
+            return None
+        if next_wait_s is not None:
+            stall['next_host_block_s'] = round(next_wait_s, 6)
+        self._stalls.append(stall)
+        counter('fused_stalls_total').inc()
+        record_event('stall', 'dispatch %d' % stall['dispatch'], **stall)
+        get_logger('perf').warning('stall: %s', json.dumps(stall))
+        return stall
+
+    def flush(self):
+        """Loop exit: a stall still waiting for the next completion goes
+        out with ``next_host_block_s`` null."""
+        return self._emit(None)
+
+    def epoch_block(self) -> Dict[str, Any]:
+        chunks, stalls = self._chunks, self._stalls
+        self._chunks, self._stalls = [], []
+        block: Dict[str, Any] = {'chunks': len(chunks), 'stalls': stalls}
+        if chunks:
+            intervals = [c[0] for c in chunks]
+            block['interval_median_s'] = round(
+                statistics.median(intervals), 6)
+            block['interval_max_s'] = round(max(intervals), 6)
+            for key in ('enqueue', 'wait', 'account', 'eval'):
+                block[key + '_median_s'] = round(statistics.median(
+                    c[1].get(key, 0.0) for c in chunks), 6)
+            # the share of the epoch's chunk intervals that the loop spent
+            # blocked on device results. Each wait lies inside its interval,
+            # so a value over 1 is a fault of the booking and is left to show
+            block['utilization'] = round(
+                sum(c[1].get('wait', 0.0) for c in chunks) / sum(intervals),
+                6)
+        return block
+
+
 def perf_status() -> Dict[str, Any]:
     """Compiled-performance block for /statusz (rendered by --status)."""
     return {
@@ -1866,6 +2132,12 @@ def validate_metrics_line(line: str, fleet: bool = False) -> Dict[str, Any]:
         ab = rec['alerts']
         if not isinstance(ab, dict) or 'active' not in ab:
             raise ValueError('alerts block malformed: %r' % (ab,))
+    if 'fused' in rec:
+        fb = rec['fused']
+        if (not isinstance(fb, dict) or not isinstance(fb.get('chunks'), int)
+                or not isinstance(fb.get('stalls'), list)
+                or (fb['chunks'] > 0 and 'interval_median_s' not in fb)):
+            raise ValueError('fused block malformed: %r' % (fb,))
     return rec
 
 

@@ -1163,6 +1163,7 @@ class Learner:
         self._chaos = guard_mod.parse_chaos()
         self._final_flushed = False
         self._fused_active = False
+        self._fused_trained = False   # a fused train dispatch has returned
         self._last_ckpt_epoch = -1
         self._last_ckpt_steps = -1
 
@@ -1614,9 +1615,11 @@ class Learner:
         # learner-side copy stays on HOST (numpy): it only feeds
         # snapshots/checkpoints; a device copy would cost one upload
         # per leaf each epoch for nothing
-        self.wrapper.params = jax.tree_util.tree_map(np.asarray, params)
+        with telemetry.trace_span('checkpoint_serialize') as span:
+            self.wrapper.params = jax.tree_util.tree_map(np.asarray, params)
+            raw = self.wrapper.params_bytes()
+            span.set(bytes=len(raw))
         os.makedirs(self.args.get('model_dir', 'models'), exist_ok=True)
-        raw = self.wrapper.params_bytes()
         # atomic (temp + fsync + rename) plus a CRC32 sidecar manifest: a
         # crash mid-write must never leave a truncated latest.ckpt /
         # trainer_state.ckpt, and resume verifies the checksum so silent
@@ -1629,20 +1632,27 @@ class Learner:
         from .utils.fs import write_layout_manifest
         layout = checkpoint_layout(self.trainer.mesh,
                                    self.trainer.partition_rules, steps=steps)
-        for path in (self.model_path(self.model_epoch), self.latest_model_path()):
-            checksummed_write_bytes(path, raw)
-            write_layout_manifest(path, layout)
-        if state_blob is not None:
-            checksummed_write_bytes(self.trainer_state_path(), state_blob)
-            write_layout_manifest(self.trainer_state_path(), layout)
-        # publish BEFORE retention GC: a version the registry is about to
-        # pin must be pinned by the time the GC pass reads the manifest
-        self._publish_checkpoint(steps)
-        self._gc_checkpoints()
-        # durable plane rides the checkpoint cadence: the ledger snapshot
-        # and the spool GC horizon must describe a state a restart can
-        # actually resume from, i.e. one with a durable checkpoint
-        self._sync_durable_state()
+        with telemetry.trace_span('checkpoint_write') as span:
+            blobs = [(self.model_path(self.model_epoch), raw),
+                     (self.latest_model_path(), raw)]
+            if state_blob is not None:
+                blobs.append((self.trainer_state_path(), state_blob))
+            for path, blob in blobs:
+                checksummed_write_bytes(path, blob)
+                write_layout_manifest(path, layout)
+            span.set(files=len(blobs),
+                     bytes=sum(len(blob) for _path, blob in blobs))
+        with telemetry.trace_span('checkpoint_publish_gc'):
+            # publish BEFORE retention GC: a version the registry is about
+            # to pin must be pinned by the time the GC pass reads the
+            # manifest
+            self._publish_checkpoint(steps)
+            self._gc_checkpoints()
+            # durable plane rides the checkpoint cadence: the ledger
+            # snapshot and the spool GC horizon must describe a state a
+            # restart can actually resume from, i.e. one with a durable
+            # checkpoint
+            self._sync_durable_state()
 
     def _registry_root(self) -> str:
         srv = self.args.get('serving') or {}
@@ -2380,7 +2390,7 @@ class Learner:
         self._print_generation_stats()
         self._print_league_stats()
 
-        with telemetry.span('epoch_update'):
+        with telemetry.trace_span('epoch_update'):
             params, steps, state_blob = self.trainer.update()
         if params is None and self.trainer.failed:
             _LOG.error('training failed (see traceback above); shutting down')
@@ -2475,8 +2485,11 @@ class Learner:
             mem_rows = telemetry.sample_device_memory()
             telemetry.gauge('device_mem_utilization').set(
                 round(telemetry.device_memory_utilization(mem_rows), 6))
+            # the fused loop's warm-up epochs can pass before its training
+            # program has compiled once: there, wait for a train dispatch
             if (not telemetry.steady_state_active()
-                    and self.model_epoch >= self._retrace_warmup):
+                    and self.model_epoch >= self._retrace_warmup
+                    and (self._fused_trained or not self._fused_active)):
                 telemetry.mark_steady_state(
                     'epoch %d (retrace_warmup_epochs=%d)'
                     % (self.model_epoch, self._retrace_warmup))
@@ -2838,18 +2851,13 @@ class Learner:
         epoch_steps = 0
         epoch_t0 = time.time()
         eval_tracker: Dict[str, int] = {}
-        timing = os.environ.get('HANDYRL_TPU_TIMING') == '1'
-        tacc = {'dispatch': 0.0, 'fetch': 0.0, 'eval': 0.0, 'epoch': 0.0,
-                'iters': 0}
         # feed_device_chunk is one fetch behind dispatch; chunk -> epoch
         # attribution therefore uses the epoch captured at dispatch time
         epoch_of_dispatch = deque()
-        # fused dispatch/fetch latency joins the same 'dispatch' /
-        # 'host_block' stage histograms the threaded trainer's StageTimer
-        # mirror feeds; epoch deltas feed the device-utilization proxy
-        m_dispatch = telemetry.histogram('stage_seconds', stage='dispatch')
-        m_block = telemetry.histogram('stage_seconds', stage='host_block')
-        tlast = {'dispatch': 0.0, 'fetch': 0.0}
+        # each iteration is one `fused_iter` span whose children (`dispatch`
+        # and `host_block` inside the pipeline, the rest below) feed the
+        # stage histograms; the monitor keeps the per-chunk record
+        monitor = telemetry.ChunkMonitor()
 
         def account(prev):
             if prev is None:
@@ -2893,69 +2901,60 @@ class Learner:
                 _LOG.warning('preemption signal received; snapshotting '
                              'and exiting')
                 break
-            if actor_epoch != self.model_epoch:
-                actor.params = (copy_params(tr.state.params)
-                                if tr.state is not None
-                                else put_tree(self.wrapper.params))
-                actor_epoch = self.model_epoch
-            epoch_of_dispatch.append(self.model_epoch)
-            # on a mesh, also hold warmup until EVERY shard's ring slice
-            # has at least one window (a shard with local size 0 would feed
-            # all-zero batches into the psum'd gradient); ring_min_host is
-            # one fetch behind, which only extends warmup by one chunk
-            warm = (self.num_returned_episodes < args['minimum_episodes']
-                    or (tr.mesh is not None and fp.dispatches > 0
-                        and fp.ring_min_host < 1))
-            t0 = time.time()
-            if warm:
-                account(fp.warm_step(actor.params))
-                dt_fetch = time.time() - t0
-                tacc['fetch'] += dt_fetch
-                m_block.observe(dt_fetch)
-            else:
-                ema = tr.data_cnt_ema
-                if tr.chaos_nan.due(tr.steps, fp.sgd_steps):
-                    _LOG.warning('chaos: injecting non-finite update at '
-                                 'step %d', tr.steps)
-                    ema = float('nan')   # poisons the on-device lr schedule
-                tr.state, prev = fp.train_step(actor.params, tr.state, ema)
-                t1 = time.time()
-                tacc['dispatch'] += t1 - t0
-                m_dispatch.observe(t1 - t0)
-                tr.steps += fp.sgd_steps
-                epoch_steps += fp.sgd_steps
-                account(prev)
-                dt_fetch = time.time() - t1
-                tacc['fetch'] += dt_fetch
-                m_block.observe(dt_fetch)
-            tacc['iters'] += 1
-
-            t2 = time.time()
-            self._run_eval_share(evaluator, eval_tracker)
-            tacc['eval'] += time.time() - t2
-
-            if cadence.due(self.num_returned_episodes):
-                t3 = time.time()
-                self._fused_epoch(pending_metrics, epoch_steps,
-                                  time.time() - epoch_t0, fp, evaluator)
-                tacc['epoch'] += time.time() - t3
-                # device-utilization proxy from this epoch's dispatch/fetch
-                # deltas: the fused loop's 'host_block' is the packed fetch
-                util = telemetry.utilization_from_stages(
-                    {'dispatch': tacc['dispatch'] - tlast['dispatch'],
-                     'host_block': tacc['fetch'] - tlast['fetch']})
-                telemetry.set_utilization_proxy(util)
-                tlast.update(dispatch=tacc['dispatch'], fetch=tacc['fetch'])
-                if timing:
-                    line = {k: round(v, 2) for k, v in tacc.items()}
-                    if util is not None:
-                        line['util'] = round(util, 4)
-                    print('timing: %s' % json.dumps(line))
-                pending_metrics.clear()   # account() closes over this list
-                epoch_steps = 0
-                epoch_t0 = time.time()
-                if self._past_epoch_budget():
-                    self.shutdown_flag = True
+            with telemetry.trace_span('fused_iter',
+                                      step_num=fp.dispatches + 1) as it:
+                if actor_epoch != self.model_epoch:
+                    with telemetry.trace_span('actor_refresh'):
+                        actor.params = (copy_params(tr.state.params)
+                                        if tr.state is not None
+                                        else put_tree(self.wrapper.params))
+                    actor_epoch = self.model_epoch
+                epoch_of_dispatch.append(self.model_epoch)
+                # on a mesh, also hold warmup until EVERY shard's ring slice
+                # has at least one window (a shard with local size 0 would
+                # feed all-zero batches into the psum'd gradient);
+                # ring_min_host is one fetch behind, which only extends
+                # warmup by one chunk
+                warm = (self.num_returned_episodes < args['minimum_episodes']
+                        or (tr.mesh is not None and fp.dispatches > 0
+                            and fp.ring_min_host < 1))
+                if warm:
+                    prev = fp.warm_step(actor.params)
+                else:
+                    ema = tr.data_cnt_ema
+                    if tr.chaos_nan.due(tr.steps, fp.sgd_steps):
+                        _LOG.warning('chaos: injecting non-finite update at '
+                                     'step %d', tr.steps)
+                        ema = float('nan')   # poisons the on-device lr schedule
+                    tr.state, prev = fp.train_step(actor.params, tr.state,
+                                                   ema)
+                    # the training program has compiled: from here a compile
+                    # is a retrace (the sentinel arms at the next boundary)
+                    self._fused_trained = True
+                    tr.steps += fp.sgd_steps
+                    epoch_steps += fp.sgd_steps
+                monitor.fetched(fp.dispatches, it)
+                with telemetry.trace_span('chunk_account') as span:
+                    account(prev)
+                    span.set(episodes_admitted=self.num_returned_episodes)
+                with telemetry.trace_span('eval_share') as span:
+                    self._run_eval_share(evaluator, eval_tracker)
+                    span.set(eval_dispatches=getattr(evaluator, 'dispatches',
+                                                     0))
+                if cadence.due(self.num_returned_episodes):
+                    with telemetry.trace_span('epoch_boundary') as span:
+                        self._fused_epoch(pending_metrics, epoch_steps,
+                                          time.time() - epoch_t0, fp,
+                                          evaluator, monitor.epoch_block())
+                        span.set(epoch=self.model_epoch)
+                    pending_metrics.clear()   # account() closes over this list
+                    epoch_steps = 0
+                    epoch_t0 = time.time()
+                    if self._past_epoch_budget():
+                        self.shutdown_flag = True
+                it.set(dispatch=fp.dispatches, warm=int(warm))
+            monitor.closed(it)
+        monitor.flush()
         account(fp.drain())
         if hasattr(evaluator, 'drain'):
             self.feed_results(evaluator.drain(),
@@ -2967,9 +2966,12 @@ class Learner:
         self.final_flush()
 
     def _fused_epoch(self, pending_metrics, epoch_steps, epoch_wall,
-                     fp, evaluator):
+                     fp, evaluator, fused_block):
         """Epoch boundary for the fused loop: drain metric futures, print
-        the reference-format lines, update the lr EMA, checkpoint."""
+        the reference-format lines, update the lr EMA, checkpoint.
+        ``fused_block`` is the loop's per-chunk record of the epoch
+        (telemetry.ChunkMonitor.epoch_block): it rides the metrics record
+        and its dispatch/wait split feeds the utilization proxy."""
         tr = self.trainer
         print()
         print('epoch %d' % self.model_epoch)
@@ -3022,14 +3024,23 @@ class Learner:
             # ONE packed transfer for params + optimizer state, not one
             # blocking np.asarray per leaf
             from .utils.fetch import fetch_tree
-            host_state = fetch_tree(tr.state)
-            self.update_model(host_state.params, tr.steps,
-                              tr.state_bytes(host_state))
+            with telemetry.trace_span('state_fetch') as span:
+                host_state = fetch_tree(tr.state)
+                span.set(bytes=sum(
+                    leaf.nbytes for leaf in
+                    jax.tree_util.tree_leaves(host_state)))
+            with telemetry.trace_span('checkpoint_serialize') as span:
+                state_blob = tr.state_bytes(host_state)
+                span.set(bytes=len(state_blob))
+            self.update_model(host_state.params, tr.steps, state_blob)
         else:
             self.update_model(None, tr.steps, write_files=False)
+        telemetry.set_utilization_proxy(fused_block.get('utilization'))
         rec_extra = {'dispatches_gen': fp.dispatches,
-                     'dispatches_eval': getattr(evaluator, 'dispatches', 0)}
-        self._write_metrics(tr.steps, rec_extra)
+                     'dispatches_eval': getattr(evaluator, 'dispatches', 0),
+                     'fused': fused_block}
+        with telemetry.trace_span('metrics_write'):
+            self._write_metrics(tr.steps, rec_extra)
         self._maybe_profile()
         self.flags = set()
 

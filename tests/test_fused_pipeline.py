@@ -178,3 +178,332 @@ def test_fused_pipeline_resume(tmp_path, capsys):
     assert learner2.model_epoch == 3
     assert learner2.trainer.steps > steps_before
     assert (tmp_path / 'models' / '3.ckpt').exists()
+
+
+# ---------------------------------------------------------------------------
+# the loop measures itself: spans, counters, named phases, stalls
+
+
+def telemetry_metric_args(name):
+    """The arguments the benchmark's metric file gives its reader."""
+    import os
+
+    from benchmark.manifest import ROOT
+    with open(os.path.join(ROOT, 'benchmark', 'metrics',
+                           name + '.json')) as f:
+        return json.load(f)['args']
+
+
+@pytest.mark.timeout(600)
+def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
+    """A short fused run leaves, per iteration, one ``fused_iter`` span with
+    ``dispatch`` and ``host_block`` children; the ``host_block`` counters
+    agree with the pipeline's own; the epoch records carry the ``fused``
+    block; and the fused program's phases carry their names."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from handyrl_tpu import telemetry
+    from handyrl_tpu.ops.fused_pipeline import FusedPipeline
+    built = []
+    init = FusedPipeline.__init__
+
+    def remember(self, *args, **kwargs):
+        built.append(self)
+        init(self, *args, **kwargs)
+    monkeypatch.setattr(FusedPipeline, '__init__', remember)
+    booked = []
+    observe = telemetry.ChunkMonitor.observe
+
+    def remember_chunk(self, dispatch, interval_s, split):
+        booked.append(dict(split, interval=interval_s))
+        return observe(self, dispatch, interval_s, split)
+    monkeypatch.setattr(telemetry.ChunkMonitor, 'observe', remember_chunk)
+
+    t_start = time.perf_counter()
+    learner = Learner(args=apply_defaults(_ttt_raw(tmp_path)))
+    learner.run()
+    fp, = built
+    recs = telemetry.spans(since=t_start)
+    iters = [r for r in recs if r['name'] == 'fused_iter']
+    assert len(iters) == fp.dispatches
+    assert [r['attrs']['dispatch'] for r in iters] == \
+        list(range(1, fp.dispatches + 1))
+    assert iters[0]['attrs']['warm'] == 1 and iters[-1]['attrs']['warm'] == 0
+    assert all(r['parent_id'] is None for r in iters)
+    children = {}
+    for rec in recs:
+        children.setdefault(rec['parent_id'], []).append(rec['name'])
+    for n, it in enumerate(iters):
+        names = children[it['span_id']]
+        assert names.count('dispatch') == 1
+        # the fetch runs one chunk behind: the first iteration has none
+        assert names.count('host_block') == (0 if n == 0 else 1)
+        assert names.count('chunk_account') == names.count('eval_share') == 1
+    boundaries = [r for r in recs if r['name'] == 'epoch_boundary']
+    assert [r['attrs']['epoch'] for r in boundaries] == [1, 2]
+    for boundary in boundaries:
+        assert set(children[boundary['span_id']]) >= {
+            'state_fetch', 'checkpoint_serialize', 'checkpoint_write',
+            'checkpoint_publish_gc', 'metrics_write'}
+    writes = [r for r in recs if r['name'] == 'checkpoint_write']
+    assert all(r['attrs']['files'] == 3 and r['attrs']['bytes'] > 0
+               for r in writes)
+
+    # counters: cumulative, one host_block per fetched chunk (the last one
+    # is the loop's drain), in step with the pipeline's own
+    blocks = [r for r in recs if r['name'] == 'host_block']
+    assert len(blocks) == fp.dispatches
+    last = blocks[-1]['attrs']
+    chunk_plies = fp.chunk_steps * fp.n_envs
+    assert [b['attrs']['plies'] for b in blocks] == \
+        [chunk_plies * (i + 1) for i in range(len(blocks))]
+    assert 0 < last['builder_plies'] <= fp.chunk_steps * len(blocks)
+    assert last['builder_plies'] * fp.n_envs <= last['plies']
+    assert last['windows_ingested'] == fp.windows_ingested_host > 0
+    # the builder makes every lane's windows on each of its plies; the ring
+    # got those of the lanes whose game had ended
+    assert last['windows_built'] == (last['builder_plies'] * fp.n_envs
+                                     * fp._windows_per_lane)
+    assert last['windows_ingested'] <= last['windows_built']
+    assert last['episodes'] == fp.episodes_host \
+        == learner.num_returned_episodes
+    assert last['builder_plies'] <= last['episodes']
+    assert blocks[0]['attrs']['sgd_steps'] == 0          # a warm-up chunk
+    assert last['sgd_steps'] == fp.sgd_steps == 4
+
+    # the epoch records carry the per-chunk block
+    lines = (tmp_path / 'metrics.jsonl').read_text().splitlines()
+    rows = [telemetry.validate_metrics_line(line) for line in lines]
+    assert sum(row['fused']['chunks'] for row in rows) <= fp.dispatches
+    block = rows[-1]['fused']
+    assert block['chunks'] > 0 and block['stalls'] == []
+    assert 0 < block['interval_median_s'] <= block['interval_max_s']
+    assert block['wait_median_s'] >= 0 and 0 <= block['utilization'] <= 1
+
+    # the benchmark's fetch_wait_ms and the loop's own per-chunk record are
+    # one quantity: a chunk's wait is its host_block plus the boundary state
+    # fetch before it, and it lies inside the chunk's interval
+    from benchmark.readers import program_span
+    from benchmark.record import Run, quantile
+    assert len(booked) == fp.dispatches - 2
+    assert all(0 <= chunk['wait'] <= chunk['interval'] for chunk in booked)
+    window = (blocks[0]['t1'], iters[-1]['t1'])   # the chunks the loop booked
+    run = Run(cell={'name': 'c'}, config={}, traffic={}, train_args={},
+              spans={}, window=window)
+    args = telemetry_metric_args('fetch_wait_ms')
+    assert program_span.read(run, **args) == {
+        'samples': len(booked), 'value': pytest.approx(
+            1e3 * quantile([chunk['wait'] for chunk in booked], 0.5))}
+
+    # named phases in the lowered program
+    tr = learner.trainer
+
+    def spec(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+    text = fp._fused.lower(
+        spec(tr.state.params), spec(tr.state), spec(fp.state),
+        spec(fp.hidden), spec(fp.wstate), spec(fp.ring), spec(fp.cursor),
+        spec(fp.size), spec(fp.rng),
+        jax.ShapeDtypeStruct((), jnp.float32)).as_text(debug_info=True)
+    for scope in ('rollout', 'ingest', 'sgd', 'pack'):
+        assert 'jit(fused_pipeline_train)/%s/' % scope in text, scope
+    # the SGD scan's body is a function of its own: its scopes are relative
+    assert 'loc("sample/gather"' in text and 'loc("update/' in text
+
+
+def test_fused_block_must_be_well_formed():
+    import json as _json
+
+    from handyrl_tpu import telemetry
+    base = {'epoch': 1, 'steps': 1, 'episodes': 1, 'time': 0.0,
+            'run_id': 'r', 'telemetry': {'counters': {}}}
+    telemetry.validate_metrics_line(_json.dumps(
+        dict(base, fused={'chunks': 0, 'stalls': []})))
+    for bad in ({'chunks': 3, 'stalls': []},        # no interval statistics
+                {'chunks': 0}, ['chunks']):
+        with pytest.raises(ValueError, match='fused block'):
+            telemetry.validate_metrics_line(_json.dumps(
+                dict(base, fused=bad)))
+
+
+def test_stall_detector_on_a_synthetic_interval_series():
+    """One 5x outlier in a steady series: one event, complete one
+    completion later, with the next chunk's wait as the diagnosis."""
+    from handyrl_tpu import telemetry
+    fired = telemetry.counter('fused_stalls_total')
+    before = fired.value
+    monitor = telemetry.ChunkMonitor()
+    steady = {'enqueue': 0.004, 'wait': 0.98, 'account': 0.003,
+              'eval': 0.002, 'epoch': 0.0}
+    for n in range(1, 13):
+        assert monitor.observe(n, 1.0 + 0.01 * (n % 3), steady) is None
+    # the outlier itself is only booked; nothing is emitted yet
+    assert monitor.observe(13, 5.0, dict(steady, wait=4.98)) is None
+    assert fired.value == before
+    # ... one completion later it goes out, with that chunk's wait
+    stall = monitor.observe(14, 1.0, dict(steady, wait=0.015))
+    assert fired.value == before + 1
+    assert stall['dispatch'] == 13
+    assert stall['interval_s'] == 5.0
+    assert stall['median_s'] == pytest.approx(1.01)
+    assert stall['split'] == dict(steady, wait=4.98)
+    assert stall['next_host_block_s'] == 0.015
+    assert stall['process_cpu_s'] >= stall['thread_cpu_s'] >= 0
+    assert stall['involuntary_switches'] >= 0 and len(stall['loadavg']) == 3
+    event = [e for e in telemetry.recorder().events()
+             if e['kind'] == 'stall'][-1]
+    assert event['dispatch'] == 13 and event['next_host_block_s'] == 0.015
+    # a boundary-sized bump (under twice the median, or under 0.5 s over
+    # it) is no stall; and nothing is emitted twice
+    assert monitor.observe(15, 1.4, steady) is None
+    assert monitor.observe(16, 1.0, steady) is None
+    block = monitor.epoch_block()
+    assert block['chunks'] == 16 and block['stalls'] == [stall]
+    assert block['interval_max_s'] == 5.0
+    assert block['wait_median_s'] == 0.98
+    assert monitor.epoch_block() == {'chunks': 0, 'stalls': []}
+    # a stall at the loop's last completion still goes out, undiagnosed
+    for n in range(8):
+        monitor.observe(n, 1.0, steady)
+    monitor.observe(9, 9.0, steady)
+    assert monitor.flush()['next_host_block_s'] is None
+    assert monitor.flush() is None
+
+
+class _FakeSpan:
+    """What ChunkMonitor reads of a span."""
+
+    def __init__(self, name, t0, t1, children=()):
+        self.name, self.t0, self.t1 = name, t0, t1
+        self.children = list(children)
+
+    def child_seconds(self, name):
+        return sum(c.t1 - c.t0 for c in self.children if c.name == name)
+
+
+def _fake_iteration(t, wait, boundary=0.0):
+    """An iteration that starts at ``t``: enqueue 2 ms, the wait, 3 ms of
+    accounting, then perhaps a boundary whose state fetch is all but 15 ms
+    of it. Returns (span as the step returns, span at its close), end."""
+    head = [_FakeSpan('dispatch', t, t + 0.002),
+            _FakeSpan('host_block', t + 0.002, t + 0.002 + wait)]
+    end = t + 0.002 + wait + 0.003
+    tail = [_FakeSpan('chunk_account', end - 0.003, end)]
+    if boundary:
+        fetch = _FakeSpan('state_fetch', end, end + boundary - 0.015)
+        tail.append(_FakeSpan('epoch_boundary', end, end + boundary, [fetch]))
+        end += boundary
+    return (_FakeSpan('fused_iter', t, None, head),
+            _FakeSpan('fused_iter', t, end, head + tail)), end
+
+
+def test_chunk_intervals_run_from_completion_to_completion():
+    """The interval ends where a chunk's fetch returns (``host_block``),
+    whatever the iterations around it held: a boundary iteration (whose
+    state fetch takes over the wait for the device) followed by a short one
+    still reads as two whole chunks, and each chunk's ``wait`` is all the
+    time blocked on the device in its interval."""
+    from handyrl_tpu import telemetry
+    monitor = telemetry.ChunkMonitor()
+    t, seen = 100.0, []
+    #           wait   boundary: the chunk in flight is waited for THERE,
+    #                            so the next iteration's own wait is ~0
+    for wait, boundary in [(0.9, 0.0), (0.9, 1.0), (0.0005, 0.0),
+                           (0.9, 1.0), (0.0005, 1.0), (0.0005, 0.0)]:
+        (open_span, closed_span), t = _fake_iteration(t, wait, boundary)
+        monitor.fetched(len(seen) + 1, open_span)
+        monitor.closed(closed_span)
+        seen.append(closed_span.t1 - closed_span.t0)
+    # the iterations themselves are bimodal: ~0.9, ~1.9, ~0.006 s ...
+    assert max(seen) > 1.9 and min(seen) < 0.01
+    splits = [split for _interval, split in monitor._chunks]
+    block = monitor.epoch_block()
+    assert block['chunks'] == 5 and block['stalls'] == []
+    # ... the intervals between completions are not: 0.905 and 1.0055 s
+    assert block['interval_max_s'] == pytest.approx(1.0055)
+    assert block['interval_median_s'] == pytest.approx(1.0055)
+    assert block['enqueue_median_s'] == pytest.approx(0.002)
+    # nor is the wait: the fetch's own 0.9 s, or 0.5 ms of it after the
+    # boundary's state fetch took 0.985 s; the boundary keeps its host work
+    assert [round(s['wait'], 4) for s in splits] == [
+        0.9, 0.9855, 0.9, 0.9855, 0.9855]
+    assert [round(s['epoch'], 4) for s in splits] == [
+        0.0, 0.015, 0.0, 0.015, 0.015]
+    assert block['wait_median_s'] == pytest.approx(0.9855)
+    assert block['utilization'] == pytest.approx(
+        (2 * 0.9 + 3 * 0.9855) / (2 * 0.905 + 3 * 1.0055), rel=1e-4)
+    # with telemetry off the loop hands over spans that hold nothing
+    assert monitor.fetched(7, telemetry._NULL_SPAN) is None
+    monitor.closed(telemetry._NULL_SPAN)
+    assert monitor.epoch_block() == {'chunks': 0, 'stalls': []}
+
+
+@pytest.mark.parametrize('series', [
+    # every iteration a boundary (an epoch a chunk)
+    [(0.0005, 1.0)] * 6,
+    # a boundary first and last: their state fetches lie in no booked
+    # interval of this record and must not be counted against it
+    [(0.9, 1.0), (0.0005, 0.0), (0.9, 0.0), (0.9, 1.0)],
+    # the record is cut (epoch_block) inside every boundary, as the loop does
+    [(0.9, 0.0), (0.9, 1.0), (0.0005, 1.0), (0.0005, 0.0), (0.9, 1.0)],
+], ids=['all_boundaries', 'boundary_at_the_ends', 'cut_in_boundaries'])
+def test_time_blocked_on_the_device_never_exceeds_the_intervals(series):
+    """``fused.utilization`` is not clipped, so the booking itself has to
+    keep every chunk's wait inside its interval: over synthetic series with
+    boundaries the blocked time is at most the sum of the intervals, per
+    chunk and per record."""
+    from handyrl_tpu import telemetry
+    monitor = telemetry.ChunkMonitor()
+    t, blocks = 50.0, []
+    for n, (wait, boundary) in enumerate(series * 3, 1):
+        (open_span, closed_span), t = _fake_iteration(t, wait, boundary)
+        monitor.fetched(n, open_span)
+        for interval, split in monitor._chunks[-1:]:
+            assert 0 <= split['wait'] <= interval
+            assert sum(split.values()) <= interval + 1e-9
+        if boundary:       # the loop cuts the record inside the boundary
+            blocks.append(monitor.epoch_block())
+        monitor.closed(closed_span)
+    blocks.append(monitor.epoch_block())
+    shares = [b['utilization'] for b in blocks if b['chunks']]
+    assert shares and all(0.9 < share <= 1.0 for share in shares)
+    assert sum(b['chunks'] for b in blocks) == 3 * len(series) - 1
+
+
+@pytest.mark.timeout(600)
+def test_retrace_sentinel_waits_for_the_first_train_dispatch(tmp_path,
+                                                             monkeypatch):
+    """One chunk can return ``minimum_episodes + update_episodes`` games, so
+    the first epoch boundary falls in an iteration whose dispatch was still
+    a warm-up one: the training program has not compiled yet, and a sentinel
+    armed there (``model_epoch >= retrace_warmup_epochs``) would count, or
+    under ``abort`` refuse, that first compile."""
+    import time
+
+    from handyrl_tpu import telemetry
+    armed = []
+    mark = telemetry.mark_steady_state
+
+    def remember(note=''):
+        armed.append(learner._fused_trained)
+        return mark(note)
+    monkeypatch.setattr(telemetry, 'mark_steady_state', remember)
+    monkeypatch.delenv('HANDYRL_TPU_RETRACE', raising=False)
+    t_start = time.perf_counter()
+    learner = Learner(args=apply_defaults(_ttt_raw(
+        tmp_path, minimum_episodes=12, update_episodes=8, epochs=3,
+        telemetry={'retrace': 'abort'})))
+    try:
+        learner.run()
+    finally:
+        telemetry.configure_perf_plane(True, 'warn')
+    recs = telemetry.spans(since=t_start)
+    iters = {r['span_id']: r for r in recs if r['name'] == 'fused_iter'}
+    first = [r for r in recs if r['name'] == 'epoch_boundary'][0]
+    assert iters[first['parent_id']]['attrs']['warm'] == 1
+    assert armed == [True]            # armed once, after a train dispatch
+    assert not learner.trainer.failed and learner.model_epoch == 3

@@ -112,15 +112,23 @@ def test_disabled_registry_is_inert(monkeypatch):
 
 
 def test_span_records_stage_histogram():
-    reg = MetricRegistry()
-    with reg.span('select'):
+    hist = telemetry.REGISTRY.histogram('stage_seconds', stage='unit_select')
+    before = hist.count, hist.sum
+    with telemetry.trace_span('unit_select'):
         time.sleep(0.01)
-    with reg.span('decode', parent='select'):
-        pass
-    snap = reg.snapshot()
-    h = snap['hists']['stage_seconds{stage="select"}']
-    assert h['count'] == 1 and h['sum'] >= 0.01
-    assert 'stage_seconds{parent="select",stage="decode"}' in snap['hists']
+    assert hist.count == before[0] + 1 and hist.sum >= before[1] + 0.01
+    assert 'stage_seconds{stage="unit_select"}' in \
+        telemetry.snapshot()['hists']
+
+
+def test_span_records_its_parent():
+    with telemetry.trace_span('unit_select'):
+        with telemetry.trace_span('unit_decode'):
+            pass
+    decode = telemetry.spans(name='unit_decode')[-1]
+    select = telemetry.spans(name='unit_select')[-1]
+    assert decode['parent_id'] == select['span_id']
+    assert select['parent_id'] is None
 
 
 def test_stage_timer_mirrors_into_registry():

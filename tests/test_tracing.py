@@ -103,6 +103,122 @@ def test_trace_span_records_stage_histogram_and_event(trace_dir):
     assert ev[0]['dur'] >= 10000          # microseconds
     assert ev[0]['args']['trace_id'] == 'g3'
     assert ev[0]['args']['run_id'] == telemetry.run_id()
+    # the trace file carries the ring's ids
+    rec = telemetry.spans(name='unit_span')[-1]
+    assert ev[0]['args']['span_id'] == rec['span_id']
+    assert ev[0]['args']['parent_id'] is None
+
+
+# ---------------------------------------------------------------------------
+# the span primitive: ring, parents, attributes, profiler annotations
+
+
+def _named(records, name):
+    return [r for r in records if r['name'] == name]
+
+
+def test_span_parent_ids_nest_and_unwind_on_exceptions():
+    t_start = time.perf_counter()
+    with telemetry.trace_span('unit_outer') as outer:
+        with telemetry.trace_span('unit_inner'):
+            pass
+        with pytest.raises(RuntimeError):
+            with telemetry.trace_span('unit_raises'):
+                with telemetry.trace_span('unit_deep'):
+                    raise RuntimeError('boom')
+        # the stack unwound: the next child hangs off the outer span again
+        with telemetry.trace_span('unit_after'):
+            pass
+    with telemetry.trace_span('unit_root'):
+        pass
+    recs = telemetry.spans(since=t_start)
+    by_name = {r['name']: r for r in recs}
+    outer_id = by_name['unit_outer']['span_id']
+    assert by_name['unit_outer']['parent_id'] is None
+    assert by_name['unit_root']['parent_id'] is None
+    for child in ('unit_inner', 'unit_raises', 'unit_after'):
+        assert by_name[child]['parent_id'] == outer_id
+    assert by_name['unit_deep']['parent_id'] == \
+        by_name['unit_raises']['span_id']
+    # finished spans, oldest first, with ends inside their parent
+    assert [r['name'] for r in recs] == [
+        'unit_inner', 'unit_deep', 'unit_raises', 'unit_after',
+        'unit_outer', 'unit_root']
+    assert by_name['unit_outer']['t0'] <= by_name['unit_inner']['t0'] \
+        <= by_name['unit_inner']['t1'] <= by_name['unit_outer']['t1']
+    # a span holds its finished direct children (the fused loop's per-chunk
+    # split reads them)
+    assert [c.name for c in outer.children] == [
+        'unit_inner', 'unit_raises', 'unit_after']
+    assert 0 < outer.child_seconds('unit_raises') <= outer.seconds
+    assert outer.child_seconds('unit_deep') == 0.0       # a grandchild
+
+
+def test_span_attributes_given_at_open_or_set_before_close_are_kept():
+    with telemetry.trace_span('unit_attrs', rows=3) as span:
+        span.set(plies=2048, note='after the work')
+    rec = _named(telemetry.spans(), 'unit_attrs')[-1]
+    assert rec['attrs'] == {'rows': 3, 'plies': 2048,
+                            'note': 'after the work'}
+
+
+def test_span_ring_is_bounded_and_since_filters():
+    for _ in range(3):
+        with telemetry.trace_span('unit_ring'):
+            pass
+    t_mid = time.perf_counter()
+    with telemetry.trace_span('unit_ring'):
+        pass
+    assert len(_named(telemetry.spans(since=t_mid), 'unit_ring')) == 1
+    assert len(telemetry.spans(name='unit_ring')) >= 4
+    assert telemetry.spans(name='unit_ring', since=time.perf_counter()) == []
+    for _ in range(telemetry.SPAN_RING_SIZE + 10):
+        with telemetry.trace_span('unit_flood'):
+            pass
+    held = telemetry.spans()
+    assert len(held) == telemetry.SPAN_RING_SIZE
+    assert {r['name'] for r in held} == {'unit_flood'}   # oldest fell out
+
+
+def test_span_with_telemetry_off_records_nothing(monkeypatch):
+    monkeypatch.setattr(telemetry, '_ENABLED', False)
+    before = len(telemetry.spans())
+    hist = telemetry.REGISTRY.histogram('stage_seconds', stage='unit_off')
+    with telemetry.trace_span('unit_off', rows=1) as span:
+        span.set(more=2)
+        with telemetry.trace_span('unit_off'):
+            pass
+    assert span.t1 is None and span.children == ()
+    assert len(telemetry.spans()) == before
+    assert telemetry.spans(name='unit_off') == []
+    assert hist.count == 0
+
+
+def test_span_is_a_profiler_annotation_on_the_host_plane(tmp_path):
+    """Inside any jax profiler session the program's spans are host events
+    named ``handyrl:<name>`` on the trace's own timeline."""
+    import jax
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.trace_span('unit_profiled', step_num=7):
+            with telemetry.trace_span('unit_profiled_child'):
+                time.sleep(0.002)
+    found = glob.glob(os.path.join(str(tmp_path), '**', '*.xplane.pb'),
+                      recursive=True)
+    assert found
+    events = {}
+    for plane in ProfileData.from_file(found[0]).planes:
+        if plane.name != '/host:CPU':
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(telemetry.SPAN_ANNOTATION_PREFIX):
+                    events[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert set(events) == {'handyrl:unit_profiled',
+                           'handyrl:unit_profiled_child'}
+    start, dur = events['handyrl:unit_profiled']
+    c_start, c_dur = events['handyrl:unit_profiled_child']
+    assert c_dur >= 2e6 and start <= c_start and c_start + c_dur <= start + dur
 
 
 # ---------------------------------------------------------------------------
