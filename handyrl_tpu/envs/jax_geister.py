@@ -28,6 +28,10 @@ N_MOVE = 4 * BOARD          # 144
 N_SET = 70
 N_ACTIONS = N_MOVE + N_SET  # 214
 MAX_PLIES = 200
+# the longest game in env steps (what a rollout records): the two set-up
+# plies come before the MAX_PLIES moves of a drawn game. Buffers that hold a
+# whole game are sized from this (ops/device_windows.py)
+MAX_STEPS = MAX_PLIES + 2
 SIMULTANEOUS = False
 # the host env hides piece colors behind its own rng (secret setup); device
 # records cannot replay through the host sampling contract byte-identically

@@ -5,18 +5,32 @@ ingest decompress -> build_window -> ring push) rebuilds every episode in
 Python: ~chunk_steps x n_envs dict constructions per dispatch. On a single
 host core that, not the accelerator, bounds the fully-device pipeline.
 
-This module closes the loop in HBM. A per-env episode history lives on
-device as fixed (N, L, ...) buffers; one jitted program consumes a rollout
-chunk ply by ply (lax.scan), and wherever an episode terminates it
+This module closes the loop in HBM, and its cost follows what it stores. A
+per-lane episode history lives on device as a CIRCULAR buffer in the ring's
+own row layout (one flat row a ply and leaf; the solo observation one row a
+ply and seat; DeviceWindower.init_state); one program consumes a rollout
+chunk:
 
-  * draws ``clip(steps // forward_steps, 1, W)`` random training windows
-    (the host ingestion rate, train.py _ingest_new_episodes),
-  * materializes them with the EXACT pad/mask semantics of
-    ops/batch.py build_window (reference train.py:33-124): prob pad 1,
-    action_mask pad +1e32, value tail = final outcome, progress pad 1,
-    episode/turn/observation masks,
-  * and scatters them into the DeviceReplay ring with prefix-sum slot
-    compaction (invalid lanes dropped via out-of-range scatter indices).
+  * the chunk's K plies of every lane go into the history as one block a
+    leaf;
+  * game lengths, the windows due ``clip(steps // forward_steps, 1, W)``
+    (the host ingestion rate, train.py _ingest_new_episodes) and the random
+    train starts and seats are computed for the whole (K, N) chunk at once,
+    from one key pair a ply;
+  * then ONE LOOP builds the windows of the games that ended in the chunk,
+    one window an iteration, in slot order (ply, lane, window): it gathers
+    the T history rows of that lane (and seat), applies the EXACT pad/mask
+    semantics of ops/batch.py build_window (reference train.py:33-124):
+    prob pad 1, action_mask pad +1e32, value tail = final outcome, progress
+    pad 1, episode/turn/observation masks, and writes one row of each ring
+    leaf in place. Its trip count is the number of windows stored: no lane
+    whose game goes on is read, nothing of size lanes x windows or of the
+    history's size is gathered, copied or scattered, and there is no cap on
+    how many games may end on a ply.
+
+The ring is bit-identical to what the all-lane builder left (every lane's W
+windows on each ply on which any lane ended a game, the finished lanes' kept
+by a drop-scatter), which is the parity oracle in tests/windower_oracle.py.
 
 The host sees only (episodes_done, outcome) scalars per chunk. Two layouts
 are supported, mirroring build_window's two player-axis regimes:
@@ -35,11 +49,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-def _take(hist_leaf, idxm):
-    """hist_leaf (L, ...) gathered at idxm (T,) -> (T, ...)."""
-    return hist_leaf[idxm]
 
 
 def flatten_window_keys(win: Dict[str, Any]) -> Dict[str, Any]:
@@ -83,62 +92,120 @@ def unflatten_window_keys(win: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+def _mask_rows(x, fill, cond):
+    """x (T, ...) with rows where ``cond`` (T,) is false replaced by fill."""
+    return jnp.where(cond.reshape((-1,) + (1,) * (x.ndim - 1)), x, fill)
+
+
+def _window_solo(take, S, ts_w, seat_w, outcome, fs: int, bi: int, L: int,
+                 has_reward: bool):
+    """ONE solo-layout window. ``take(key, idxm)`` returns the EVALUATED
+    SEAT's values of that history leaf at game plies idxm (T,): (T, ...),
+    whatever the storage (the observation may stay flat: only its leading
+    axis is used here)."""
+    T = bi + fs
+    m = ts_w - bi + jnp.arange(T)                    # (T,)
+    in_ep = (m >= 0) & (m < S)
+    idxm = jnp.clip(m, 0, L - 1)
+    valid = in_ep & take('acting', idxm)
+    tail = (m >= S)
+
+    obs = jax.tree_util.tree_map(              # obs may be a pytree
+        lambda x: _mask_rows(x[:, None], 0.0, valid), take('obs', idxm))
+    prob = jnp.where(valid, take('prob', idxm), 1.0)
+    act = jnp.where(valid, take('action', idxm), 0)
+    amask = _mask_rows(take('amask', idxm)[:, None], 1e32, valid)
+    val = jnp.where(valid, take('value', idxm)[:, 0],
+                    jnp.where(tail, outcome[seat_w], 0.0))
+    if has_reward:
+        rew = jnp.where(in_ep, take('reward', idxm), 0.0)
+        ret = jnp.where(in_ep, take('return', idxm), 0.0)
+    else:
+        rew = jnp.zeros((T,), jnp.float32)
+        ret = jnp.zeros((T,), jnp.float32)
+    progress = jnp.where(in_ep, m.astype(jnp.float32) / S, 1.0)
+    f32 = jnp.float32
+    return {
+        'observation': obs,
+        'selected_prob': prob.astype(f32)[:, None, None],
+        'action': act.astype(jnp.int32)[:, None, None],
+        'action_mask': amask.astype(f32),
+        'value': val.astype(f32)[:, None, None],
+        'reward': rew.astype(f32)[:, None, None],
+        'return': ret.astype(f32)[:, None, None],
+        'outcome': outcome[seat_w].astype(f32).reshape(1, 1, 1),
+        'episode_mask': in_ep.astype(f32)[:, None, None],
+        'turn_mask': valid.astype(f32)[:, None, None],
+        'observation_mask': valid.astype(f32)[:, None, None],
+        'progress': progress.astype(f32)[:, None],
+    }
+
+
+def _window_turn(take, S, ts_w, outcome, fs: int, bi: int, L: int,
+                 num_players: int, has_reward: bool):
+    """ONE turn-layout window. ``take(key, idxm)`` returns that history
+    leaf (the turn player's data; reward/return for all P) at game plies
+    idxm (T,): (T, ...). Mask/value leaves span all P players, data leaves
+    P axis 1."""
+    T = bi + fs
+    P = num_players
+    m = ts_w - bi + jnp.arange(T)
+    in_ep = (m >= 0) & (m < S)
+    idxm = jnp.clip(m, 0, L - 1)
+    player = take('player', idxm)                    # (T,)
+    tail = (m >= S)
+
+    obs = jax.tree_util.tree_map(              # obs may be a pytree
+        lambda x: _mask_rows(x[:, None], 0.0, in_ep), take('obs', idxm))
+    prob = jnp.where(in_ep, take('prob', idxm), 1.0)
+    act = jnp.where(in_ep, take('action', idxm), 0)
+    amask = _mask_rows(take('amask', idxm)[:, None], 1e32, in_ep)
+    # (T, P) per-player masks: the turn player acted and observed
+    is_turn = (player[:, None] == jnp.arange(P)[None, :]) & in_ep[:, None]
+    val_turn = take('value', idxm)[:, 0]             # (T,)
+    val = jnp.where(is_turn, val_turn[:, None],
+                    jnp.where(tail[:, None], outcome[None, :], 0.0))
+    if has_reward:
+        rew = jnp.where(in_ep[:, None], take('reward', idxm), 0.0)  # (T, P)
+        ret = jnp.where(in_ep[:, None], take('return', idxm), 0.0)
+    else:
+        rew = jnp.zeros((T, P), jnp.float32)
+        ret = jnp.zeros((T, P), jnp.float32)
+    progress = jnp.where(in_ep, m.astype(jnp.float32) / S, 1.0)
+    f32 = jnp.float32
+    return {
+        'observation': obs,
+        'selected_prob': prob.astype(f32)[:, None, None],
+        'action': act.astype(jnp.int32)[:, None, None],
+        'action_mask': amask.astype(f32),
+        'value': val.astype(f32)[:, :, None],
+        'reward': rew.astype(f32)[:, :, None],
+        'return': ret.astype(f32)[:, :, None],
+        'outcome': outcome.astype(f32).reshape(1, P, 1),
+        'episode_mask': in_ep.astype(f32)[:, None, None],
+        'turn_mask': is_turn.astype(f32)[:, :, None],
+        'observation_mask': is_turn.astype(f32)[:, :, None],
+        'progress': progress.astype(f32)[:, None],
+    }
+
+
 def build_windows_solo(hist: Dict[str, Any], S, ts, seat, outcome,
                        fs: int, bi: int, L: int):
-    """Windows for ONE env in solo layout.
+    """Windows for ONE env in solo layout, from a game-ordered history.
 
     hist leaves are (L, P, ...); S scalar episode length; ts (W,) train
     starts; seat (W,) evaluated seats; outcome (P,). Returns a window dict
-    with leading axis W.
+    with leading axis W. (The shape oracle of ``init_ring`` and the host
+    parity tests; the ingest reads its flat history through the same
+    ``_window_solo``.)
     """
-    T = bi + fs
-
     def one(ts_w, seat_w):
-        m = ts_w - bi + jnp.arange(T)                    # (T,)
-        in_ep = (m >= 0) & (m < S)
-        idxm = jnp.clip(m, 0, L - 1)
-        acting = _take(hist['acting'], idxm)[:, seat_w]  # (T,)
-        valid = in_ep & acting
-        tail = (m >= S)
+        take = lambda key, idxm: jax.tree_util.tree_map(
+            lambda x: x[idxm][:, seat_w], hist[key])
+        return flatten_window_keys(_window_solo(
+            take, S, ts_w, seat_w, outcome, fs, bi, L, 'reward' in hist))
 
-        def vmask(x, fill, cond):
-            c = cond.reshape((-1,) + (1,) * (x.ndim - 1))
-            return jnp.where(c, x, fill)
-
-        obs = jax.tree_util.tree_map(          # obs may be a pytree
-            lambda x: vmask(_take(x, idxm)[:, seat_w][:, None], 0.0, valid),
-            hist['obs'])                                            # (T,1,...)
-        prob = jnp.where(valid, _take(hist['prob'], idxm)[:, seat_w], 1.0)
-        act = jnp.where(valid, _take(hist['action'], idxm)[:, seat_w], 0)
-        amask = vmask(_take(hist['amask'], idxm)[:, seat_w][:, None],
-                      1e32, valid)
-        val = _take(hist['value'], idxm)[:, seat_w, 0]
-        val = jnp.where(valid, val,
-                        jnp.where(tail, outcome[seat_w], 0.0))
-        if 'reward' in hist:
-            rew = jnp.where(in_ep, _take(hist['reward'], idxm)[:, seat_w], 0.0)
-            ret = jnp.where(in_ep, _take(hist['return'], idxm)[:, seat_w], 0.0)
-        else:
-            rew = jnp.zeros((T,), jnp.float32)
-            ret = jnp.zeros((T,), jnp.float32)
-        progress = jnp.where(in_ep, m.astype(jnp.float32) / S, 1.0)
-        f32 = jnp.float32
-        return {
-            'observation': obs,
-            'selected_prob': prob.astype(f32)[:, None, None],
-            'action': act.astype(jnp.int32)[:, None, None],
-            'action_mask': amask.astype(f32),
-            'value': val.astype(f32)[:, None, None],
-            'reward': rew.astype(f32)[:, None, None],
-            'return': ret.astype(f32)[:, None, None],
-            'outcome': outcome[seat_w].astype(f32).reshape(1, 1, 1),
-            'episode_mask': in_ep.astype(f32)[:, None, None],
-            'turn_mask': valid.astype(f32)[:, None, None],
-            'observation_mask': valid.astype(f32)[:, None, None],
-            'progress': progress.astype(f32)[:, None],
-        }
-
-    return jax.vmap(lambda t, s: flatten_window_keys(one(t, s)))(ts, seat)
+    return jax.vmap(one)(ts, seat)
 
 
 def build_windows_turn(hist: Dict[str, Any], S, ts, outcome,
@@ -149,58 +216,14 @@ def build_windows_turn(hist: Dict[str, Any], S, ts, outcome,
     hist['player'] (L,); outcome (P,). Returns a window dict with leading
     axis W; mask/value leaves span all P players, data leaves P axis 1.
     """
-    T = bi + fs
-    P = num_players
-
     def one(ts_w):
-        m = ts_w - bi + jnp.arange(T)
-        in_ep = (m >= 0) & (m < S)
-        idxm = jnp.clip(m, 0, L - 1)
-        player = _take(hist['player'], idxm)             # (T,)
-        tail = (m >= S)
+        take = lambda key, idxm: jax.tree_util.tree_map(
+            lambda x: x[idxm], hist[key])
+        return flatten_window_keys(_window_turn(
+            take, S, ts_w, outcome, fs, bi, L, num_players,
+            'reward' in hist))
 
-        def vmask(x, fill, cond):
-            c = cond.reshape((-1,) + (1,) * (x.ndim - 1))
-            return jnp.where(c, x, fill)
-
-        obs = jax.tree_util.tree_map(          # obs may be a pytree
-            lambda x: vmask(_take(x, idxm)[:, None], 0.0, in_ep),
-            hist['obs'])
-        prob = jnp.where(in_ep, _take(hist['prob'], idxm), 1.0)
-        act = jnp.where(in_ep, _take(hist['action'], idxm), 0)
-        amask = vmask(_take(hist['amask'], idxm)[:, None], 1e32, in_ep)
-        # (T, P) per-player masks: the turn player acted and observed
-        is_turn = (player[:, None] == jnp.arange(P)[None, :]) \
-            & in_ep[:, None]
-        val_turn = _take(hist['value'], idxm)[:, 0]       # (T,)
-        val = jnp.where(is_turn, val_turn[:, None],
-                        jnp.where(tail[:, None], outcome[None, :], 0.0))
-        if 'reward' in hist:
-            rew = jnp.where(in_ep[:, None],
-                            _take(hist['reward'], idxm), 0.0)   # (T, P)
-            ret = jnp.where(in_ep[:, None],
-                            _take(hist['return'], idxm), 0.0)
-        else:
-            rew = jnp.zeros((T, P), jnp.float32)
-            ret = jnp.zeros((T, P), jnp.float32)
-        progress = jnp.where(in_ep, m.astype(jnp.float32) / S, 1.0)
-        f32 = jnp.float32
-        return {
-            'observation': obs,
-            'selected_prob': prob.astype(f32)[:, None, None],
-            'action': act.astype(jnp.int32)[:, None, None],
-            'action_mask': amask.astype(f32),
-            'value': val.astype(f32)[:, :, None],
-            'reward': rew.astype(f32)[:, :, None],
-            'return': ret.astype(f32)[:, :, None],
-            'outcome': outcome.astype(f32).reshape(1, P, 1),
-            'episode_mask': in_ep.astype(f32)[:, None, None],
-            'turn_mask': is_turn.astype(f32)[:, :, None],
-            'observation_mask': is_turn.astype(f32)[:, :, None],
-            'progress': progress.astype(f32)[:, None],
-        }
-
-    return jax.vmap(lambda t: flatten_window_keys(one(t)))(ts)
+    return jax.vmap(one)(ts)
 
 
 def _discounted_returns(rewards, valid, gamma: float):
@@ -220,13 +243,47 @@ def _discounted_returns(rewards, valid, gamma: float):
     return rev(rets)
 
 
+def _ply_width(leaf, rows: int) -> int:
+    """Values in one history row of a records leaf (K, N, ...) that takes
+    ``rows`` rows a ply."""
+    return int(np.prod(leaf.shape[2:])) // rows
+
+
+def _row_width(leaf, rows: int) -> int:
+    """Stored width of that row: a wide row is padded to whole 128-lane
+    tiles. With no padding in it, (lane, row, value) order is the layout
+    the device gives the buffer by default, which is the one a row gather
+    reads; a 1,309-wide observation row would get the row axis minor
+    instead (least padding), and a relayout of the whole history on every
+    chunk."""
+    flat = _ply_width(leaf, rows)
+    return -(-flat // 128) * 128 if flat > 128 else flat
+
+
+def _first_true(mask):
+    """Flat indices of the true entries of ``mask``, in increasing order,
+    then the others: (mask.size,) int32. A stable sort: ``jnp.nonzero`` with
+    a static size lowers to a scatter-add over every entry. Keep ``mask``
+    to the (K, N) chunk: the v5e's compiler takes 14 s over a sort of
+    24,576 flags and 0.2 s over one of 2,048."""
+    return jnp.argsort(jnp.logical_not(mask.reshape(-1)),
+                       stable=True).astype(jnp.int32)
+
+
 class DeviceWindower:
     """Owns the per-env episode history and the chunk-ingest program.
 
     ``ingest(records, state, ring, cursor, size, rng)`` consumes one rollout
-    chunk and returns updated (state, ring, cursor, size, rng, n_done).
-    The ring/state/cursor/size live as device arrays owned by the caller
-    (single-owner: the trainer thread), so buffers are donated in place.
+    chunk and returns updated (state, ring, cursor, size, rng, n_done,
+    n_windows). The ring/state/cursor/size live as device arrays owned by
+    the caller (single-owner: the trainer thread), so buffers are donated
+    in place.
+
+    THE CONTRACT: a game is at most ``max_steps`` plies (the env module's
+    MAX_STEPS / MAX_PLIES; train.py asserts that it declares one). The
+    circular history holds ``max_steps`` plies plus the rest of a chunk a
+    lane, so a longer game would overwrite its own first plies before its
+    windows are built.
     """
 
     def __init__(self, mode: str, fs: int, bi: int, max_steps: int,
@@ -245,25 +302,47 @@ class DeviceWindower:
         self._ingest = None   # jitted lazily once ring shapes exist
 
     # -- state/ring allocation --------------------------------------------
-    def init_state(self, records) -> Dict[str, Any]:
-        """Zero history buffers shaped after one rollout chunk's records."""
-        hist = {}
-        for key in self._hist_keys():
-            # records leaf (K, N, ...) -> hist (N, L, ...); 'obs' may be a
-            # pytree (dict observations), so map over leaves
-            hist[key] = jax.tree_util.tree_map(
-                lambda leaf: jnp.zeros(
-                    (leaf.shape[1], self.L) + leaf.shape[2:], leaf.dtype),
-                records[key])
-        return {'hist': hist,
-                'counts': jnp.zeros((records['done'].shape[1],), jnp.int32)}
-
     def _hist_keys(self):
         keys = ['obs', 'action', 'prob', 'amask', 'value']
         keys.append('acting' if self.mode == 'solo' else 'player')
         if self.has_reward:
             keys.append('reward')
         return keys
+
+    def _rows_per_ply(self, key):
+        """History rows one ply takes in that leaf: the solo observation
+        keeps one row PER SEAT, so a window gathers its seat's rows and
+        never touches the other seats'; every other leaf is one row."""
+        return self.P if (self.mode == 'solo' and key == 'obs') else 1
+
+    def init_state(self, records) -> Dict[str, Any]:
+        """Zero history shaped after one rollout chunk's records.
+
+        The history is a CIRCULAR buffer of C plies a lane, written a
+        whole chunk at a time at ``head``, in the ring's own row layout:
+        records leaf (K, N, ...) -> (N, C * rows, width) with the ply's
+        values flattened into the minor axis (natural (..., 7, 11) minor
+        dimensions are tiled to (8, 128) on the TPU; see init_ring) and a
+        wide row padded to whole tiles (_row_width). C is
+        the smallest multiple of K that holds a longest game (L plies)
+        plus the rest of the chunk it ended in, so that at the chunk's end
+        every game that ended inside it is still whole, and a chunk never
+        wraps. Every lane advances one ply a chunk step, so ``head`` is one
+        number; it is stored per lane only so that the whole state splits
+        along the lane axis on a mesh."""
+        K, N = records['done'].shape
+        C = K * -(-(self.L + K - 1) // K)
+        hist = {}
+        for key in self._hist_keys():
+            rows = self._rows_per_ply(key)
+            # 'obs' may be a pytree (dict observations): map over leaves
+            hist[key] = jax.tree_util.tree_map(
+                lambda leaf: jnp.zeros(
+                    (N, C * rows, _row_width(leaf, rows)), leaf.dtype),
+                records[key])
+        return {'hist': hist,
+                'counts': jnp.zeros((N,), jnp.int32),
+                'head': jnp.zeros((N,), jnp.int32)}
 
     def init_ring(self, records) -> Dict[str, Any]:
         """Zero ring buffers, shaped via eval_shape — NOTHING runs on
@@ -325,98 +404,130 @@ class DeviceWindower:
         """The pure (un-jitted) chunk-ingest function — used by the jitted
         standalone path above and inlined into the fused
         generate+ingest+train program (ops/fused_pipeline.py)."""
-        return self._build_ingest()
-
-    def _build_ingest(self):
         fs, bi, L, W, cap = self.fs, self.bi, self.L, self.W, self.capacity
-        P, gamma, mode = self.P, self.gamma, self.mode
+        P, gamma, solo = self.P, self.gamma, self.mode == 'solo'
         has_reward = self.has_reward
-        hist_record_keys = [k for k in self._hist_keys() if k != 'return']
-
-        def ply(carry, rec):
-            hist, counts, ring, cursor, size, rng = carry
-            hist = dict(hist)   # never mutate the traced carry structure
-            N = counts.shape[0]
-            rows = jnp.arange(N)
-            idx = jnp.clip(counts, 0, L - 1)
-
-            for key in hist_record_keys:
-                hist[key] = jax.tree_util.tree_map(
-                    lambda h, r: h.at[rows, idx].set(r),
-                    hist[key], rec[key])
-            counts = counts + 1
-            done = rec['done']                       # (N,) bool
-            S = counts                               # (N,) episode lengths
-            rng, k_ts, k_seat = jax.random.split(rng, 3)
-            outcome = rec['outcome']                 # (N, P)
-
-            def finalize(_):
-                """Returns recompute + window build + ring scatter — only
-                reached on plies where some episode actually ended (most
-                plies skip all of this via the cond below)."""
-                win_hist = dict(hist)
-                if has_reward:
-                    valid = (jnp.arange(L)[None, :] < S[:, None])  # (N, L)
-                    win_hist['return'] = jax.vmap(
-                        _discounted_returns, in_axes=(0, 0, None))(
-                            hist['reward'], valid, gamma)
-
-                # windows per finished episode: the host ingestion rate
-                wcount = jnp.clip(S // fs, 1, W)     # (N,)
-                span = jnp.maximum(S - fs, 0) + 1    # train_start in [0, span)
-                u = jax.random.uniform(k_ts, (N, W))
-                ts = jnp.minimum((u * span[:, None]).astype(jnp.int32),
-                                 span[:, None] - 1)
-
-                if mode == 'solo':
-                    seat = jax.random.randint(k_seat, (N, W), 0, P)
-                    windows = jax.vmap(
-                        build_windows_solo,
-                        in_axes=(0, 0, 0, 0, 0, None, None, None))(
-                            win_hist, S, ts, seat, outcome, fs, bi, L)
-                else:
-                    windows = jax.vmap(
-                        build_windows_turn,
-                        in_axes=(0, 0, 0, 0, None, None, None, None))(
-                            win_hist, S, ts, outcome, fs, bi, L, P)
-
-                # ring slots with prefix-sum compaction over done envs
-                dcount = jnp.where(done, wcount, 0)  # (N,)
-                base = cursor + jnp.cumsum(dcount) - dcount
-                w_ix = jnp.arange(W)[None, :]
-                slot = (base[:, None] + w_ix) % cap
-                valid_w = done[:, None] & (w_ix < wcount[:, None])
-                slot = jnp.where(valid_w, slot, cap)  # cap = dropped
-                flat_slot = slot.reshape(-1)
-
-                def scatter(rb, wb):
-                    # ring rows are flat (see init_ring): (N, W, ...) ->
-                    # (N*W, prod(window shape))
-                    return rb.at[flat_slot].set(
-                        wb.reshape((wb.shape[0] * wb.shape[1], -1)),
-                        mode='drop')
-
-                return (jax.tree_util.tree_map(scatter, ring, windows),
-                        jnp.sum(dcount))
-
-            ring, n_new = jax.lax.cond(
-                jnp.any(done), finalize,
-                lambda _: (ring, jnp.int32(0)), None)
-            cursor = (cursor + n_new) % cap
-            size = jnp.minimum(size + n_new, cap)
-            counts = jnp.where(done, 0, counts)
-            return ((hist, counts, ring, cursor, size, rng),
-                    (jnp.sum(done), n_new))
+        T = bi + fs
+        hist_keys = self._hist_keys()
 
         def ingest(records, state, ring, cursor, size, rng):
-            rec_scan = {k: records[k] for k in hist_record_keys}
-            rec_scan['done'] = records['done']
-            rec_scan['outcome'] = records['outcome']
-            ((hist, counts, ring, cursor, size, rng),
-             (dones, wins)) = jax.lax.scan(
-                ply, (state['hist'], state['counts'], ring, cursor, size,
-                      rng), rec_scan)
-            return ({'hist': hist, 'counts': counts}, ring, cursor, size,
-                    rng, jnp.sum(dones), jnp.sum(wins))
+            done = records['done']                   # (K, N) bool
+            K, N = done.shape
+            C = state['hist']['prob'].shape[1]
+            assert C % K == 0 and C >= L + K - 1, \
+                'history of %d plies a lane was not made for chunks of %d' \
+                % (C, K)
+            head = state['head'][0]
+
+            # 1. the chunk's K plies of every lane, one block a leaf. The
+            # plies are flattened into rows one at a time: a scan over the
+            # ply axis keeps that axis major in the records; flattened in
+            # one piece the compiler makes it minor, and the rollout's
+            # per-ply writes into the records cost 5 ms a chunk more (v5e)
+            tree_map = jax.tree_util.tree_map
+            stored = state['hist']           # exactly the hist_keys leaves
+
+            def as_rows(h, rec):         # one ply -> (N, rows a ply, width)
+                x = rec.reshape((N, h.shape[1] // C, -1))
+                return jnp.pad(x, ((0, 0), (0, 0),
+                                   (0, h.shape[2] - x.shape[2])))
+
+            def store(h, rows):          # (K, N, rows a ply, width)
+                return jax.lax.dynamic_update_slice(
+                    h, jnp.swapaxes(rows, 0, 1).reshape((N, -1, h.shape[2])),
+                    (0, head * (h.shape[1] // C), 0))
+            hist = tree_map(store, stored, jax.lax.map(
+                lambda ply: tree_map(as_rows, stored, ply),
+                {key: records[key] for key in hist_keys}))
+
+            # 2. every (ply, lane)'s game length so far: the plies since
+            # the lane's last end in this chunk, or since before it
+            step = jnp.arange(1, K + 1, dtype=jnp.int32)[:, None]
+            ended = jax.lax.cummax(jnp.where(done, step, 0), axis=0)
+            ended = jnp.concatenate(
+                [jnp.zeros((1, N), jnp.int32), ended[:-1]])
+            S = step - ended + jnp.where(ended == 0, state['counts'], 0)
+            first = head + step - S          # the game's first ply (mod C)
+
+            # 3. the host ingestion rate and the random train starts and
+            # seats, from one key pair a ply (finished lanes use theirs)
+            def ply_keys(key, _):
+                key, k_ts, k_seat = jax.random.split(key, 3)
+                return key, (k_ts, k_seat)
+            rng, (k_ts, k_seat) = jax.lax.scan(ply_keys, rng, None, length=K)
+            wcount = jnp.clip(S // fs, 1, W)         # (K, N)
+            span = jnp.maximum(S - fs, 0) + 1        # train_start in [0, span)
+            u = jax.vmap(lambda k: jax.random.uniform(k, (N, W)))(k_ts)
+            ts = jnp.minimum((u * span[..., None]).astype(jnp.int32),
+                             span[..., None] - 1)    # (K, N, W)
+            if solo:
+                seat = jax.vmap(
+                    lambda k: jax.random.randint(k, (N, W), 0, P))(k_seat)
+
+            def rows_of(key, n, at):
+                """That lane's history rows ``at``, less their padding."""
+                rows = self._rows_per_ply(key)
+                return tree_map(
+                    lambda h, rec: h[n, at][:, :_ply_width(rec, rows)],
+                    hist[key], records['reward' if key == 'return' else key])
+
+            # 4. the games that ended, in slot order (ply, then lane), and
+            # with rewards their discounted returns, game by game
+            games = _first_true(done)
+            if has_reward:
+                def returns_of(i, returns):
+                    k, n = games[i] // N, games[i] % N
+                    at = (first[k, n] + jnp.arange(L)) % C
+                    valid = jnp.arange(L) < S[k, n]
+                    ret = _discounted_returns(
+                        rows_of('reward', n, at), valid, gamma)
+                    return returns.at[n, jnp.where(valid, at, C)].set(
+                        ret, mode='drop')
+                hist['return'] = jax.lax.fori_loop(
+                    0, jnp.sum(done), returns_of,
+                    jnp.zeros_like(hist['reward']))
+
+            # 5. one window an iteration, game after game and a game's
+            # windows in turn (so in slot order): T history rows of one
+            # lane (and seat) in, one ring row out
+            n_win = jnp.sum(jnp.where(done, wcount, 0))
+
+            def build(j, carry):
+                ring, game, w = carry
+                k, n = games[game] // N, games[game] % N
+
+                def take(key, idxm):
+                    at = (first[k, n] + idxm) % C
+                    if key == 'obs':   # stays flat: rows in, a ring row out
+                        return rows_of(key, n, at * P + seat[k, n, w]
+                                       if solo else at)
+                    ply = records['reward' if key == 'return' else key]
+                    x = rows_of(key, n, at).reshape((T,) + ply.shape[2:])
+                    return x[:, seat[k, n, w]] if solo else x
+
+                if solo:
+                    win = _window_solo(
+                        take, S[k, n], ts[k, n, w], seat[k, n, w],
+                        records['outcome'][k, n], fs, bi, L, has_reward)
+                else:
+                    win = _window_turn(
+                        take, S[k, n], ts[k, n, w],
+                        records['outcome'][k, n], fs, bi, L, P, has_reward)
+                win = flatten_window_keys(win)
+                slot = (cursor + j) % cap
+                ring = {key: jax.lax.dynamic_update_slice(
+                            rb, win[key].reshape((1, -1)), (slot, 0))
+                        for key, rb in ring.items()}
+                last = w + 1 == wcount[k, n]
+                return ring, game + last, jnp.where(last, 0, w + 1)
+            ring, _, _ = jax.lax.fori_loop(
+                0, n_win, build, (ring, jnp.int32(0), jnp.int32(0)))
+
+            state = {'hist': {key: hist[key] for key in hist_keys},
+                     'counts': jnp.where(done[-1], 0, S[-1]),
+                     'head': (state['head'] + K) % C}
+            return (state, ring, (cursor + n_win) % cap,
+                    jnp.minimum(size + n_win, cap), rng,
+                    jnp.sum(done), n_win)
 
         return ingest

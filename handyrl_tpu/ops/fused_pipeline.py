@@ -253,13 +253,11 @@ class FusedPipeline:
         self.windows_ingested_host = 0  # cumulative windows ingested
         # cumulative host counters of the fetched chunks (the ``host_block``
         # span carries them): plies played, plies on which any lane ended a
-        # game (the ingest's window builder ran), the windows it built there
-        # (every lane's, on each shard where a game ended), games ended
+        # game (those that gave the ingest's window builder work), games
+        # ended
         self.plies_host = 0
         self.builder_plies_host = 0
-        self.windows_built_host = 0
         self.episodes_host = 0
-        self._windows_per_lane = windower.W
 
     # -- multi-chip construction -------------------------------------------
     def _shard_loop_state(self, mesh):
@@ -368,13 +366,12 @@ class FusedPipeline:
             self.windows_ingested_host += int(rest[2])
             self.plies_host += K * N
             self.builder_plies_host += int(done.any(axis=1).sum())
-            self.windows_built_host += (
-                int(done.reshape(K, self.ndev, -1).any(axis=2).sum())
-                * (N // self.ndev) * self._windows_per_lane)
             self.episodes_host += int(done.sum())
             span.set(plies=self.plies_host,
                      builder_plies=self.builder_plies_host,
-                     windows_built=self.windows_built_host,
+                     # the builder makes one window a loop iteration,
+                     # for the games that ended, and stores every one
+                     windows_built=self.windows_ingested_host,
                      episodes=self.episodes_host,
                      windows_ingested=self.windows_ingested_host,
                      ring_size=self.ring_size_host,

@@ -1077,6 +1077,22 @@ class Trainer:
         self.batcher.stop()
 
 
+def _declared_max_steps(env_mod) -> int:
+    """The longest game the device env module can play, in plies: its
+    ``MAX_STEPS`` or ``MAX_PLIES``. The device windower sizes its circular
+    episode history from it (ops/device_windows.py DeviceWindower), so a
+    module that declares neither cannot be windowed on device: a guessed
+    bound that a game outlasts would let the game overwrite its own first
+    plies."""
+    max_steps = getattr(env_mod, 'MAX_STEPS',
+                        getattr(env_mod, 'MAX_PLIES', None))
+    assert max_steps is not None, (
+        'device window ingest needs the longest game in plies: %s declares '
+        'neither MAX_STEPS nor MAX_PLIES'
+        % getattr(env_mod, '__name__', env_mod))
+    return int(max_steps)
+
+
 class _EpochCadence:
     """Epoch trigger shared by every generation front-end: an epoch is due
     every ``update_episodes`` returned episodes past the warmup minimum
@@ -2698,8 +2714,7 @@ class Learner:
 
         def build_windower(mode):
             from .ops.device_windows import DeviceWindower
-            max_steps = int(getattr(env_mod, 'MAX_STEPS',
-                                    getattr(env_mod, 'MAX_PLIES', 256)))
+            max_steps = _declared_max_steps(env_mod)
             windows_cap = (args.get('replay_windows_per_episode')
                            or max(1, 64 // args['forward_steps']))
             return DeviceWindower(

@@ -310,3 +310,281 @@ def test_flatten_window_keys_arbitrary_depth_roundtrip():
         flatten_window_keys({'observation': {'bad.key': np.zeros(2)}})
     with pytest.raises(AssertionError, match='not an array'):
         flatten_window_keys({'observation': {'v': [1, 2, 3]}})
+
+
+# -- the event-driven ingest against the builder it replaced ----------------
+# (tests/windower_oracle.py: cond over "any lane ended" + all-lane vmap +
+# drop-scatter, verbatim). Same records, same key -> the same ring, bit for
+# bit, and the same cursor, size, rng and counts.
+
+import functools
+
+import pytest
+
+from windower_oracle import OracleWindower
+
+PK, PN, PL, PFS, PW, PCAP, PA = 8, 4, 20, 2, 3, 20, 3
+
+
+def _ends(*plies_lanes, chunks=1):
+    done = np.zeros((chunks * PK, PN), bool)
+    for ply, lane in plies_lanes:
+        done[ply, lane] = True
+    return done
+
+
+DONE_PATTERNS = {
+    'no_lane_ends': _ends(),
+    'one_lane_ends': _ends((5, 2)),
+    'several_lanes_end_on_one_ply': _ends((3, 0), (3, 2), (6, 1)),
+    'every_lane_ends_on_one_ply': _ends(*[(3, n) for n in range(PN)]),
+    # 32 one-ply games a chunk: every slot of the chunk is an event
+    'every_lane_ends_on_every_ply': _ends(
+        *[(ply, n) for ply in range(PK) for n in range(PN)]),
+    'a_lane_ends_twice': _ends((2, 1), (6, 1), (4, 3)),
+    'a_game_spans_three_chunks': _ends((17, 0), (9, 2), chunks=3),
+    'a_game_of_exactly_L_plies': _ends((PL - 1, 3), (PL + 2, 3), chunks=3),
+    # 16 one-window games a chunk into 20 slots: the second chunk wraps
+    'ring_wraps_inside_a_chunk': _ends(
+        *[(ply, n) for ply in range(1, 2 * PK, 2) for n in range(PN)],
+        chunks=2),
+    # the circular history (32 plies a lane here) wraps under whole games
+    'the_history_wraps_under_a_game': _ends(
+        (14, 1), (34, 1), (45, 1), (10, 0), (25, 0), (41, 0), (12, 2),
+        (31, 2), (13, 3), (32, 3), chunks=6),
+}
+
+
+# the observation a ply: a small array, geister's kind of pytree, and an
+# array wider than 128 values (165 -> history rows padded to 256: the path
+# the benchmark cells' 1,309-value rows take)
+OBSERVATIONS = ['array', 'pytree', 'wide']
+
+
+def _parity_records(rng, mode, has_reward, obs_kind, done, P):
+    K, N = done.shape
+    lead = (K, N, P) if mode == 'solo' else (K, N)
+    f32 = np.float32
+    board = rng.rand(*lead, *((3, 5, 11) if obs_kind == 'wide'
+                              else (2, 2, 2))).astype(f32)
+    records = {
+        'obs': ({'scalar': rng.rand(*lead, 5).astype(f32), 'board': board}
+                if obs_kind == 'pytree' else board),
+        'prob': rng.uniform(0.2, 1, lead).astype(f32),
+        'action': rng.randint(0, PA, lead).astype(np.int32),
+        'amask': np.where(rng.rand(*lead, PA) < 0.3, 1e32, 0).astype(f32),
+        'value': rng.uniform(-1, 1, lead + (1,)).astype(f32),
+        'done': done,
+        'outcome': rng.uniform(-1, 1, (K, N, P)).astype(f32),
+    }
+    if mode == 'solo':
+        acting = rng.rand(*lead) < 0.7
+        acting[..., 0] = True
+        records['acting'] = acting
+    else:
+        records['player'] = rng.randint(0, P, lead).astype(np.int32)
+    if has_reward:
+        records['reward'] = rng.uniform(-0.1, 0.1, (K, N, P)).astype(f32)
+    return records
+
+
+@functools.lru_cache(maxsize=None)
+def _windower_pair(mode, has_reward, bi):
+    """One compiled ingest a side and configuration: the done patterns and
+    the observation's structure only change the data (jit caches by shape)."""
+    make = lambda cls: cls(
+        mode=mode, fs=PFS, bi=bi, max_steps=PL, windows_cap=PW,
+        capacity=PCAP, num_players=3 if mode == 'solo' else 2, gamma=GAMMA,
+        has_reward=has_reward)
+    return make(OracleWindower), make(DeviceWindower)
+
+
+@pytest.mark.parametrize('pattern', sorted(DONE_PATTERNS))
+@pytest.mark.parametrize('bi', [0, 4])
+@pytest.mark.parametrize('obs_kind', OBSERVATIONS)
+@pytest.mark.parametrize('has_reward', [False, True],
+                         ids=['no_reward', 'reward'])
+@pytest.mark.parametrize('mode', ['solo', 'turn'])
+def test_ingest_is_bit_identical_to_the_all_lane_builder(
+        mode, has_reward, obs_kind, bi, pattern):
+    done_all = DONE_PATTERNS[pattern]
+    oracle, new = _windower_pair(mode, has_reward, bi)
+    rng = np.random.RandomState(len(pattern) + bi)
+    sides = []
+    for wd in (oracle, new):
+        first = _parity_records(np.random.RandomState(0), mode, has_reward,
+                                obs_kind, done_all[:PK], wd.P)
+        sides.append([wd, wd.init_state(first), wd.init_ring(first),
+                      jnp.int32(0), jnp.int32(0), jax.random.PRNGKey(7)])
+    total = 0
+    for c in range(len(done_all) // PK):
+        records = _parity_records(rng, mode, has_reward, obs_kind,
+                                  done_all[c * PK:(c + 1) * PK], oracle.P)
+        outs = []
+        for side in sides:
+            wd = side[0]
+            out = wd.ingest(jax.tree_util.tree_map(jnp.asarray, records),
+                            *side[1:])
+            side[1:] = out[:5]
+            outs.append(out)
+        (_, ring_o, cur_o, size_o, key_o, done_o, win_o), \
+            (state_n, ring_n, cur_n, size_n, key_n, done_n, win_n) = outs
+        assert sorted(ring_o) == sorted(ring_n)
+        for key in ring_o:
+            np.testing.assert_array_equal(
+                np.asarray(ring_n[key]), np.asarray(ring_o[key]),
+                err_msg='%s, chunk %d, ring leaf %s' % (pattern, c, key))
+        assert (int(cur_n), int(size_n), int(done_n), int(win_n)) == \
+            (int(cur_o), int(size_o), int(done_o), int(win_o))
+        np.testing.assert_array_equal(np.asarray(key_n), np.asarray(key_o))
+        np.testing.assert_array_equal(np.asarray(state_n['counts']),
+                                      np.asarray(outs[0][0]['counts']))
+        assert int(done_n) == done_all[c * PK:(c + 1) * PK].sum()
+        total += int(win_n)
+    # the patterns do what their names say
+    if pattern == 'no_lane_ends':
+        assert total == 0 and not np.asarray(ring_n['episode_mask']).any()
+    else:
+        assert total > 0 and np.asarray(ring_n['episode_mask']).any()
+    if pattern in ('ring_wraps_inside_a_chunk',
+                   'every_lane_ends_on_every_ply'):
+        assert total == 32 > PCAP and int(cur_n) == 32 % PCAP
+        assert int(size_n) == PCAP
+    if obs_kind == 'wide':
+        assert state_n['hist']['obs'].shape[2] == 256
+    if pattern == 'a_game_of_exactly_L_plies':
+        assert PL // PFS >= PW and total == PW + 1
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr, loop and branch bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                inner = getattr(sub, 'jaxpr', sub)
+                if hasattr(inner, 'eqns'):
+                    yield from _walk_eqns(inner)
+
+
+def _cell_shape_faults(cls, bi):
+    """The shape guard (traced on abstract values, nothing runs): the
+    ingest of ``cls`` at the benchmark cells' geometry (N=64 lanes, games
+    of up to L=200 plies, W=12 windows a game, T=16 + ``bi`` plies a window
+    of the 4-seat 17x7x11 observation, the 49,152-window ring), and what in
+    it does not follow the windows stored:
+
+      * a gather of more than one window's rows of one seat (T rows, a row
+        padded to whole 128-lane tiles);
+      * a value the size of all lanes' windows (N x W x T plies of one
+        seat) or more that is not the history or the ring itself, carried
+        by a loop or updated in place: so nothing of lanes x windows size,
+        no copy or relayout of the history, and the only ring-shaped
+        operation is the in-place row write.
+
+    Returns (faults, number of gathers, ring-shaped operations by name)."""
+    K, N, P, W, T, L, A = 32, 64, 4, 12, 16 + bi, 200, 4
+    obs = (17, 7, 11)
+    f32 = jnp.float32
+    sds = jax.ShapeDtypeStruct
+    records = {
+        'obs': sds((K, N, P) + obs, f32), 'prob': sds((K, N, P), f32),
+        'action': sds((K, N, P), jnp.int32), 'amask': sds((K, N, P, A), f32),
+        'value': sds((K, N, P, 1), f32), 'acting': sds((K, N, P), bool),
+        'done': sds((K, N), bool), 'outcome': sds((K, N, P), f32)}
+    wd = cls(mode='solo', fs=16, bi=bi, max_steps=L, windows_cap=W,
+             capacity=49152, num_players=P, gamma=1.0, has_reward=False)
+    state = jax.eval_shape(wd.init_state, records)
+    ring = jax.eval_shape(wd.init_ring, records)
+    assert ring['observation'].shape == (49152, T * int(np.prod(obs)))
+    scalar = sds((), jnp.int32)
+    closed = jax.make_jaxpr(wd.ingest_fn())(
+        records, state, ring, scalar, scalar, sds((2,), jnp.uint32))
+
+    seat_ply = int(np.prod(obs))
+    all_lane_windows = N * W * T * seat_ply
+    history = N * L * P * seat_ply
+    carriers = {'dynamic_update_slice', 'while', 'scan', 'cond', 'pjit'}
+    faults, n_gathers, ring_ops = [], 0, set()
+    for eqn in _walk_eqns(closed.jaxpr):
+        name = eqn.primitive.name
+        for out in eqn.outvars:
+            size = int(np.prod(out.aval.shape))
+            if name == 'gather':
+                n_gathers += 1
+                if size > T * -(-seat_ply // 128) * 128:
+                    faults.append((name, out.aval.shape))
+            if out.aval.shape == ring['observation'].shape:
+                ring_ops.add(name)
+            if size >= all_lane_windows and not (
+                    size >= history and name in carriers
+                    and any(getattr(v.aval, 'shape', None) == out.aval.shape
+                            for v in eqn.invars)):
+                faults.append((name, out.aval.shape))
+    return faults, n_gathers, ring_ops
+
+
+@pytest.mark.parametrize('bi', [0, 4], ids=['T16', 'T20'])
+def test_ingest_at_the_cells_shapes_follows_the_windows_stored(bi):
+    faults, n_gathers, ring_ops = _cell_shape_faults(DeviceWindower, bi)
+    assert not faults, faults
+    assert n_gathers > 0
+    # the observation ring: carried by the one window loop, written a row
+    # at a time in place, and nothing else
+    assert ring_ops == {'while', 'dynamic_update_slice'}
+
+
+def test_the_shape_guard_rejects_the_all_lane_builder():
+    """The guard has teeth: the builder before PR 26 gathers every lane's
+    windows (64 x 12 x 16 plies of all four seats) and scatters them."""
+    faults, _, ring_ops = _cell_shape_faults(OracleWindower, 0)
+    assert {'gather', 'scatter'} <= {name for name, _ in faults}
+    assert 'scatter' in ring_ops
+
+
+def test_an_env_module_must_declare_its_longest_game():
+    """The circular history is sized from the env's longest game: the
+    learner refuses a device env module that declares neither MAX_STEPS nor
+    MAX_PLIES (it used to assume 256 plies in silence)."""
+    import types
+
+    from handyrl_tpu.envs import jax_geister, jax_hungry_geese
+    from handyrl_tpu.train import _declared_max_steps
+
+    assert _declared_max_steps(jax_hungry_geese) == 200
+    # Geister's draw comes after MAX_PLIES moves, and its two set-up plies
+    # are env steps too: the module says so in MAX_STEPS
+    assert _declared_max_steps(jax_geister) == 202
+    assert _declared_max_steps(types.SimpleNamespace(MAX_PLIES=40)) == 40
+    undeclared = types.ModuleType('jax_endless_game')
+    with pytest.raises(AssertionError, match='jax_endless_game declares '
+                                             'neither MAX_STEPS'):
+        _declared_max_steps(undeclared)
+
+
+def test_no_geister_game_outlasts_the_declared_bound():
+    """The windower's contract, held by the env: under random legal play a
+    quarter of the Geister games are draws, and a draw is exactly the
+    declared number of env steps (two set-up plies + MAX_PLIES moves),
+    never more."""
+    from handyrl_tpu.envs import jax_geister as env
+    from handyrl_tpu.train import _declared_max_steps
+
+    def play(state, key):
+        def ply(carry, _):
+            state, key = carry
+            key, sub = jax.random.split(key)
+            action = jax.random.categorical(
+                sub, jnp.where(env.legal_mask(state), 0.0, -1e9))
+            state = env.step(state, action)
+            done = env.terminal(state)
+            return (env.auto_reset(state, done), key), done
+        return jax.lax.scan(ply, (state, key), None, length=420)[1]
+
+    done = np.asarray(jax.jit(play)(env.init_state(128, 0),
+                                    jax.random.PRNGKey(1)))
+    lengths = [b - a for lane in done.T
+               for a, b in zip(np.r_[-1, np.flatnonzero(lane)],
+                               np.flatnonzero(lane))]
+    assert max(lengths) == _declared_max_steps(env) == env.MAX_PLIES + 2
+    assert lengths.count(max(lengths)) > 10

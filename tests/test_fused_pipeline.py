@@ -263,11 +263,12 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
     assert 0 < last['builder_plies'] <= fp.chunk_steps * len(blocks)
     assert last['builder_plies'] * fp.n_envs <= last['plies']
     assert last['windows_ingested'] == fp.windows_ingested_host > 0
-    # the builder makes every lane's windows on each of its plies; the ring
-    # got those of the lanes whose game had ended
-    assert last['windows_built'] == (last['builder_plies'] * fp.n_envs
-                                     * fp._windows_per_lane)
-    assert last['windows_ingested'] <= last['windows_built']
+    # the builder makes the windows of the games that ended, one each loop
+    # iteration, and the ring gets every one: 1 to W a game
+    assert last['windows_built'] == last['windows_ingested']
+    # (TicTacToe's 5-9 plies are one or two windows of forward_steps 4)
+    assert last['episodes'] <= last['windows_ingested'] \
+        <= last['episodes'] * 2
     assert last['episodes'] == fp.episodes_host \
         == learner.num_returned_episodes
     assert last['builder_plies'] <= last['episodes']
@@ -352,7 +353,9 @@ def test_stall_detector_on_a_synthetic_interval_series():
     assert stall['median_s'] == pytest.approx(1.01)
     assert stall['split'] == dict(steady, wait=4.98)
     assert stall['next_host_block_s'] == 0.015
-    assert stall['process_cpu_s'] >= stall['thread_cpu_s'] >= 0
+    # two clocks read one after the other over a few microseconds: the
+    # process's may trail the thread's by a tick
+    assert stall['process_cpu_s'] + 1e-4 >= stall['thread_cpu_s'] >= 0
     assert stall['involuntary_switches'] >= 0 and len(stall['loadavg']) == 3
     event = [e for e in telemetry.recorder().events()
              if e['kind'] == 'stall'][-1]
