@@ -249,15 +249,31 @@ def _ply_width(leaf, rows: int) -> int:
     return int(np.prod(leaf.shape[2:])) // rows
 
 
-def _row_width(leaf, rows: int) -> int:
-    """Stored width of that row: a wide row is padded to whole 128-lane
-    tiles. With no padding in it, (lane, row, value) order is the layout
-    the device gives the buffer by default, which is the one a row gather
-    reads; a 1,309-wide observation row would get the row axis minor
-    instead (least padding), and a relayout of the whole history on every
-    chunk."""
-    flat = _ply_width(leaf, rows)
+def _row_width(flat: int) -> int:
+    """Stored width of a row of ``flat`` values, in the history and in the
+    ring: a wide row is padded to whole 128-lane tiles. With no padding in
+    it, row-major order is the layout the device gives the buffer by
+    default, which is the one a row write and a row gather use; a
+    1,309-wide history row or a 20,944-wide ring row would get the row
+    axis minor instead (least padding), and a relayout of the whole buffer
+    into every program that touches it and out again."""
     return -(-flat // 128) * 128 if flat > 128 else flat
+
+
+def _pad_rows(x, width: int):
+    """x (..., flat) with zeros appended to its minor axis up to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def unflatten_rows(rows: Dict[str, Any],
+                   window_spec: Dict[str, Tuple]) -> Dict[str, Any]:
+    """(n, stored width) ring rows -> batch pytree: the ONE place that
+    strips a row's padding (_row_width) and restores (n,) + window shape
+    per leaf, dotted keys rebuilt into the nested observation."""
+    def window(key, v):
+        shape = window_spec[key][0]
+        return v[:, :int(np.prod(shape))].reshape((v.shape[0],) + shape)
+    return unflatten_window_keys({k: window(k, v) for k, v in rows.items()})
 
 
 def _first_true(mask):
@@ -338,7 +354,8 @@ class DeviceWindower:
             # 'obs' may be a pytree (dict observations): map over leaves
             hist[key] = jax.tree_util.tree_map(
                 lambda leaf: jnp.zeros(
-                    (N, C * rows, _row_width(leaf, rows)), leaf.dtype),
+                    (N, C * rows, _row_width(_ply_width(leaf, rows))),
+                    leaf.dtype),
                 records[key])
         return {'hist': hist,
                 'counts': jnp.zeros((N,), jnp.int32),
@@ -349,12 +366,16 @@ class DeviceWindower:
         device here. (Running the window builder eagerly, op by op, makes
         every un-jitted op its own compile + dispatch.)
 
-        Ring storage is FLATTENED per window: leaf (capacity, prod(shape)).
-        TPU tiled layouts pad the two minormost dims to (8, 128); storing
-        windows in natural (T, P, ...) shape put tiny trailing dims (e.g.
-        Hungry Geese's 7x11 board) in the tile, inflating a 4 GB ring to a
-        31 GB allocation. 2-D storage pads ~1%; consumers reshape after
-        gather via ``window_spec``."""
+        Ring storage is FLATTENED per window: leaf (capacity, stored width
+        of prod(shape) values). TPU tiled layouts pad the two minormost
+        dims to (8, 128); storing windows in natural (T, P, ...) shape put
+        tiny trailing dims (e.g. Hungry Geese's 7x11 board) in the tile,
+        inflating a 4 GB ring to a 31 GB allocation. The whole window row
+        is padded once (_row_width: 20,944 -> 20,992 values), so that the
+        device keeps the ring by rows, as the window loop writes it and the
+        SGD loop gathers it, and no dispatch relays it. ``window_spec``
+        keeps the LOGICAL window shapes; consumers strip the padding and
+        reshape after the gather (``unflatten_rows``)."""
         def spec_of(key):
             return jax.tree_util.tree_map(
                 lambda leaf: jax.ShapeDtypeStruct(
@@ -380,16 +401,12 @@ class DeviceWindower:
         self.window_spec = {k: (tuple(w.shape[1:]), w.dtype)
                             for k, w in win.items()}
         return {k: jnp.zeros(
-                    (self.capacity, int(np.prod(shape)) if shape else 1),
-                    dtype)
+                    (self.capacity, _row_width(int(np.prod(shape)))), dtype)
                 for k, (shape, dtype) in self.window_spec.items()}
 
     def unflatten_rows(self, rows: Dict[str, Any]) -> Dict[str, Any]:
-        """(n, flat) ring rows -> batch pytree: (n,) + window shape per
-        leaf, dotted keys rebuilt into the nested observation."""
-        return unflatten_window_keys(
-            {k: v.reshape((v.shape[0],) + self.window_spec[k][0])
-             for k, v in rows.items()})
+        """Ring rows -> batch pytree, by this ring's ``window_spec``."""
+        return unflatten_rows(rows, self.window_spec)
 
     # -- the ingest program ------------------------------------------------
     def ingest(self, records, state, ring, cursor, size, rng):
@@ -428,9 +445,8 @@ class DeviceWindower:
             stored = state['hist']           # exactly the hist_keys leaves
 
             def as_rows(h, rec):         # one ply -> (N, rows a ply, width)
-                x = rec.reshape((N, h.shape[1] // C, -1))
-                return jnp.pad(x, ((0, 0), (0, 0),
-                                   (0, h.shape[2] - x.shape[2])))
+                return _pad_rows(rec.reshape((N, h.shape[1] // C, -1)),
+                                 h.shape[2])
 
             def store(h, rows):          # (K, N, rows a ply, width)
                 return jax.lax.dynamic_update_slice(
@@ -516,7 +532,8 @@ class DeviceWindower:
                 win = flatten_window_keys(win)
                 slot = (cursor + j) % cap
                 ring = {key: jax.lax.dynamic_update_slice(
-                            rb, win[key].reshape((1, -1)), (slot, 0))
+                            rb, _pad_rows(win[key].reshape((1, -1)),
+                                          rb.shape[1]), (slot, 0))
                         for key, rb in ring.items()}
                 last = w + 1 == wcount[k, n]
                 return ring, game + last, jnp.where(last, 0, w + 1)

@@ -170,15 +170,13 @@ class FusedPipeline:
                 key, sub = jax.random.split(key)
                 slots = recency_slots(sub, size, cursor, capacity,
                                       batch_rows)
-                # ring rows are stored flat (device_windows.init_ring);
-                # restore the (B, T, P, ...) window shape after the gather
-                # and rebuild the batch pytree (dotted keys -> nested obs)
-                from .device_windows import unflatten_window_keys
+                # ring rows are stored flat and padded
+                # (device_windows.init_ring); restore the (B, T, P, ...)
+                # window shape after the gather and rebuild the batch
+                # pytree (dotted keys -> nested obs)
                 with jax.named_scope('sample'):
-                    batch = unflatten_window_keys(
-                        {k: ring[k][slots].reshape(
-                            (batch_rows,) + windower.window_spec[k][0])
-                         for k in ring})
+                    batch = windower.unflatten_rows(
+                        {k: ring[k][slots] for k in ring})
                 lr = (default_lr * data_cnt_ema
                       / (1 + ts.steps.astype(jnp.float32) * 1e-5))
                 with jax.named_scope('update'):

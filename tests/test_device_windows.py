@@ -14,7 +14,7 @@ from handyrl_tpu.ops.batch import build_window
 from handyrl_tpu.ops.device_windows import (DeviceWindower,
                                             build_windows_solo,
                                             build_windows_turn,
-                                            _discounted_returns)
+                                            _discounted_returns, _row_width)
 
 FS, BI = 4, 2
 L = 16
@@ -355,21 +355,22 @@ DONE_PATTERNS = {
 }
 
 
-# the observation a ply: a small array, geister's kind of pytree, and an
-# array wider than 128 values (165 -> history rows padded to 256: the path
-# the benchmark cells' 1,309-value rows take)
-OBSERVATIONS = ['array', 'pytree', 'wide']
+# the observation a ply: a small array, geister's kind of pytree, an array
+# wider than 128 values (165 -> history rows padded to 256, ring rows of
+# 2 or 6 plies to 384 or 1,024: the path the benchmark cells' 1,309-value
+# plies and 20,944-value windows take), and a pytree with such a leaf
+OBSERVATIONS = ['array', 'pytree', 'wide', 'wide_pytree']
 
 
 def _parity_records(rng, mode, has_reward, obs_kind, done, P):
     K, N = done.shape
     lead = (K, N, P) if mode == 'solo' else (K, N)
     f32 = np.float32
-    board = rng.rand(*lead, *((3, 5, 11) if obs_kind == 'wide'
+    board = rng.rand(*lead, *((3, 5, 11) if obs_kind.startswith('wide')
                               else (2, 2, 2))).astype(f32)
     records = {
         'obs': ({'scalar': rng.rand(*lead, 5).astype(f32), 'board': board}
-                if obs_kind == 'pytree' else board),
+                if obs_kind.endswith('pytree') else board),
         'prob': rng.uniform(0.2, 1, lead).astype(f32),
         'action': rng.randint(0, PA, lead).astype(np.int32),
         'amask': np.where(rng.rand(*lead, PA) < 0.3, 1e32, 0).astype(f32),
@@ -431,9 +432,14 @@ def test_ingest_is_bit_identical_to_the_all_lane_builder(
             (state_n, ring_n, cur_n, size_n, key_n, done_n, win_n) = outs
         assert sorted(ring_o) == sorted(ring_n)
         for key in ring_o:
+            # the oracle's rows are as wide as the window; the ring's hold
+            # the same values and, where padded to whole tiles, zeros after
+            stored, flat = np.asarray(ring_n[key]), ring_o[key].shape[1]
+            assert stored.shape == (PCAP, _row_width(flat))
             np.testing.assert_array_equal(
-                np.asarray(ring_n[key]), np.asarray(ring_o[key]),
+                stored[:, :flat], np.asarray(ring_o[key]),
                 err_msg='%s, chunk %d, ring leaf %s' % (pattern, c, key))
+            assert not stored[:, flat:].any(), (pattern, c, key)
         assert (int(cur_n), int(size_n), int(done_n), int(win_n)) == \
             (int(cur_o), int(size_o), int(done_o), int(win_o))
         np.testing.assert_array_equal(np.asarray(key_n), np.asarray(key_o))
@@ -450,8 +456,24 @@ def test_ingest_is_bit_identical_to_the_all_lane_builder(
                    'every_lane_ends_on_every_ply'):
         assert total == 32 > PCAP and int(cur_n) == 32 % PCAP
         assert int(size_n) == PCAP
-    if obs_kind == 'wide':
-        assert state_n['hist']['obs'].shape[2] == 256
+    # what a consumer reads: the padding stripped, the windows the oracle
+    # built, the observation nested again
+    jax.tree_util.tree_map(np.testing.assert_array_equal,
+                           new.unflatten_rows(ring_n),
+                           oracle.unflatten_rows(ring_o))
+    T = bi + PFS
+    wide_leaf = {'wide': 'observation',
+                 'wide_pytree': 'observation.board'}.get(obs_kind)
+    for key in ring_n:
+        if key == wide_leaf:
+            assert ring_o[key].shape[1] == T * 165
+            assert ring_n[key].shape[1] == {2: 384, 6: 1024}[T]
+        else:
+            assert ring_n[key].shape == ring_o[key].shape
+    if wide_leaf:
+        hist_obs = state_n['hist']['obs']
+        assert (hist_obs['board'] if obs_kind == 'wide_pytree'
+                else hist_obs).shape[2] == 256
     if pattern == 'a_game_of_exactly_L_plies':
         assert PL // PFS >= PW and total == PW + 1
 
@@ -482,7 +504,8 @@ def _cell_shape_faults(cls, bi):
         no copy or relayout of the history, and the only ring-shaped
         operation is the in-place row write.
 
-    Returns (faults, number of gathers, ring-shaped operations by name)."""
+    Returns (faults, number of gathers, ring-shaped operations by name,
+    the ring's shapes, the windower's ``window_spec``)."""
     K, N, P, W, T, L, A = 32, 64, 4, 12, 16 + bi, 200, 4
     obs = (17, 7, 11)
     f32 = jnp.float32
@@ -496,7 +519,6 @@ def _cell_shape_faults(cls, bi):
              capacity=49152, num_players=P, gamma=1.0, has_reward=False)
     state = jax.eval_shape(wd.init_state, records)
     ring = jax.eval_shape(wd.init_ring, records)
-    assert ring['observation'].shape == (49152, T * int(np.prod(obs)))
     scalar = sds((), jnp.int32)
     closed = jax.make_jaxpr(wd.ingest_fn())(
         records, state, ring, scalar, scalar, sds((2,), jnp.uint32))
@@ -521,23 +543,33 @@ def _cell_shape_faults(cls, bi):
                     and any(getattr(v.aval, 'shape', None) == out.aval.shape
                             for v in eqn.invars)):
                 faults.append((name, out.aval.shape))
-    return faults, n_gathers, ring_ops
+    return (faults, n_gathers, ring_ops,
+            {key: leaf.shape for key, leaf in ring.items()}, wd.window_spec)
 
 
 @pytest.mark.parametrize('bi', [0, 4], ids=['T16', 'T20'])
 def test_ingest_at_the_cells_shapes_follows_the_windows_stored(bi):
-    faults, n_gathers, ring_ops = _cell_shape_faults(DeviceWindower, bi)
+    faults, n_gathers, ring_ops, ring, spec = _cell_shape_faults(
+        DeviceWindower, bi)
     assert not faults, faults
     assert n_gathers > 0
+    # the observation's window row (20,944 or 26,180 values) is stored
+    # padded to whole 128-lane tiles, every narrow leaf as wide as it is,
+    # and ``window_spec`` keeps the logical shapes
+    T = 16 + bi
+    assert ring.pop('observation') == (49152, {16: 20992, 20: 26240}[T])
+    assert spec['observation'][0] == (T, 1, 17, 7, 11)
+    assert ring == {key: (49152, int(np.prod(spec[key][0]))) for key in ring}
+    assert max(width for _, width in ring.values()) == 4 * T <= 128
     # the observation ring: carried by the one window loop, written a row
-    # at a time in place, and nothing else
+    # at a time in place (at the stored width), and nothing else
     assert ring_ops == {'while', 'dynamic_update_slice'}
 
 
 def test_the_shape_guard_rejects_the_all_lane_builder():
     """The guard has teeth: the builder before PR 26 gathers every lane's
     windows (64 x 12 x 16 plies of all four seats) and scatters them."""
-    faults, _, ring_ops = _cell_shape_faults(OracleWindower, 0)
+    faults, _, ring_ops, _, _ = _cell_shape_faults(OracleWindower, 0)
     assert {'gather', 'scatter'} <= {name for name, _ in faults}
     assert 'scatter' in ring_ops
 

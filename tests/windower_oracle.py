@@ -4,15 +4,19 @@
 lanes x W (``vmap`` of the per-env builders over the whole (N, L, ...)
 history) and a scatter with ``mode='drop'`` that keeps the finished lanes'.
 Copied verbatim from ``handyrl_tpu/ops/device_windows.py`` at commit a6bc120
-(only the class header and the imports are new): the event-driven builder
-must leave ring, cursor, size, rng and both counts bit-identical to this
-for the same records and key (tests/test_device_windows.py).
+(only the class header, the imports and ``init_ring`` are new): the
+event-driven builder must leave ring, cursor, size, rng and both counts
+bit-identical to this for the same records and key
+(tests/test_device_windows.py). Its ring keeps the rows of that commit too,
+as wide as the window and no wider: the production ring must hold the same
+values in its logical columns and zeros in its padding.
 """
 
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from handyrl_tpu.ops.device_windows import (DeviceWindower,
                                             _discounted_returns,
@@ -146,8 +150,14 @@ def build_windows_turn(hist: Dict[str, Any], S, ts, outcome,
 
 
 class OracleWindower(DeviceWindower):
-    """DeviceWindower with the all-lane builder and its (N, L, ...) history;
-    the ring (``init_ring``) is the production one."""
+    """DeviceWindower with the all-lane builder, its (N, L, ...) history
+    and its ring of unpadded rows."""
+
+    def init_ring(self, records) -> Dict[str, Any]:
+        """The production ring's leaves at their logical width."""
+        super().init_ring(records)               # sets window_spec
+        return {k: jnp.zeros((self.capacity, int(np.prod(shape))), dtype)
+                for k, (shape, dtype) in self.window_spec.items()}
 
     def init_state(self, records) -> Dict[str, Any]:
         """Zero history buffers shaped after one rollout chunk's records."""
