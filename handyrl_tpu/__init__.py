@@ -38,7 +38,7 @@ def setup_compile_cache() -> str:
     The fused pipeline and the recurrent update steps take tens of seconds
     to compile; caching makes every compile a one-time cost across
     processes and runs. Called from the framework's own entry points (CLI,
-    Learner, spawned workers, bench, tests) — NOT at package import, so
+    Learner, spawned workers, tests) — NOT at package import, so
     embedding applications keep full control of jax config and ``import
     handyrl_tpu`` stays side-effect free.
 

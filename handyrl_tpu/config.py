@@ -43,13 +43,12 @@ TRAIN_DEFAULTS: Dict[str, Any] = {
     'eval_envs': None,            # concurrent online-eval matches; None = max(4, generation_envs // 8)
     'device_chunk_steps': 16,     # plies per device-generation program dispatch
     'device_eval': True,          # device-resident eval matches when device_generation is on and the opponent is 'random'
-    'device_ingest': True,        # assemble training windows on device (device_generation + device_replay, single-device)
+    'device_ingest': True,        # assemble training windows on device and train in the fused program: one dispatch = rollout chunk + ingest + K SGD steps (device_generation + device_replay)
     'device_generation': False,   # fully device-resident rollouts (envs with a pure-JAX twin)
     'device_replay': False,       # HBM-resident replay ring; batches sampled on device
     'replay_windows_per_episode': None,  # windows ingested per episode (uniformly placed); sets both the ring budget and the sampling WEIGHTING — 1 = exact per-episode mass like the reference's draw (train.py:291-306), >1 weights long episodes by min(len//fs, W). None = max(1, 64 // forward_steps)
     'replay_fused_steps': 8,      # SGD steps fused into one device program in device_replay mode
     'max_sample_reuse': None,     # device_replay threaded trainer: cap samples-drawn / windows-ingested (None = free-spin like the reference)
-    'fused_pipeline': True,       # one dispatch = rollout chunk + ingest + K SGD steps (device_ingest configs)
     'sgd_steps_per_chunk': None,  # fused-pipeline SGD steps per rollout chunk (pins the replay ratio); None = 16
     'checkpoint_interval': 1,     # fused loop: write model/trainer ckpt files every N epochs (params still refresh on device every epoch; a final flush always lands on shutdown)
     'model_dir': 'models',        # checkpoint directory
@@ -333,6 +332,10 @@ def validate(args: Dict[str, Any]) -> None:
     assert ta['compress_steps'] >= 1
     assert 0.0 <= ta['eval_rate'] <= 1.0
     assert ta['batch_size'] >= 1
+    if 'fused_pipeline' in ta:
+        raise ValueError(
+            'fused_pipeline is not an option: the fused program is the only '
+            'device-ingest learner (remove the key)')
     if ta.get('max_sample_reuse') is not None:
         assert float(ta['max_sample_reuse']) > 0, \
             'max_sample_reuse must be > 0 (unset it to free-spin)'

@@ -226,14 +226,14 @@ def make_gen_body(env_mod, apply_fn, recurrent: bool, simultaneous: bool):
 class DeviceGenerator:
     """Runs chunks of device-resident self-play for a pure-JAX env module.
 
-    Dispatch is PIPELINED one chunk deep: each ``step_chunk*`` call enqueues
+    Dispatch is PIPELINED one chunk deep: each ``step_chunk`` call enqueues
     the NEXT rollout program before fetching the previous chunk's results,
     so the host's blocking fetch overlaps with device execution of the
     following chunk instead of leaving the device idle. Callers see a
     one-chunk delay in episode accounting, nothing else.
     """
 
-    pipelined = True    # step_chunk* returns the PREVIOUS dispatch's chunk
+    pipelined = True    # step_chunk returns the PREVIOUS dispatch's chunk
 
     def __init__(self, env_mod, wrapper, args: Dict[str, Any],
                  n_envs: int = 256, chunk_steps: int = 16, seed: int = 0):
@@ -242,7 +242,6 @@ class DeviceGenerator:
         _init_rollout_engine(self, env_mod, wrapper, n_envs, seed)
         self._partials: List[List[dict]] = [[] for _ in range(n_envs)]
         self._pending = None
-        self._acct_pack = None
         self._full_pack = None
         self.dispatches = 0
 
@@ -260,39 +259,6 @@ class DeviceGenerator:
             self.wrapper.params, self.state, self.hidden, self.rng)
         self.dispatches += 1
         return dict(records)
-
-    def _dispatch_acct(self):
-        """Dispatch rollout + the tiny done/outcome pack (one fetchable)."""
-        records = self._dispatch()
-        if self._acct_pack is None:
-            self._acct_pack = _RecordPacker(
-                {'done': records['done'], 'outcome': records['outcome']})
-        return records, self._acct_pack.pack(
-            {'done': records['done'], 'outcome': records['outcome']})
-
-    def step_chunk_records(self):
-        """Run one compiled chunk, keeping the trajectory ON DEVICE.
-
-        For the device-ingest pipeline (ops/device_windows.py): returns the
-        raw records pytree (device arrays, leading axes (K, N)) plus host
-        copies of ONLY the tiny done/outcome arrays for episode accounting,
-        fetched as ONE packed array (one blocking transfer, not two).
-        The heavy leaves (observations, masks) never reach the host.
-        """
-        if self._pending is None:
-            self._pending = self._dispatch_acct()
-        (records, pack), self._pending = self._pending, self._dispatch_acct()
-        acct = self._acct_pack.unpack(pack)
-        return records, acct['done'], acct['outcome']
-
-    def drain_records(self):
-        """Fetch the in-flight speculative chunk at loop shutdown (device-
-        ingest mode); returns (records, done, outcome) or None."""
-        if self._pending is None:
-            return None
-        (records, pack), self._pending = self._pending, None
-        acct = self._acct_pack.unpack(pack)
-        return records, acct['done'], acct['outcome']
 
     # -- host-side episode splicing ---------------------------------------
     def _dispatch_full(self):

@@ -682,7 +682,7 @@ class InferenceEngine:
         self._fault: Optional[tuple] = None       # (kind, due_at, stall_s)
         # local tallies mirror the registry so the fill ratio (and the
         # serving tier's per-service shed accounting) is computable even
-        # with telemetry disabled (the bench/smoke contract reads them)
+        # with telemetry disabled (the service stats and tests read them)
         self.requests_served = 0
         self.batches_run = 0
         self.sheds = 0
@@ -1081,7 +1081,7 @@ class EngineSupervisor:
                                         name='engine-supervisor', daemon=True)
         self._thread.start()
 
-    # -- bench/back-compat surface ----------------------------------------
+    # -- tallies across engine restarts (service stats, tests) ------------
 
     @property
     def requests_served(self) -> int:

@@ -151,8 +151,8 @@ def _replace_none(value, fallback):
 
 # ---------------------------------------------------------------------------
 # reference builder — the original implementation, kept VERBATIM as the
-# semantic pin for the arena builder (and the denominator of the ingest
-# benchmark, bench.py BENCH_MODE=ingest). Not used on the production path.
+# semantic pin for the arena builder (tests/test_batch_vectorized.py
+# compares against it). Not used on the production path.
 
 
 def build_window_reference(moments: List[dict], ep: dict, args: Dict[str, Any]

@@ -265,17 +265,6 @@ def _pad_rows(x, width: int):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
-def unflatten_rows(rows: Dict[str, Any],
-                   window_spec: Dict[str, Tuple]) -> Dict[str, Any]:
-    """(n, stored width) ring rows -> batch pytree: the ONE place that
-    strips a row's padding (_row_width) and restores (n,) + window shape
-    per leaf, dotted keys rebuilt into the nested observation."""
-    def window(key, v):
-        shape = window_spec[key][0]
-        return v[:, :int(np.prod(shape))].reshape((v.shape[0],) + shape)
-    return unflatten_window_keys({k: window(k, v) for k, v in rows.items()})
-
-
 def _first_true(mask):
     """Flat indices of the true entries of ``mask``, in increasing order,
     then the others: (mask.size,) int32. A stable sort: ``jnp.nonzero`` with
@@ -287,13 +276,12 @@ def _first_true(mask):
 
 
 class DeviceWindower:
-    """Owns the per-env episode history and the chunk-ingest program.
+    """Owns the per-env episode history and the chunk-ingest function.
 
-    ``ingest(records, state, ring, cursor, size, rng)`` consumes one rollout
-    chunk and returns updated (state, ring, cursor, size, rng, n_done,
-    n_windows). The ring/state/cursor/size live as device arrays owned by
-    the caller (single-owner: the trainer thread), so buffers are donated
-    in place.
+    ``ingest_fn()(records, state, ring, cursor, size, rng)`` consumes one
+    rollout chunk and returns updated (state, ring, cursor, size, rng,
+    n_done, n_windows). The ring/state/cursor/size live as device arrays
+    owned by the caller (the fused program, which donates them in place).
 
     THE CONTRACT: a game is at most ``max_steps`` plies (the env module's
     MAX_STEPS / MAX_PLIES; train.py asserts that it declares one). The
@@ -315,7 +303,6 @@ class DeviceWindower:
         self.gamma = gamma
         self.has_reward = has_reward
         self.window_spec: Optional[Dict[str, Tuple]] = None  # set by init_ring
-        self._ingest = None   # jitted lazily once ring shapes exist
 
     # -- state/ring allocation --------------------------------------------
     def _hist_keys(self):
@@ -405,22 +392,19 @@ class DeviceWindower:
                 for k, (shape, dtype) in self.window_spec.items()}
 
     def unflatten_rows(self, rows: Dict[str, Any]) -> Dict[str, Any]:
-        """Ring rows -> batch pytree, by this ring's ``window_spec``."""
-        return unflatten_rows(rows, self.window_spec)
+        """(n, stored width) ring rows -> batch pytree: the ONE place that
+        strips a row's padding (_row_width) and restores (n,) + window shape
+        per leaf, dotted keys rebuilt into the nested observation."""
+        def window(key, v):
+            shape = self.window_spec[key][0]
+            return v[:, :int(np.prod(shape))].reshape((v.shape[0],) + shape)
+        return unflatten_window_keys(
+            {k: window(k, v) for k, v in rows.items()})
 
     # -- the ingest program ------------------------------------------------
-    def ingest(self, records, state, ring, cursor, size, rng):
-        if self._ingest is None:
-            # donate history/ring/cursor/size/rng: the trainer thread is the
-            # single owner and always rebinds them from the outputs
-            self._ingest = jax.jit(self.ingest_fn(),
-                                   donate_argnums=(1, 2, 3, 4, 5))
-        return self._ingest(records, state, ring, cursor, size, rng)
-
     def ingest_fn(self):
-        """The pure (un-jitted) chunk-ingest function — used by the jitted
-        standalone path above and inlined into the fused
-        generate+ingest+train program (ops/fused_pipeline.py)."""
+        """The pure (un-jitted) chunk-ingest function, inlined into the
+        fused generate+ingest+train program (ops/fused_pipeline.py)."""
         fs, bi, L, W, cap = self.fs, self.bi, self.L, self.W, self.capacity
         P, gamma, solo = self.P, self.gamma, self.mode == 'solo'
         has_reward = self.has_reward

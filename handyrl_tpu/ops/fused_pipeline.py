@@ -1,14 +1,8 @@
 """The fully-fused device pipeline: ONE dispatch = rollout chunk + window
 ingest + K SGD steps.
 
-The split pipeline (DeviceGenerator dispatch -> chunk queue -> trainer-thread
-ingest dispatch -> fused-update dispatch) keeps the whole loop on device, but
-still pays one host round trip per program, and the generation thread's tiny
-done/outcome fetch queues BEHIND the trainer thread's in-flight programs on
-the single device stream — three dispatches and a serialized fetch per
-chunk where one of each will do.
-
-Here the entire steady-state loop body is one XLA program:
+The entire steady-state loop body is one XLA program, so a chunk costs one
+dispatch and one fetch:
 
     rollout chunk (lax.scan over plies, make_gen_body)
       -> windower chunk ingest (episode windows scattered into the HBM ring)
@@ -26,8 +20,8 @@ counter and Adam state never see empty-ring batches.
 
 Sample-reuse note: steps-per-chunk is a DIAL (sgd_steps_per_chunk), making
 the replay ratio explicit: reuse ~= sgd_steps * batch_size / windows-per-
-chunk. The threaded mode's reuse is implicit (however fast the trainer spins
-vs generation); here it is pinned and logged.
+chunk. The threaded replay trainer's reuse is implicit (however fast it
+spins vs generation); here it is pinned and logged.
 """
 
 from __future__ import annotations

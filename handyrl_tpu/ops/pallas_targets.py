@@ -15,7 +15,7 @@ Status (measured on a real TPU v5e chip, round 2): the kernels compile,
 run, and agree with the scan reference on silicon (tests/test_pallas_targets.py
 with HANDYRL_TPU_TESTS=1), but inside the full update step they are SLOWER
 than the lax.scan path — 56.9 vs 51.4 ms/step for TD/TD and 110.7 vs 50.0
-for UPGO/VTRACE at B=128 T=16 (benchmarks.jsonl pallas-vs-scan rows, a
+for UPGO/VTRACE at B=128 T=16 (the builders' round-2 record, taken on a
 set-up that no longer exists; ROADMAP D3). The recursion is elementwise
 on tiny (T, B·P) blocks, so XLA fuses the scan into the surrounding program,
 while a pallas_call is an opaque custom call that forces its inputs to be

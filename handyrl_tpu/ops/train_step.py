@@ -220,17 +220,10 @@ def build_replay_update(module, cfg: LossConfig, capacity: int,
     def gather(buffers, slots):
         """Ring rows are stored FLAT (capacity, prod(window shape)) to
         avoid TPU tile-padding blowup (ops/replay.py); ``spec_fn`` supplies
-        the per-leaf window shapes at trace time. Two storage flavors:
-        DeviceReplay's (leaf list + treedef) and DeviceWindower's ring
-        (flat dict keyed like the batch, wide rows padded to whole tiles:
-        device_windows.unflatten_rows strips that)."""
+        DeviceReplay's per-leaf window shapes and treedef at trace time."""
         if spec_fn is None:
             return jax.tree_util.tree_map(lambda b: b[slots], buffers)
         spec, treedef = spec_fn()
-        if isinstance(buffers, dict):
-            from .device_windows import unflatten_rows
-            return unflatten_rows(
-                {k: buffers[k][slots] for k in buffers}, spec)
         rows = [b[slots].reshape((batch_size,) + shape)
                 for b, (shape, _) in zip(buffers, spec)]
         return jax.tree_util.tree_unflatten(treedef, rows)

@@ -1,8 +1,8 @@
 """Standalone service runner: ``python -m handyrl_tpu.serving [flags]``.
 
 The ``main.py --serve`` mode serves whatever ``config.yaml`` describes;
-this runner is the harness-friendly flavor (bench.py BENCH_MODE=serve,
-scripts/serve_smoke.py, ad-hoc ops): every knob is a flag, defaults come
+this runner is the harness-friendly flavor (tests/test_serving.py, the
+fleet and gateway smokes, ad-hoc ops): every knob is a flag, defaults come
 from the same config layer, and the ready line on stdout carries the bound
 ports. Exit code follows the PreemptionGuard contract (75 after a SIGTERM
 drain).

@@ -332,8 +332,8 @@ class MatchGateway:
                     self.hub.send(ep, (SERVE_KIND,
                                        {'sessions': self.session_table()}))
                 elif op == 'trace':
-                    # runtime tracing toggle (bench A/B legs flip the
-                    # SAME warmed gateway on and off between legs)
+                    # runtime tracing toggle, as on the service
+                    # (docs/observability.md)
                     telemetry.configure_tracing(
                         str(body.get('dir') or ''), body.get('rate'),
                         force=True)

@@ -410,8 +410,8 @@ class InferenceService:
         elif op == 'warm':
             self._warm(ep, str(body.get('model')))
         elif op == 'trace':
-            # runtime tracing toggle (bench A/B legs flip the SAME warmed
-            # process on and off instead of comparing two cold runs)
+            # runtime tracing toggle: turn a warmed service's tracing on
+            # and off without a restart (docs/observability.md)
             telemetry.configure_tracing(str(body.get('dir') or ''),
                                         body.get('rate'), force=True)
             self.hub.send(ep, (SERVE_KIND,

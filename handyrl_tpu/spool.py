@@ -33,8 +33,8 @@ Anatomy:
   ``keep_segments`` closed segments as cushion — disk stays bounded.
 
 Appends are NOT per-record fsynced: a process SIGKILL cannot lose bytes
-the kernel accepted, and the fsync-per-episode cost would blow the ≤2%
-ingest-bench budget. Segment rotation and ``close`` fsync, so the
+the kernel accepted, and an fsync per episode would put a disk flush on
+the admission path. Segment rotation and ``close`` fsync, so the
 machine-crash exposure is bounded to the live segment (documented in
 docs/large_scale_training.md).
 """

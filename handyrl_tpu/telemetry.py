@@ -19,9 +19,8 @@ throughput go across processes). Four pieces, all stdlib-only:
   (``spans()``), feed the ``stage_seconds{stage=...}`` histogram family
   and the trace file, and while open are ``handyrl:<name>`` annotations
   in any jax profiler session. The stage vocabulary subsumes the ingest
-  StageTimer's canonical names (``INGEST_STAGES``): a bench row, a live
-  epoch timing line, and an exported histogram all speak the same stage
-  language.
+  StageTimer's canonical names (``INGEST_STAGES``): a live epoch timing
+  line and an exported histogram speak the same stage language.
 
 * **Leveled logger** — ``get_logger()``; verbosity from
   ``HANDYRL_TPU_LOG_LEVEL`` (debug/info/warning/error, default info).
@@ -96,7 +95,6 @@ def set_enabled(flag: bool):
 
 
 # the flight recorder rides the same master switch but also has its own
-# (bench.py's recorder A/B isolates the ring cost from metric/span cost)
 _RECORDER_ON = True
 
 
@@ -191,7 +189,8 @@ def configure_tracing(trace_dir: Optional[str] = None,
     """Adopt trace settings from the run config, mirrored into the
     environment so spawned children (batchers, gathers, workers) inherit
     them. An operator-set ``HANDYRL_TPU_TRACE`` / ``HANDYRL_TPU_TRACE_RATE``
-    wins over config values unless ``force`` (tests, bench A/B runs)."""
+    wins over config values unless ``force`` (tests, the services' runtime
+    'trace' op)."""
     if sample_rate is not None and (force or
                                     not os.environ.get('HANDYRL_TPU_TRACE_RATE')):
         _TRACE.rate = min(1.0, max(0.0, float(sample_rate)))
@@ -717,7 +716,7 @@ def configure_recorder(events: Optional[int] = None,
     """Adopt recorder geometry from the run config, mirrored into the
     environment so spawned children inherit it. Operator-set
     ``HANDYRL_TPU_RECORDER_EVENTS`` / ``HANDYRL_TPU_BLACKBOX`` win over
-    config values unless ``force`` (tests, bench A/B runs)."""
+    config values unless ``force`` (tests)."""
     global _BLACKBOX_DIR
     if events is not None and (force or
                                not os.environ.get('HANDYRL_TPU_RECORDER_EVENTS')):
@@ -827,8 +826,8 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 
-# Canonical ingest-path stage vocabulary, shared by StageTimer epoch lines,
-# BENCH_MODE=ingest rows, and the stage_seconds histogram family. The old
+# Canonical ingest-path stage vocabulary, shared by StageTimer epoch lines
+# and the stage_seconds histogram family. The old
 # aggregate 'compute' stage is decomposed into 'dispatch' (the async
 # compiled-step call returning) and 'host_block' (block_until_ready / lazy
 # metric fetch — the host pinned to the device stream), which is what the

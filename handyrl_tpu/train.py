@@ -199,17 +199,13 @@ class Batcher:
     ``SharedBatch`` wrappers whose ``release()`` hands the slot back.
 
     ``timer`` (utils.timing.StageTimer) aggregates the select/decode/
-    assemble stage breakdown across all batcher threads/processes;
-    ``build_fn`` swaps the batch builder (bench.py's ingest benchmark pins
-    the reference builder as its denominator through the SAME machinery).
+    assemble stage breakdown across all batcher threads/processes.
     """
 
-    def __init__(self, args: Dict[str, Any], episodes: deque,
-                 timer=None, build_fn=None):
+    def __init__(self, args: Dict[str, Any], episodes: deque, timer=None):
         self.args = args
         self.episodes = episodes
         self.timer = timer
-        self.build_fn = build_fn or make_batch
         # decoded-block LRU shared by every batcher THREAD (each spawned
         # process keeps its own); recency-biased selection re-reads the
         # same episodes constantly, so steady-state decode cost ~vanishes
@@ -319,8 +315,8 @@ class Batcher:
                 if self.timer is not None:
                     self.timer.add('select', time.perf_counter() - t0)
                 self._observe_lag(selected)
-                batch = self.build_fn(selected, self.args, timer=self.timer,
-                                      cache=self.cache)
+                batch = make_batch(selected, self.args, timer=self.timer,
+                                   cache=self.cache)
                 if telemetry.trace_enabled():
                     batch = TracedBatch(batch, _selected_trace_ids(selected))
             except (IndexError, ValueError):
@@ -427,8 +423,7 @@ class Trainer:
         self.steps = 0
         # per-stage ingest-path accounting (select/decode/assemble/ipc/h2d/
         # compute/drain), shared by the batcher threads/processes and the
-        # trainer loop; printed per epoch under HANDYRL_TPU_TIMING=1 and
-        # reported by bench.py's BENCH_MODE=ingest
+        # trainer loop; printed per epoch under HANDYRL_TPU_TIMING=1
         from .utils.timing import StageTimer
         self.ingest_timer = StageTimer(registry=telemetry.REGISTRY)
         self.batcher = Batcher(args, self.episodes, timer=self.ingest_timer)
@@ -468,20 +463,12 @@ class Trainer:
             self.replay_stats = {'dropped_episodes': 0,
                                  'windows_ingested': 0,
                                  'samples_drawn': 0}
-            # device-ingest mode (ops/device_windows.py): the learner
-            # installs a DeviceWindower when the env/config supports it;
-            # rollout chunks then arrive as device arrays on chunk_queue
-            # and windows are assembled straight into the ring in HBM
+            # device-ingest mode (ops/device_windows.py): the fused loop
+            # installs its DeviceWindower here and mirrors the ring's size
+            # in _ring_size_host, for ring_occupancy
             self.windower = None
-            self.chunk_queue: queue.Queue = queue.Queue(maxsize=4)
+            self._ring_size_host = 0
             self.seen_episodes = 0     # learner-fed count (no host deque)
-            self._ring = None
-            self._ring_state = None
-            self._ring_cursor = None
-            self._ring_size = None
-            self._ring_ready = False
-            self._ingest_key = jax.random.PRNGKey(args.get('seed', 0) + 2)
-            self._pending_ingest: List[Any] = []
         self.update_flag = False
         self.update_queue: queue.Queue = queue.Queue(maxsize=1)
         self._loss_sum: Dict[str, float] = {}
@@ -516,8 +503,7 @@ class Trainer:
 
     def build_replay_update(self, cfg: LossConfig):
         """The fused K-step replay trainer for ``cfg`` — the ONE place its
-        geometry is defined (the ingest gate rebuilds it when the device
-        'turn' layout serves an observation=True config)."""
+        geometry is defined."""
         from .ops.train_step import build_replay_update
         return build_replay_update(
             self.wrapper.module, cfg, capacity=self.replay.capacity,
@@ -525,12 +511,8 @@ class Trainer:
             default_lr=self.default_lr, mesh=self.mesh,
             state_shardings=self.state_sharding,
             # window shapes resolved at trace time (first update): by
-            # then either the windower ring (device ingest) or the
-            # DeviceReplay (host push) has seen its first windows
-            spec_fn=lambda: (
-                (self.windower.window_spec, None)
-                if getattr(self, 'windower', None) is not None
-                else (self.replay.window_spec, self.replay.treedef)))
+            # then the DeviceReplay has seen its first windows
+            spec_fn=lambda: (self.replay.window_spec, self.replay.treedef))
 
     def _lr(self) -> float:
         return self.default_lr * self.data_cnt_ema / (1 + self.steps * 1e-5)
@@ -695,21 +677,13 @@ class Trainer:
             if self.replay is not None:
                 # fused path: one dispatch = fused_steps SGD steps, with
                 # batch sampling, LR schedule and PRNG advance all on device
-                if self.windower is not None:
-                    self._ingest_device_chunks()
-                    if not self._ring_ready:
-                        time.sleep(0.1)
-                        continue
-                    buffers = self._ring
-                    size, cursor = self._ring_size, self._ring_cursor
-                else:
-                    self._ingest_new_episodes()
-                    if self.replay.size == 0:
-                        time.sleep(0.1)
-                        continue
-                    buffers = self.replay.buffers
-                    size = jnp.asarray(self.replay.size, jnp.int32)
-                    cursor = jnp.asarray(self.replay.cursor, jnp.int32)
+                self._ingest_new_episodes()
+                if self.replay.size == 0:
+                    time.sleep(0.1)
+                    continue
+                buffers = self.replay.buffers
+                size = jnp.asarray(self.replay.size, jnp.int32)
+                cursor = jnp.asarray(self.replay.cursor, jnp.int32)
                 # optional replay-ratio cap: the threaded trainer otherwise
                 # free-spins as fast as dispatch allows (implicit, hardware-
                 # dependent reuse — the reference's behavior); with
@@ -835,53 +809,11 @@ class Trainer:
         from .utils.fetch import fetch_tree
         return fetch_tree(self.state.params)
 
-    def _ingest_device_chunks(self):
-        """Drain rollout-record chunks (device arrays) into the HBM ring via
-        the windower's compiled ingest program. First chunk allocates the
-        history and ring buffers from the observed record shapes. This
-        thread is the single owner of ring/history state, so the program
-        donates them in place."""
-        ingested = 0
-        while ingested < 8:
-            try:
-                records = self.chunk_queue.get_nowait()
-            except queue.Empty:
-                break
-            ingested += 1
-            if self._ring is None:
-                self._ring_state = self.windower.init_state(records)
-                self._ring = self.windower.init_ring(records)
-                self._ring_cursor = jnp.zeros((), jnp.int32)
-                self._ring_size = jnp.zeros((), jnp.int32)
-            (self._ring_state, self._ring, self._ring_cursor,
-             self._ring_size, self._ingest_key, _n_done, n_win) = \
-                self.windower.ingest(records, self._ring_state, self._ring,
-                                     self._ring_cursor, self._ring_size,
-                                     self._ingest_key)
-            self._pending_ingest.append(n_win)
-        # fetch window counts lazily; the startup gate needs a real sync,
-        # and a configured reuse cap needs a CURRENT windows_ingested or it
-        # over-throttles by the un-flushed backlog
-        if self._pending_ingest and (not self._ring_ready
-                                     or len(self._pending_ingest) >= 8
-                                     or self.args.get('max_sample_reuse')):
-            total = int(sum(int(x) for x in self._pending_ingest))
-            self._pending_ingest = []
-            self.replay_stats['windows_ingested'] += total
-            # host mirror of the device ring size: other threads (metrics)
-            # must never touch _ring_size itself — it is donated in flight
-            self._ring_size_host = min(
-                getattr(self, '_ring_size_host', 0) + total,
-                self.replay.capacity)
-            if total > 0:
-                self._ring_ready = True
-
     def ring_occupancy(self) -> float:
         if self.replay is None:
             return 0.0
-        if getattr(self, 'windower', None) is not None:
-            return (getattr(self, '_ring_size_host', 0)
-                    / self.replay.capacity)
+        if self.windower is not None:
+            return self._ring_size_host / self.replay.capacity
         return self.replay.size / self.replay.capacity
 
     PUSH_CHUNK = 8   # fixed ring-push size => one XLA scatter compile
@@ -1028,11 +960,6 @@ class Trainer:
                and getattr(self, 'seen_episodes', 0)
                < self.args['minimum_episodes']
                and not self.shutdown_flag):
-            if getattr(self, 'windower', None) is not None:
-                # keep consuming rollout chunks while waiting: generation
-                # blocks on the chunk queue (stream contiguity), so the ring
-                # must fill during warmup too
-                self._ingest_device_chunks()
             time.sleep(0.1)
         if self.state is not None and not self.shutdown_flag:
             if self.replay is None:
@@ -2621,8 +2548,7 @@ class Learner:
         from .parallel.partition import pure_data_parallel
         mesh_fused_ok = (
             self.trainer.mesh is None
-            or (args.get('fused_pipeline', True)
-                and int(self.trainer.mesh.shape.get('model', 1)) == 1
+            or (int(self.trainer.mesh.shape.get('model', 1)) == 1
                 and pure_data_parallel(self.trainer.partition_rules)))
         want_ingest = (env_mod is not None and args.get('device_replay')
                        and args.get('device_ingest', True))
@@ -2659,18 +2585,13 @@ class Learner:
                 # with observation=False to match the layout.
                 ingest_mode = 'turn'
 
-        # the loss config the DEVICE pipelines train with: identical to
+        # the loss config the fused pipeline trains with: identical to
         # the host trainer's except when 'turn' ingest serves an
         # observation=True config (see the gate comment above)
         tr = self.trainer
         tr.device_cfg = tr.cfg
         if ingest_mode == 'turn' and args['observation']:
             tr.device_cfg = tr.cfg._replace(observation=False)
-            if tr.replay is not None:
-                # the threaded replay trainer samples windower rows in the
-                # compact layout too — rebuild its fused K-step program
-                # with the matching cfg (nothing traced yet at this point)
-                tr.replay_update = tr.build_replay_update(tr.device_cfg)
 
         opponents = args.get('eval', {}).get('opponent', []) or ['random']
 
@@ -2712,26 +2633,23 @@ class Learner:
             evaluator = BatchedEvaluator(make_env_fn, actor, args,
                                          n_envs=eval_envs)
 
-        def build_windower(mode):
+        if ingest_mode is not None:
+            # the fully-fused loop: rollout + ingest + K SGD steps per
+            # dispatch, driven single-threaded (ops/fused_pipeline.py)
             from .ops.device_windows import DeviceWindower
-            max_steps = _declared_max_steps(env_mod)
-            windows_cap = (args.get('replay_windows_per_episode')
-                           or max(1, 64 // args['forward_steps']))
-            return DeviceWindower(
-                mode=mode, fs=args['forward_steps'],
-                bi=args['burn_in_steps'], max_steps=max_steps,
-                windows_cap=windows_cap,
+            windower = DeviceWindower(
+                mode=ingest_mode, fs=args['forward_steps'],
+                bi=args['burn_in_steps'],
+                max_steps=_declared_max_steps(env_mod),
+                windows_cap=(args.get('replay_windows_per_episode')
+                             or max(1, 64 // args['forward_steps'])),
                 # on a mesh each shard owns ring_capacity/n_dev rows; the
                 # global ring keeps the configured total budget
                 capacity=max(1, self.trainer.replay.capacity // n_dev),
                 num_players=env_mod.NUM_PLAYERS, gamma=args['gamma'],
                 has_reward=hasattr(env_mod, 'rewards'))
-
-        if ingest_mode is not None and args.get('fused_pipeline', True):
-            # the fully-fused loop: rollout + ingest + K SGD steps per
-            # dispatch, driven single-threaded (ops/fused_pipeline.py)
-            return self._run_fused(env_mod, actor, evaluator,
-                                   build_windower(ingest_mode), ingest_mode)
+            return self._run_fused(env_mod, actor, evaluator, windower,
+                                   ingest_mode)
 
         gen = None
         if env_mod is not None:
@@ -2743,16 +2661,6 @@ class Learner:
         if gen is None:
             gen = BatchedGenerator(make_env_fn, actor, args,
                                    n_envs=args.get('generation_envs', 64))
-
-        # device ingest: trajectories never leave the accelerator — rollout
-        # records flow straight into the windower's HBM ring; the host does
-        # episode accounting from the (done, outcome) arrays only
-        device_ingest = False
-        if ingest_mode is not None:
-            self.trainer.windower = build_windower(ingest_mode)
-            device_ingest = True
-            print('device ingest: windows assembled on device '
-                  '(%s mode)' % ingest_mode)
 
         cadence = _EpochCadence(args)
         actor_epoch = self.model_epoch
@@ -2784,28 +2692,12 @@ class Learner:
                 actor.params = put_tree(self.wrapper.params)
                 actor_epoch = self.model_epoch
             dispatch_epoch = self.model_epoch
-            if device_ingest:
-                records, done, outcome = gen.step_chunk_records()
-                self.feed_device_chunk(done, outcome, chunk_epoch)
-                self.trainer.seen_episodes = self.num_returned_episodes
-                # BLOCKING hand-off: the windower's per-env histories track
-                # a contiguous ply stream, so dropping a chunk would splice
-                # different episodes together — backpressure generation
-                # instead (the trainer drains chunks even while it waits
-                # for minimum_episodes)
-                while not self.shutdown_flag and not self.preempt.requested():
-                    try:
-                        self.trainer.chunk_queue.put(records, timeout=1.0)
-                        break
-                    except queue.Full:
-                        continue
-            else:
-                # pipelined generators return the PREVIOUS dispatch's
-                # episodes (stamp with that dispatch's epoch); host-path
-                # generators return episodes finished under current params
-                stamp_and_feed(gen.step(),
-                               chunk_epoch if getattr(gen, 'pipelined', False)
-                               else dispatch_epoch)
+            # pipelined generators return the PREVIOUS dispatch's
+            # episodes (stamp with that dispatch's epoch); host-path
+            # generators return episodes finished under current params
+            stamp_and_feed(gen.step(),
+                           chunk_epoch if getattr(gen, 'pipelined', False)
+                           else dispatch_epoch)
             chunk_epoch = dispatch_epoch
 
             self._run_eval_share(evaluator, eval_tracker)
@@ -2816,12 +2708,7 @@ class Learner:
                     self.shutdown_flag = True
 
         # account the one speculative chunk still in the pipeline
-        if hasattr(gen, 'drain_records') and device_ingest:
-            tail = gen.drain_records()
-            if tail is not None:
-                _records, done, outcome = tail
-                self.feed_device_chunk(done, outcome, chunk_epoch)
-        elif hasattr(gen, 'drain_episodes'):
+        if hasattr(gen, 'drain_episodes'):
             stamp_and_feed(gen.drain_episodes(), chunk_epoch)
         if hasattr(evaluator, 'drain'):
             self.feed_results(evaluator.drain(),

@@ -8,8 +8,7 @@ them hides inside an aggregate episodes/sec number. ``StageTimer``
 accumulates wall seconds and event counts per named stage from any thread
 (batcher threads and the trainer thread share one instance), and the
 ``HANDYRL_TPU_TIMING=1`` hook prints one compact JSON line per epoch with
-the breakdown — the same stage names ``BENCH_MODE=ingest`` (bench.py)
-reports, so a bench row and a live-run epoch line are directly comparable.
+the breakdown.
 
 Canonical stage names for the ingest path (telemetry.INGEST_STAGES is the
 one authoritative tuple):
@@ -40,11 +39,11 @@ class StageTimer:
 
     ``registry`` (a telemetry.MetricRegistry) mirrors every ``add`` into
     the ``stage_seconds{stage=...}`` span-histogram family, so the same
-    measurements that feed the per-epoch timing line and the ingest bench
-    also feed the fleet-wide telemetry/exporter view — and, when episode
+    measurements that feed the per-epoch timing line also feed the
+    fleet-wide telemetry/exporter view — and, when episode
     tracing is active (``HANDYRL_TPU_TRACE``), each registry-mirrored add
     also lands as a rate-sampled batch-level span in the trace file (one
-    vocabulary for bench rows, timing lines, histograms and traces).
+    vocabulary for timing lines, histograms and traces).
     """
 
     def __init__(self, registry=None):

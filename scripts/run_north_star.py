@@ -1,7 +1,7 @@
 """North-star quality run: long Hungry Geese self-play on the device pipeline.
 
 BASELINE.json's quality metric is Hungry Geese win-rate-vs-random at scale
-(the throughput half is covered by bench.py / run_benchmark_matrix.py).
+(speed is measured by benchmark/, on the chip).
 This driver runs the geese-device config for as many episodes as the
 wall-clock allows, writing one metrics-JSONL row per epoch (win_rate,
 episodes, sgd steps) so scripts/north_star_curve.py can plot the

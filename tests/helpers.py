@@ -32,6 +32,37 @@ def turn_based_episode(steps=5, obs_shape=(3, 3, 3), n_actions=9, seed=None):
     }
 
 
+def _synthetic_geese_episodes(n_eps, rng, compress_steps=4, num_players=4,
+                              min_steps=24, max_steps=96):
+    """Buffered-episode stand-ins at the HungryGeese record geometry:
+    (17, 7, 11) float32 observation planes per player per ply, 4 actions,
+    all seats acting every ply (simultaneous env, solo-training config).
+    Planes are sparse binary like real goose boards."""
+    players = list(range(num_players))
+    eps = []
+    for _ in range(n_eps):
+        steps = int(rng.randint(min_steps, max_steps + 1))
+        moments = []
+        for _t in range(steps):
+            moments.append({
+                'observation': {p: (rng.rand(17, 7, 11) < 0.08)
+                                .astype(np.float32) for p in players},
+                'selected_prob': {p: float(rng.rand()) for p in players},
+                'action_mask': {p: np.zeros(4, np.float32) for p in players},
+                'action': {p: int(rng.randint(4)) for p in players},
+                'value': {p: np.array([float(rng.rand())], np.float32)
+                          for p in players},
+                'reward': {p: 0.0 for p in players},
+                'return': {p: float(rng.rand()) - 0.5 for p in players},
+                'turn': players,
+            })
+        eps.append({'args': {'player': players}, 'steps': steps,
+                    'outcome': {p: float(np.sign(rng.randn()))
+                                for p in players},
+                    'moment': compress_moments(moments, compress_steps)})
+    return eps
+
+
 def ragged_act_rows(n, n_actions=9, obs_shape=(3, 3, 3), hidden_dim=None,
                     seed=0):
     """Shared ragged-row fixture: ``n`` act requests with mixed legal-action

@@ -220,9 +220,9 @@ def test_ingest_fills_ring_and_counts_episodes():
                         has_reward=False)
     state = wd.init_state(records)
     ring = wd.init_ring(records)
-    state, ring, cursor, size, key, n_done, n_windows = wd.ingest(
-        records, state, ring, jnp.int32(0), jnp.int32(0),
-        jax.random.PRNGKey(0))
+    state, ring, cursor, size, key, n_done, n_windows = jax.jit(
+        wd.ingest_fn())(records, state, ring, jnp.int32(0), jnp.int32(0),
+                        jax.random.PRNGKey(0))
     # 2 envs x 2 episodes each completed in 6 plies
     assert int(n_done) == 4
     assert int(n_windows) == 4
@@ -264,9 +264,9 @@ def test_ingest_with_pytree_observations():
     state = wd.init_state(records)
     ring = wd.init_ring(records)
     assert 'observation.scalar' in ring and 'observation.board' in ring
-    state, ring, cursor, size, key, n_done, n_windows = wd.ingest(
-        records, state, ring, jnp.int32(0), jnp.int32(0),
-        jax.random.PRNGKey(0))
+    state, ring, cursor, size, key, n_done, n_windows = jax.jit(
+        wd.ingest_fn())(records, state, ring, jnp.int32(0), jnp.int32(0),
+                        jax.random.PRNGKey(0))
     assert int(n_done) == 4 and int(size) == 4
     got = wd.unflatten_rows(
         jax.tree_util.tree_map(lambda b: np.asarray(b[:4]), ring))
@@ -397,7 +397,8 @@ def _windower_pair(mode, has_reward, bi):
         mode=mode, fs=PFS, bi=bi, max_steps=PL, windows_cap=PW,
         capacity=PCAP, num_players=3 if mode == 'solo' else 2, gamma=GAMMA,
         has_reward=has_reward)
-    return make(OracleWindower), make(DeviceWindower)
+    return tuple((wd, jax.jit(wd.ingest_fn()))
+                 for wd in (make(OracleWindower), make(DeviceWindower)))
 
 
 @pytest.mark.parametrize('pattern', sorted(DONE_PATTERNS))
@@ -409,13 +410,14 @@ def _windower_pair(mode, has_reward, bi):
 def test_ingest_is_bit_identical_to_the_all_lane_builder(
         mode, has_reward, obs_kind, bi, pattern):
     done_all = DONE_PATTERNS[pattern]
-    oracle, new = _windower_pair(mode, has_reward, bi)
+    pair = _windower_pair(mode, has_reward, bi)
+    (oracle, _), (new, _) = pair
     rng = np.random.RandomState(len(pattern) + bi)
     sides = []
-    for wd in (oracle, new):
+    for wd, ingest in pair:
         first = _parity_records(np.random.RandomState(0), mode, has_reward,
                                 obs_kind, done_all[:PK], wd.P)
-        sides.append([wd, wd.init_state(first), wd.init_ring(first),
+        sides.append([ingest, wd.init_state(first), wd.init_ring(first),
                       jnp.int32(0), jnp.int32(0), jax.random.PRNGKey(7)])
     total = 0
     for c in range(len(done_all) // PK):
@@ -423,9 +425,8 @@ def test_ingest_is_bit_identical_to_the_all_lane_builder(
                                   done_all[c * PK:(c + 1) * PK], oracle.P)
         outs = []
         for side in sides:
-            wd = side[0]
-            out = wd.ingest(jax.tree_util.tree_map(jnp.asarray, records),
-                            *side[1:])
+            out = side[0](jax.tree_util.tree_map(jnp.asarray, records),
+                          *side[1:])
             side[1:] = out[:5]
             outs.append(out)
         (_, ring_o, cur_o, size_o, key_o, done_o, win_o), \

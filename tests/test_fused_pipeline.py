@@ -129,38 +129,12 @@ def test_geister_fused_pipeline_learner(tmp_path, capsys):
     assert (tmp_path / 'models' / '2.ckpt').exists()
 
 
-@pytest.mark.timeout(600)
-def test_geister_threaded_turn_ingest(tmp_path, capsys):
-    """fused_pipeline: False with an observation=True turn-based env:
-    the THREADED device-ingest path must train with the rebuilt
-    (observation=False) replay program against the compact windower rows
-    — the Trainer.build_replay_update relayering, not the fused path."""
-    from handyrl_tpu.models.geister import GeisterNet
-
-    raw = {
-        'env_args': {'env': 'Geister'},
-        'train_args': {
-            'turn_based_training': True, 'observation': True,
-            'gamma': 0.9, 'forward_steps': 2, 'burn_in_steps': 0,
-            'compress_steps': 2, 'batch_size': 4, 'update_episodes': 4,
-            'minimum_episodes': 4, 'epochs': 1, 'generation_envs': 4,
-            'num_batchers': 1, 'device_generation': True,
-            'device_replay': True, 'fused_pipeline': False,
-            'replay_fused_steps': 2, 'device_chunk_steps': 8,
-            'model_dir': str(tmp_path / 'models'),
-        },
-    }
-    args = apply_defaults(raw)
-    learner = Learner(args=args,
-                      net=GeisterNet(filters=4, drc_layers=1,
-                                     drc_repeats=1))
-    learner.run()
-    out = capsys.readouterr().out
-    assert 'device ingest: windows assembled on device' in out
-    assert learner.model_epoch == 1
-    assert learner.trainer.steps > 0
-    assert learner.trainer.device_cfg.observation is False
-    assert (tmp_path / 'models' / '1.ckpt').exists()
+@pytest.mark.parametrize('value', [False, True])
+def test_a_fused_pipeline_key_is_refused(tmp_path, value):
+    """The switch went with the split learner it selected: a config that
+    still sets it must not silently run something else."""
+    with pytest.raises(ValueError, match='only device-ingest learner'):
+        apply_defaults(_ttt_raw(tmp_path, fused_pipeline=value))
 
 
 @pytest.mark.timeout(600)

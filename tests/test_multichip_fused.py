@@ -207,20 +207,18 @@ def test_fused_run_leaves_the_all_lane_builders_ring(sharded):
 
 
 @pytest.mark.timeout(560)
-def test_both_consumers_read_a_padded_ring_as_an_unpadded_one(monkeypatch):
+def test_the_fused_sample_reads_a_padded_ring_as_an_unpadded_one(monkeypatch):
     """The ring's wide rows are padded to whole 128-lane tiles (TicTacToe at
-    eight plies a window: 216 observation values stored as 256). Both
-    consumers must hand the update step the batch they would gather from
-    rows of the logical width holding the same windows (the oracle's ring,
-    tests/windower_oracle.py): the fused learner's ``sample`` (two
-    dispatches of two steps, same seed, so the same games and slots) and
-    the split path's ``build_replay_update`` (same key, size and cursor).
-    The update step is replaced by one that shows its batch."""
+    eight plies a window: 216 observation values stored as 256). The fused
+    learner's ``sample`` must hand the update step the batch it would gather
+    from rows of the logical width holding the same windows (the oracle's
+    ring, tests/windower_oracle.py; two dispatches of two steps, same seed,
+    so the same games and slots). The update step is replaced by one that
+    shows its batch."""
     import jax.numpy as jnp
 
     from handyrl_tpu.ops import fused_pipeline, train_step
     from handyrl_tpu.ops.device_windows import DeviceWindower, _row_width
-    from handyrl_tpu.ops.losses import LossConfig
     from windower_oracle import OracleWindower
 
     seen = []
@@ -232,17 +230,10 @@ def test_both_consumers_read_a_padded_ring_as_an_unpadded_one(monkeypatch):
         return update
     monkeypatch.setattr(fused_pipeline, '_update_core', showing_update)
 
-    sides, windowers = [], []
-
-    def remembered(cls):
-        def make(**kwargs):
-            windowers.append(cls(**kwargs))
-            return windowers[-1]
-        return make
-
+    sides = []
     for cls in (OracleWindower, DeviceWindower):
         fp, params = _ttt_pipeline(None, fs=8, windows_cap=1, capacity=64,
-                                   windower_cls=remembered(cls))
+                                   windower_cls=cls)
         train_state = train_step.init_train_state(
             jax.tree_util.tree_map(lambda x: x + 0, params))
         fp.warm_step(params)
@@ -264,20 +255,3 @@ def test_both_consumers_read_a_padded_ring_as_an_unpadded_one(monkeypatch):
         assert got['observation'].shape == (16, 8, 1, 3, 3, 3)
         assert np.asarray(got['episode_mask']).any()
         jax.tree_util.tree_map(np.testing.assert_array_equal, got, want)
-
-    # the split path over the two rings the fused runs left
-    monkeypatch.setattr(
-        train_step, '_update_core',
-        lambda *args, **kwargs: lambda state, batch, lr: (state, batch))
-    batches = []
-    for fp, wd in zip((old, new), windowers):
-        update = train_step.build_replay_update(
-            None, LossConfig(), capacity=fp.capacity, batch_size=16,
-            num_steps=1, spec_fn=lambda wd=wd: (wd.window_spec, None))
-        # one step, so the metrics' sum over the steps is the batch itself
-        batches.append(update(
-            train_step.init_train_state({'w': jnp.zeros(())}), fp.ring,
-            jax.random.PRNGKey(3), fp.size, fp.cursor, jnp.float32(1.0))[2])
-    assert batches[1]['observation'].shape == (16, 8, 1, 3, 3, 3)
-    assert np.asarray(batches[1]['observation']).any()
-    jax.tree_util.tree_map(np.testing.assert_array_equal, *batches[::-1])

@@ -1,13 +1,8 @@
 """Compiled-performance plane: device-memory gauges (stubbed accelerator
 stats + the CPU RSS fallback), the steady-state retrace sentinel (counting,
 flight-recorder events, warn/abort policies), the dispatch/host_block span
-split on a real compiled CPU train step, and the perf_gate.py exit
-contract against synthetic benchmarks.jsonl fixtures."""
+split on a real compiled CPU train step."""
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -17,11 +12,6 @@ import pytest
 
 from handyrl_tpu import telemetry
 from handyrl_tpu.model import ModelWrapper  # noqa: F401 (env setup parity)
-
-SCRIPTS = os.path.join(os.path.dirname(__file__), '..', 'scripts')
-sys.path.insert(0, os.path.abspath(SCRIPTS))
-
-import perf_gate  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -292,96 +282,6 @@ def test_statusz_render_includes_perf_block():
     assert 'steady' in out and 'retraces=2' in out
     assert 'device_util=80%' in out and 'mem_util=40%' in out
     assert 'process_rss' in out
-
-
-# ---------------------------------------------------------------------------
-# perf-regression gate
-
-
-def _hist(tmp_path, rows, name='hist.jsonl'):
-    path = tmp_path / name
-    path.write_text('\n'.join(json.dumps(r) for r in rows) + '\n')
-    return str(path)
-
-
-def _row(value, **kw):
-    row = {'row': 'bench-ingest', 'value': value, 'backend': 'cpu',
-           'geometry': 'headline'}
-    row.update(kw)
-    return row
-
-
-def test_perf_gate_passes_fresh_row_within_tolerance(tmp_path):
-    hist = _hist(tmp_path, [_row(40.0), _row(42.0), _row(41.0)])
-    fresh = _hist(tmp_path, [_row(39.0)], 'fresh.json')
-    assert perf_gate.main(['--history', hist, '--fresh', fresh]) == 0
-
-
-def test_perf_gate_fails_regressed_row(tmp_path):
-    hist = _hist(tmp_path, [_row(40.0), _row(42.0), _row(41.0)])
-    fresh = _hist(tmp_path, [_row(20.0)], 'fresh.json')
-    assert perf_gate.main(['--history', hist, '--fresh', fresh]) == 1
-
-
-def test_perf_gate_insufficient_history_exit_2_or_allowed(tmp_path):
-    hist = _hist(tmp_path, [_row(40.0)])
-    fresh = _hist(tmp_path, [_row(5.0)], 'fresh.json')
-    argv = ['--history', hist, '--fresh', fresh]
-    assert perf_gate.main(argv) == 2
-    assert perf_gate.main(argv + ['--allow-insufficient']) == 0
-
-
-def test_perf_gate_tolerates_pre_v2_rows(tmp_path):
-    """Rows without a numeric value (pre-schema-v2 history) are skipped,
-    not crashed on, and do not count as history."""
-    hist = _hist(tmp_path, [
-        {'row': 'bench-ingest', 'note': 'ancient row, no value'},
-        {'row': 'bench-ingest', 'value': 'n/a'},
-        _row(40.0), _row(42.0)])
-    fresh = _hist(tmp_path, [_row(41.0)], 'fresh.json')
-    assert perf_gate.main(['--history', hist, '--fresh', fresh]) == 0
-
-
-def test_perf_gate_degraded_rows_never_gate_or_enter_history(tmp_path):
-    # degraded history rows are excluded from the baseline...
-    hist = _hist(tmp_path, [_row(40.0), _row(42.0),
-                            _row(2.0, degraded=True)])
-    fresh = _hist(tmp_path, [_row(39.0)], 'fresh.json')
-    assert perf_gate.main(['--history', hist, '--fresh', fresh]) == 0
-    # ...and a degraded fresh row is skipped, not diffed against silicon
-    deg = _hist(tmp_path, [_row(2.0, degraded=True)], 'deg.json')
-    assert perf_gate.main(['--history', hist, '--fresh', deg,
-                           '--allow-insufficient']) == 0
-
-
-def test_perf_gate_newest_history_row_gates_without_fresh(tmp_path):
-    hist = _hist(tmp_path, [_row(40.0), _row(42.0), _row(10.0)])
-    assert perf_gate.main(['--history', hist]) == 1
-
-
-def test_perf_gate_tolerance_override_and_baseline_update(tmp_path):
-    hist = _hist(tmp_path, [_row(40.0), _row(42.0)])
-    fresh = _hist(tmp_path, [_row(30.0)], 'fresh.json')
-    # 41 -> 30 is ~-27%: fails at 10% tolerance, passes at 40%
-    assert perf_gate.main(['--history', hist, '--fresh', fresh,
-                           '--tolerance', 'bench-ingest=10']) == 1
-    assert perf_gate.main(['--history', hist, '--fresh', fresh,
-                           '--tolerance', 'bench-ingest=40']) == 0
-    base = str(tmp_path / 'base.json')
-    assert perf_gate.main(['--history', hist, '--fresh', fresh,
-                           '--update-baseline', '--baseline', base]) == 0
-    pinned = json.loads(open(base).read())
-    assert pinned == {'bench-ingest|cpu|headline': 40.0}
-
-
-def test_perf_gate_cli_entry(tmp_path):
-    """The script is runnable as a CI step (python scripts/perf_gate.py)."""
-    hist = _hist(tmp_path, [_row(40.0), _row(42.0), _row(41.0)])
-    out = subprocess.run(
-        [sys.executable, os.path.join(SCRIPTS, 'perf_gate.py'),
-         '--history', hist], capture_output=True, text=True)
-    assert out.returncode == 0, out.stdout + out.stderr
-    assert 'PASS' in out.stdout
 
 
 # ---------------------------------------------------------------------------

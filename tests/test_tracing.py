@@ -227,8 +227,7 @@ def test_span_is_a_profiler_annotation_on_the_host_plane(tmp_path):
 
 def _synthetic_task_episode(sample_key=7, model_epoch=1):
     """One geese-geometry episode stamped like a served generation task."""
-    sys.path.insert(0, REPO)
-    from bench import _synthetic_geese_episodes
+    from helpers import _synthetic_geese_episodes
     rng = np.random.RandomState(3)
     ep = _synthetic_geese_episodes(1, rng, min_steps=24, max_steps=24)[0]
     players = ep['args']['player']

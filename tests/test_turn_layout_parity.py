@@ -129,11 +129,10 @@ def test_wide_and_compact_layouts_train_identically(wide_batch_and_params):
         np.testing.assert_allclose(
             float(aux_w['losses'][k]), float(aux_c['losses'][k]),
             rtol=1e-5, atol=1e-6, err_msg=k)
-    # gradient criterion is RELATIVE to each leaf's own scale (the
-    # hbm_experiments parity-gate approach): a fixed absolute band is wrong
-    # in both directions — float32 grads of scale ~5 legitimately differ by
-    # a few e-6 between the two scan splits, while a tiny-scale leaf could
-    # hide a real bug under the same band
+    # gradient criterion is RELATIVE to each leaf's own scale: a fixed
+    # absolute band is wrong in both directions — float32 grads of scale ~5
+    # legitimately differ by a few e-6 between the two scan splits, while a
+    # tiny-scale leaf could hide a real bug under the same band
     flat_w = jax.tree_util.tree_leaves(grads_w)
     flat_c = jax.tree_util.tree_leaves(grads_c)
     for gw, gc in zip(flat_w, flat_c):
