@@ -316,7 +316,14 @@ def trace_event(name: str, ts: Optional[float] = None, dur: float = 0.0,
 
 # -- spans: the one way the program times a section
 
-SPAN_RING_SIZE = 16384     # a 51 s window of 100 ms chunks at ten spans each
+# The ring has to hold one benchmark window whole, with the set-up before it
+# (benchmark/readers take a counter's growth from the last record BEFORE the
+# window's opening). A 51 s window of 16 ms chunks is ~3,200 loop iterations
+# x 5 spans + ~1,800 epoch boundaries x 10 spans (the writer thread's
+# included) = 34,000 spans, set-up's few hundred on top: the size leaves a
+# factor of nearly two. Only :func:`spans` copies the ring, and only
+# readers call it: nothing on the per-chunk path does.
+SPAN_RING_SIZE = 65536
 SPAN_ANNOTATION_PREFIX = 'handyrl:'
 
 _SPAN_LOCK = threading.Lock()

@@ -37,7 +37,8 @@ import threading
 import time
 import warnings
 from collections import deque
-from typing import Any, Dict, List, Optional
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -348,6 +349,17 @@ class Batcher:
         # names when the (daemon) children die with us.
 
 
+def train_state_bytes(host_state: TrainState, steps: int,
+                      data_cnt_ema: float) -> bytes:
+    """``trainer_state.ckpt``'s bytes from plain host values: what
+    :meth:`Trainer.load_state_bytes` reads back. Takes the counters as
+    arguments so that a checkpoint captured at one epoch boundary can be
+    serialised while the live trainer has moved on."""
+    from flax import serialization
+    return serialization.to_bytes({'state': host_state, 'steps': steps,
+                                   'data_cnt_ema': data_cnt_ema})
+
+
 class Trainer:
     """SGD loop thread: compiled update step + EMA learning-rate schedule."""
 
@@ -546,14 +558,11 @@ class Trainer:
     # lost on resume, docs/parameters.md:76-82); here the whole TrainState
     # round-trips so restarts continue the same optimization trajectory.
     def state_bytes(self, host_state: Optional[TrainState] = None) -> bytes:
-        from flax import serialization
         from .utils.fetch import fetch_tree
         # fetch the whole state in one packed transfer first: serialization
         # walks leaves with np.asarray — one blocking transfer per leaf
         state = host_state if host_state is not None else fetch_tree(self.state)
-        payload = {'state': state, 'steps': self.steps,
-                   'data_cnt_ema': self.data_cnt_ema}
-        return serialization.to_bytes(payload)
+        return train_state_bytes(state, self.steps, self.data_cnt_ema)
 
     def place_state(self, state: TrainState) -> TrainState:
         """Lay a (host or misplaced) TrainState out per the partition
@@ -1037,6 +1046,67 @@ class _EpochCadence:
         return False
 
 
+class _CheckpointJob(NamedTuple):
+    """One epoch's checkpoint as plain host values, all captured at that
+    epoch's boundary: what :meth:`Learner._write_checkpoint` turns into
+    files and :meth:`Learner._announce_checkpoint` then tells the registry,
+    the retention GC and the durable plane about."""
+    epoch: int
+    steps: int
+    params: Any                     # host (numpy) params
+    state: Optional[TrainState]     # host train state still to serialise...
+    state_blob: Optional[bytes]     # ...or the trainer thread's own bytes
+    data_cnt_ema: float
+    layout: Dict[str, Any]          # parallel.partition.checkpoint_layout
+    durable: Dict[str, int]         # Learner._durable_marks
+
+
+class _CheckpointWriter:
+    """The one thread beside the fused loop that serialises and writes
+    checkpoints, ONE job at a time in hand-over order: ``submit`` refuses a
+    second job while one is outstanding, so no epoch's write is ever
+    skipped, merged or overtaken. Everything but the write itself stays
+    with the loop thread, which asks ``busy`` and calls ``wait``."""
+
+    def __init__(self, write):
+        self._write = write
+        self._pool: Optional[ThreadPoolExecutor] = None
+        self._pending = None            # (job, future) of the job in flight
+
+    def submit(self, job: _CheckpointJob):
+        if self._pending is not None:
+            raise RuntimeError('checkpoint writer: epoch %d handed over '
+                               'with a write outstanding' % job.epoch)
+        if self._pool is None:          # the first boundary of a fused run
+            self._pool = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix='checkpoint-writer')
+        self._pending = (job, self._pool.submit(self._write, job))
+
+    def busy(self) -> bool:
+        return self._pending is not None and not self._pending[1].done()
+
+    def wait(self):
+        """Block until the job in flight is on disk; returns ``(job,
+        seconds blocked)``, ``(None, 0.0)`` with none outstanding. What the
+        write raised is raised here, on the caller's thread."""
+        pending, self._pending = self._pending, None
+        if pending is None:
+            return None, 0.0
+        job, future = pending
+        t0 = time.perf_counter()
+        try:
+            future.result()
+        finally:
+            waited = time.perf_counter() - t0
+            telemetry.counter('checkpoint_wait_seconds_total').inc(waited)
+        return job, waited
+
+    def close(self):
+        if self._pool is not None:
+            self._pool.shutdown(wait=True)
+            self._pool = None
+
+
 class Learner:
     """Central conductor: owns the model, episode/eval accounting, epoch
     cadence, checkpoints, and the generation front-end."""
@@ -1109,6 +1179,7 @@ class Learner:
         self._fused_trained = False   # a fused train dispatch has returned
         self._last_ckpt_epoch = -1
         self._last_ckpt_steps = -1
+        self._ckpt_writer = _CheckpointWriter(self._write_checkpoint)
 
         self.model_epoch = args['restart_epoch']
         module = net if net is not None else self.env.net()
@@ -1500,10 +1571,12 @@ class Learner:
             'run_id': str(self.args.get('run_id')),
             'generation': self._run_generation}
 
-    def _sync_durable_state(self):
-        """Epoch-sync the durable plane (rides every checkpoint write):
-        republish the ledger snapshot — folding the delta journal — and
-        GC spool segments behind the new consumption horizon."""
+    def _durable_marks(self) -> Dict[str, int]:
+        """What :meth:`_sync_durable_state` will publish for the checkpoint
+        being captured NOW: the episode counters and the spool's
+        consumption horizon as they stand at this epoch boundary (a
+        checkpoint written beside the fused loop becomes durable later,
+        when the counters have moved on)."""
         # the consumption horizon holds back to the oldest OPEN streamed
         # assembly's first WAL mark: a restart must be able to replay every
         # window of a partially-delivered episode, even ones spooled before
@@ -1512,18 +1585,24 @@ class Learner:
         open_mark = self._assembler.min_open_mark()
         if open_mark is not None:
             horizon = min(horizon, int(open_mark))
+        return {'num_episodes': self.num_episodes,
+                'num_results': self.num_results,
+                'num_returned_episodes': self.num_returned_episodes,
+                'spool_horizon': horizon}
+
+    def _sync_durable_state(self, marks: Dict[str, int]):
+        """Epoch-sync the durable plane (rides every checkpoint write):
+        republish the ledger snapshot — folding the delta journal — and
+        GC spool segments behind the consumption horizon of ``marks``
+        (:meth:`_durable_marks` at the boundary of the checkpoint that has
+        just become durable)."""
         if self.ledger is not None and self._ledger_journal is not None:
             self.ledger.flush_journal()
             state = self.ledger.snapshot_state()
-            state['extra'] = {
-                'num_episodes': self.num_episodes,
-                'num_results': self.num_results,
-                'num_returned_episodes': self.num_returned_episodes,
-                'spool_horizon': horizon,
-            }
+            state['extra'] = dict(marks)
             self._ledger_journal.snapshot(state)
         if self._spool is not None:
-            self._spool_horizon = horizon
+            self._spool_horizon = marks['spool_horizon']
             self._spool.gc(self._spool_horizon)
 
     # -- checkpoints ------------------------------------------------------
@@ -1543,7 +1622,24 @@ class Learner:
                      write_files: bool = True):
         """Advance the model epoch; persist snapshot + ckpt files unless
         ``write_files`` is False (checkpoint_interval's skip epochs, where
-        params never leave the device)."""
+        params never leave the device). Synchronous: when it returns the
+        files are on disk and announced. (The threaded/server learner's
+        epoch close and every final flush: SGD runs on the trainer thread
+        meanwhile, and a worker may ask for this epoch's file at once.)"""
+        job = self._advance_epoch(params, steps, state_blob=state_blob,
+                                  bump=bump, write_files=write_files)
+        if job is not None:
+            self._write_checkpoint(job)
+            self._announce_checkpoint(job)
+
+    def _advance_epoch(self, params, steps: int,
+                       state: Optional[TrainState] = None,
+                       state_blob: Optional[bytes] = None, bump: bool = True,
+                       write_files: bool = True) -> Optional[_CheckpointJob]:
+        """The part of an epoch close that only the loop thread may do:
+        bump the epoch, take the host params as the learner's snapshot, and
+        capture the checkpoint as a job of plain values (None on a
+        ``write_files`` False epoch)."""
         print('updated model(%d)' % steps)
         if bump:
             self.model_epoch += 1
@@ -1552,50 +1648,105 @@ class Learner:
             if self._chaos.get('nanepoch') == self.model_epoch:
                 self.trainer.chaos_nan.arm(self.trainer.steps + 1)
         if not write_files:
-            return
+            return None
         self._last_ckpt_epoch = self.model_epoch
         self._last_ckpt_steps = steps
         # learner-side copy stays on HOST (numpy): it only feeds
         # snapshots/checkpoints; a device copy would cost one upload
         # per leaf each epoch for nothing
+        self.wrapper.params = jax.tree_util.tree_map(np.asarray, params)
+        # A mesh-layout manifest rides along: checkpoints serialize full
+        # host arrays, so they restore under ANY device/host count — the
+        # manifest records what wrote them so the mesh change is logged,
+        # and a corrupt manifest disqualifies the pair like a bad CRC.
+        from .parallel.partition import checkpoint_layout
+        return _CheckpointJob(
+            epoch=self.model_epoch, steps=steps, params=self.wrapper.params,
+            state=state, state_blob=state_blob,
+            data_cnt_ema=self.trainer.data_cnt_ema,
+            layout=checkpoint_layout(self.trainer.mesh,
+                                     self.trainer.partition_rules,
+                                     steps=steps),
+            durable=self._durable_marks())
+
+    def _write_checkpoint(self, job: _CheckpointJob):
+        """``job``'s bytes and files: ``<epoch>.ckpt``, ``latest.ckpt`` and
+        ``trainer_state.ckpt``, in that order. Reads nothing but ``job`` and
+        the run's paths, so it runs inline (:meth:`update_model`) or on the
+        fused loop's writer thread (:meth:`_hand_over_checkpoint`) alike."""
+        from flax import serialization
+        from .utils.fs import write_layout_manifest
+        state_blob = job.state_blob
+        if job.state is not None:
+            with telemetry.trace_span('checkpoint_serialize') as span:
+                state_blob = train_state_bytes(job.state, job.steps,
+                                               job.data_cnt_ema)
+                span.set(bytes=len(state_blob))
         with telemetry.trace_span('checkpoint_serialize') as span:
-            self.wrapper.params = jax.tree_util.tree_map(np.asarray, params)
-            raw = self.wrapper.params_bytes()
+            raw = serialization.to_bytes(job.params)
             span.set(bytes=len(raw))
         os.makedirs(self.args.get('model_dir', 'models'), exist_ok=True)
         # atomic (temp + fsync + rename) plus a CRC32 sidecar manifest: a
         # crash mid-write must never leave a truncated latest.ckpt /
         # trainer_state.ckpt, and resume verifies the checksum so silent
         # on-disk corruption falls back instead of poisoning the restart.
-        # A mesh-layout manifest rides along: checkpoints serialize full
-        # host arrays, so they restore under ANY device/host count — the
-        # manifest records what wrote them so the mesh change is logged,
-        # and a corrupt manifest disqualifies the pair like a bad CRC.
-        from .parallel.partition import checkpoint_layout
-        from .utils.fs import write_layout_manifest
-        layout = checkpoint_layout(self.trainer.mesh,
-                                   self.trainer.partition_rules, steps=steps)
         with telemetry.trace_span('checkpoint_write') as span:
-            blobs = [(self.model_path(self.model_epoch), raw),
+            blobs = [(self.model_path(job.epoch), raw),
                      (self.latest_model_path(), raw)]
             if state_blob is not None:
                 blobs.append((self.trainer_state_path(), state_blob))
             for path, blob in blobs:
                 checksummed_write_bytes(path, blob)
-                write_layout_manifest(path, layout)
+                write_layout_manifest(path, job.layout)
             span.set(files=len(blobs),
                      bytes=sum(len(blob) for _path, blob in blobs))
+        telemetry.counter('checkpoint_writes_total').inc()
+
+    def _announce_checkpoint(self, job: _CheckpointJob):
+        """``job``'s files are durable: tell everyone who may now rely on
+        them. Loop thread only (the registry, the league, the task ledger
+        and the spool are not shared with the writer). Everything here goes
+        by ``job.epoch``: after a ``checkpoint_interval`` skip epoch the
+        live ``self.model_epoch`` is already past it."""
         with telemetry.trace_span('checkpoint_publish_gc'):
             # publish BEFORE retention GC: a version the registry is about
             # to pin must be pinned by the time the GC pass reads the
             # manifest
-            self._publish_checkpoint(steps)
+            self._publish_checkpoint(job.steps, job.epoch)
             self._gc_checkpoints()
             # durable plane rides the checkpoint cadence: the ledger
             # snapshot and the spool GC horizon must describe a state a
             # restart can actually resume from, i.e. one with a durable
             # checkpoint
-            self._sync_durable_state()
+            self._sync_durable_state(job.durable)
+
+    def _hand_over_checkpoint(self, host_state: TrainState):
+        """The fused loop's epoch close. That loop owns the only thread
+        that feeds the device, so it keeps what only it may do and gives
+        serialisation and the fsynced writes to the writer thread; the next
+        dispatch is enqueued while they run. Depth ONE: the previous epoch's
+        write is awaited and, where the loop's poll has not done so yet,
+        announced first (span ``checkpoint_wait``, at every boundary, ~0
+        when the writer kept up). Returns this epoch's job, for the caller
+        to ``submit`` as the boundary's last act, and the seconds blocked."""
+        with telemetry.trace_span('checkpoint_wait'):
+            waited = self._collect_checkpoint()
+        return self._advance_epoch(host_state.params, self.trainer.steps,
+                                   state=host_state), waited
+
+    def _collect_checkpoint(self, block: bool = True) -> float:
+        """Announce the fused loop's checkpoint once it is on disk; with
+        ``block`` wait for it first. Called, blocking, before anything that
+        reads or rewrites ``model_dir`` or tells anyone a checkpoint
+        exists: the files there are then exactly what synchronous writes
+        would have left. A no-op with no write outstanding (always, outside
+        the fused loop). Returns the seconds it blocked."""
+        if not block and self._ckpt_writer.busy():
+            return 0.0
+        done, waited = self._ckpt_writer.wait()
+        if done is not None:
+            self._announce_checkpoint(done)
+        return waited
 
     def _registry_root(self) -> str:
         srv = self.args.get('serving') or {}
@@ -1607,15 +1758,18 @@ class Learner:
             self._registry = ModelRegistry(self._registry_root())
         return self._registry
 
-    def _publish_checkpoint(self, steps: int):
+    def _publish_checkpoint(self, steps: int, epoch: Optional[int] = None):
         """``serving.publish``: register the just-written numbered
-        checkpoint with the ModelRegistry as ``<line>@<epoch>`` (pinning it
+        checkpoint of ``epoch`` (the live epoch by default) with the
+        ModelRegistry as ``<line>@<epoch>`` (pinning it
         against ``keep_checkpoints`` GC); ``serving.auto_promote`` also
         makes it the line's champion in the same atomic manifest swap —
         unless the league owns promotion (league.enabled), in which case
         versions publish as candidates and the champion only flips through
         the rating gate (:meth:`_league_epoch_sync`). A registry failure is
         loud but never takes training down."""
+        if epoch is None:
+            epoch = self.model_epoch
         srv = self.args.get('serving') or {}
         if not srv.get('publish'):
             return
@@ -1633,25 +1787,27 @@ class Learner:
                 promote = False
             self._registry.publish(
                 str(srv.get('line', 'default')),
-                path=self.model_path(self.model_epoch),
+                path=self.model_path(epoch),
                 architecture=model_zoo.architecture_name(self.wrapper.module),
                 config=module_config(self.wrapper.module) or None,
-                steps=int(steps), version=self.model_epoch,
-                promote=promote)
+                steps=int(steps), version=epoch, promote=promote)
         except Exception as exc:
             _LOG.error('registry publish of epoch %d failed (%s: %s); '
-                       'training continues unpublished', self.model_epoch,
+                       'training continues unpublished', epoch,
                        type(exc).__name__, str(exc)[:200])
             telemetry.counter('registry_publish_failures_total').inc()
         sync = getattr(self, '_league_epoch_sync', None)
         if sync is not None:
-            sync()
+            sync(epoch)
 
-    def _league_epoch_sync(self):
+    def _league_epoch_sync(self, epoch: Optional[int] = None):
         """League epoch boundary (after publish, before retention GC):
         refresh the member window from the registry manifest, run the
-        rating-gated promotion, export the rating gauges, and journal the
-        book atomically. Failures are loud but never take training down."""
+        rating-gated promotion of ``epoch`` (the live epoch by default),
+        export the rating gauges, and journal the book atomically. Failures
+        are loud but never take training down."""
+        if epoch is None:
+            epoch = self.model_epoch
         if getattr(self, '_league', None) is None \
                 or self._league_ratings is None:
             return
@@ -1667,12 +1823,12 @@ class Learner:
                     book.seed(m, book.rating(league_mod.LEARNER))
             if self._league.should_promote(book):
                 incumbent = self._league.champion
-                reg.promote(self._league.line, self.model_epoch)
+                reg.promote(self._league.line, epoch)
                 book.note_promotion()
                 telemetry.counter('league_promotions_total').inc()
                 self._league.refresh(reg)
                 print('league: promoted %s@%d (learner %.1f vs incumbent '
-                      '%s %.1f)' % (self._league.line, self.model_epoch,
+                      '%s %.1f)' % (self._league.line, epoch,
                                     book.rating(league_mod.LEARNER),
                                     incumbent,
                                     book.rating(incumbent)
@@ -1734,6 +1890,7 @@ class Learner:
         for the non-finite guard's in-place rollback; None before the first
         checkpoint lands (the guard then stays in skip mode)."""
         from .utils.fs import read_verified_bytes
+        self._collect_checkpoint()   # never a pair with a write half done
         blob = read_verified_bytes(self.trainer_state_path())
         if blob is None:
             return None
@@ -1747,6 +1904,7 @@ class Learner:
         """The trainer restored its TrainState in place; rewind the
         model-pool epoch and the actor-facing host params to match, so
         subsequent checkpoints overwrite the poisoned trajectory."""
+        self._collect_checkpoint()
         try:
             with open(self.model_path(epoch), 'rb') as f:
                 self.wrapper.load_params_bytes(f.read(), self._example_obs)
@@ -1855,6 +2013,11 @@ class Learner:
         trainer_state.ckpt twice with different step counts."""
         if self._final_flushed:
             return
+        # drain, then write inline: this snapshot is the last
+        # trainer_state.ckpt written, after every epoch's own. (A failed
+        # write is raised here with the flush still to make: run()'s exit
+        # makes it.)
+        self._collect_checkpoint()
         self._final_flushed = True
         tr = self.trainer
         params = steps = blob = None
@@ -2805,6 +2968,9 @@ class Learner:
                 break
             with telemetry.trace_span('fused_iter',
                                       step_num=fp.dispatches + 1) as it:
+                # a checkpoint the writer has finished is announced here,
+                # one chunk after its boundary at the earliest
+                self._collect_checkpoint(block=False)
                 if actor_epoch != self.model_epoch:
                     with telemetry.trace_span('actor_refresh'):
                         actor.params = (copy_params(tr.state.params)
@@ -2914,12 +3080,15 @@ class Learner:
                 tr.replay_stats['windows_ingested'],
                 fp.windows_ingested_host)
 
-        # Fetching + serializing the full train state stalls the fused loop
-        # at every epoch boundary (its share of a short epoch on the chip:
-        # not measured, ROADMAP R6):
-        # with checkpoint_interval > 1, intermediate epochs skip the host
-        # round trip entirely — the actor/eval params refresh device-to-
-        # device in the fused loop, so nothing here needs host bytes.
+        # What a checkpoint costs the loop is the fetch of the train state:
+        # it waits for the chunk in flight, and the next dispatch donates
+        # tr.state, so the host copy is taken here. Serialisation and the
+        # fsynced writes (two thirds of all device idle time while the loop
+        # did them itself: PERF.md section 6, PR 32) run on the writer
+        # thread under the next dispatch. With checkpoint_interval > 1,
+        # intermediate epochs skip the host round trip entirely — the
+        # actor/eval params refresh device-to-device in the fused loop, so
+        # nothing here needs host bytes.
         interval = int(self.args.get('checkpoint_interval') or 1)
         final = 0 <= self.args['epochs'] <= self.model_epoch + 1
         if interval <= 1 or (self.model_epoch + 1) % interval == 0 or final:
@@ -2931,20 +3100,28 @@ class Learner:
                 span.set(bytes=sum(
                     leaf.nbytes for leaf in
                     jax.tree_util.tree_leaves(host_state)))
-            with telemetry.trace_span('checkpoint_serialize') as span:
-                state_blob = tr.state_bytes(host_state)
-                span.set(bytes=len(state_blob))
-            self.update_model(host_state.params, tr.steps, state_blob)
+            job, waited = self._hand_over_checkpoint(host_state)
+            fused_block['ckpt_wait_s'] = round(waited, 6)
         else:
+            job = None
             self.update_model(None, tr.steps, write_files=False)
-        telemetry.set_utilization_proxy(fused_block.get('utilization'))
-        rec_extra = {'dispatches_gen': fp.dispatches,
-                     'dispatches_eval': getattr(evaluator, 'dispatches', 0),
-                     'fused': fused_block}
-        with telemetry.trace_span('metrics_write'):
-            self._write_metrics(tr.steps, rec_extra)
-        self._maybe_profile()
-        self.flags = set()
+        try:
+            telemetry.set_utilization_proxy(fused_block.get('utilization'))
+            rec_extra = {'dispatches_gen': fp.dispatches,
+                         'dispatches_eval': getattr(evaluator, 'dispatches',
+                                                    0),
+                         'fused': fused_block}
+            with telemetry.trace_span('metrics_write'):
+                self._write_metrics(tr.steps, rec_extra)
+            self._maybe_profile()
+            self.flags = set()
+        finally:
+            # the writer starts LAST: until the next dispatch is enqueued
+            # the device has nothing to run, and the writer's serialisation
+            # takes the interpreter lock from what the loop still has to do
+            # before that (PERF.md section 6, PR 32)
+            if job is not None:
+                self._ckpt_writer.submit(job)
 
     def _print_eval_stats(self):
         if self.model_epoch not in self.results:
@@ -3366,6 +3543,7 @@ class Learner:
             self._spool.close()
         if self._ledger_journal is not None:
             self._ledger_journal.close()
+        self._ckpt_writer.close()
         self.trainer.shutdown()
         if self._trainer_thread is not None:
             self._trainer_thread.join(timeout=300)
@@ -3401,17 +3579,24 @@ class Learner:
                 self.worker.run()
                 self.server()
         finally:
-            if self.preempt.fired:
-                # flush the full checkpoint BEFORE tearing children down:
-                # the supervisor restart must find TrainState + trainer
-                # accounting exactly as of the last safe point
-                try:
-                    self.final_flush()
-                    self._write_preempt_record()
-                except Exception:
-                    import traceback
-                    traceback.print_exc()
-            self.shutdown()
+            try:
+                # no way out of run(), an exception's included, leaves a
+                # write in flight or a finished one unannounced. A failed
+                # write is raised from here, never swallowed: the flush
+                # below is still tried first, as when the loop itself wrote
+                self._collect_checkpoint()
+            finally:
+                if self.preempt.fired:
+                    # flush the full checkpoint BEFORE tearing children
+                    # down: the supervisor restart must find TrainState +
+                    # trainer accounting exactly as of the last safe point
+                    try:
+                        self.final_flush()
+                        self._write_preempt_record()
+                    except Exception:
+                        import traceback
+                        traceback.print_exc()
+                self.shutdown()
 
 
 def _init_multihost(args):

@@ -220,11 +220,17 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
     assert [r['attrs']['epoch'] for r in boundaries] == [1, 2]
     for boundary in boundaries:
         assert set(children[boundary['span_id']]) >= {
-            'state_fetch', 'checkpoint_serialize', 'checkpoint_write',
-            'checkpoint_publish_gc', 'metrics_write'}
+            'state_fetch', 'checkpoint_wait', 'metrics_write'}
+    # serialisation and the writes are the writer thread's (root spans
+    # there), one set a boundary; the run ends on a boundary, so the final
+    # flush has nothing new to write
     writes = [r for r in recs if r['name'] == 'checkpoint_write']
+    assert len(writes) == len(boundaries)
     assert all(r['attrs']['files'] == 3 and r['attrs']['bytes'] > 0
-               for r in writes)
+               and r['parent_id'] is None for r in writes)
+    names = [r['name'] for r in recs]
+    assert names.count('checkpoint_serialize') == 2 * len(writes)
+    assert names.count('checkpoint_publish_gc') == len(writes)
 
     # counters: cumulative, one host_block per fetched chunk (the last one
     # is the loop's drain), in step with the pipeline's own
