@@ -11,8 +11,13 @@ one metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
     metrics/<metric>.json    a reader by name with its arguments
     readers/<reader>.py      ``read(run, **args)`` -> number or None
     hooks/<span>.json        ``module:qualname`` to wrap, and what to capture
+    rehearsal/<config>.json  the CPU rehearsal's sizes (``rehearse.py``)
 
-A later PR adds files and entries; it edits none of these loaders.
+A later PR adds files and entries; it edits none of these loaders. A root
+other than the checkout (``--root``: a scratch root for a first chip run, the
+tests' fixture root) may bring readers of its own: its ``benchmark/readers``
+joins the search path of the package ``benchmark.readers``, behind the
+checkout's, so a reader there is found by name and shadows none.
 """
 
 import json
@@ -59,12 +64,22 @@ def _read_json(path):
         raise ManifestError('%s is not JSON: %s' % (path, exc))
 
 
+def _readers_of(root):
+    """``<root>/benchmark/readers`` onto ``benchmark.readers``' path."""
+    from . import readers
+    folder = os.path.realpath(os.path.join(root, 'benchmark', 'readers'))
+    if os.path.isdir(folder) and folder not in map(os.path.realpath,
+                                                   readers.__path__):
+        readers.__path__.append(folder)
+
+
 class Manifest:
     """The parsed ``BENCHMARK.json`` of a checkout."""
 
     def __init__(self, root=ROOT):
         self.root = root
         self.raw = _read_json(os.path.join(root, 'BENCHMARK.json'))
+        _readers_of(root)
         self.run_seconds = int(self.raw['run_seconds'])
         self.configs = {}
         for entry in self.raw['configs']:

@@ -26,9 +26,12 @@ that matter here (checkpoint writes, tens of ms) can.
 The traced window is the span from the end of the first to the end of the
 last host annotation ``bench:<window_span>`` (the harness's hook; whole
 dispatch cycles); without such annotations, the extent of the device's own
-events. An idle gap goes to the program's own span the host was inside
-(``handyrl:<name>``, ``telemetry.trace_span``): those reach inside the calls
-that the harness's hooks can only time whole.
+events. An idle gap goes to the program's own span
+(``handyrl:<name>``, ``telemetry.trace_span``) that the thread which feeds
+the device was inside: those reach inside the calls that the harness's hooks
+can only time whole. That thread is the one whose line carries the window's
+marks (the dispatch call is made there); a span of another thread, such as a
+checkpoint writer's beside the loop, owns no gap, whatever it overlaps.
 """
 
 import re
@@ -85,14 +88,16 @@ def _module_name(event_name):
     return re.sub(r'\(\d+\)$', '', event_name)
 
 
-def _host_spans(host, prefix):
-    spans = []
-    if host is not None:
-        for line in host.lines:
-            spans += [(start, end, name[len(prefix):])
-                      for start, end, name in _intervals(line)
-                      if name.startswith(prefix)]
-    return spans
+def _host_spans(host, prefix, thread_of=None):
+    """The host's annotations that start with ``prefix``, the prefix cut
+    off. A line is a thread: with ``thread_of``, only the lines that carry
+    an annotation of that full name; all of them where none does."""
+    lines = [_intervals(line) for line in host.lines] if host else []
+    if thread_of is not None:
+        lines = [ivs for ivs in lines
+                 if any(name == thread_of for _s, _e, name in ivs)] or lines
+    return [(start, end, name[len(prefix):]) for ivs in lines
+            for start, end, name in ivs if name.startswith(prefix)]
 
 
 def _attribute(gaps, spans):
@@ -186,7 +191,8 @@ def reduce(path, window_span='train_dispatch', gap_prefix=GAP_PREFIX):
         'busy_s': sum(busy) / len(busy),
         'modules': modules,
         'device_ops': _top(_self_times(in_window)),
-        'idle_gaps': _top(_attribute(gaps, _host_spans(host, gap_prefix))),
+        'idle_gaps': _top(_attribute(gaps, _host_spans(
+            host, gap_prefix, thread_of=SPAN_PREFIX + window_span))),
     }
 
 

@@ -47,7 +47,17 @@ from .record import Run, intervals
 PREEMPT_EXIT_CODE = 75          # handyrl_tpu.guard.PREEMPT_EXIT_CODE
 SEED_MODULUS = 2 ** 31 - 101    # the program adds small offsets to its seed
 COMPILE_EVENT_PREFIX = '/jax/core/compile/'
-HARD_LIMIT_S = 1150             # the driver's allowance for a cold run is 1200
+# The driver ends a run at 360 s of wall clock and says only that it was
+# still running; a first run in a checkout, which compiles, gets 1,200. The
+# watchdog ends the run before that and says where its time went. Founded on
+# the four cells' slowest runs (traced, from an empty cache: 180-250 s; my
+# chip runs, PRs 31-33): 345 s, plus the seconds this process spent in
+# backend compiles (3.3 s of cache reads in a warm run, 21-60 s from an empty
+# cache), never past 1,150.
+HARD_LIMIT_S = 345
+COLD_LIMIT_S = 1150
+PHASES = ('imports, device claim', 'checks', 'learner start', 'warm-up',
+          'before the window', 'window', 'trace', 'flush', 'reading')
 
 
 class RunFailed(RuntimeError):
@@ -169,6 +179,84 @@ class _CompileLog:
         return sum(d for _t, e, d in self.events if e.endswith(suffix))
 
 
+def phase_table(t_process_start, marks, spans, window, dispatch_span, now):
+    """``[(phase, seconds so far), ...]`` in ``PHASES``' order, the last
+    entry the phase the run is in at ``now``. A phase begins where the
+    harness marked it (``marks``) or where its first record says: the first
+    call through any hook (warm-up), the first call of the dispatch span,
+    the window's opening, the profiler's start, the SIGTERM."""
+    first = [records[0][0] for records in list(spans.values()) if records]
+    dispatches = spans.get(dispatch_span)
+    begins = dict(marks)
+    begins.update({
+        'imports, device claim': t_process_start,
+        'warm-up': min(first) if first else None,
+        'before the window': dispatches[0][0] if dispatches else None,
+    })
+    if window is not None:
+        begins.update({
+            'window': window.open, 'trace': window.trace_open,
+            'flush': (window.trace_close if window.trace_dir
+                      else window.close)})
+    begun = [(phase, begins[phase]) for phase in PHASES
+             if begins.get(phase) is not None]
+    ends = [t for _phase, t in begun[1:]] + [now]
+    rows = [(phase, end - t) for (phase, t), end in zip(begun, ends)]
+    return [row for row in rows[:-1] if row[1] > 0] + rows[-1:]
+
+
+class _Watchdog:
+    """Ends a run that overstays ``limit_s`` of wall clock since the process
+    started (plus its backend-compile seconds, to ``COLD_LIMIT_S``): the
+    phase table on standard error, exit code 4, no result line. ``run_cell``
+    hands it what the table is read from as it makes it."""
+
+    def __init__(self, limit_s, t_process_start, log):
+        self.limit_s, self.t0, self.log = limit_s, t_process_start, log
+        self.compiles = self.window = self.dispatch_span = None
+        self.spans, self.marks = {}, {}
+        self._arm()
+
+    def watch(self, compiles, spans, window, dispatch_span):
+        self.compiles, self.spans = compiles, spans
+        self.window, self.dispatch_span = window, dispatch_span
+
+    def mark(self, phase):
+        self.marks[phase] = time.perf_counter()
+
+    def _compile_s(self):
+        return (self.compiles.seconds('backend_compile_duration')
+                if self.compiles else 0.0)
+
+    def _allowance(self):
+        return min(self.limit_s + self._compile_s(), COLD_LIMIT_S)
+
+    def _arm(self):
+        left = self._allowance() - (time.perf_counter() - self.t0)
+        self.timer = threading.Timer(max(0.0, left), self._ring)
+        self.timer.daemon = True
+        self.timer.start()
+
+    def _ring(self):
+        now = time.perf_counter()
+        if now - self.t0 < self._allowance():   # it compiled meanwhile
+            return self._arm()
+        rows = phase_table(self.t0, self.marks, self.spans, self.window,
+                           self.dispatch_span, now)
+        self.log.write(
+            'benchmark: hard time limit: %.0f s since the process started '
+            '(allowed: %.0f + %.1f s of backend compiles), giving up in the '
+            'phase "%s"\n' % (now - self.t0, self.limit_s,
+                              self._compile_s(), rows[-1][0]))
+        for phase, seconds in rows:
+            self.log.write('benchmark:   %-22s %8.1f s\n' % (phase, seconds))
+        self.log.flush()
+        os._exit(4)
+
+    def cancel(self):
+        self.timer.cancel()
+
+
 def _device_info():
     import jax
     devices = jax.local_devices()
@@ -259,10 +347,21 @@ def read_metrics(manifest, run, names):
 
 
 def run_cell(manifest, workload, seed, seconds, trace, t_process_start,
-             log=sys.stderr):
+             log=sys.stderr, hard_limit_s=HARD_LIMIT_S):
     """Run the cell once; returns the result dict (``run.py`` prints it).
     ``manifest.root`` holds the benchmark's data and the run directory; the
-    program is the checkout this file lies in."""
+    program is the checkout this file lies in. A run that overstays
+    ``hard_limit_s`` ends itself and says in which phase (``_Watchdog``)."""
+    watchdog = _Watchdog(hard_limit_s, t_process_start, log)
+    try:
+        return _run_cell(manifest, workload, seed, seconds, trace,
+                         t_process_start, log, watchdog)
+    finally:
+        watchdog.cancel()
+
+
+def _run_cell(manifest, workload, seed, seconds, trace, t_process_start, log,
+              watchdog):
     cell = manifest.cell(workload)
     config = manifest.load_config(cell['config'])
     traffic = manifest.load_traffic(cell['traffic'])
@@ -293,6 +392,10 @@ def run_cell(manifest, workload, seed, seconds, trace, t_process_start,
     for span in window.open_after:
         recorder.on(span, window.counter(span))
 
+    watchdog.watch(compiles, recorder.spans, window,
+                   window_spec['dispatch_span'])
+    watchdog.mark('checks')
+
     # the configuration's own comparisons, during set-up, on this device
     # and on the very weights the learner starts from
     variables = checks.starting_variables(config, train_args)
@@ -301,14 +404,7 @@ def run_cell(manifest, workload, seed, seconds, trace, t_process_start,
             config, variables, fold_seed(seed), train_args)
         for entry in config['checks']}
     del variables
-
-    def give_up():
-        log.write('benchmark: hard time limit, giving up\n')
-        os._exit(4)
-    watchdog = threading.Timer(
-        HARD_LIMIT_S - (time.perf_counter() - t_process_start), give_up)
-    watchdog.daemon = True
-    watchdog.start()
+    watchdog.mark('learner start')
 
     exit_code = None
     cwd, argv = os.getcwd(), sys.argv
@@ -325,8 +421,8 @@ def run_cell(manifest, workload, seed, seconds, trace, t_process_start,
     finally:
         os.chdir(cwd)
         sys.argv = argv
-        watchdog.cancel()
         uninstall()
+        watchdog.mark('reading')
     if window.close is None or (trace and window.trace_close is None):
         raise RunFailed('the learner stopped (exit %r) before the window '
                         'closed; see %s/train.log' % (exit_code, run_dir))

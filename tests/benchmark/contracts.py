@@ -1,0 +1,247 @@
+"""What every manifest root has to hold, as functions of a ``Manifest``, and
+the pins on what the checkout ships, as functions of a ``Manifest`` too.
+
+The contracts hold for any number of configurations and cells. The pins name
+what they pin (four cells, two configurations, nine metrics) and reach no
+further: they hold on a root that has MORE than the checkout, so a later PR
+that appends entries and adds files edits none of them. tests/benchmark runs
+both on the checkout and on ``fixture/make_root.build()``'s root, which has a
+fifth cell of a seeded configuration with checks, a FLOP count, a traffic
+mix, a hook, a reader, a metric and a rehearsal overlay of its own."""
+
+import importlib
+import os
+
+from benchmark import hooks
+from benchmark.manifest import ROOT
+
+FOUR = ['geese.sgd_heavy', 'geese.rollout_heavy',
+        'geese_lstm.sgd_heavy', 'geese_lstm.rollout_heavy']
+PAIR = ['geese', 'geese_lstm']
+HARNESS_VERDICTS = ['all_updates_finite', 'replay_ratio_as_configured',
+                    'learner_took_its_preemption_exit',
+                    'no_compilation_in_window']
+SIX = HARNESS_VERDICTS + ['forward_matches_reference',
+                          'vtrace_matches_reference']
+# (source, layer, reader) of the nine metrics that read the program's own
+# measurement, and the cells each lists: those four, by name
+NINE = {
+    'rollout_ms': ('device_trace', 'rollout', 'trace_scope_time'),
+    'ingest_ms': ('device_trace', 'ingest', 'trace_scope_time'),
+    'sgd_ms': ('device_trace', 'update step', 'trace_scope_time'),
+    'unscoped_ms': ('device_trace', 'fused dispatch', 'trace_scope_time'),
+    'dispatch_enqueue_ms': ('program_span', 'fused dispatch', 'program_span'),
+    'fetch_wait_ms': ('program_span', 'fused dispatch', 'program_span'),
+    'host_busy_ms': ('program_span', 'entry, orchestration', 'program_span'),
+    'checkpoint_write_ms': ('program_span', 'param publish, checkpoint',
+                            'program_span'),
+    'ingest_builder_ply_share': ('program_counter', 'ingest',
+                                 'program_counter_ratio'),
+}
+
+
+# ---------------------------------------------------------------------------
+# contracts: any root
+
+
+def keys_and_limits(manifest):
+    raw = manifest.raw
+    assert set(raw) == {'command', 'paths', 'run_seconds', 'configs',
+                        'workloads', 'end_to_end', 'per_layer'}
+    assert raw['command'] == ['python3', 'benchmark/run.py']
+    assert raw['paths'] == ['benchmark', 'tests/benchmark']
+    assert 1 <= raw['run_seconds'] <= 51
+    assert os.path.getsize(os.path.join(manifest.root,
+                                        'BENCHMARK.json')) < 64 * 1024
+    assert 1 <= len(raw['configs']) <= 24
+    assert 1 <= len(raw['workloads']) <= 24
+    assert 1 <= len(raw['end_to_end']) <= 16
+    assert 1 <= len(raw['per_layer']) <= 128
+    # a full check of 24 cells must fit: (2 + 14 * 24) runs
+    runs = 2 + 14 * 24
+    assert (runs * (raw['run_seconds'] + 60) + 24 * 2 * 90 + 1200) <= 43200
+
+
+def entries_have_just_the_contracts_keys(manifest):
+    raw = manifest.raw
+    for entry in raw['configs']:
+        assert set(entry) == {'name', 'source', 'file', 'reduced', 'why'}
+        assert entry['file'].startswith('benchmark/')
+        assert 1 <= len(entry['source']) <= 200
+        assert 1 <= len(entry['why']) <= 200
+        assert len(entry['reduced']) <= 16
+    files = [entry['file'] for entry in raw['configs']]
+    assert len(set(files)) == len(files)
+    for cell in raw['workloads']:
+        assert set(cell) == {'name', 'config', 'traffic', 'chips', 'why'}
+        assert 1 <= len(cell['why']) <= 200 and '\n' not in cell['why']
+    pairs = [(c['config'], c['traffic']) for c in raw['workloads']]
+    assert len(set(pairs)) == len(pairs)
+    used = {c['config'] for c in raw['workloads']}
+    assert used == set(manifest.configs)   # each used by some cell
+    for entry in raw['end_to_end']:
+        assert set(entry) - {'workloads'} == {'name', 'unit', 'better',
+                                              'bound', 'source'}
+        assert entry['source'] in ('host_clock', 'device_trace')
+        assert 0.01 <= entry['bound'] <= 0.1
+    for entry in raw['per_layer']:
+        assert set(entry) - {'workloads'} == {'name', 'unit', 'better',
+                                              'source', 'layer', 'moves'}
+        assert 1 <= len(entry['layer']) <= 200 and '\n' not in entry['layer']
+    assert 'setup_s' in manifest.metrics
+
+
+def a_layer_metric_moves_what_its_cells_report(manifest):
+    for name, entry in manifest.metrics.items():
+        if entry['group'] != 'per_layer':
+            continue
+        moved = manifest.metrics[entry['moves']]
+        assert moved['group'] == 'end_to_end'
+        assert set(manifest.cells_of(name)) <= \
+            set(manifest.cells_of(entry['moves'])), name
+
+
+def every_cell_reports_setup_one_more_and_a_layer_metric(manifest):
+    for cell in manifest.cells:
+        e2e = manifest.metrics_of(cell, 'end_to_end')
+        assert 'setup_s' in e2e and len(e2e) >= 2
+        assert manifest.metrics_of(cell, 'per_layer')
+
+
+def four_chip_cells_are_a_quarter_at_most(manifest):
+    chips = [cell['chips'] for cell in manifest.cells.values()]
+    assert set(chips) <= {1, 4}
+    assert chips.count(4) <= max(1, len(chips) // 4)
+
+
+def any_configuration(manifest, name):
+    """What holds for ANY configuration's file: it agrees with its entry;
+    ``weights`` is one of the two kinds and says why (a checkpoint also where
+    it came from, and is a file of about 4 bytes a parameter); ``checks`` is
+    a non-empty list of unique names that resolve; ``flops`` resolves."""
+    config = manifest.load_config(name)   # raises on an unresolved name
+    entry = manifest.configs[name]
+    assert config['name'] == name
+    assert config['reduced'] == entry['reduced']
+    assert config['source'] == entry['source']
+    weights = config['weights']
+    assert weights['why']
+    if weights.get('seeded') is True:     # ask the kind before the key
+        assert 'checkpoint' not in weights
+    else:
+        assert weights['provenance']
+        size = os.path.getsize(os.path.join(ROOT, weights['checkpoint']))
+        assert abs(size / (4 * config['model']['parameters']) - 1) < 0.1
+    names = [check['name'] for check in config['checks']]
+    assert names and len(set(names)) == len(names)
+    assert not set(names) & set(HARNESS_VERDICTS)
+    for check in config['checks']:
+        assert callable(hooks.resolve(check['check'])[2])
+    assert callable(hooks.resolve(config['flops'])[2])
+    assert 'checkpoint' not in config   # one place, one path: `weights`
+
+
+def every_named_file_exists_and_agrees(manifest):
+    for name in manifest.configs:
+        any_configuration(manifest, name)
+    for cell in manifest.cells.values():
+        traffic = manifest.load_traffic(cell['traffic'])
+        for key in ('sgd_steps_per_chunk', 'batch_size'):
+            assert traffic['train_args'][key] == traffic['replay'][key]
+        window = traffic['window']
+        spans = [window[k] for k in ('dispatch_span', 'account_span',
+                                     'fetch_span')]
+        manifest.load_hooks(spans + list(window.get('open_after', {}))
+                            + list(window.get('spans', ())))
+    for name in manifest.metrics:
+        spec = manifest.load_metric(name)   # raises where it disagrees
+        module = importlib.import_module('benchmark.readers.'
+                                         + spec['reader'])
+        assert callable(module.read)
+        args = spec.get('args', {})
+        manifest.load_hooks([args[k] for k in ('span', 'inner') if k in args])
+    peaks = manifest.load_peaks()
+    assert peaks['TPU v5 lite']['bf16_flops_per_s'] == 197e12
+    assert all('source' in row for row in peaks.values())
+
+
+CONTRACTS = [keys_and_limits, entries_have_just_the_contracts_keys,
+             a_layer_metric_moves_what_its_cells_report,
+             every_cell_reports_setup_one_more_and_a_layer_metric,
+             four_chip_cells_are_a_quarter_at_most,
+             every_named_file_exists_and_agrees]
+
+
+# ---------------------------------------------------------------------------
+# pins: what the checkout ships, by name, and no further
+
+
+def the_first_four_cells(manifest):
+    assert list(manifest.cells)[:4] == FOUR
+    for name in FOUR:
+        assert manifest.cells[name]['chips'] == 1
+
+
+def the_pair_states_all_three_explicitly(manifest, name):
+    assert name in PAIR
+    config = manifest.load_config(name)
+    assert set(config['weights']) >= {'checkpoint', 'why', 'provenance'}
+    assert config['weights']['checkpoint'] == \
+        'benchmark/checkpoints/%s.ckpt' % name
+    with open(os.path.join(ROOT, config['weights']['checkpoint']),
+              'rb') as f:
+        assert len(f.read()) > 400_000   # ~116k float32 parameters
+    assert [c['name'] for c in config['checks']] == SIX[4:]
+    assert [c['check'] for c in config['checks']] == [
+        'benchmark.checks:forward_check', 'benchmark.checks:vtrace_check']
+    assert config['flops'] == 'benchmark.flops:train_window_flops'
+
+
+def one_of_the_nine_agrees_with_its_entry(manifest, name):
+    import json
+    source, layer, reader = NINE[name]
+    entry = manifest.metrics[name]
+    spec = manifest.load_metric(name)           # raises where they disagree
+    assert (entry['source'], entry['layer'], spec['reader']) == \
+        (source, layer, reader)
+    assert entry['moves'] == 'train_windows_per_s'
+    assert entry['workloads'] == FOUR
+    with open(os.path.join(manifest.root, 'benchmark', 'metrics',
+                           name + '.json')) as f:
+        raw = json.load(f)
+    for key in ('name', 'unit', 'better', 'source', 'layer', 'moves'):
+        assert raw[key] == entry[key]
+    # the harness takes a metric's `span` / `inner` argument for a hook to
+    # install: the program's own spans go by `stage`
+    assert not {'span', 'inner'} & set(spec['args'])
+    assert spec['args'].get('stat', 'median') == 'median'
+    module = importlib.import_module('benchmark.readers.' + reader)
+    assert callable(module.read)
+
+
+def a_rehearsal_line(manifest, workload, line):
+    """The verdict keys of a rehearsed cell: the harness's four, letter for
+    letter and in order, then the configuration's ``checks`` as its file
+    lists them; for the four shipped cells that makes the six, and the
+    forward check ran ``tiny.json``'s three plies."""
+    assert line['cpu_rehearsal'] is True and line['platform'] == 'cpu'
+    config = manifest.load_config(manifest.cell(workload)['config'])
+    own = [check['name'] for check in config['checks']]
+    assert list(line['checks']) == HARNESS_VERDICTS + own
+    assert list(line['reference']) == own
+    if workload in FOUR:
+        assert list(line['checks']) == SIX
+        assert line['reference']['forward_matches_reference']['plies'] == 3
+    assert line['flops']['train_window'] > 0
+    # counts only: nothing under a device metric's name
+    assert not set(line) & set(manifest.metrics)
+    assert 'metrics' not in line and 'device' not in line
+
+
+def pins(manifest):
+    """Every pin, on one root."""
+    the_first_four_cells(manifest)
+    for name in PAIR:
+        the_pair_states_all_three_explicitly(manifest, name)
+    for name in NINE:
+        one_of_the_nine_agrees_with_its_entry(manifest, name)

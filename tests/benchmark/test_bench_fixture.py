@@ -1,8 +1,9 @@
 """What a configuration's file names: ``weights``, ``checks`` and ``flops``,
 held on a fixture configuration that is no entry of ``BENCHMARK.json``
 (``fixture/seeded_toy.json``: seeded weights, a FLOP count of its own and a
-third check) run through the harness's one path on the CPU rehearsal, and on
-the loader's refusals."""
+third check) in a cell that brings its own traffic mix, hook, reader, metric
+and rehearsal overlay (``fixture/make_root.py``), run through the harness's
+one path on the CPU rehearsal, and on the loader's refusals."""
 
 import json
 import os
@@ -15,14 +16,13 @@ from benchmark import checks
 from benchmark.manifest import Manifest, ManifestError, ROOT
 from benchmark.session import fold_seed, merged_args
 
+from tests.benchmark import contracts
+from tests.benchmark.contracts import PAIR, SIX
 from tests.benchmark.fixture import make_root, toy
 
 FIXTURE = os.path.dirname(os.path.abspath(make_root.__file__))
 
 SEED = 2 ** 31 + 11
-SIX = ['all_updates_finite', 'replay_ratio_as_configured',
-       'learner_took_its_preemption_exit', 'no_compilation_in_window',
-       'forward_matches_reference', 'vtrace_matches_reference']
 
 
 def _env():
@@ -46,10 +46,12 @@ def _rehearse(root, *extra, script=os.path.join(ROOT, 'benchmark',
 @pytest.fixture(scope='module')
 def rehearsed(tmp_path_factory):
     root = make_root.build(str(tmp_path_factory.mktemp('fixture_root')))
-    proc, line = _rehearse(root)
+    # traced, so that the line lists the per-layer metrics that were read
+    proc, line = _rehearse(root, '--trace', '1')
     assert proc.returncode == 0, proc.stderr[-3000:]
     run_dir = os.path.join(ROOT, '.bench_runs', 'rehearsal', make_root.CELL,
                            '.bench_runs', make_root.CELL)
+    contracts.a_rehearsal_line(Manifest(root), make_root.CELL, line)
     with open(os.path.join(run_dir, 'config.yaml')) as f:
         return proc, line, json.load(f)
 
@@ -74,9 +76,39 @@ def test_the_third_check_is_a_verdict_key_after_the_six(rehearsed):
 @pytest.mark.timeout(600)
 def test_the_configurations_own_flop_count_reaches_the_line(rehearsed):
     _proc, line, written = rehearsed
-    # 12345 a step (the file's number) x the rehearsal's forward_steps
+    # a number a step (the file's 12345, the overlay's in a rehearsal) x
+    # the window's forward_steps
+    per_step = make_root.OVERLAY['model']['flops_per_window']
     assert line['flops'] == {
-        'train_window': 12345 * written['train_args']['forward_steps']}
+        'train_window': per_step * written['train_args']['forward_steps']}
+
+
+@pytest.mark.timeout(600)
+def test_the_cell_rehearses_through_its_own_overlay(rehearsed):
+    """``rehearsal/seeded_toy.json``, not ``tiny.json``: its ``model``, its
+    check sizes and its ``train_args`` reached the run; what it leaves to
+    ``tiny.json``'s values is as there."""
+    _proc, line, written = rehearsed
+    own = make_root.OVERLAY
+    assert written['train_args']['forward_steps'] == \
+        own['train_args']['forward_steps'] == 5
+    forward = line['reference']['forward_matches_reference']
+    assert forward['plies'] == own['config']['reference_plies'] == 2
+    with open(os.path.join(ROOT, 'benchmark', 'rehearsal',
+                           'tiny.json')) as f:
+        tiny = json.load(f)
+    assert tiny['train_args']['forward_steps'] == 4
+    assert tiny['config']['reference_plies'] == 3
+    assert written['train_args']['batch_size'] == \
+        tiny['train_args']['batch_size']
+
+
+@pytest.mark.timeout(600)
+def test_the_cells_own_hook_reader_and_metric_are_found_by_name(rehearsed):
+    _proc, line, _written = rehearsed
+    assert make_root.HOOK in line['spans']
+    assert make_root.METRIC in line['metrics_read']
+    assert line['counts']['epochs_in_window'] >= 1
 
 
 @pytest.mark.timeout(600)
@@ -175,18 +207,32 @@ def test_weights_are_a_checkpoint_or_seeded_and_not_both(tmp_path, weights):
     assert 'weights' in str(err.value)
 
 
-@pytest.mark.parametrize('name', list(Manifest().configs))
+@pytest.mark.parametrize('name', PAIR)
 def test_shipped_configurations_state_all_three_explicitly(name):
-    config = Manifest().load_config(name)
-    assert set(config['weights']) >= {'checkpoint', 'why', 'provenance'}
-    assert [c['name'] for c in config['checks']] == SIX[4:]
-    assert [c['check'] for c in config['checks']] == [
-        'benchmark.checks:forward_check', 'benchmark.checks:vtrace_check']
-    assert config['flops'] == 'benchmark.flops:train_window_flops'
-    assert 'checkpoint' not in config   # one place, one path
+    contracts.the_pair_states_all_three_explicitly(Manifest(), name)
 
 
 @pytest.mark.parametrize('name', list(Manifest().configs))
+def test_any_configuration_states_its_weights_checks_and_flops(name):
+    contracts.any_configuration(Manifest(), name)
+
+
+def test_a_seeded_configuration_states_them_too(fifth):
+    for name in fifth.configs:
+        contracts.any_configuration(fifth, name)
+    assert fifth.load_config(make_root.CONFIG)['weights']['seeded'] is True
+
+
+def _with_a_checkpoint(manifest):
+    return [name for name in manifest.configs
+            if 'checkpoint' in manifest.load_config(name)['weights']]
+
+
+def test_the_configurations_with_a_checkpoint_are_found_by_the_kind(fifth):
+    assert _with_a_checkpoint(fifth) == PAIR == _with_a_checkpoint(Manifest())
+
+
+@pytest.mark.parametrize('name', _with_a_checkpoint(Manifest()))
 def test_a_checkpoints_variables_are_the_files_bytes(name):
     """``weights.checkpoint``: the learner's ``init_params`` and the checks'
     variables are the same file, leaf for leaf."""
@@ -207,3 +253,18 @@ def test_a_checkpoints_variables_are_the_files_bytes(name):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert sum(np.asarray(a).size for a in jax.tree_util.tree_leaves(got)) \
         == config['model']['parameters']
+
+
+def test_seeded_weights_are_the_seeds_and_no_files(fifth):
+    """The twin of the test above for ``weights.seeded``: no ``init_params``
+    reaches the learner, the same seed gives the same variables leaf for
+    leaf, another seed gives others."""
+    config = fifth.load_config(make_root.CONFIG)
+    traffic = fifth.load_traffic(make_root.TRAFFIC)
+    train_args = merged_args(config, traffic, SEED)['train_args']
+    assert 'init_params' not in train_args
+    first = toy.digest(checks.starting_variables(config, train_args))
+    again = merged_args(config, traffic, SEED)['train_args']
+    assert toy.digest(checks.starting_variables(config, again)) == first
+    other = merged_args(config, traffic, SEED + 1)['train_args']
+    assert toy.digest(checks.starting_variables(config, other)) != first

@@ -1,5 +1,8 @@
-"""BENCHMARK.json against its contract, and the loader's refusals."""
+"""BENCHMARK.json against its contract, on the checkout and on a root with a
+fifth cell, and the loader's refusals. The contracts and the pins are
+functions of a ``Manifest`` (``contracts.py``)."""
 
+import filecmp
 import json
 import os
 import shutil
@@ -9,81 +12,87 @@ import pytest
 from benchmark import manifest
 from benchmark.manifest import Manifest, ManifestError, ROOT
 
-
-@pytest.fixture(scope='module')
-def shipped():
-    return Manifest()
+from tests.benchmark import contracts
+from tests.benchmark.fixture import make_root
 
 
 def test_keys_and_limits(shipped):
-    raw = shipped.raw
-    assert set(raw) == {'command', 'paths', 'run_seconds', 'configs',
-                        'workloads', 'end_to_end', 'per_layer'}
-    assert raw['command'] == ['python3', 'benchmark/run.py']
-    assert raw['paths'] == ['benchmark', 'tests/benchmark']
-    assert 1 <= raw['run_seconds'] <= 51
-    assert os.path.getsize(os.path.join(ROOT, 'BENCHMARK.json')) < 64 * 1024
-    # a full check of 24 cells must fit: (2 + 14 * 24) runs
-    runs = 2 + 14 * 24
-    assert (runs * (raw['run_seconds'] + 60) + 24 * 2 * 90 + 1200) <= 43200
+    contracts.keys_and_limits(shipped)
 
 
 def test_cells_are_the_issues_four_in_order(shipped):
-    assert list(shipped.cells) == [
-        'geese.sgd_heavy', 'geese.rollout_heavy',
-        'geese_lstm.sgd_heavy', 'geese_lstm.rollout_heavy'][:len(shipped.cells)]
-    for cell in shipped.cells.values():
-        assert cell['chips'] == 1
-        assert set(cell) == {'name', 'config', 'traffic', 'chips', 'why'}
-        assert 1 <= len(cell['why']) <= 200 and '\n' not in cell['why']
+    contracts.the_first_four_cells(shipped)
 
 
 def test_entries_have_just_the_contracts_keys(shipped):
-    for entry in shipped.raw['configs']:
-        assert set(entry) == {'name', 'source', 'file', 'reduced', 'why'}
-        assert entry['file'].startswith('benchmark/')
-        assert len(entry['source']) <= 200 and len(entry['why']) <= 200
-    for entry in shipped.raw['end_to_end']:
-        assert set(entry) - {'workloads'} == {'name', 'unit', 'better',
-                                              'bound', 'source'}
-        assert entry['source'] in ('host_clock', 'device_trace')
-        assert 0.01 <= entry['bound'] <= 0.1
-    for entry in shipped.raw['per_layer']:
-        assert set(entry) - {'workloads'} == {'name', 'unit', 'better',
-                                              'source', 'layer', 'moves'}
-        assert len(entry['layer']) <= 200
-    assert 'setup_s' in shipped.metrics
+    contracts.entries_have_just_the_contracts_keys(shipped)
 
 
 def test_every_cell_reports_setup_one_more_and_a_layer_metric(shipped):
-    for cell in shipped.cells:
-        e2e = shipped.metrics_of(cell, 'end_to_end')
-        assert 'setup_s' in e2e and len(e2e) >= 2
-        assert shipped.metrics_of(cell, 'per_layer')
+    contracts.every_cell_reports_setup_one_more_and_a_layer_metric(shipped)
+    contracts.a_layer_metric_moves_what_its_cells_report(shipped)
 
 
 def test_every_named_file_exists_and_agrees(shipped):
-    for name in shipped.configs:
-        config = shipped.load_config(name)
-        assert config['name'] == name
-        assert config['reduced'] == shipped.configs[name]['reduced']
-        assert config['source'] == shipped.configs[name]['source']
-        with open(os.path.join(ROOT, config['weights']['checkpoint']),
-                  'rb') as f:
-            assert len(f.read()) > 400_000   # ~116k float32 parameters
-    for cell in shipped.cells.values():
-        traffic = shipped.load_traffic(cell['traffic'])
-        assert traffic['train_args']['sgd_steps_per_chunk'] == \
-            traffic['replay']['sgd_steps_per_chunk']
-        assert traffic['train_args']['batch_size'] == \
-            traffic['replay']['batch_size']
-    for name in shipped.metrics:
-        spec = shipped.load_metric(name)
-        assert os.path.exists(os.path.join(
-            ROOT, 'benchmark', 'readers', spec['reader'] + '.py'))
-    peaks = shipped.load_peaks()
-    assert peaks['TPU v5 lite']['bf16_flops_per_s'] == 197e12
-    assert all('source' in row for row in peaks.values())
+    contracts.every_named_file_exists_and_agrees(shipped)
+
+
+def test_at_most_a_quarter_of_the_cells_and_never_fewer_than_one_on_4_chips(
+        shipped):
+    contracts.four_chip_cells_are_a_quarter_at_most(shipped)
+
+
+@pytest.mark.parametrize('contract', contracts.CONTRACTS,
+                         ids=lambda fn: fn.__name__)
+def test_contract_holds_on_a_root_with_a_fifth_cell(contract, fifth, shipped):
+    assert len(fifth.cells) == len(shipped.cells) + 1 >= 5
+    assert len(fifth.configs) == len(shipped.configs) + 1 >= 3
+    contract(fifth)
+
+
+def test_the_pins_hold_on_a_root_with_a_fifth_cell(fifth):
+    """They keep their values and lose their reach: a cell list compared
+    whole, a ``weights.checkpoint`` read without asking the kind,
+    ``workloads == list(cells)`` or the six check names held against a
+    configuration that is not of the pair would each fail here."""
+    contracts.pins(fifth)
+    contracts.any_configuration(fifth, make_root.CONFIG)
+    assert fifth.metrics[make_root.METRIC]['workloads'] == [make_root.CELL]
+    for cell in contracts.FOUR:
+        assert make_root.METRIC not in fifth.metrics_of(cell)
+
+
+def test_appending_entries_and_adding_files_changes_no_shipped_entry_list_or_file_and_leaves_every_contract_green(  # noqa: E501
+        shipped, fifth):
+    for group in ('configs', 'workloads', 'end_to_end', 'per_layer'):
+        ours, theirs = shipped.raw[group], fifth.raw[group]
+        assert theirs[:len(ours)] == ours          # entries and their lists
+    assert len(fifth.raw['workloads']) == len(shipped.raw['workloads']) + 1
+    for key in ('command', 'paths', 'run_seconds'):
+        assert fifth.raw[key] == shipped.raw[key]
+    added = []
+    for folder in ('configs', 'traffic', 'metrics', 'hooks', 'readers',
+                   'rehearsal'):
+        ours = os.path.join(shipped.root, 'benchmark', folder)
+        theirs = os.path.join(fifth.root, 'benchmark', folder)
+        for name in os.listdir(ours):
+            if name != '__pycache__':     # every shipped file, byte for byte
+                assert filecmp.cmp(os.path.join(ours, name),
+                                   os.path.join(theirs, name), shallow=False)
+        added += [folder + '/' + name for name in os.listdir(theirs)
+                  if not os.path.exists(os.path.join(ours, name))]
+    assert sorted(added) == sorted([
+        'configs/%s.json' % make_root.CONFIG,
+        'traffic/%s.json' % make_root.TRAFFIC,
+        'metrics/%s.json' % make_root.METRIC,
+        'hooks/%s.json' % make_root.HOOK,
+        'readers/%s.py' % make_root.READER,
+        'rehearsal/%s.json' % make_root.CONFIG])
+    for contract in contracts.CONTRACTS:
+        contract(fifth)
+        contract(shipped)
+    contracts.pins(fifth)
+    contracts.pins(shipped)
 
 
 def test_traffic_mixes_differ_only_in_the_replay_dial(shipped):
@@ -94,6 +103,22 @@ def test_traffic_mixes_differ_only_in_the_replay_dial(shipped):
     assert (heavy['sgd_steps_per_chunk'], light['sgd_steps_per_chunk']) == \
         (32, 2)
     assert heavy['batch_size'] == 128 and heavy['generation_envs'] == 64
+
+
+@pytest.mark.parametrize('chips, refused', [
+    ([1, 1, 1, 4], False), ([4], False), ([1, 4], False),
+    ([1, 1, 4, 4], True), ([1] * 6 + [4, 4], False),
+    ([1] * 5 + [4, 4], True)],
+    ids=['1_of_4', 'the_only_cell', '1_of_2', '2_of_4', '2_of_8', '2_of_7'])
+def test_the_four_chip_share_is_counted_over_any_number_of_cells(chips,
+                                                                 refused):
+    class Cells:
+        cells = {str(i): {'chips': n} for i, n in enumerate(chips)}
+    if refused:
+        with pytest.raises(AssertionError):
+            contracts.four_chip_cells_are_a_quarter_at_most(Cells)
+    else:
+        contracts.four_chip_cells_are_a_quarter_at_most(Cells)
 
 
 @pytest.fixture

@@ -3,8 +3,6 @@
 a hand-written trace with named phases (exact answers) and on the trace
 recorded on a v5e, and the nine metrics that use them against their entries."""
 
-import importlib
-import json
 import os
 
 import pytest
@@ -15,22 +13,12 @@ from benchmark.readers import (program_counter_ratio, program_span,
                                trace_scope_time)
 from benchmark.record import Run
 
+from tests.benchmark import contracts
+
 TESTDATA = os.path.join(ROOT, 'benchmark', 'testdata')
 SCOPES = ['rollout', 'ingest', 'sgd', 'pack']
 MODULE = 'jit_fused_pipeline_train'
-NEW_METRICS = {
-    'rollout_ms': ('device_trace', 'rollout', 'trace_scope_time'),
-    'ingest_ms': ('device_trace', 'ingest', 'trace_scope_time'),
-    'sgd_ms': ('device_trace', 'update step', 'trace_scope_time'),
-    'unscoped_ms': ('device_trace', 'fused dispatch', 'trace_scope_time'),
-    'dispatch_enqueue_ms': ('program_span', 'fused dispatch', 'program_span'),
-    'fetch_wait_ms': ('program_span', 'fused dispatch', 'program_span'),
-    'host_busy_ms': ('program_span', 'entry, orchestration', 'program_span'),
-    'checkpoint_write_ms': ('program_span', 'param publish, checkpoint',
-                            'program_span'),
-    'ingest_builder_ply_share': ('program_counter', 'ingest',
-                                 'program_counter_ratio'),
-}
+NEW_METRICS = contracts.NINE
 
 
 def _run(trace=None, name='c'):
@@ -309,22 +297,24 @@ def test_scope_decoder_reads_the_trace_recorded_on_a_v5e():
 
 @pytest.mark.parametrize('name', sorted(NEW_METRICS))
 def test_metric_file_agrees_with_its_entry(name):
+    """Each lists the four shipped cells, by name: a fifth cell lists
+    itself in the entries it brings and edits none of these."""
+    contracts.one_of_the_nine_agrees_with_its_entry(Manifest(), name)
+
+
+def test_checkpoint_wait_ms_reads_the_loops_wait_for_its_writer(ring):
+    """The tenth of the kind (PR 33): ``checkpoint_wait``, the loop's wait
+    before it hands the next checkpoint to the writer thread."""
     shipped = Manifest()
-    source, layer, reader = NEW_METRICS[name]
-    entry = shipped.metrics[name]
-    spec = shipped.load_metric(name)           # raises where they disagree
-    assert (entry['source'], entry['layer'], spec['reader']) == \
-        (source, layer, reader)
+    entry, spec = (shipped.metrics['checkpoint_wait_ms'],
+                   shipped.load_metric('checkpoint_wait_ms'))
+    assert (entry['source'], entry['layer'], spec['reader']) == (
+        'program_span', 'param publish, checkpoint', 'program_span')
     assert entry['moves'] == 'train_windows_per_s'
-    assert entry['workloads'] == list(shipped.cells)
-    with open(os.path.join(ROOT, 'benchmark', 'metrics',
-                           name + '.json')) as f:
-        raw = json.load(f)
-    for key in ('name', 'unit', 'better', 'source', 'layer', 'moves'):
-        assert raw[key] == entry[key]
-    # the harness takes a metric's `span` / `inner` argument for a hook to
-    # install: the program's own spans go by `stage`
-    assert not {'span', 'inner'} & set(spec['args'])
-    assert spec['args'].get('stat', 'median') == 'median'
-    module = importlib.import_module('benchmark.readers.' + reader)
-    assert callable(module.read)
+    assert entry['workloads'] == contracts.FOUR
+    assert spec['args'] == {'stage': 'checkpoint_wait', 'stat': 'median'}
+    assert program_span.read(_run(), **spec['args']) is None   # no such span
+    ring.append(_rec('checkpoint_wait', 13.025, 13.025004, 135, 133))
+    ring.append(_rec('checkpoint_wait', 14.1, 14.13, 145, 143))
+    assert program_span.read(_run(), **spec['args']) == {
+        'value': pytest.approx(15.002), 'samples': 2}

@@ -65,6 +65,34 @@ def test_gaps_go_to_the_innermost_program_span(synthetic):
     assert reduce_trace.NO_SPAN in gaps
 
 
+def test_a_gap_belongs_to_the_thread_that_feeds_the_device(synthetic):
+    """The trace's second host line is a checkpoint writer: its
+    checkpoint_serialize (15.2..15.8 us) and its own checkpoint_write
+    (19..19.5 us) lie over idle time and are shorter than the loop's
+    epoch_boundary, which was the rule for an owner while any thread's span
+    could be one. The loop's thread is the line that carries the window's
+    marks; the writer's spans own nothing."""
+    gaps = dict(synthetic['idle_gaps'])
+    assert 'checkpoint_serialize' not in gaps
+    # the loop's own checkpoint_write, 12..15 us, and not 0.5 us more
+    assert gaps['checkpoint_write'] == pytest.approx(3 * US)
+    assert gaps['epoch_boundary'] == pytest.approx(2 * US)
+
+
+def test_without_the_windows_marks_every_thread_may_own_a_gap(tmp_path):
+    """A trace that holds no ``bench:<window_span>`` annotation names no
+    loop thread: the window is the extent of the device's events and the
+    owners come from every line, as before."""
+    from jax.profiler import ProfileData
+    with open(os.path.join(TESTDATA, 'synthetic.xplane.txt')) as f:
+        text = ''.join(line for line in f if not line.startswith('#'))
+    path = tmp_path / 'unmarked.xplane.pb'
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(
+        text.replace('bench:train_dispatch', 'bench:other')))
+    gaps = dict(reduce_trace.reduce(str(path))['idle_gaps'])
+    assert gaps['checkpoint_serialize'] == pytest.approx(0.6 * US)
+
+
 def test_no_device_plane_reduces_to_nothing(tmp_path):
     from jax.profiler import ProfileData
     path = tmp_path / 'host_only.xplane.pb'

@@ -79,7 +79,7 @@ def test_numpy_vtrace_matches_ops_targets(with_rewards):
     got = compute_target('VTRACE', jnp.asarray(values), jnp.asarray(returns),
                          None if rewards is None else jnp.asarray(rewards),
                          0.7, 0.99, jnp.asarray(rhos), jnp.asarray(cs),
-                         jnp.asarray(masks), use_pallas=False)
+                         jnp.asarray(masks))
     want = vtrace(values, returns, rewards, 0.7, 0.99, rhos, cs, masks)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), w, atol=1e-5)
