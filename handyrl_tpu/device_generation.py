@@ -96,8 +96,13 @@ def _ply_inference(env_mod, apply_fn, recurrent, simultaneous,
     return obs, logits, amask, hidden, out
 
 
-def _reset_hidden_where_done(hidden, done):
-    """Fresh episodes start with zero recurrent state."""
+def _reset_hidden_where_done(hidden, done, module=None):
+    """Fresh episodes start with zero recurrent state. A net whose state is
+    a cache kept by counters supplies ``reset_hidden(hidden, done)`` and
+    resets those alone; the zero-fill of the whole tree is for the nets
+    that do not."""
+    if hasattr(module, 'reset_hidden'):
+        return module.reset_hidden(hidden, done)
     return jax.tree_util.tree_map(
         lambda h: jnp.where(done.reshape((-1,) + (1,) * (h.ndim - 1)),
                             jnp.zeros_like(h), h), hidden)
@@ -165,7 +170,8 @@ def _init_rollout_engine(engine, env_mod, wrapper, n_envs: int, seed: int):
         (n_envs, env_mod.NUM_PLAYERS)) if engine.recurrent else None)
 
 
-def make_gen_body(env_mod, apply_fn, recurrent: bool, simultaneous: bool):
+def make_gen_body(env_mod, apply_fn, recurrent: bool, simultaneous: bool,
+                  module=None):
     """The one self-play ply: inference, sampling, transition, record.
 
     Shared between DeviceGenerator's standalone rollout program and the
@@ -178,6 +184,11 @@ def make_gen_body(env_mod, apply_fn, recurrent: bool, simultaneous: bool):
     identity, and a body shared across traces would smuggle one trace's
     param tracers into the next (UnexpectedTracerError).
     """
+    # ``module``: the net, where it resets its own state (``reset_hidden``);
+    # by default the one the bound ``apply`` belongs to
+    if module is None:
+        module = getattr(apply_fn, '__self__', None)
+
     def rollout_chunk(params, state, hidden, rng, chunk_steps: int):
         def body(carry, _):
             state, hidden, rng = carry
@@ -213,7 +224,7 @@ def make_gen_body(env_mod, apply_fn, recurrent: bool, simultaneous: bool):
                 record['reward'] = env_mod.rewards(nstate)   # (N, P)
             nstate = env_mod.auto_reset(nstate, done)
             if recurrent:
-                hidden = _reset_hidden_where_done(hidden, done)
+                hidden = _reset_hidden_where_done(hidden, done, module)
             return (nstate, hidden, rng), record
 
         (state, hidden, rng), records = jax.lax.scan(
@@ -523,10 +534,11 @@ class DeviceEvaluator:
                 seat = jnp.where(done,
                                  (seat + 1) % env_mod.NUM_PLAYERS, seat)
                 if recurrent:
-                    hidden = _reset_hidden_where_done(hidden, done)
+                    hidden = _reset_hidden_where_done(hidden, done,
+                                                      wrapper.module)
                     if opp_hidden is not None:
                         opp_hidden = _reset_hidden_where_done(
-                            opp_hidden, done)
+                            opp_hidden, done, wrapper.module)
                 return (nstate, hidden, opp_hidden, seat, rng), record
 
             (state, hidden, opp_hidden, seat, rng), records = jax.lax.scan(

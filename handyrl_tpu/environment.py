@@ -24,6 +24,7 @@ ENVS = {
     'Geister': 'handyrl_tpu.envs.geister',
     'HungryGeese': 'handyrl_tpu.envs.kaggle.hungry_geese',
     'ConnectX': 'handyrl_tpu.envs.kaggle.connectx',
+    'ByteGame': 'handyrl_tpu.envs.bytegame',
 }
 
 # Pure-JAX twins: envs re-implemented as jittable array functions for
@@ -33,15 +34,21 @@ JAX_ENVS = {
     'HungryGeese': 'handyrl_tpu.envs.jax_hungry_geese',
     'Geister': 'handyrl_tpu.envs.jax_geister',
     'ConnectX': 'handyrl_tpu.envs.jax_connectx',
+    'ByteGame': 'handyrl_tpu.envs.jax_bytegame',
 }
 
 
 def make_jax_env(env_args: Dict[str, Any]):
-    """Return the pure-JAX twin module for an env, or None."""
+    """Return the pure-JAX twin for an env, or None: the twin's module, or,
+    where the game's sizes are the env's own ``env_args``, the object its
+    ``configured(env_args)`` makes (the same protocol as a module)."""
     name = env_args['env']
     if name not in JAX_ENVS:
         return None
-    return importlib.import_module(JAX_ENVS[name])
+    module = importlib.import_module(JAX_ENVS[name])
+    if hasattr(module, 'configured'):
+        return module.configured(env_args)
+    return module
 
 
 def _resolve_module(env_args: Dict[str, Any]):

@@ -1,0 +1,360 @@
+"""EvaByte as a policy trunk: a byte-level decoder whose attention is exact
+inside a window and reads everything older through chunk summaries.
+
+Source: https://huggingface.co/EvaByte/EvaByte/blob/main/config.json
+(EvaByte 6.5B: 32 layers, hidden 4096, 32 heads of 128, SwiGLU 11008,
+vocabulary 320, ``chunk_size`` 16, ``window_size`` 2048, 8 prediction heads,
+``rope_theta`` 1e5, RMSNorm with unit offset, float32 residual adds and
+logits). With absolute position p, chunk c(p) = p // 16, window w(p) = p //
+2048, per head for query n:
+
+* the exact set ``L_n = {m <= n : w(m) = w(n)}``;
+* the remote set ``R_n``: the chunks lying wholly in windows before w(n),
+  each read through ``k~_c = mean_{m in c} k_m + mu_h`` and ``v~_c = sum_{m in
+  c} softmax_{m in c}(s <k_m, phi_h>) v_m`` (``mu_h``, ``phi_h`` learned per
+  head and layer);
+* ONE softmax over both (EVA, "Efficient Attention via Control Variates",
+  ICLR 2023, in the chunked form of the model's published code).
+
+Two entries over one set of parameters:
+
+* ``sequence(ids, first_position, valid)``: T positions of each sequence in
+  one causal forward (the learner's window, ops/losses.py; the checks);
+* ``__call__(id, hidden)``: one position through the cache (rollout, eval,
+  the serving engine). ``hidden`` holds, a layer, the current window's K and
+  V (window, heads, d), the summaries of the whole game (max_positions /
+  chunk, heads, d), and ONE position counter a sequence. A row is written at the
+  sequence's own counter; nothing is ever cleared: what a counter does not
+  reach is masked, so a new game resets the counter alone (``reset_hidden``).
+
+The layer holds ``heads_held`` of the ``heads_published`` heads: this chip's
+share where four chips share each layer by heads. ``W_q``, ``W_k``, ``W_v``
+have those heads' columns and ``W_o`` their rows; the attention output is
+this chip's part of ``W_o``'s sum and is passed on as such. No code stands
+in for the other chips.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from . import register
+
+NEG = -1e30
+f32 = jnp.float32
+
+
+def _rms_norm(x, g, eps, dtype):
+    """Float32 in, ``dtype`` out; the published norm's unit offset."""
+    x = x.astype(f32)
+    y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (y * (1.0 + g.astype(f32))).astype(dtype)
+
+
+def _rotary(x, positions, theta):
+    """x (..., d) at absolute ``positions`` (broadcast over x's leading
+    axes): the pair (i, i + d/2) turned by p * theta^(-2i/d), in float32."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=f32) / half)
+    angle = positions.astype(f32)[..., None] * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = x[..., :half].astype(f32), x[..., half:].astype(f32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _dot(x, w, dtype, out=None):
+    return jnp.dot(x.astype(dtype), w.astype(dtype),
+                   preferred_element_type=out or dtype)
+
+
+def _summarise(k, v, mu, phi, member):
+    """Chunk summaries of one sequence. k, v (H, T, d); ``member`` (C, T)
+    says which positions each chunk holds. Returns k~, v~ (H, C, d)."""
+    with jax.named_scope('eva_summarise'):
+        m = member.astype(k.dtype)
+        count = jnp.maximum(member.sum(axis=1), 1).astype(f32)
+        k_mean = jnp.einsum('ct,htd->hcd', m, k,
+                            preferred_element_type=f32) / count[None, :, None]
+        sk = (k_mean + mu.astype(f32)[:, None, :]).astype(k.dtype)
+        scale = k.shape[-1] ** -0.5
+        logit = scale * jnp.einsum('htd,hd->ht', k, phi.astype(k.dtype),
+                                   preferred_element_type=f32)
+        logit = jnp.where(member[None], logit[:, None, :], NEG)   # (H, C, T)
+        weight = jax.nn.softmax(logit, axis=-1) * member[None]
+        sv = jnp.einsum('hct,htd->hcd', weight.astype(v.dtype), v,
+                        preferred_element_type=f32).astype(v.dtype)
+        return sk, sv
+
+
+class EvaBlock(nn.Module):
+    """One decoder layer: this chip's heads of the attention, the whole MLP."""
+    hidden_size: int
+    heads_held: int
+    head_dim: int
+    mlp_size: int
+    chunk_size: int
+    window_size: int
+    rope_theta: float
+    norm_eps: float
+    query_block: int
+    dtype: Any
+
+    def setup(self):
+        init = nn.initializers.normal(0.02)
+        D, A = self.hidden_size, self.heads_held * self.head_dim
+        self.wq = self.param('wq', init, (D, A))
+        self.wk = self.param('wk', init, (D, A))
+        self.wv = self.param('wv', init, (D, A))
+        self.wo = self.param('wo', init, (A, D))
+        self.mu = self.param('mu', init, (self.heads_held, self.head_dim))
+        self.phi = self.param('phi', init, (self.heads_held, self.head_dim))
+        self.w_gate = self.param('w_gate', init, (D, self.mlp_size))
+        self.w_up = self.param('w_up', init, (D, self.mlp_size))
+        self.w_down = self.param('w_down', init, (self.mlp_size, D))
+        self.norm_attn = self.param('norm_attn', nn.initializers.zeros, (D,))
+        self.norm_mlp = self.param('norm_mlp', nn.initializers.zeros, (D,))
+
+    # -- shared -------------------------------------------------------------
+    def _qkv(self, x, positions):
+        """x (..., D) float32 -> q, k, v (..., H, d) in ``dtype``, q and k
+        turned by their positions' phases."""
+        h = _rms_norm(x, self.norm_attn, self.norm_eps, self.dtype)
+        shape = x.shape[:-1] + (self.heads_held, self.head_dim)
+        q = _dot(h, self.wq, self.dtype).reshape(shape)
+        k = _dot(h, self.wk, self.dtype).reshape(shape)
+        v = _dot(h, self.wv, self.dtype).reshape(shape)
+        pos = positions[..., None]
+        return (_rotary(q, pos, self.rope_theta),
+                _rotary(k, pos, self.rope_theta), v)
+
+    def mlp(self, x):
+        with jax.named_scope('trunk_mlp'):
+            h = _rms_norm(x, self.norm_mlp, self.norm_eps, self.dtype)
+            act = (jax.nn.silu(_dot(h, self.w_gate, self.dtype))
+                   * _dot(h, self.w_up, self.dtype))
+            return x + _dot(act, self.w_down, self.dtype, out=f32)
+
+    # -- a whole window -----------------------------------------------------
+    def attention_part(self, x, positions, valid, no_grad_prefix=0):
+        """This chip's part of the attention output for (B, T, D) float32
+        inputs at absolute ``positions`` (B, T): (B, T, D) float32."""
+        with jax.named_scope('eva_attention'):
+            q, k, v = self._qkv(x, positions)
+            if no_grad_prefix:
+                # the burn-in's positions are state, not trained: their
+                # keys and values (and so their summaries) carry no gradient
+                keep = (jnp.arange(x.shape[1]) >= no_grad_prefix)[
+                    None, :, None, None]
+                k = jnp.where(keep, k, jax.lax.stop_gradient(k))
+                v = jnp.where(keep, v, jax.lax.stop_gradient(v))
+            y = jax.vmap(self._sequence_attention)(q, k, v, positions, valid)
+            return _dot(y, self.wo, self.dtype, out=f32)
+
+    def _sequence_attention(self, q, k, v, positions, valid):
+        """One sequence. q, k, v (T, H, d) -> (T, H * d)."""
+        T, H, d = q.shape
+        W, chunk = self.window_size, self.chunk_size
+        q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))      # (H, T, d)
+        n_chunks = T // chunk + 1
+        chunk_ids = positions[0] // chunk + jnp.arange(n_chunks)
+        member = ((positions[None, :] // chunk == chunk_ids[:, None])
+                  & valid[None, :])                               # (C, T)
+        sk, sv = _summarise(k, v, self.mu, self.phi, member)
+        chunk_window = chunk_ids * chunk // W
+        present = member.any(axis=1)
+        scale = d ** -0.5
+        bq = min(self.query_block, T)
+        assert T % bq == 0, (T, bq)
+
+        @jax.checkpoint
+        def block(args):
+            qb, pq = args                                  # (H, bq, d), (bq,)
+            local = ((pq[:, None] // W == positions[None, :] // W)
+                     & (positions[None, :] <= pq[:, None]) & valid[None, :])
+            remote = ((chunk_window[None, :] < pq[:, None] // W)
+                      & present[None, :])
+            s_local = scale * jnp.einsum('hqd,hkd->hqk', qb, k,
+                                         preferred_element_type=f32)
+            s_remote = scale * jnp.einsum('hqd,hcd->hqc', qb, sk,
+                                          preferred_element_type=f32)
+            scores = jnp.concatenate(
+                [jnp.where(local[None], s_local, NEG),
+                 jnp.where(remote[None], s_remote, NEG)], axis=-1)
+            prob = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+            return (jnp.einsum('hqk,hkd->hqd', prob[..., :T], v,
+                               preferred_element_type=f32)
+                    + jnp.einsum('hqc,hcd->hqd', prob[..., T:], sv,
+                                 preferred_element_type=f32)).astype(v.dtype)
+
+        qs = q.reshape(H, T // bq, bq, d).swapaxes(0, 1)       # (nb, H, bq, d)
+        out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
+        return out.transpose(0, 2, 1, 3).reshape(T, H * d)
+
+    def sequence(self, x, positions, valid, no_grad_prefix=0):
+        x = x + self.attention_part(x, positions, valid, no_grad_prefix)
+        return self.mlp(x)
+
+    # -- one position through the cache -------------------------------------
+    def step(self, x, pos, cache):
+        """x (B, D) float32 at each sequence's own position ``pos`` (B,);
+        cache = (k, v (B, window, H, d), sk, sv (B, chunks, H, d))."""
+        ck, cv, csk, csv = cache
+        W, chunk = self.window_size, self.chunk_size
+        B = x.shape[0]
+        rows = jnp.arange(B)
+        with jax.named_scope('eva_attention'):
+            q, k, v = self._qkv(x, pos)                        # (B, H, d)
+            slot = pos % W
+            with jax.named_scope('state_update'):
+                ck = ck.at[rows, slot].set(k)
+                cv = cv.at[rows, slot].set(v)
+            # the chunk this position lies in, summarised over its members
+            # so far; its slot is read only once its window is over, by
+            # which time it is whole
+            start = (slot // chunk) * chunk
+            inside = start[:, None] + jnp.arange(chunk)[None, :]   # (B, chunk)
+            member = inside <= slot[:, None]
+            sk, sv = jax.vmap(
+                lambda kc, vc, m: _summarise(
+                    jnp.swapaxes(kc, 0, 1), jnp.swapaxes(vc, 0, 1),
+                    self.mu, self.phi, m[None]))(
+                ck[rows[:, None], inside], cv[rows[:, None], inside],
+                member)                                        # (B, H, 1, d)
+            with jax.named_scope('state_update'):
+                csk = csk.at[rows, pos // chunk].set(sk[:, :, 0])
+                csv = csv.at[rows, pos // chunk].set(sv[:, :, 0])
+            scale = self.head_dim ** -0.5
+            local = jnp.arange(W)[None, :] <= slot[:, None]    # (B, W)
+            remote = (jnp.arange(csk.shape[1])[None, :]
+                      < (pos // W * (W // chunk))[:, None])    # (B, chunks)
+            s_local = scale * jnp.einsum('bhd,bwhd->bhw', q, ck,
+                                         preferred_element_type=f32)
+            s_remote = scale * jnp.einsum('bhd,bchd->bhc', q, csk,
+                                          preferred_element_type=f32)
+            scores = jnp.concatenate(
+                [jnp.where(local[:, None], s_local, NEG),
+                 jnp.where(remote[:, None], s_remote, NEG)], axis=-1)
+            prob = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
+            y = (jnp.einsum('bhw,bwhd->bhd', prob[..., :W], cv,
+                            preferred_element_type=f32)
+                 + jnp.einsum('bhc,bchd->bhd', prob[..., W:], csv,
+                              preferred_element_type=f32))
+            x = x + _dot(y.reshape(B, -1), self.wo, self.dtype, out=f32)
+        return self.mlp(x), (ck, cv, csk, csv)
+
+
+@register('EvaByteNet')
+class EvaByteNet(nn.Module):
+    """The trunk with eight heads of prediction (head 0 is the policy over
+    the ids, heads 1-7 the published multi-byte objective) and a value row.
+    Observations are int32 ids. The published widths are the defaults; the
+    depth and the heads held are the deployment's cut (ISSUE 34)."""
+    hidden_size: int = 4096
+    layers: int = 4
+    heads_held: int = 8
+    heads_published: int = 32
+    head_dim: int = 128
+    mlp_size: int = 11008
+    vocab: int = 320
+    chunk_size: int = 16
+    window_size: int = 2048
+    max_positions: int = 8192
+    pred_heads: int = 8
+    rope_theta: float = 1e5
+    norm_eps: float = 1e-5
+    query_block: int = 512
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def setup(self):
+        init = nn.initializers.normal(0.02)
+        self.embed = self.param('embed', init, (self.vocab, self.hidden_size))
+        self.blocks = [EvaBlock(
+            self.hidden_size, self.heads_held, self.head_dim, self.mlp_size,
+            self.chunk_size, self.window_size, self.rope_theta, self.norm_eps,
+            self.query_block, self.dtype, name='layer_%d' % i)
+            for i in range(self.layers)]
+        self.norm_out = self.param('norm_out', nn.initializers.zeros,
+                                   (self.hidden_size,))
+        self.heads = self.param(
+            'heads', init, (self.pred_heads, self.hidden_size, self.vocab))
+        self.value = self.param('value', init, (self.hidden_size, 1))
+
+    @property
+    def actor_param_dtype(self):
+        """The actor's copy of the parameters is kept in the compute dtype
+        (train.py ``actor_refresh``): rollout reads every weight each ply."""
+        return self.dtype
+
+    # -- the cache -----------------------------------------------------------
+    def init_hidden(self, batch_shape=()):
+        lead = tuple(batch_shape)
+        H, d = self.heads_held, self.head_dim
+
+        def zeros(n):
+            return tuple(jnp.zeros(lead + (n, H, d), self.dtype)
+                         for _ in range(self.layers))
+        chunks = self.max_positions // self.chunk_size
+        return {'k': zeros(self.window_size), 'v': zeros(self.window_size),
+                'sk': zeros(chunks), 'sv': zeros(chunks),
+                'pos': jnp.zeros(lead, jnp.int32)}
+
+    @staticmethod
+    def reset_hidden(hidden, done):
+        """A finished game resets its sequences' counters, not their
+        buffers: what a counter has not reached is masked."""
+        pos = hidden['pos']
+        done = done.reshape(done.shape + (1,) * (pos.ndim - done.ndim))
+        return dict(hidden, pos=jnp.where(done, 0, pos))
+
+    # -- outputs ---------------------------------------------------------------
+    def _readout(self, x):
+        h = _rms_norm(x, self.norm_out, self.norm_eps, self.dtype)
+        logits = jnp.einsum('...d,ndv->...nv', h,
+                            self.heads.astype(self.dtype),
+                            preferred_element_type=f32)
+        value = jnp.tanh(_dot(h, self.value, self.dtype, out=f32))
+        return {'policy': logits[..., 0, :], 'value': value,
+                'heads': logits[..., 1:, :]}
+
+    def __call__(self, obs, hidden, train: bool = False):
+        """One position a sequence: obs (B,) int32 ids."""
+        if hidden is None:
+            hidden = self.init_hidden(obs.shape)
+        pos = hidden['pos']
+        x = self.embed[obs].astype(f32)
+        new = {'k': [], 'v': [], 'sk': [], 'sv': []}
+        for i, block in enumerate(self.blocks):
+            x, cache = block.step(
+                x, pos, tuple(hidden[key][i] for key in ('k', 'v', 'sk', 'sv')))
+            for key, leaf in zip(('k', 'v', 'sk', 'sv'), cache):
+                new[key].append(leaf)
+        out = self._readout(x)
+        out.pop('heads')    # acting reads head 0 alone
+        out['hidden'] = dict({key: tuple(v) for key, v in new.items()},
+                             pos=pos + 1)
+        return out
+
+    def sequence(self, ids, first_position, valid, no_grad_prefix: int = 0):
+        """T positions a sequence in one causal forward. ids (B, T) int32,
+        first_position (B,) the absolute position of each sequence's first
+        element, valid (B, T) bool. Returns policy (B, T, vocab), value
+        (B, T, 1), heads (B, T, pred_heads - 1, vocab), all float32."""
+        positions = first_position[:, None] + jnp.arange(ids.shape[1])
+        x = self.embed[ids].astype(f32)
+        for block in self.blocks:
+            # one layer rematerialised at a time: the backward pass keeps
+            # each layer's input and recomputes the rest
+            x = nn.remat(EvaBlock.sequence, static_argnums=(4,))(
+                block, x, positions, valid, no_grad_prefix)
+        return self._readout(x)
+
+    def attention_part(self, layer: int, x, positions, valid):
+        """Layer ``layer``'s attention output for this chip's heads alone
+        (the head-share test sums four of these against the uncut layer)."""
+        return self.blocks[layer].attention_part(x, positions, valid)

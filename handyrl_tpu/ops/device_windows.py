@@ -97,12 +97,22 @@ def _mask_rows(x, fill, cond):
     return jnp.where(cond.reshape((-1,) + (1,) * (x.ndim - 1)), x, fill)
 
 
+def _masked_obs(x, cond):
+    """Observation rows (T, ...) given a player axis, zero where ``cond``
+    (T,) is false: 0.0 for boards, the id 0 for integer observations (a
+    float fill would make the leaf float)."""
+    fill = 0.0 if jnp.issubdtype(x.dtype, jnp.floating) else 0
+    return _mask_rows(x[:, None], fill, cond)
+
+
 def _window_solo(take, S, ts_w, seat_w, outcome, fs: int, bi: int, L: int,
-                 has_reward: bool):
+                 has_reward: bool, first_position: bool = False):
     """ONE solo-layout window. ``take(key, idxm)`` returns the EVALUATED
     SEAT's values of that history leaf at game plies idxm (T,): (T, ...),
     whatever the storage (the observation may stay flat: only its leading
-    axis is used here)."""
+    axis is used here). With ``first_position`` the window also says which
+    game ply its first row is (a net that reads a window as a sequence
+    places rotary phases, chunks and windows by absolute position)."""
     T = bi + fs
     m = ts_w - bi + jnp.arange(T)                    # (T,)
     in_ep = (m >= 0) & (m < S)
@@ -111,7 +121,7 @@ def _window_solo(take, S, ts_w, seat_w, outcome, fs: int, bi: int, L: int,
     tail = (m >= S)
 
     obs = jax.tree_util.tree_map(              # obs may be a pytree
-        lambda x: _mask_rows(x[:, None], 0.0, valid), take('obs', idxm))
+        lambda x: _masked_obs(x, valid), take('obs', idxm))
     prob = jnp.where(valid, take('prob', idxm), 1.0)
     act = jnp.where(valid, take('action', idxm), 0)
     amask = _mask_rows(take('amask', idxm)[:, None], 1e32, valid)
@@ -125,7 +135,7 @@ def _window_solo(take, S, ts_w, seat_w, outcome, fs: int, bi: int, L: int,
         ret = jnp.zeros((T,), jnp.float32)
     progress = jnp.where(in_ep, m.astype(jnp.float32) / S, 1.0)
     f32 = jnp.float32
-    return {
+    window = {
         'observation': obs,
         'selected_prob': prob.astype(f32)[:, None, None],
         'action': act.astype(jnp.int32)[:, None, None],
@@ -139,6 +149,9 @@ def _window_solo(take, S, ts_w, seat_w, outcome, fs: int, bi: int, L: int,
         'observation_mask': valid.astype(f32)[:, None, None],
         'progress': progress.astype(f32)[:, None],
     }
+    if first_position:
+        window['first_position'] = m[:1].astype(jnp.int32).reshape(1, 1, 1)
+    return window
 
 
 def _window_turn(take, S, ts_w, outcome, fs: int, bi: int, L: int,
@@ -190,7 +203,8 @@ def _window_turn(take, S, ts_w, outcome, fs: int, bi: int, L: int,
 
 
 def build_windows_solo(hist: Dict[str, Any], S, ts, seat, outcome,
-                       fs: int, bi: int, L: int):
+                       fs: int, bi: int, L: int,
+                       first_position: bool = False):
     """Windows for ONE env in solo layout, from a game-ordered history.
 
     hist leaves are (L, P, ...); S scalar episode length; ts (W,) train
@@ -203,7 +217,8 @@ def build_windows_solo(hist: Dict[str, Any], S, ts, seat, outcome,
         take = lambda key, idxm: jax.tree_util.tree_map(
             lambda x: x[idxm][:, seat_w], hist[key])
         return flatten_window_keys(_window_solo(
-            take, S, ts_w, seat_w, outcome, fs, bi, L, 'reward' in hist))
+            take, S, ts_w, seat_w, outcome, fs, bi, L, 'reward' in hist,
+            first_position))
 
     return jax.vmap(one)(ts, seat)
 
@@ -292,8 +307,13 @@ class DeviceWindower:
 
     def __init__(self, mode: str, fs: int, bi: int, max_steps: int,
                  windows_cap: int, capacity: int, num_players: int,
-                 gamma: float, has_reward: bool):
+                 gamma: float, has_reward: bool,
+                 first_position: bool = False):
         assert mode in ('solo', 'turn')
+        # a ``first_position`` leaf a window, for a net that asks for it
+        # (one that declares ``sequence``); solo layout only
+        assert not first_position or mode == 'solo'
+        self.first_position = first_position
         self.mode = mode
         self.fs, self.bi = fs, bi
         self.L = max_steps
@@ -378,7 +398,8 @@ class DeviceWindower:
         if self.mode == 'solo':
             win = jax.eval_shape(
                 lambda h, s, t, seat, oc: build_windows_solo(
-                    h, s, t, seat, oc, self.fs, self.bi, self.L),
+                    h, s, t, seat, oc, self.fs, self.bi, self.L,
+                    self.first_position),
                 hist1, s_one, ts, ts, outcome1)
         else:
             win = jax.eval_shape(
@@ -408,6 +429,7 @@ class DeviceWindower:
         fs, bi, L, W, cap = self.fs, self.bi, self.L, self.W, self.capacity
         P, gamma, solo = self.P, self.gamma, self.mode == 'solo'
         has_reward = self.has_reward
+        first_position = self.first_position
         T = bi + fs
         hist_keys = self._hist_keys()
 
@@ -508,7 +530,8 @@ class DeviceWindower:
                 if solo:
                     win = _window_solo(
                         take, S[k, n], ts[k, n, w], seat[k, n, w],
-                        records['outcome'][k, n], fs, bi, L, has_reward)
+                        records['outcome'][k, n], fs, bi, L, has_reward,
+                        first_position)
                 else:
                     win = _window_turn(
                         take, S[k, n], ts[k, n, w],

@@ -250,6 +250,21 @@ class FusedPipeline:
         self.plies_host = 0
         self.builder_plies_host = 0
         self.episodes_host = 0
+        # what a window read as a sequence wastes and what the rollout's
+        # per-sequence state holds: positions trained (batch x
+        # forward_steps x SGD steps; the burn-in trains nothing) and those
+        # of them past a game's end, from the fetched data count; the
+        # bytes of ``hidden`` (a cache kept by counters is never cleared,
+        # so only its counters are ever reset)
+        self.window_len = windower.fs
+        self.batch_size = batch_size
+        self.window_positions_host = 0
+        self.window_padded_host = 0
+        self.chunks_host = 0
+        self.state_cache_bytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.hidden))
+        self.state_resets = hasattr(wrapper.module, 'reset_hidden')
+        telemetry.gauge('state_cache_bytes').set(self.state_cache_bytes)
 
     # -- multi-chip construction -------------------------------------------
     def _shard_loop_state(self, mesh):
@@ -359,7 +374,26 @@ class FusedPipeline:
             self.plies_host += K * N
             self.builder_plies_host += int(done.any(axis=1).sum())
             self.episodes_host += int(done.sum())
+            self.chunks_host += 1
+            if self.state_resets:
+                telemetry.counter('state_resets_total').inc(
+                    int(done.sum()) * P)
+            keys = self._metric_keys
+            if has_metrics and 'data_count' in keys:
+                positions = (self.sgd_steps * self.batch_size
+                             * self.window_len)
+                padded = positions - int(rest[3 + keys.index('data_count')])
+                self.window_positions_host += positions
+                self.window_padded_host += padded
+                telemetry.counter('window_positions_total').inc(positions)
+                telemetry.counter('window_padded_positions_total').inc(
+                    padded)
             span.set(plies=self.plies_host,
+                     chunks=self.chunks_host,
+                     window_positions=self.window_positions_host,
+                     window_padded_positions=self.window_padded_host,
+                     state_cache_byte_chunks=(self.state_cache_bytes
+                                              * self.chunks_host),
                      builder_plies=self.builder_plies_host,
                      # the builder makes one window a loop iteration,
                      # for the games that ended, and stores every one

@@ -11,6 +11,10 @@ XLA program:
     hidden carry; burn-in steps run in a separate scan whose carry passes
     through ``stop_gradient`` (the reference's no_grad replay,
     train.py:159-162);
+  * nets that declare ``sequence`` (models/evabyte.py): the window's T
+    positions of each (window, seat) in ONE causal forward at the window's
+    absolute positions, the burn-in prefix in the same call with no
+    gradient through its keys and values;
   * turn-alternating batches (P_obs=1, P=2): the acting player's policy row
     is gathered by multiplying with turn_mask and summing the player axis
     (train.py:179-180); per-player hidden state is gated by
@@ -82,8 +86,35 @@ def split_batch_stats(variables):
     return variables, None
 
 
+# weight of the auxiliary cross-entropy of a sequence net's further heads of
+# prediction (``outputs['heads']``) in the total loss: the source publishes
+# the heads, not a coefficient for them beside a policy-gradient loss
+# (benchmark/configs/evabyte.json ``assumed``)
+AUX_HEADS_COEF = 0.1
+
+
+def _sequence_prediction(sequence_fn, params, batch: Dict[str, Any],
+                         cfg: LossConfig):
+    """A window as ONE causal forward: the net's ``sequence`` over all
+    burn-in + T positions of each (window, seat), at the absolute positions
+    the window's ``first_position`` leaf gives; the state at the burn-in's
+    end carries no gradient (``no_grad_prefix``). Observations are integer
+    ids (B, T, P_obs). (The recurrent branch below is not touched: its two
+    ``astype``s for bfloat16 are a `benchmark` PR's, ROADMAP C4.)"""
+    ids = batch['observation']
+    B, T, P_obs = ids.shape[:3]
+    fold = lambda x: jnp.moveaxis(x, 2, 1).reshape((B * P_obs, T))
+    first = jnp.repeat(batch['first_position'].reshape(B), P_obs)
+    out = sequence_fn(params, fold(ids), first,
+                      fold(batch['episode_mask'][..., 0]
+                           * jnp.ones((1, 1, P_obs))) > 0,
+                      cfg.burn_in_steps)
+    return {k: jnp.moveaxis(v.reshape((B, P_obs, T) + v.shape[2:]), 1, 2)
+            for k, v in out.items()}
+
+
 def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
-                       cfg: LossConfig, batch_stats=None):
+                       cfg: LossConfig, batch_stats=None, sequence_fn=None):
     """Run the net over a training window; returns time-major-stacked outputs
     shaped (B, T, P, ...) with policy/value/return masking applied.
 
@@ -109,7 +140,10 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
                             h_in, train=True, mutable=['batch_stats'])
         return dict(out), lax.stop_gradient(mut['batch_stats'])
 
-    if hidden is None:
+    if sequence_fn is not None:
+        outputs, new_bs = _sequence_prediction(sequence_fn, params, batch,
+                                               cfg), None
+    elif hidden is None:
         obs = tmap(_fold_bt, observations)
         outputs, new_bs = net(batch_stats, obs, None)
         outputs = {k: v.reshape((B, T, P_obs) + v.shape[1:])
@@ -172,6 +206,8 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
                 # turn-alternating batch: gather the acting player's row
                 o = o.sum(axis=2, keepdims=True)
             masked[k] = o - batch['action_mask']
+        elif o.ndim > batch['observation_mask'].ndim:   # (B, T, P, n, A)
+            masked[k] = o * batch['observation_mask'][..., None]
         else:
             masked[k] = o * batch['observation_mask']
     if batch_stats is None and new_bs is None:
@@ -210,10 +246,36 @@ def compose_losses(outputs: Dict[str, jnp.ndarray],
     losses['ent'] = entropy.sum()
 
     base = losses['p'] + losses.get('v', 0) + losses.get('r', 0)
+    if 'heads' in outputs:
+        losses['aux'] = _further_heads_loss(outputs['heads'], batch)
+        base = base + AUX_HEADS_COEF * losses['aux']
     decay = 1 - batch['progress'] * (1 - cfg.entropy_regularization_decay)
     entropy_loss = (entropy * decay).sum() * -cfg.entropy_regularization
     losses['total'] = base + entropy_loss
     return losses, dcnt
+
+
+def _further_heads_loss(heads: jnp.ndarray, batch: Dict[str, Any]
+                        ) -> jnp.ndarray:
+    """The published multi-byte objective as an auxiliary cross-entropy:
+    head j of ``heads`` (B, T, P, n, A) at position t predicts the id the
+    same seat observes at t + 2 + j, counted where both positions lie in the
+    game and in the window."""
+    ids = batch['observation']                           # (B, T, P) int
+    in_game = batch['observation_mask'][..., 0]          # (B, T, P)
+    T = ids.shape[1]
+    logp = jax.nn.log_softmax(heads, axis=-1)
+    total = 0.0
+    for j in range(heads.shape[3]):
+        ahead = 2 + j
+        if ahead >= T:
+            break
+        target = ids[:, ahead:]
+        picked = jnp.take_along_axis(logp[:, :T - ahead, :, j],
+                                     target[..., None], axis=-1)[..., 0]
+        total = total - (picked * in_game[:, :T - ahead]
+                         * in_game[:, ahead:]).sum()
+    return total
 
 
 def optax_huber(pred: jnp.ndarray, target: jnp.ndarray, delta: float = 1.0
@@ -226,8 +288,8 @@ def optax_huber(pred: jnp.ndarray, target: jnp.ndarray, delta: float = 1.0
 
 
 def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
-                 cfg: LossConfig, batch_stats=None, target_params=None
-                 ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+                 cfg: LossConfig, batch_stats=None, target_params=None,
+                 sequence_fn=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """Full pipeline: forward, targets, advantages, composed losses.
 
     Returns (total_loss, aux) where aux carries per-term sums and the data
@@ -249,7 +311,7 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     if batch_stats is None:
         params, batch_stats = split_batch_stats(params)
     outputs = forward_prediction(apply_fn, params, init_hidden, batch, cfg,
-                                 batch_stats)
+                                 batch_stats, sequence_fn=sequence_fn)
     new_bs = None
     if batch_stats is not None:
         outputs, new_bs = outputs
@@ -259,7 +321,8 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     if use_target:
         t_params, t_bs = split_batch_stats(target_params)
         tgt_outputs = forward_prediction(apply_fn, t_params, init_hidden,
-                                         batch, cfg, t_bs)
+                                         batch, cfg, t_bs,
+                                         sequence_fn=sequence_fn)
         if t_bs is not None:
             tgt_outputs, _ = tgt_outputs   # target stats never advance
         tgt_outputs = {k: lax.stop_gradient(v)

@@ -59,9 +59,16 @@ def _update_core(module, cfg: LossConfig, optimizer, axis_name=None):
     the global-norm clip, which must see the GLOBAL gradient — is identical
     on every shard, keeping params replicated without a broadcast."""
     apply_fn = module.apply
+    # a net that declares ``sequence`` consumes a window as one causal
+    # forward (losses.py ``_sequence_prediction``); its ``init_hidden`` is
+    # the rollout's cache and the learner never builds one
+    sequence_fn = None
+    if hasattr(module, 'sequence'):
+        def sequence_fn(params, *args):
+            return module.apply(params, *args, method=module.sequence)
 
     def init_hidden_for(batch):
-        if not hasattr(module, 'init_hidden'):
+        if sequence_fn is not None or not hasattr(module, 'init_hidden'):
             return None
         B = batch['value'].shape[0]
         P = batch['value'].shape[2]
@@ -76,7 +83,8 @@ def _update_core(module, cfg: LossConfig, optimizer, axis_name=None):
         def loss_fn(params):
             return compute_loss(apply_fn, params, init_hidden, batch, cfg,
                                 batch_stats=batch_stats,
-                                target_params=target_params)
+                                target_params=target_params,
+                                sequence_fn=sequence_fn)
 
         (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(trainable)
         new_bs = aux.pop('batch_stats', None)

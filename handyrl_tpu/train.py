@@ -109,6 +109,10 @@ def _batcher_process(conn, bid: int):
 
 _SHM_SLOTS = 4   # in-flight shared-memory batches per batcher child
 
+# The fused loop: how long one eval share may hold the loop, as a share of
+# what the iteration's training stretch held it (Learner._run_eval_share).
+EVAL_SHARE_OF_TRAINING = 0.25
+
 
 def _is_free_msg(msg) -> bool:
     return (isinstance(msg, tuple) and len(msg) == 2
@@ -2646,18 +2650,26 @@ class Learner:
         jax.jit(chaos_retrace_probe)(jax.device_put(
             np.zeros((self.model_epoch % 7 + 1,), np.float32)))
 
-    def _run_eval_share(self, evaluator, tracker: Dict[str, int]):
+    def _run_eval_share(self, evaluator, tracker: Dict[str, int],
+                        budget_s: Optional[float] = None):
         """Advance online evaluation until its share of episodes reaches
         eval_rate. The host evaluator advances all its matches ONE ply per
         call while chunked generators deliver episodes in bursts, so it gets
         several plies per loop iteration or it never finishes a match; the
         device evaluator finishes whole batches per call and exits after one
-        step once the share is met. ``tracker`` carries the previous
+        step once the share is met. ``budget_s`` (the fused loop's) bounds
+        how long one share may hold the loop: after its first step, a share
+        whose steps have taken that long ends, and what is still owed is
+        made up in the shares that follow. ``tracker`` carries the previous
         dispatch's epoch for pipelined evaluators (their results arrive one
         dispatch late)."""
         pipelined = getattr(evaluator, 'pipelined', False)
-        for _ in range(16):
+        t0 = time.perf_counter()
+        for n in range(16):
             if self.num_results >= self.eval_rate * self.num_episodes:
+                break
+            if (n and budget_s is not None
+                    and time.perf_counter() - t0 >= budget_s):
                 break
             cur = self.model_epoch
             results = evaluator.step()
@@ -2810,7 +2822,10 @@ class Learner:
                 # global ring keeps the configured total budget
                 capacity=max(1, self.trainer.replay.capacity // n_dev),
                 num_players=env_mod.NUM_PLAYERS, gamma=args['gamma'],
-                has_reward=hasattr(env_mod, 'rewards'))
+                has_reward=hasattr(env_mod, 'rewards'),
+                # a net that reads a window as one sequence is told where
+                # in its game the window starts
+                first_position=hasattr(self.wrapper.module, 'sequence'))
             return self._run_fused(env_mod, actor, evaluator, windower,
                                    ingest_mode)
 
@@ -2939,6 +2954,16 @@ class Learner:
         # no host round trip, and correct even on epochs where
         # checkpoint_interval skipped the host snapshot. A real copy (not an
         # alias) is required — the next fused dispatch donates tr.state.
+        # A net that acts through a cache reads every weight each ply: it
+        # names the dtype its actor's copy is kept in (``actor_param_dtype``,
+        # its compute dtype), and the copy is a cast. Every other net's copy
+        # is the float32 parameters as they are.
+        actor_dtype = getattr(self.wrapper.module, 'actor_param_dtype', None)
+        copy_leaf = (jnp.copy if actor_dtype is None
+                     else lambda x: x.astype(actor_dtype))
+
+        def copy_tree(p):
+            return jax.tree_util.tree_map(copy_leaf, p)
         if tr.mesh is not None:
             # pin the replicated layout up front so dispatches never
             # re-broadcast device-0 arrays across the mesh
@@ -2947,17 +2972,23 @@ class Learner:
             actor.params = jax.device_put(actor.params, repl)
             if tr.state is not None:
                 tr.state = jax.device_put(tr.state, repl)
-            copy_params = jax.jit(
-                lambda p: jax.tree_util.tree_map(jnp.copy, p),
-                out_shardings=repl)
+            copy_params = jax.jit(copy_tree, out_shardings=repl)
         else:
-            copy_params = jax.jit(
-                lambda p: jax.tree_util.tree_map(jnp.copy, p))
+            copy_params = jax.jit(copy_tree)
         if tr.state is not None:
             # first refresh NOW (same values the actor already holds): the
             # copy program compiles during warm-up, not at the first epoch
             # boundary after the retrace sentinel has armed
             actor.params = copy_params(tr.state.params)
+            if actor_dtype is not None and isinstance(
+                    jax.tree_util.tree_leaves(self.wrapper.params)[0],
+                    jax.Array):
+                # the learner's snapshot lives on the host from the first
+                # checkpoint on (_advance_epoch); a seeded initialisation
+                # of gigabytes is moved there now, not left on the device
+                # beside the train state that was copied from it
+                from .utils.fetch import fetch_tree
+                self.wrapper.params = fetch_tree(self.wrapper.params)
 
         while not self.shutdown_flag:
             if self._deadline and time.time() >= self._deadline:
@@ -2968,6 +2999,7 @@ class Learner:
                 break
             with telemetry.trace_span('fused_iter',
                                       step_num=fp.dispatches + 1) as it:
+                iter_t0 = time.perf_counter()
                 # a checkpoint the writer has finished is announced here,
                 # one chunk after its boundary at the earliest
                 self._collect_checkpoint(block=False)
@@ -3006,9 +3038,21 @@ class Learner:
                     account(prev)
                     span.set(episodes_admitted=self.num_returned_episodes)
                 with telemetry.trace_span('eval_share') as span:
-                    self._run_eval_share(evaluator, eval_tracker)
+                    # evaluation may hold the loop for a share of what the
+                    # training stretch of this iteration just did (its
+                    # dispatch, the wait for the previous one, the
+                    # accounting): the small nets' 3 ms chunks never feel
+                    # it, a net whose eval chunk costs a third of its
+                    # training dispatch gets one chunk an iteration instead
+                    # of a burst of up to sixteen that stops training for
+                    # ten seconds
+                    budget_s = EVAL_SHARE_OF_TRAINING * (
+                        time.perf_counter() - iter_t0)
+                    self._run_eval_share(evaluator, eval_tracker,
+                                         budget_s=budget_s)
                     span.set(eval_dispatches=getattr(evaluator, 'dispatches',
-                                                     0))
+                                                     0),
+                             budget_ms=round(1e3 * budget_s, 3))
                 if cadence.due(self.num_returned_episodes):
                     with telemetry.trace_span('epoch_boundary') as span:
                         self._fused_epoch(pending_metrics, epoch_steps,

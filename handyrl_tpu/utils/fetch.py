@@ -6,7 +6,8 @@ does not shrink with the array, while the bytes themselves are cheap at
 these sizes. Naive ``np.asarray`` per pytree leaf therefore costs leaves x
 that fixed cost at every epoch boundary. ``fetch_tree`` flattens the tree
 into ONE device buffer per dtype (a tiny jitted concat, dispatched async)
-and pays one transfer per dtype group instead. (The per-transfer cost on a
+and pays one transfer per dtype group instead; a leaf over
+``LARGE_LEAF_BYTES`` goes on its own. (The per-transfer cost on a
 directly attached chip: not measured on today's code, ROADMAP S2.)
 """
 
@@ -19,6 +20,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry
+
+# A leaf over this many bytes is fetched on its own: packing exists to save
+# the fixed cost of a transfer, which a leaf of tens of megabytes does not
+# feel, while the packed buffer is a second copy of every leaf in it ON THE
+# DEVICE: for a train state of gigabytes, one it has no room for.
+LARGE_LEAF_BYTES = 64 << 20
 
 _PACKERS: Dict[Tuple, Any] = {}
 _SPLITTERS: Dict[Tuple, Any] = {}
@@ -65,10 +72,12 @@ def fetch_tree(tree: Any) -> Any:
     device_ix: Dict[Any, List[int]] = {}
     out: List[Any] = [None] * len(leaves)
     for i, leaf in enumerate(leaves):
-        if isinstance(leaf, jax.Array):
-            device_ix.setdefault(jnp.asarray(leaf).dtype, []).append(i)
-        else:
+        if not isinstance(leaf, jax.Array):
             out[i] = leaf
+        elif leaf.nbytes > LARGE_LEAF_BYTES:
+            out[i] = np.asarray(leaf)
+        else:
+            device_ix.setdefault(jnp.asarray(leaf).dtype, []).append(i)
     for dtype, idxs in device_ix.items():
         group = [leaves[i] for i in idxs]
         if len(group) == 1:
@@ -98,8 +107,18 @@ def put_tree(tree: Any) -> Any:
     groups: Dict[Any, List[int]] = {}
     out: List[Any] = [None] * len(leaves)
     for i, leaf in enumerate(leaves):
+        if isinstance(leaf, jax.Array):
+            # already on the device (a seeded initialisation): it stays. A
+            # round trip would be a SECOND device copy of it, which a
+            # parameter set of gigabytes has no room for beside the train
+            # state that is made from it
+            out[i] = leaf
+            continue
         arr = np.asarray(leaf)
         leaves[i] = arr
+        if arr.nbytes > LARGE_LEAF_BYTES:
+            out[i] = jax.device_put(arr)
+            continue
         groups.setdefault(arr.dtype, []).append(i)
     for dtype, idxs in groups.items():
         group = [leaves[i] for i in idxs]

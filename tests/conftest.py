@@ -83,6 +83,23 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(skip)
 
 
+@pytest.fixture(autouse=True)
+def _retrace_sentinel_ends_with_its_test():
+    """The retrace sentinel's policy and its steady-state flag are
+    process-global (telemetry.clear_steady_state: "learner shutdown, or test
+    teardown"). A test that runs a cell's config in-process leaves the
+    ``abort`` policy behind, and one that warms a device actor marks steady
+    state with no learner to shut down: the next jit on that xdist worker
+    then raises RetraceError. Which files share a worker changes with every
+    file added, so each test leaves the process as a fresh one has it."""
+    yield
+    from handyrl_tpu import telemetry
+    if telemetry.steady_state_active():
+        telemetry.clear_steady_state()
+    telemetry._STEADY['retraces'] = 0    # only mark_steady_state zeroes it
+    telemetry.configure_perf_plane(True, 'warn')
+
+
 @pytest.hookimpl(wrapper=True)
 def pytest_runtest_call(item):
     mark = item.get_closest_marker('timeout')

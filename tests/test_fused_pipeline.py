@@ -216,6 +216,11 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
         # the fetch runs one chunk behind: the first iteration has none
         assert names.count('host_block') == (0 if n == 0 else 1)
         assert names.count('chunk_account') == names.count('eval_share') == 1
+    # every eval share is told how long it may hold the loop: a share of
+    # its own iteration's training stretch
+    shares = [r for r in recs if r['name'] == 'eval_share']
+    assert all(r['attrs']['budget_ms'] >= 0 for r in shares)
+    assert any(r['attrs']['budget_ms'] > 0 for r in shares)
     boundaries = [r for r in recs if r['name'] == 'epoch_boundary']
     assert [r['attrs']['epoch'] for r in boundaries] == [1, 2]
     for boundary in boundaries:

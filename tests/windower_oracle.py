@@ -10,6 +10,11 @@ bit-identical to this for the same records and key
 (tests/test_device_windows.py). Its ring keeps the rows of that commit too,
 as wide as the window and no wider: the production ring must hold the same
 values in its logical columns and zeros in its padding.
+
+Two additions for a net that reads a window as a sequence (PR 34), neither
+of which touches a float observation's arithmetic: an integer observation is
+masked with the id 0 of its own dtype, and with ``first_position`` a window
+also carries the game ply of its first row.
 """
 
 from typing import Any, Dict
@@ -29,7 +34,7 @@ def _take(hist_leaf, idxm):
 
 
 def build_windows_solo(hist: Dict[str, Any], S, ts, seat, outcome,
-                       fs: int, bi: int, L: int):
+                       fs: int, bi: int, L: int, first_position=False):
     """Windows for ONE env in solo layout.
 
     hist leaves are (L, P, ...); S scalar episode length; ts (W,) train
@@ -51,7 +56,9 @@ def build_windows_solo(hist: Dict[str, Any], S, ts, seat, outcome,
             return jnp.where(c, x, fill)
 
         obs = jax.tree_util.tree_map(          # obs may be a pytree
-            lambda x: vmask(_take(x, idxm)[:, seat_w][:, None], 0.0, valid),
+            lambda x: vmask(_take(x, idxm)[:, seat_w][:, None],
+                            0.0 if jnp.issubdtype(x.dtype, jnp.floating)
+                            else 0, valid),
             hist['obs'])                                            # (T,1,...)
         prob = jnp.where(valid, _take(hist['prob'], idxm)[:, seat_w], 1.0)
         act = jnp.where(valid, _take(hist['action'], idxm)[:, seat_w], 0)
@@ -68,7 +75,7 @@ def build_windows_solo(hist: Dict[str, Any], S, ts, seat, outcome,
             ret = jnp.zeros((T,), jnp.float32)
         progress = jnp.where(in_ep, m.astype(jnp.float32) / S, 1.0)
         f32 = jnp.float32
-        return {
+        window = {
             'observation': obs,
             'selected_prob': prob.astype(f32)[:, None, None],
             'action': act.astype(jnp.int32)[:, None, None],
@@ -82,6 +89,10 @@ def build_windows_solo(hist: Dict[str, Any], S, ts, seat, outcome,
             'observation_mask': valid.astype(f32)[:, None, None],
             'progress': progress.astype(f32)[:, None],
         }
+        if first_position:
+            window['first_position'] = m[:1].astype(jnp.int32).reshape(
+                1, 1, 1)
+        return window
 
     return jax.vmap(lambda t, s: flatten_window_keys(one(t, s)))(ts, seat)
 
@@ -230,8 +241,9 @@ class OracleWindower(DeviceWindower):
                     seat = jax.random.randint(k_seat, (N, W), 0, P)
                     windows = jax.vmap(
                         build_windows_solo,
-                        in_axes=(0, 0, 0, 0, 0, None, None, None))(
-                            win_hist, S, ts, seat, outcome, fs, bi, L)
+                        in_axes=(0, 0, 0, 0, 0, None, None, None, None))(
+                            win_hist, S, ts, seat, outcome, fs, bi, L,
+                            self.first_position)
                 else:
                     windows = jax.vmap(
                         build_windows_turn,
