@@ -2013,31 +2013,55 @@ class ChunkMonitor:
         self._done: Optional[float] = None     # the last completion
         self._tail: Dict[str, float] = {}      # the last iteration's tail
 
-    def fetched(self, dispatch: int, span) -> Optional[Dict[str, Any]]:
+    def fetched(self, dispatch: int, span,
+                boundary=None) -> Optional[Dict[str, Any]]:
         """The step of iteration ``span`` (still open) has returned: if it
-        fetched a chunk, book the interval that chunk's completion ends."""
-        blocks = [c for c in span.children if c.name == 'host_block']
+        fetched a chunk, book the interval that chunk's completion ends.
+        ``boundary`` is the iteration's open ``epoch_boundary`` span where
+        it made the NEXT iteration's step itself, ahead of its state fetch:
+        the interval then lies inside this one iteration, from its own
+        step's completion through its accounting, its eval share and the
+        boundary so far."""
+        scope = span if boundary is None else boundary
+        blocks = [c for c in scope.children if c.name == 'host_block']
         if not blocks:
             return None
         done, before = blocks[-1].t1, self._done
         self._done = done
+        split, self._tail = self._tail, {}
         if before is None:
             return None
-        split = dict(self._tail)
         for key, stage in CHUNK_HEAD:
-            split[key] = split.get(key, 0.0) + span.child_seconds(stage)
+            split[key] = split.get(key, 0.0) + scope.child_seconds(stage)
+        if boundary is not None:
+            for key, stage in CHUNK_TAIL[:2]:
+                split[key] = split.get(key, 0.0) + span.child_seconds(stage)
+            split['epoch'] = (split.get('epoch', 0.0) + done - boundary.t0
+                              - sum(scope.child_seconds(stage)
+                                    for _key, stage in CHUNK_HEAD))
         return self.observe(dispatch, done - before, split)
 
     def closed(self, span):
         """Iteration ``span`` has finished: its tail opens the next
-        interval. The boundary's packed state fetch waits for the chunk in
-        flight: that part of the boundary is the next interval's wait."""
+        interval. A boundary's packed state fetch waits for the chunk in
+        flight: that part of the boundary is the next interval's wait. Of a
+        boundary that made the next step itself (``fetched`` has booked the
+        iteration up to that step's completion) the rest is the tail."""
         tail = {key: span.child_seconds(stage) for key, stage in CHUNK_TAIL}
-        tail['wait'] = sum(
-            boundary.child_seconds('state_fetch')
-            for boundary in span.children if boundary.name == 'epoch_boundary')
-        tail['epoch'] -= tail['wait']
-        self._tail = tail
+        tail['wait'] = 0.0
+        for boundary in span.children:
+            if boundary.name != 'epoch_boundary':
+                continue
+            stepped = [c for c in boundary.children
+                       if c.name == 'host_block']
+            if stepped:
+                tail = {'epoch': boundary.t1 - stepped[-1].t1}
+            tail['wait'] = boundary.child_seconds('state_fetch')
+            tail['epoch'] -= tail['wait']
+        # (added, not set: the iteration after such a boundary has no step
+        # of its own, and its tail joins what is left of the boundary's)
+        for key, seconds in tail.items():
+            self._tail[key] = self._tail.get(key, 0.0) + seconds
 
     def observe(self, dispatch: int, interval_s: float,
                 split: Dict[str, float]) -> Optional[Dict[str, Any]]:

@@ -1636,6 +1636,16 @@ class Learner:
             self._write_checkpoint(job)
             self._announce_checkpoint(job)
 
+    def _bump_epoch(self):
+        """The part of an epoch close that needs no bytes: the fused loop's
+        boundary does it before it enqueues the next dispatch, which goes
+        out under the new epoch's tag, and fetches the train state after."""
+        self.model_epoch += 1
+        # chaos 'nanepoch': poison updates right after this epoch's
+        # checkpoint lands, so a rollback target provably exists
+        if self._chaos.get('nanepoch') == self.model_epoch:
+            self.trainer.chaos_nan.arm(self.trainer.steps + 1)
+
     def _advance_epoch(self, params, steps: int,
                        state: Optional[TrainState] = None,
                        state_blob: Optional[bytes] = None, bump: bool = True,
@@ -1646,11 +1656,7 @@ class Learner:
         ``write_files`` False epoch)."""
         print('updated model(%d)' % steps)
         if bump:
-            self.model_epoch += 1
-            # chaos 'nanepoch': poison updates right after this epoch's
-            # checkpoint lands, so a rollback target provably exists
-            if self._chaos.get('nanepoch') == self.model_epoch:
-                self.trainer.chaos_nan.arm(self.trainer.steps + 1)
+            self._bump_epoch()
         if not write_files:
             return None
         self._last_ckpt_epoch = self.model_epoch
@@ -1724,19 +1730,25 @@ class Learner:
             # checkpoint
             self._sync_durable_state(job.durable)
 
-    def _hand_over_checkpoint(self, host_state: TrainState):
+    def _hand_over_checkpoint(self, host_state: TrainState,
+                              steps: Optional[int] = None, bump: bool = True):
         """The fused loop's epoch close. That loop owns the only thread
         that feeds the device, so it keeps what only it may do and gives
         serialisation and the fsynced writes to the writer thread; the next
         dispatch is enqueued while they run. Depth ONE: the previous epoch's
         write is awaited and, where the loop's poll has not done so yet,
         announced first (span ``checkpoint_wait``, at every boundary, ~0
-        when the writer kept up). Returns this epoch's job, for the caller
-        to ``submit`` as the boundary's last act, and the seconds blocked."""
+        when the writer kept up). ``steps`` is the count ``host_state``
+        holds (the trainer's, unless the boundary has enqueued the next
+        dispatch already and bumped the epoch: ``bump`` False). Returns this
+        epoch's job, for the caller to ``submit`` as the boundary's last
+        act, and the seconds blocked."""
         with telemetry.trace_span('checkpoint_wait'):
             waited = self._collect_checkpoint()
-        return self._advance_epoch(host_state.params, self.trainer.steps,
-                                   state=host_state), waited
+        if steps is None:
+            steps = self.trainer.steps
+        return self._advance_epoch(host_state.params, steps,
+                                   state=host_state, bump=bump), waited
 
     def _collect_checkpoint(self, block: bool = True) -> float:
         """Announce the fused loop's checkpoint once it is on disk; with
@@ -2990,6 +3002,62 @@ class Learner:
                 from .utils.fetch import fetch_tree
                 self.wrapper.params = fetch_tree(self.wrapper.params)
 
+        def warming():
+            # on a mesh, also hold warmup until EVERY shard's ring slice
+            # has at least one window (a shard with local size 0 would
+            # feed all-zero batches into the psum'd gradient);
+            # ring_min_host is one fetch behind, which only extends
+            # warmup by one chunk
+            return (self.num_returned_episodes < args['minimum_episodes']
+                    or (tr.mesh is not None and fp.dispatches > 0
+                        and fp.ring_min_host < 1))
+
+        def step(warm):
+            """One chunk: the actor follows the epoch, the chunk is
+            enqueued under the epoch's tag and the PREVIOUS chunk's result
+            is collected (returned). Called at the head of an iteration or,
+            for the next iteration, from inside an epoch boundary."""
+            nonlocal actor_epoch
+            if actor_epoch != self.model_epoch:
+                with telemetry.trace_span('actor_refresh'):
+                    actor.params = (copy_params(tr.state.params)
+                                    if tr.state is not None
+                                    else put_tree(self.wrapper.params))
+                actor_epoch = self.model_epoch
+            epoch_of_dispatch.append(self.model_epoch)
+            if warm:
+                return fp.warm_step(actor.params)
+            ema = tr.data_cnt_ema
+            if tr.chaos_nan.due(tr.steps, fp.sgd_steps):
+                _LOG.warning('chaos: injecting non-finite update at '
+                             'step %d', tr.steps)
+                ema = float('nan')   # poisons the on-device lr schedule
+            tr.state, prev = fp.train_step(actor.params, tr.state, ema)
+            # the training program has compiled: from here a compile
+            # is a retrace (the sentinel arms at the next boundary)
+            self._fused_trained = True
+            tr.steps += fp.sgd_steps
+            return prev
+
+        # a boundary that took the next iteration's step itself
+        # (_fused_epoch): the result that step collected, and its seconds
+        ahead = None
+
+        def enqueue_next(it, boundary):
+            """The next iteration's step, from inside ``boundary``. Not
+            where the loop ends at this boundary (no chunk runs past the
+            last checkpoint) or the next chunk is a warm-up one (it donates
+            nothing)."""
+            nonlocal ahead
+            if (self.shutdown_flag or self._past_epoch_budget()
+                    or self.preempt.requested() or warming()):
+                return False
+            t0 = time.perf_counter()
+            prev = step(False)
+            ahead = (prev, time.perf_counter() - t0)
+            monitor.fetched(fp.dispatches, it, boundary)
+            return True
+
         while not self.shutdown_flag:
             if self._deadline and time.time() >= self._deadline:
                 break                      # wall-clock budget spent mid-epoch
@@ -2997,43 +3065,25 @@ class Learner:
                 _LOG.warning('preemption signal received; snapshotting '
                              'and exiting')
                 break
-            with telemetry.trace_span('fused_iter',
-                                      step_num=fp.dispatches + 1) as it:
+            with telemetry.trace_span(
+                    'fused_iter',
+                    step_num=fp.dispatches + (ahead is None)) as it:
                 iter_t0 = time.perf_counter()
                 # a checkpoint the writer has finished is announced here,
                 # one chunk after its boundary at the earliest
                 self._collect_checkpoint(block=False)
-                if actor_epoch != self.model_epoch:
-                    with telemetry.trace_span('actor_refresh'):
-                        actor.params = (copy_params(tr.state.params)
-                                        if tr.state is not None
-                                        else put_tree(self.wrapper.params))
-                    actor_epoch = self.model_epoch
-                epoch_of_dispatch.append(self.model_epoch)
-                # on a mesh, also hold warmup until EVERY shard's ring slice
-                # has at least one window (a shard with local size 0 would
-                # feed all-zero batches into the psum'd gradient);
-                # ring_min_host is one fetch behind, which only extends
-                # warmup by one chunk
-                warm = (self.num_returned_episodes < args['minimum_episodes']
-                        or (tr.mesh is not None and fp.dispatches > 0
-                            and fp.ring_min_host < 1))
-                if warm:
-                    prev = fp.warm_step(actor.params)
+                if ahead is None:
+                    warm = warming()
+                    prev = step(warm)
+                    if not warm:
+                        epoch_steps += fp.sgd_steps
+                    monitor.fetched(fp.dispatches, it)
                 else:
-                    ema = tr.data_cnt_ema
-                    if tr.chaos_nan.due(tr.steps, fp.sgd_steps):
-                        _LOG.warning('chaos: injecting non-finite update at '
-                                     'step %d', tr.steps)
-                        ema = float('nan')   # poisons the on-device lr schedule
-                    tr.state, prev = fp.train_step(actor.params, tr.state,
-                                                   ema)
-                    # the training program has compiled: from here a compile
-                    # is a retrace (the sentinel arms at the next boundary)
-                    self._fused_trained = True
-                    tr.steps += fp.sgd_steps
-                    epoch_steps += fp.sgd_steps
-                monitor.fetched(fp.dispatches, it)
+                    # the last boundary made this iteration's step: its
+                    # seconds count towards the eval share's budget here
+                    (prev, ahead_s), ahead, warm = ahead, None, False
+                    iter_t0 -= ahead_s
+                chunk = fp.dispatches
                 with telemetry.trace_span('chunk_account') as span:
                     account(prev)
                     span.set(episodes_admitted=self.num_returned_episodes)
@@ -3055,17 +3105,25 @@ class Learner:
                              budget_ms=round(1e3 * budget_s, 3))
                 if cadence.due(self.num_returned_episodes):
                     with telemetry.trace_span('epoch_boundary') as span:
-                        self._fused_epoch(pending_metrics, epoch_steps,
-                                          time.time() - epoch_t0, fp,
-                                          evaluator, monitor.epoch_block())
-                        span.set(epoch=self.model_epoch)
+                        first = self._fused_epoch(
+                            pending_metrics, epoch_steps,
+                            time.time() - epoch_t0, fp, evaluator,
+                            monitor.epoch_block(),
+                            lambda: enqueue_next(it, span))
+                        span.set(epoch=self.model_epoch,
+                                 enqueued_first=int(first))
                     pending_metrics.clear()   # account() closes over this list
-                    epoch_steps = 0
+                    # a chunk the boundary enqueued is the new epoch's
+                    epoch_steps = fp.sgd_steps if first else 0
                     epoch_t0 = time.time()
                     if self._past_epoch_budget():
                         self.shutdown_flag = True
-                it.set(dispatch=fp.dispatches, warm=int(warm))
+                it.set(dispatch=chunk, warm=int(warm))
             monitor.closed(it)
+        if ahead is not None:
+            # the loop ended between a boundary and the iteration it had
+            # enqueued for (a deadline, a signal): that chunk's predecessor
+            account(ahead[0])
         monitor.flush()
         account(fp.drain())
         if hasattr(evaluator, 'drain'):
@@ -3078,12 +3136,16 @@ class Learner:
         self.final_flush()
 
     def _fused_epoch(self, pending_metrics, epoch_steps, epoch_wall,
-                     fp, evaluator, fused_block):
+                     fp, evaluator, fused_block, enqueue_next):
         """Epoch boundary for the fused loop: drain metric futures, print
         the reference-format lines, update the lr EMA, checkpoint.
         ``fused_block`` is the loop's per-chunk record of the epoch
         (telemetry.ChunkMonitor.epoch_block): it rides the metrics record
-        and its dispatch/wait split feeds the utilization proxy."""
+        and its dispatch/wait split feeds the utilization proxy.
+        ``enqueue_next`` is the loop's: it makes the next iteration's step
+        (that chunk enqueued, the chunk in flight collected; False where it
+        must not). Returns whether the boundary enqueued that chunk BEFORE
+        it fetched the train state."""
         tr = self.trainer
         print()
         print('epoch %d' % self.model_epoch)
@@ -3126,46 +3188,74 @@ class Learner:
 
         # What a checkpoint costs the loop is the fetch of the train state:
         # it waits for the chunk in flight, and the next dispatch donates
-        # tr.state, so the host copy is taken here. Serialisation and the
-        # fsynced writes (two thirds of all device idle time while the loop
-        # did them itself: PERF.md section 6, PR 32) run on the writer
-        # thread under the next dispatch. With checkpoint_interval > 1,
-        # intermediate epochs skip the host round trip entirely — the
-        # actor/eval params refresh device-to-device in the fused loop, so
-        # nothing here needs host bytes.
+        # tr.state. The fetch is two steps (utils/fetch.py): a pack of the
+        # leaves into ONE new device buffer, enqueued behind that chunk, and
+        # the blocking transfer of it. The buffer is no argument of the
+        # fused program, so where every leaf packs it is a snapshot that
+        # outlives the donation: the boundary packs, bumps the epoch,
+        # MAKES THE NEXT ITERATION'S STEP (`enqueue_next`: the refreshed
+        # actor, the new epoch's tag, the new ema: what that iteration
+        # would give it; the step collects the chunk in flight as ever, so
+        # the loop stays one dispatch deep) and only then fetches, with the
+        # device at work on the new chunk through the transfer, the record
+        # and the hand-over. A state with a leaf over LARGE_LEAF_BYTES (a
+        # trunk of gigabytes, fetched leaf by leaf because a second device
+        # copy has no room) keeps fetch-then-enqueue. Serialisation and the
+        # fsynced writes run on the writer thread (PERF.md section 6, PRs 32
+        # and 35). With checkpoint_interval > 1, intermediate epochs skip
+        # the host round trip entirely — the actor/eval params refresh
+        # device-to-device in the fused loop, so nothing here needs host
+        # bytes.
         interval = int(self.args.get('checkpoint_interval') or 1)
         final = 0 <= self.args['epochs'] <= self.model_epoch + 1
+        # what the record and the checkpoint say of THIS epoch's end: a
+        # step made ahead books the next chunk's SGD steps and dispatch
+        steps, dispatches = tr.steps, fp.dispatches
+        enqueued_first = False
+        telemetry.counter('epoch_boundaries_total').inc()
         if interval <= 1 or (self.model_epoch + 1) % interval == 0 or final:
+            from .utils import fetch
+            snapshot = None
+            if fetch.packs_whole(tr.state):
+                snapshot = fetch.pack_tree(tr.state, detach=True)
+                self._bump_epoch()
+                enqueued_first = enqueue_next()
             # ONE packed transfer for params + optimizer state, not one
             # blocking np.asarray per leaf
-            from .utils.fetch import fetch_tree
             with telemetry.trace_span('state_fetch') as span:
-                host_state = fetch_tree(tr.state)
+                host_state = (fetch.fetch_tree(tr.state) if snapshot is None
+                              else fetch.fetch_packed(snapshot))
                 span.set(bytes=sum(
                     leaf.nbytes for leaf in
                     jax.tree_util.tree_leaves(host_state)))
-            job, waited = self._hand_over_checkpoint(host_state)
+            job, waited = self._hand_over_checkpoint(
+                host_state, steps, bump=snapshot is None)
             fused_block['ckpt_wait_s'] = round(waited, 6)
         else:
             job = None
-            self.update_model(None, tr.steps, write_files=False)
+            self.update_model(None, steps, write_files=False)
+        # (an inc of 0 registers the counter: its share of the boundaries
+        # reads 0, not "no such counter", where the order is always kept)
+        telemetry.counter('epoch_boundaries_enqueued_first_total').inc(
+            int(enqueued_first))
+        fused_block['enqueued_first'] = enqueued_first
         try:
             telemetry.set_utilization_proxy(fused_block.get('utilization'))
-            rec_extra = {'dispatches_gen': fp.dispatches,
+            rec_extra = {'dispatches_gen': dispatches,
                          'dispatches_eval': getattr(evaluator, 'dispatches',
                                                     0),
                          'fused': fused_block}
             with telemetry.trace_span('metrics_write'):
-                self._write_metrics(tr.steps, rec_extra)
+                self._write_metrics(steps, rec_extra)
             self._maybe_profile()
             self.flags = set()
         finally:
-            # the writer starts LAST: until the next dispatch is enqueued
-            # the device has nothing to run, and the writer's serialisation
-            # takes the interpreter lock from what the loop still has to do
-            # before that (PERF.md section 6, PR 32)
+            # the writer starts LAST: its serialisation takes the
+            # interpreter lock from what the loop still has to do before
+            # the device has its next chunk (PERF.md section 6, PR 32)
             if job is not None:
                 self._ckpt_writer.submit(job)
+        return enqueued_first
 
     def _print_eval_stats(self):
         if self.model_epoch not in self.results:

@@ -9,11 +9,16 @@ into ONE device buffer per dtype (a tiny jitted concat, dispatched async)
 and pays one transfer per dtype group instead; a leaf over
 ``LARGE_LEAF_BYTES`` goes on its own. (The per-transfer cost on a
 directly attached chip: not measured on today's code, ROADMAP S2.)
+
+The two steps have names of their own, ``pack_tree`` (asynchronous) and
+``fetch_packed`` (blocking). The packed buffer is a new one, so a tree whose
+leaves all pack can be handed to a program that donates it between the two:
+the fused loop's epoch boundary enqueues its next dispatch there.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,38 +67,80 @@ def _splitter(sig: Tuple) -> Any:
     return fn
 
 
-def fetch_tree(tree: Any) -> Any:
-    """Device pytree -> host numpy pytree in one round trip per dtype.
+class PackedTree(NamedTuple):
+    """A pytree on its way to the host (``pack_tree`` -> ``fetch_packed``):
+    ``out`` holds the leaves that need no packing in their places (host
+    values; a device leaf that goes on its own), ``groups`` one ``(leaf
+    indices, shapes, flat device buffer)`` per packed dtype."""
+    treedef: Any
+    out: List[Any]
+    groups: List[Tuple[List[int], Tuple, Any]]
 
-    Leaves already on host (numpy / python scalars) pass through untouched.
-    Structure, shapes, and dtypes are preserved exactly.
-    """
+
+def packs_whole(tree: Any) -> bool:
+    """No device leaf of ``tree`` is over ``LARGE_LEAF_BYTES``: a detached
+    pack of it is a second device copy that the device has room for."""
+    return all(leaf.nbytes <= LARGE_LEAF_BYTES
+               for leaf in jax.tree_util.tree_leaves(tree)
+               if isinstance(leaf, jax.Array))
+
+
+def pack_tree(tree: Any, detach: bool = False) -> PackedTree:
+    """Step one of a fetch, which returns at once: the device leaves of each
+    dtype are concatenated into ONE new device buffer by a tiny jitted
+    program that is enqueued behind whatever produces them. A dtype's only
+    leaf, and a leaf over ``LARGE_LEAF_BYTES``, go as they are.
+
+    With ``detach`` a dtype's only leaf is copied on the device too: the
+    pack of a tree that ``packs_whole`` then shares no buffer with it, so it
+    is a SNAPSHOT that outlives the tree's donation to a later program."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     device_ix: Dict[Any, List[int]] = {}
     out: List[Any] = [None] * len(leaves)
     for i, leaf in enumerate(leaves):
-        if not isinstance(leaf, jax.Array):
+        if not isinstance(leaf, jax.Array) or leaf.nbytes > LARGE_LEAF_BYTES:
             out[i] = leaf
-        elif leaf.nbytes > LARGE_LEAF_BYTES:
-            out[i] = np.asarray(leaf)
         else:
             device_ix.setdefault(jnp.asarray(leaf).dtype, []).append(i)
+    groups = []
     for dtype, idxs in device_ix.items():
         group = [leaves[i] for i in idxs]
-        if len(group) == 1:
-            flat_host = np.asarray(group[0]).reshape(-1)
-        else:
-            sig = (str(dtype), tuple(g.shape for g in group))
-            # per-signature cached jit: a FRESH signature compiles once by
-            # design, so the scope is declared to the retrace sentinel
-            with telemetry.expected_compile('fetch_tree packer'):
-                flat_host = np.asarray(_packer(sig)(group))
+        shapes = tuple(g.shape for g in group)
+        # per-signature cached jit: a FRESH signature compiles once by
+        # design, so the scope is declared to the retrace sentinel
+        with telemetry.expected_compile('fetch_tree packer'):
+            if len(group) > 1:
+                flat = _packer((str(dtype), shapes))(group)
+            else:
+                flat = jnp.copy(group[0]) if detach else group[0]
+        groups.append((idxs, shapes, flat))
+    return PackedTree(treedef, out, groups)
+
+
+def fetch_packed(packed: PackedTree) -> Any:
+    """Step two, which blocks: one device->host transfer per packed buffer
+    and per leaf that goes alone, then the split back into leaves (views of
+    the fetched buffer)."""
+    out = [np.asarray(leaf) if isinstance(leaf, jax.Array) else leaf
+           for leaf in packed.out]
+    for idxs, shapes, flat in packed.groups:
+        flat_host = np.asarray(flat).reshape(-1)
         pos = 0
-        for i, g in zip(idxs, group):
-            n = int(np.prod(g.shape)) if g.shape else 1
-            out[i] = flat_host[pos:pos + n].reshape(g.shape)
+        for i, shape in zip(idxs, shapes):
+            n = int(np.prod(shape)) if shape else 1
+            out[i] = flat_host[pos:pos + n].reshape(shape)
             pos += n
-    return jax.tree_util.tree_unflatten(treedef, out)
+    return jax.tree_util.tree_unflatten(packed.treedef, out)
+
+
+def fetch_tree(tree: Any) -> Any:
+    """Device pytree -> host numpy pytree in one round trip per dtype:
+    ``pack_tree`` then ``fetch_packed``.
+
+    Leaves already on host (numpy / python scalars) pass through untouched.
+    Structure, shapes, and dtypes are preserved exactly.
+    """
+    return fetch_packed(pack_tree(tree))
 
 
 def put_tree(tree: Any) -> Any:

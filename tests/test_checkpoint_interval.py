@@ -93,3 +93,36 @@ def test_interval_cadence_and_final_flush(tmp_path):
     assert rep2['steps_at_start'] > 0     # trainer state actually loaded
     assert rep2['model_epoch'] == 8
     assert 8 in _ckpt_numbers(model_dir)
+
+
+@pytest.mark.timeout(560)
+def test_skip_epochs_keep_their_order_and_fetching_ones_enqueue_first(
+        tmp_path):
+    """A skip epoch fetches nothing and enqueues nothing ahead; an epoch
+    that writes packs the train state on the device, enqueues the next
+    dispatch and fetches afterwards; the final one ends the run and leaves
+    no chunk past its checkpoint. The record's ``fused`` block and the two
+    counters say which."""
+    metrics = os.path.join(str(tmp_path), 'metrics.jsonl')
+    args = _args(str(tmp_path), epochs=5, checkpoint_interval=2,
+                 metrics_jsonl=metrics)
+    rep = _run_learner(args, str(tmp_path), 'order')
+    assert rep['model_epoch'] == 5
+    assert _ckpt_numbers(args['train_args']['model_dir']) == [2, 4, 5]
+    with open(metrics) as f:
+        rows = [json.loads(line) for line in f]
+    assert [row['epoch'] for row in rows] == [1, 2, 3, 4, 5]
+    assert [row['fused']['enqueued_first'] for row in rows] == [
+        False, True, False, True, False]
+    assert ['ckpt_wait_s' in row['fused'] for row in rows] == [
+        False, True, False, True, True]
+    counters = rows[-1]['telemetry']['counters']
+    assert counters['epoch_boundaries_total'] == 5
+    assert counters['epoch_boundaries_enqueued_first_total'] == 2
+    # a record's steps are its epoch's end, whatever was enqueued ahead
+    steps = [row['steps'] for row in rows]
+    assert steps == sorted(steps) and len(set(steps)) == 5
+    from flax import serialization
+    with open(os.path.join(args['train_args']['model_dir'],
+                           'trainer_state.ckpt'), 'rb') as f:
+        assert serialization.msgpack_restore(f.read())['steps'] == steps[-1]

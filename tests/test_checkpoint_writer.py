@@ -156,8 +156,9 @@ def test_every_boundary_writes_its_own_files_one_job_in_flight(
 @pytest.mark.timeout(600)
 def test_next_dispatch_opens_before_the_boundarys_write_closes(
         tmp_path, monkeypatch):
-    """(b) In a fused run the iteration after a boundary enqueues its
-    dispatch while that boundary's files are still being written."""
+    """(b) In a fused run the dispatch that follows a boundary's opening
+    (the boundary's own since PR 35, which enqueues it before it fetches the
+    train state) is enqueued before that boundary's files are written."""
     _Writes(monkeypatch, delay=0.1)
     t_start = time.perf_counter()
     ln = Learner(args=apply_defaults(_raw(tmp_path)))
@@ -171,7 +172,7 @@ def test_next_dispatch_opens_before_the_boundarys_write_closes(
     overlapped = 0
     for boundary, write in zip(boundaries, writes):
         assert boundary['t0'] < write['t0']
-        later = [d for d in dispatches if d['t0'] > boundary['t1']]
+        later = [d for d in dispatches if d['t0'] > boundary['t0']]
         if later:     # the last boundary ends the run: no dispatch follows
             assert later[0]['t0'] < write['t1']
             overlapped += 1
@@ -393,6 +394,22 @@ class _InlineExecutor:
         pass
 
 
+def _files_left(model_dir):
+    """Every file of ``model_dir`` by name: size and CRC of a checkpoint,
+    what a sidecar or a layout manifest says."""
+    left = {}
+    for fname in sorted(os.listdir(model_dir)):
+        data = _read(str(model_dir / fname))
+        if fname.endswith('.crc'):
+            side = json.loads(data)
+            left[fname] = (side['size'], side['crc32'])
+        elif fname.endswith('.layout'):
+            left[fname] = json.loads(data)
+        else:
+            left[fname] = (len(data), zlib.crc32(data) & 0xffffffff)
+    return left
+
+
 def _preempted_run(tmp_path, monkeypatch, name):
     """A fused run that takes the preemption exit on the first training
     dispatch after its second boundary; returns what it left on disk."""
@@ -413,19 +430,9 @@ def _preempted_run(tmp_path, monkeypatch, name):
     monkeypatch.setattr(FusedPipeline, 'train_step', train_step)
     ln.run()
     monkeypatch.setattr(FusedPipeline, 'train_step', step)
-    left = {}
-    for fname in sorted(os.listdir(folder / 'models')):
-        data = _read(str(folder / 'models' / fname))
-        if fname.endswith('.crc'):
-            side = json.loads(data)
-            left[fname] = (side['size'], side['crc32'])
-        elif fname.endswith('.layout'):
-            left[fname] = json.loads(data)
-        else:
-            left[fname] = (len(data), zlib.crc32(data) & 0xffffffff)
     state = serialization.msgpack_restore(
         _read(str(folder / 'models' / 'trainer_state.ckpt')))
-    return ln, left, state
+    return ln, _files_left(folder / 'models'), state
 
 
 @pytest.mark.timeout(600)
@@ -474,3 +481,262 @@ def test_span_ring_holds_a_window_and_the_record_before_it():
     args = Manifest().load_metric('ingest_builder_ply_share')['args']
     assert program_counter_ratio.read(run, **args) == pytest.approx(
         100 * (40 * 20 * 64) / (2048 * 50))
+
+
+# ---------------------------------------------------------------------------
+# the boundary's order (PR 35): pack the train state ON the device, enqueue
+# the next dispatch, and only then fetch the pack and write the record. A
+# state with a leaf over ``LARGE_LEAF_BYTES`` keeps fetch-then-enqueue, which
+# is also these tests' control: with the threshold at 0 every leaf is one.
+
+_ROW_KEYS = ('epoch', 'steps', 'episodes', 'dispatches_gen', 'entropy',
+             'grad_norm', 'rho_clip_fraction', 'importance_ratio_mean',
+             'guard_nonfinite', 'guard_rollbacks')
+
+
+def _order_run(tmp_path, monkeypatch, capsys, name, large_leaf_bytes=None,
+               arrange=None, **over):
+    """A seeded fused run under the boundary's new order, or, with
+    ``large_leaf_bytes`` (a number, or a function of the learner), under the
+    kept one. Online evaluation's share is held to the episode counts: its
+    budget follows the wall clock. Returns the learner, the files it left
+    and what it printed and recorded of the trajectory."""
+    from handyrl_tpu.utils import fetch
+    folder = tmp_path / name
+    folder.mkdir()
+    share = Learner._run_eval_share
+    with monkeypatch.context() as patch:
+        patch.setattr(Learner, '_run_eval_share',
+                      lambda self, evaluator, tracker, budget_s=None:
+                      share(self, evaluator, tracker))
+        ln = Learner(args=apply_defaults(_raw(folder, **over)))
+        if large_leaf_bytes is not None:
+            patch.setattr(fetch, 'LARGE_LEAF_BYTES',
+                          large_leaf_bytes(ln) if callable(large_leaf_bytes)
+                          else large_leaf_bytes)
+        if arrange is not None:
+            arrange(ln, patch)
+        capsys.readouterr()
+        ln.run()
+    printed = capsys.readouterr().out.splitlines()
+    rows = [json.loads(line) for line in
+            (folder / 'metrics.jsonl').read_text().splitlines()]
+    return ln, _files_left(folder / 'models'), {
+        'printed': [line for line in printed
+                    if line.startswith(('epoch ', 'loss =', 'updated model'))],
+        'rows': [{k: row.get(k) for k in _ROW_KEYS} for row in rows],
+        'steps': ln.trainer.steps,
+    }, [row['fused']['enqueued_first'] for row in rows if 'fused' in row]
+
+
+def _one_leaf_over(ln):
+    """A threshold that exactly the train state's largest leaves pass."""
+    return max(leaf.nbytes for leaf in
+               jax.tree_util.tree_leaves(ln.trainer.state)) - 1
+
+
+_ROLLBACK = {'guard': {'nonfinite_policy': 'rollback', 'rollback_after': 4}}
+
+
+@pytest.mark.timeout(900)
+@pytest.mark.parametrize('case, over, flags', [
+    ('plain', {'epochs': 4}, [True, True, True, False]),
+    # skip epochs fetch nothing and keep their order; 5 is final
+    ('interval_2', {'epochs': 5, 'checkpoint_interval': 2},
+     [False, True, False, True, False]),
+    # a NaN burst after epoch 1's checkpoint: the guard restores it in place
+    ('rollback', dict(_ROLLBACK, epochs=4), None),
+])
+def test_both_orders_leave_the_same_bits(tmp_path, monkeypatch, capsys,
+                                         case, over, flags):
+    """(b) One seed, several epochs: every checkpoint file, sidecar and
+    layout manifest, the printed losses and the records' dynamics are the
+    same under enqueue-then-fetch and under fetch-then-enqueue."""
+    if case == 'rollback':
+        monkeypatch.setenv('HANDYRL_TPU_CHAOS', 'nanepoch=1,nanburst=64')
+    ln, left, told, first = _order_run(tmp_path, monkeypatch, capsys, 'new',
+                                       **over)
+    controls = [0] + ([_one_leaf_over] if case == 'plain' else [])
+    for n, threshold in enumerate(controls):
+        _ln, left_kept, told_kept, first_kept = _order_run(
+            tmp_path, monkeypatch, capsys, 'kept%d' % n,
+            large_leaf_bytes=threshold, **over)
+        assert not any(first_kept)
+        assert left_kept == left
+        assert told_kept == told
+    assert ln.model_epoch == over['epochs'] and told['steps'] > 0
+    assert 'trainer_state.ckpt' in left and 'latest.ckpt' in left
+    if flags is not None:
+        assert first == flags
+    else:
+        assert told['rows'][-1]['guard_rollbacks'] >= 1 and any(first)
+    snap = telemetry.summarize(telemetry.snapshot())['counters']
+    assert snap['epoch_boundaries_enqueued_first_total'] \
+        <= snap['epoch_boundaries_total']
+
+
+@pytest.mark.timeout(600)
+def test_the_snapshot_outlives_the_donation_of_the_train_state(
+        tmp_path, monkeypatch, capsys):
+    """(a, c, e) At a boundary the next program is on the device before the
+    blocking fetch starts, and exactly one is in flight through it; the
+    pack holds the state after chunk *i* although chunk *i+1* has donated
+    and overwritten ``tr.state``. A state with one leaf over the threshold
+    takes no detached pack at all."""
+    from handyrl_tpu.ops.fused_pipeline import FusedPipeline
+    from handyrl_tpu.utils import fetch
+    built, packs, seen = [], [], []
+    init, pack, unpack = (FusedPipeline.__init__, fetch.pack_tree,
+                          fetch.fetch_packed)
+
+    def remember(self, *args, **kwargs):
+        built.append(self)
+        init(self, *args, **kwargs)
+
+    def pack_tree(tree, detach=False):
+        packs.append(detach)
+        if detach:      # what the state holds now, after chunk i
+            fp, = built
+            seen.append({'leaves': jax.tree_util.tree_leaves(tree),
+                         'want': jax.tree_util.tree_map(np.array, tree),
+                         'packed_at': fp.dispatches})
+        return pack(tree, detach)
+
+    def fetch_packed(packed):
+        fp, = built
+        now = seen[-1] if packs[-1] else None
+        if now is None or fp.dispatches == now['packed_at']:
+            return unpack(packed)
+        # the next iteration's step is made: chunk i+1 is enqueued and has
+        # taken the train state with it, chunk i's result is collected, so
+        # exactly one program is in flight
+        assert fp.dispatches == now['packed_at'] + 1 == fp.chunks_host + 1
+        assert fp._pending is not None
+        assert all(leaf.is_deleted() for leaf in now['leaves'])
+        got = unpack(packed)
+        now['got'], now['dispatches'] = got, fp.dispatches
+        return got
+
+    def arrange(ln, patch):
+        patch.setattr(FusedPipeline, '__init__', remember)
+        patch.setattr(fetch, 'pack_tree', pack_tree)
+        patch.setattr(fetch, 'fetch_packed', fetch_packed)
+
+    t_start = time.perf_counter()
+    ln, _left, told, first = _order_run(tmp_path, monkeypatch, capsys, 'new',
+                                        arrange=arrange, epochs=3)
+    fp, = built
+    assert first == [True, True, False] and len(seen) == 3
+    for now, row in zip(seen[:2], told['rows']):
+        for want, got in zip(jax.tree_util.tree_leaves(now['want']),
+                             jax.tree_util.tree_leaves(now['got'])):
+            np.testing.assert_array_equal(want, got)
+        # the record and the checkpoint speak of chunk i, not of i+1
+        assert int(now['got'].steps) == row['steps']
+        assert row['dispatches_gen'] == now['dispatches'] - 1
+    boundaries = telemetry.spans('epoch_boundary', since=t_start)
+    assert [b['attrs']['enqueued_first'] for b in boundaries] == [1, 1, 0]
+
+    # one leaf over the threshold: no snapshot is taken, the order is kept
+    packs.clear(), built.clear()
+    t_start = time.perf_counter()
+    _ln, _left, _told, first = _order_run(
+        tmp_path, monkeypatch, capsys, 'large', arrange=arrange, epochs=3,
+        large_leaf_bytes=_one_leaf_over)
+    assert first == [False] * 3 and len(packs) >= 3 and not any(packs)
+    recs = telemetry.spans(since=t_start)
+    fetches = [r for r in recs if r['name'] == 'state_fetch']
+    dispatches = [r for r in recs if r['name'] == 'dispatch']
+    for fetch_span in fetches[:2]:
+        assert [d for d in dispatches if d['t0'] > fetch_span['t0']][0][
+            't0'] > fetch_span['t1']
+
+
+def _exit_at_second_boundary(site):
+    """Patches that bring the exit at the OPENING of the second boundary."""
+    def arrange(ln, patch):
+        epoch_close = Learner._fused_epoch
+
+        def _fused_epoch(self, *args, **kwargs):
+            if self.model_epoch == 1:
+                if site == 'preemption':
+                    self.preempt.signum = 15
+                    self.preempt._event.set()
+                elif site == 'deadline':
+                    self._deadline = time.time() - 1.0
+            return epoch_close(self, *args, **kwargs)
+        patch.setattr(Learner, '_fused_epoch', _fused_epoch)
+    return arrange
+
+
+@pytest.mark.timeout(600)
+@pytest.mark.parametrize('site', ['preemption', 'deadline', 'epoch_budget'])
+def test_an_exit_at_a_boundary_leaves_what_the_kept_order_leaves(
+        tmp_path, monkeypatch, capsys, site):
+    """(d) Preemption, the deadline and the epoch budget arriving at a
+    boundary: that boundary enqueues nothing, no chunk runs past the last
+    checkpoint, and file for file the run leaves what fetch-then-enqueue
+    leaves (``test_preempted_run_leaves_what_inline_writes_leave``'s
+    method)."""
+    over = {'epochs': 2 if site == 'epoch_budget' else -1}
+    arrange = None if site == 'epoch_budget' else \
+        _exit_at_second_boundary(site)
+    ln, left, told, first = _order_run(tmp_path, monkeypatch, capsys, 'new',
+                                       arrange=arrange, **over)
+    ln2, left_kept, told_kept, first_kept = _order_run(
+        tmp_path, monkeypatch, capsys, 'kept', large_leaf_bytes=0,
+        arrange=arrange, **over)
+    assert first == [True, False] and first_kept == [False, False]
+    assert ln.model_epoch == ln2.model_epoch == 2
+    assert ln.trainer.steps == ln._last_ckpt_steps == ln2.trainer.steps > 0
+    assert left == left_kept
+    assert told['rows'][:2] == told_kept['rows'][:2]
+    assert told['printed'] == told_kept['printed']
+    assert {'1.ckpt', '2.ckpt', 'latest.ckpt', 'trainer_state.ckpt'} \
+        <= set(left)
+    assert left['2.ckpt.layout']['steps'] == ln.trainer.steps
+    assert ln.preempt.fired == (site == 'preemption')
+
+
+@pytest.mark.timeout(600)
+def test_a_preemption_after_the_enqueue_books_both_chunks(
+        tmp_path, monkeypatch, capsys):
+    """The signal lands while the boundary waits for its fetch, with the
+    next chunk on the device already: the boundary completes, the exit
+    books the result that the boundary's step collected and collects the
+    chunk in flight, and the flush writes the state after that chunk with
+    its own step count."""
+    from handyrl_tpu.utils import fetch
+    unpack = fetch.fetch_packed
+
+    def arrange(ln, patch):
+        def fetch_packed(packed):
+            if ln.model_epoch == 2:     # bumped: the second boundary's
+                ln.preempt.signum = 15
+                ln.preempt._event.set()
+            return unpack(packed)
+        patch.setattr(fetch, 'fetch_packed', fetch_packed)
+
+    t_start = time.perf_counter()
+    ln, left, told, first = _order_run(tmp_path, monkeypatch, capsys, 'new',
+                                       arrange=arrange, epochs=-1)
+    assert first == [True, True] and ln.preempt.fired and ln.model_epoch == 2
+    steps_at_boundary = told['rows'][1]['steps']
+    sgd = ln.args['sgd_steps_per_chunk']
+    assert ln.trainer.steps == ln._last_ckpt_steps == steps_at_boundary + sgd
+    state = serialization.msgpack_restore(
+        _read(ln.trainer_state_path()))
+    assert state['steps'] == ln.trainer.steps
+    assert left['2.ckpt.layout']['steps'] == ln.trainer.steps
+    for name in ('1.ckpt', '2.ckpt', 'latest.ckpt', 'trainer_state.ckpt'):
+        assert verify_checkpoint(
+            os.path.join(os.path.dirname(ln.trainer_state_path()), name)) \
+            == (True, 'ok')
+    # every chunk enqueued was fetched and booked, the last two booked at
+    # the exit
+    blocks = telemetry.spans('host_block', since=t_start)
+    dispatches = telemetry.spans('dispatch', since=t_start)
+    assert len(blocks) == len(dispatches)
+    assert ln.num_returned_episodes == blocks[-1]['attrs']['episodes']
+    # two boundaries' writes and the flush
+    assert len(telemetry.spans('checkpoint_write', since=t_start)) == 3

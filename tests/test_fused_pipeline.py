@@ -210,18 +210,34 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
     children = {}
     for rec in recs:
         children.setdefault(rec['parent_id'], []).append(rec['name'])
+    boundaries = [r for r in recs if r['name'] == 'epoch_boundary']
+    # the first boundary made the next iteration's step itself, before it
+    # fetched the train state; the second ends the run and makes none
+    assert [r['attrs']['enqueued_first'] for r in boundaries] == [1, 0]
+    ahead = False
     for n, it in enumerate(iters):
         names = children[it['span_id']]
-        assert names.count('dispatch') == 1
-        # the fetch runs one chunk behind: the first iteration has none
-        assert names.count('host_block') == (0 if n == 0 else 1)
+        # one step a chunk (the enqueue, and the fetch of the chunk before,
+        # which the first iteration has not): the iteration's own, or the
+        # one that the boundary which closed the last iteration made for it
+        assert names.count('dispatch') == (0 if ahead else 1)
+        assert names.count('host_block') == (0 if ahead or n == 0 else 1)
         assert names.count('chunk_account') == names.count('eval_share') == 1
+        held = [b for b in boundaries if b['parent_id'] == it['span_id']]
+        ahead = bool(held and held[0]['attrs']['enqueued_first'])
+        if ahead:
+            inside = children[held[0]['span_id']]
+            assert inside.count('dispatch') == inside.count('host_block') == 1
+            assert inside.index('actor_refresh') < inside.index('dispatch') \
+                < inside.index('host_block') < inside.index('state_fetch') \
+                < inside.index('metrics_write')
+    assert not ahead
+    assert sum(r['name'] == 'dispatch' for r in recs) == fp.dispatches
     # every eval share is told how long it may hold the loop: a share of
     # its own iteration's training stretch
     shares = [r for r in recs if r['name'] == 'eval_share']
     assert all(r['attrs']['budget_ms'] >= 0 for r in shares)
     assert any(r['attrs']['budget_ms'] > 0 for r in shares)
-    boundaries = [r for r in recs if r['name'] == 'epoch_boundary']
     assert [r['attrs']['epoch'] for r in boundaries] == [1, 2]
     for boundary in boundaries:
         assert set(children[boundary['span_id']]) >= {
@@ -387,6 +403,59 @@ def _fake_iteration(t, wait, boundary=0.0):
         end += boundary
     return (_FakeSpan('fused_iter', t, None, head),
             _FakeSpan('fused_iter', t, end, head + tail)), end
+
+
+def test_a_step_made_inside_a_boundary_is_the_next_chunks_step():
+    """A boundary that makes the next iteration's step before it fetches the
+    train state (1 ms of host work, the 2 ms enqueue, the wait for the chunk
+    in flight; then a 5 ms state fetch and 15 ms of host work): the
+    iteration after it has no step of its own, and every chunk's record still
+    reads one enqueue, its waits and the boundary's host work, each once and
+    all inside the interval from completion to completion."""
+    from handyrl_tpu import telemetry
+    monitor = telemetry.ChunkMonitor()
+    t, ahead, intervals = 10.0, False, []
+    #   the step's wait, whether a boundary closes the iteration and steps
+    for n, (wait, steps) in enumerate(
+            [(0.9, None), (0.9, True), (0.8, None), (0.7, True),
+             (0.6, True), (0.5, False), (0.4, None)], 1):
+        head = []
+        if not ahead:
+            head = [_FakeSpan('dispatch', t, t + 0.002),
+                    _FakeSpan('host_block', t + 0.002, t + 0.002 + wait)]
+            t += 0.002 + wait
+            monitor.fetched(n, _FakeSpan('fused_iter', t, None, head))
+        tail = [_FakeSpan('chunk_account', t, t + 0.003)]
+        t += 0.003
+        if steps is not None:
+            t0, inside = t, []
+            if steps:
+                inside = [_FakeSpan('dispatch', t + 0.001, t + 0.003),
+                          _FakeSpan('host_block', t + 0.003, t + 0.003 + wait)]
+                t += 0.003 + wait
+                monitor.fetched(n + 1, _FakeSpan('fused_iter', t, None,
+                                                 head + tail),
+                                _FakeSpan('epoch_boundary', t0, None, inside))
+            inside.append(_FakeSpan('state_fetch', t, t + 0.005))
+            t += 0.020
+            tail.append(_FakeSpan('epoch_boundary', t0, t, inside))
+        monitor.closed(_FakeSpan('fused_iter', t, t, head + tail))
+        ahead = bool(steps)
+    for interval, split in monitor._chunks:
+        assert sum(split.values()) == pytest.approx(interval)
+    splits = [split for _interval, split in monitor._chunks]
+    assert len(splits) == 6          # seven completions
+    assert [round(s['enqueue'], 4) for s in splits] == [0.002] * 6
+    # a state fetch made after a boundary's own step joins the NEXT wait
+    assert [round(s['wait'], 4) for s in splits] == [
+        0.9, 0.9, 0.705, 0.7, 0.605, 0.41]
+    assert [round(s['account'], 4) for s in splits] == [
+        0.003, 0.003, 0.003, 0.003, 0.003, 0.003]
+    # 1 ms before a boundary's step; the 15 ms after its fetch are the next
+    # interval's, as is the whole of a boundary that keeps the order (the
+    # last interval holds one of each)
+    assert [round(s.get('epoch', 0.0), 4) for s in splits] == [
+        0.0, 0.001, 0.015, 0.001, 0.016, 0.03]
 
 
 def test_chunk_intervals_run_from_completion_to_completion():
