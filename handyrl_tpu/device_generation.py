@@ -34,6 +34,7 @@ from . import telemetry
 from .generation import (Generator, _blank_moment, _finalize_episode,
                          bucketed_inference, build_chunk, masked_sample,
                          pad_to_bucket, sample_seed, seed_env_rng)
+from .ops import maskbits
 from .ops.batch import compress_moments
 from .utils.tree import map_structure
 
@@ -200,6 +201,10 @@ def make_gen_body(env_mod, apply_fn, recurrent: bool, simultaneous: bool,
             probs = jax.nn.softmax(logits, axis=-1)
             sel = jnp.take_along_axis(probs, actions[..., None],
                                       axis=-1)[..., 0]
+            if getattr(env_mod, 'MASK_AS_BITS', False):
+                # a wide id space: the record, the history and the ring
+                # keep the legal set as bits (ops/maskbits.py)
+                amask = maskbits.pack(amask > 0)
             if simultaneous:
                 N, P = obs.shape[:2]
                 value = out.get('value')

@@ -1,0 +1,577 @@
+"""Trinity-Mini as a policy trunk: grouped-query attention of two kinds in
+one net, and sparse experts of which this chip holds a share.
+
+Source: https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json
+(``model_type: afmoe``: 32 layers of hidden 2048; 32 query heads and 4 KV
+heads of 128; ``layer_types`` three ``sliding_attention`` (window 2048) then
+one ``full_attention``, eight times; the first 2 layers dense (SwiGLU 6144),
+the others 128 routed experts (SwiGLU 1024 each), 8 a token, one shared
+expert; sigmoid scores, ``route_norm``, ``route_scale`` 2.826,
+``load_balance_coeff`` 0.001; vocabulary 200,192, untied; ``rope_theta`` 1e4;
+RMSNorm eps 1e-5). A layer, on the float32 residual ``h``:
+
+* ``a = N_in(h)``; ``q = W_q a``, ``k = W_k a``, ``v = W_v a``, ``g = W_g a``;
+  ``q``, ``k`` normalised over a head's 128 (one weight vector each a layer).
+  A ``sliding`` layer turns ``q`` and ``k`` by rotary phases and query ``i``
+  sees key ``j`` iff ``i - window < j <= i``; a ``full`` layer has no
+  positional encoding and ``j <= i``. Query head ``n`` reads KV head ``n //
+  (heads / kv_heads)``; ``attn = W_o (softmax(q k^T / sqrt(d)) v *
+  sigmoid(g))``;
+* ``h = h + N_post_attn(attn)``; ``m = N_pre_mlp(h)``; ``h = h +
+  N_post_mlp(f(m))``;
+* dense: ``f(m) = W_down (silu(W_gate m) * W_up m)``;
+* experts: ``s = sigmoid(W_r m)`` in float32; ``S`` the 8 largest of ``s +
+  b``; ``w_e = route_scale * s_e / (sum_{e in S} s_e + 1e-20)``; ``f(m) =
+  Shared(m) + sum_{e in S} w_e Expert_e(m)``; after every update ``b <- b +
+  rate * centred(sign(mean(c) - c))``, ``c`` the tokens each expert was
+  chosen for (``post_update``).
+
+The layer holds ``heads_held`` / ``kv_heads_held`` of the published heads
+and the experts ``experts_held`` (indices among the published 128): this
+chip's share where several chips share each layer. It routes over ALL
+experts at the published router width, keeps the published 8 a token and
+their normalisation over all 8, and computes the part of the sum that ITS
+experts give (rows sorted by expert, one grouped product, no capacity and
+no dropped row) plus the shared expert; the attention output is its heads'
+part of ``W_o``'s sum. Both partial results go on as they are: no code
+stands in for the other chips. On a lone share the absent experts add
+nothing, so the part of the router's gradient this chip sees points at
+them; in a deployment that gradient is summed over the shares. Here the
+router takes NO gradient (``W_r`` stays as seeded; ``b`` follows the rule).
+
+``param_scale`` (1 by default, a power of two): every matrix is stored at
+``param_scale`` times its value and each product's result divided by it, so
+the same function of the same weights, whose stored numbers the learner's
+one learning rate (3e-8 a trained position: 2.5e-4 for 8,192 of them, made
+for nets of 1e5 parameters) moves by 1 / ``param_scale`` of their size. At
+the plain parametrisation a seeded net of this width loses its positions'
+differences within ten update steps of that rate (Adam's first steps are
+sign steps, coherent over a 2048 x 6144 matrix), and every token then
+chooses the same 8 experts (PERF.md section 6, PR 38).
+
+Two entries over one set of parameters, as ``models/evabyte.py``:
+``sequence(ids, first_position, valid)`` (a window as one causal forward;
+it returns ``policy_features``, which ``policy_logits`` turns into logits a
+block of positions at a time, ops/losses.py) and ``__call__(id, hidden)``
+(one position through the cache). ``hidden`` holds K and V of two lengths
+side by side: a circle of ``window_size`` rows on a sliding layer (keys are
+stored already turned, so a row needs no position), ``max_positions`` rows
+on a full one, (sequence, row, KV heads x d), and ONE counter a sequence.
+Nothing is cleared: what the counter has not reached is masked.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+
+from . import register
+from .evabyte import NEG, _dot, _rotary, f32
+
+PUBLISHED_LAYERS = ('sliding', 'sliding', 'sliding', 'full') * 8
+
+
+def _rms_norm(x, g, eps, dtype):
+    """Float32 in, ``dtype`` out; the weight multiplies (no unit offset)."""
+    x = x.astype(f32)
+    y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (y * g.astype(f32)).astype(dtype)
+
+
+def _swiglu(x, w_gate, w_up, w_down, dtype, inv):
+    """``inv``: 1 / ``param_scale``, taken on each product's result."""
+    act = (jax.nn.silu(_dot(x, w_gate, dtype) * inv)
+           * (_dot(x, w_up, dtype) * inv))
+    return _dot(act, w_down, dtype, out=f32) * inv
+
+
+class TrinityBlock(nn.Module):
+    """One decoder layer: this chip's heads, and either the whole dense MLP
+    or the router, the shared expert and this chip's routed experts."""
+    hidden_size: int
+    heads_held: int
+    kv_heads_held: int
+    head_dim: int
+    kind: str                     # 'sliding' | 'full'
+    window_size: int
+    rope_theta: float
+    norm_eps: float
+    mlp_size: int                 # the dense MLP's width; 0 on an expert layer
+    expert_size: int
+    experts_published: int
+    experts_held: Tuple[int, ...]
+    experts_per_token: int
+    route_scale: float
+    query_block: int
+    dense_rows: int
+    param_scale: float
+    dtype: Any
+
+    def setup(self):
+        init = nn.initializers.normal(0.02 * self.param_scale)
+        ones = nn.initializers.ones
+        D, d = self.hidden_size, self.head_dim
+        A, KV = self.heads_held * d, self.kv_heads_held * d
+        self.wq = self.param('wq', init, (D, A))
+        self.wk = self.param('wk', init, (D, KV))
+        self.wv = self.param('wv', init, (D, KV))
+        self.wg = self.param('wg', init, (D, A))
+        self.wo = self.param('wo', init, (A, D))
+        self.q_norm = self.param('q_norm', ones, (d,))
+        self.k_norm = self.param('k_norm', ones, (d,))
+        for name in ('norm_in', 'norm_post_attn', 'norm_pre_mlp',
+                     'norm_post_mlp'):
+            setattr(self, name, self.param(name, ones, (D,)))
+        if self.mlp_size:
+            M = self.mlp_size
+            self.w_gate = self.param('w_gate', init, (D, M))
+            self.w_up = self.param('w_up', init, (D, M))
+            self.w_down = self.param('w_down', init, (M, D))
+            return
+        E, F, held = self.experts_published, self.expert_size, \
+            len(self.experts_held)
+        self.router = self.param('router', init, (D, E))
+        self.router_bias = self.param('router_bias', nn.initializers.zeros,
+                                      (E,))
+        self.experts_gate = self.param('experts_gate', init, (held, D, F))
+        self.experts_up = self.param('experts_up', init, (held, D, F))
+        self.experts_down = self.param('experts_down', init, (held, F, D))
+        self.shared_gate = self.param('shared_gate', init, (D, F))
+        self.shared_up = self.param('shared_up', init, (D, F))
+        self.shared_down = self.param('shared_down', init, (F, D))
+
+    @property
+    def inv(self):
+        """What each product's result is multiplied by (``param_scale``)."""
+        return 1 / self.param_scale
+
+    # -- attention -----------------------------------------------------------
+    def _qkvg(self, x, positions):
+        """x (..., D) float32 at ``positions`` (...,) -> q (..., H, d), k, v
+        (..., KV, d) and the gate (..., H * d), in ``dtype``."""
+        a = _rms_norm(x, self.norm_in, self.norm_eps, self.dtype)
+        lead, d, inv = x.shape[:-1], self.head_dim, self.inv
+        q = (_dot(a, self.wq, self.dtype) * inv).reshape(
+            lead + (self.heads_held, d))
+        k = (_dot(a, self.wk, self.dtype) * inv).reshape(
+            lead + (self.kv_heads_held, d))
+        v = (_dot(a, self.wv, self.dtype) * inv).reshape(
+            lead + (self.kv_heads_held, d))
+        q = _rms_norm(q, self.q_norm, self.norm_eps, self.dtype)
+        k = _rms_norm(k, self.k_norm, self.norm_eps, self.dtype)
+        if self.kind == 'sliding':
+            pos = positions[..., None]
+            q = _rotary(q, pos, self.rope_theta)
+            k = _rotary(k, pos, self.rope_theta)
+        return q, k, v, _dot(a, self.wg, self.dtype) * inv
+
+    def _out(self, y, gate):
+        """This chip's part of ``W_o``'s sum, before the branch's norm."""
+        return _dot(y * jax.nn.sigmoid(gate.astype(f32)).astype(y.dtype),
+                    self.wo, self.dtype, out=f32) * self.inv
+
+    def _after_attention(self, x, part):
+        return x + _rms_norm(part, self.norm_post_attn, self.norm_eps, f32)
+
+    def attention_part(self, x, positions, valid, no_grad_prefix=0):
+        """This chip's part of the attention output, before the branch's
+        norm: (B, T, D) float32 (the head-share test sums four of these)."""
+        q, k, v, gate = self._qkvg(x, positions)
+        if no_grad_prefix:
+            keep = (jnp.arange(x.shape[1]) >= no_grad_prefix)[
+                None, :, None, None]
+            k = jnp.where(keep, k, jax.lax.stop_gradient(k))
+            v = jnp.where(keep, v, jax.lax.stop_gradient(v))
+        y = jax.vmap(self._sequence_attention)(q, k, v, positions, valid)
+        return self._out(y, gate)
+
+    def _sequence_attention(self, q, k, v, positions, valid):
+        """One sequence. q (T, H, d), k, v (T, KV, d) -> (T, H * d)."""
+        T, H, d = q.shape
+        KV = k.shape[1]
+        G, W = H // KV, self.window_size
+        sliding = self.kind == 'sliding'
+        q = q.reshape(T, KV, G, d).transpose(1, 2, 0, 3)       # (KV, G, T, d)
+        k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)    # (KV, T, d)
+        scale = d ** -0.5
+        bq = min(self.query_block, T)
+        assert T % bq == 0, (T, bq)
+
+        @jax.checkpoint
+        def block(args):
+            qb, pq = args                             # (KV, G, bq, d), (bq,)
+            seen = (positions[None, :] <= pq[:, None]) & valid[None, :]
+            if sliding:
+                seen = seen & (positions[None, :] > pq[:, None] - W)
+            s = scale * jnp.einsum('kgqd,ktd->kgqt', qb, k,
+                                   preferred_element_type=f32)
+            prob = jax.nn.softmax(jnp.where(seen[None, None], s, NEG),
+                                  axis=-1).astype(v.dtype)
+            return jnp.einsum('kgqt,ktd->kgqd', prob, v,
+                              preferred_element_type=f32).astype(v.dtype)
+
+        qs = q.reshape(KV, G, T // bq, bq, d).transpose(2, 0, 1, 3, 4)
+        out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
+        return out.transpose(0, 3, 1, 2, 4).reshape(T, H * d)
+
+    # -- the MLP: dense, or the experts held -------------------------------
+    def _route(self, m32):
+        """Scores and the published top-k over ALL experts, in float32:
+        (ids (n, k), weights (n, k), tokens an expert (E,)). No gradient
+        passes (module docstring)."""
+        s = jax.nn.sigmoid(jnp.dot(m32, self.router.astype(f32),
+                                   precision=jax.lax.Precision.HIGHEST)
+                           * self.inv)
+        _, ids = jax.lax.top_k(s + self.router_bias.astype(f32),
+                               self.experts_per_token)
+        # the choices, for whoever asks for them (the checks compare them
+        # with the reference's); nothing is kept where nobody does
+        self.sow('intermediates', 'route_ids', ids)
+        picked = jnp.take_along_axis(s, ids, axis=1)
+        w = self.route_scale * picked / (
+            picked.sum(axis=1, keepdims=True) + 1e-20)
+        counts = (ids[..., None] == jnp.arange(self.experts_published)).sum(
+            axis=(0, 1), dtype=jnp.int32)
+        return ids, jax.lax.stop_gradient(w), counts
+
+    def _held_slot(self, ids):
+        """Published expert ids -> slots among the experts held here; an
+        expert that lies on another chip gets the slot ``len(held)``."""
+        held = len(self.experts_held)
+        # a host constant: as a device scatter of arange into a constant the
+        # v5e's compiler aborts inside a scan (one constant, two operands)
+        slot = np.full((self.experts_published,), held, np.int32)
+        slot[list(self.experts_held)] = np.arange(held)
+        return jnp.asarray(slot)[ids]
+
+    def _experts_every_row(self, m, slot, w):
+        """A few rows: every held expert on every row, weighted by ``w_e``
+        or by 0."""
+        held = len(self.experts_held)
+        with jax.named_scope('moe_route'):
+            gate = ((slot[..., None] == jnp.arange(held)) * w[..., None]
+                    ).sum(axis=1)                               # (n, held)
+        with jax.named_scope('moe_experts'):
+            cast, inv = (lambda p: p.astype(self.dtype)), self.inv
+            act = (jax.nn.silu(jnp.einsum('nd,edf->enf', m,
+                                          cast(self.experts_gate)) * inv)
+                   * (jnp.einsum('nd,edf->enf', m, cast(self.experts_up))
+                      * inv))
+            # in ``dtype``, as the grouped product's rows are. The weighted
+            # sum stays in this scope and ends it: the compiler names a
+            # fusion after its last operation, and without the barrier the
+            # product that reads ``experts_down`` is fused on into what
+            # follows the scope and timed there
+            y = jnp.einsum('enf,efd->end', act,
+                           cast(self.experts_down)) * inv
+            return jax.lax.optimization_barrier(jnp.einsum(
+                'end,ne->nd', y.astype(f32), gate)), jnp.int32(0)
+
+    def _experts_grouped(self, m, slot, w):
+        """Dropless under any imbalance: the (row, choice) pairs sorted by
+        the expert's slot (pairs of absent experts last), ONE grouped
+        product over the experts held, whose work follows the rows that
+        came and not the buffer's size, and the weighted sum back by row."""
+        n, K = slot.shape
+        held, M = len(self.experts_held), n * K
+        with jax.named_scope('moe_route'):
+            flat = slot.reshape(M)
+            onehot = flat[:, None] == jnp.arange(held + 1)      # (M, held+1)
+            rank = (jnp.cumsum(onehot, axis=0, dtype=jnp.int32)
+                    * onehot).sum(axis=1) - 1
+            sizes = onehot.sum(axis=0, dtype=jnp.int32)
+            dest = (jnp.cumsum(sizes) - sizes)[flat] + rank     # (M,)
+            source = jnp.zeros((M,), jnp.int32).at[dest].set(
+                jnp.arange(M, dtype=jnp.int32))
+            # both gathers are by permutations of the pairs, and say so:
+            # their transposes are then plain scatters, not scatter-adds
+            rows = jnp.repeat(m, K, axis=0).at[source].get(
+                unique_indices=True)                            # (M, D)
+            groups = sizes[:held]
+            # rows past the last group belong to no expert held here: the
+            # product leaves whatever was there, forward AND backward, so
+            # nothing of them is read and no cotangent of theirs passes
+            in_group = (jnp.arange(M) < groups.sum())[:, None]
+            rows = jnp.where(in_group, rows, 0)
+            # by construction 0: the buffer holds every pair
+            dropped = jnp.sum((flat < held) & (dest >= M), dtype=jnp.int32)
+        with jax.named_scope('moe_experts'):
+            cast = lambda p: p.astype(self.dtype)
+            grouped = lambda x, p: jax.lax.ragged_dot(
+                x, cast(p), groups, preferred_element_type=self.dtype
+            ) * self.inv
+            act = (jax.nn.silu(grouped(rows, self.experts_gate))
+                   * grouped(rows, self.experts_up))
+            y = grouped(act, self.experts_down)                 # (M, D)
+        with jax.named_scope('moe_route'):
+            y = jnp.where(in_group, y, 0)
+            y = y.at[dest].get(unique_indices=True).reshape(n, K, -1)
+            return jnp.einsum('nkd,nk->nd', y, (w * (slot < held)).astype(
+                y.dtype), preferred_element_type=f32), dropped
+
+    def mlp_branch(self, x, shared: bool = True):
+        """x (n, D) float32 -> ``f(N_pre_mlp(x))`` before the branch's norm
+        and, on an expert layer, the tokens each published expert was chosen
+        for (E,) and the rows dropped (0). ``shared=False`` leaves the
+        shared expert out (the share test counts it once)."""
+        m32 = _rms_norm(x, self.norm_pre_mlp, self.norm_eps, f32)
+        m = m32.astype(self.dtype)
+        if self.mlp_size:
+            with jax.named_scope('trunk_mlp'):
+                return _swiglu(m, self.w_gate, self.w_up, self.w_down,
+                               self.dtype, self.inv), None, None
+        with jax.named_scope('moe_route'):
+            ids, w, counts = self._route(m32)
+            slot = self._held_slot(ids)
+        experts = (self._experts_every_row if m.shape[0] <= self.dense_rows
+                   else self._experts_grouped)
+        f, dropped = experts(m, slot, w)
+        if shared:
+            with jax.named_scope('moe_shared'):
+                f = f + _swiglu(m, self.shared_gate, self.shared_up,
+                                self.shared_down, self.dtype, self.inv)
+        return f, counts, dropped
+
+    def _mlp(self, x):
+        f, counts, dropped = self.mlp_branch(x)
+        x = x + _rms_norm(f, self.norm_post_mlp, self.norm_eps, f32)
+        return x, counts, dropped
+
+    # -- a whole window ------------------------------------------------------
+    def sequence(self, x, positions, valid, no_grad_prefix=0):
+        B, T, D = x.shape
+        with jax.named_scope('gqa_attention'):
+            x = self._after_attention(x, self.attention_part(
+                x, positions, valid, no_grad_prefix))
+        x, counts, dropped = self._mlp(x.reshape(B * T, D))
+        return x.reshape(B, T, D), counts, dropped
+
+    # -- one position through the cache --------------------------------------
+    def step(self, x, pos, cache):
+        """x (B, D) float32 at each sequence's own position ``pos`` (B,);
+        cache = (k, v), each (B, rows, KV * d): ``window_size`` rows written
+        round and round on a sliding layer, ``max_positions`` on a full one
+        (a row is the KV heads side by side: with a head axis of its own a
+        lone KV head of 128 would be padded to a tile of 8)."""
+        ck, cv = cache
+        B, n_rows = x.shape[0], ck.shape[1]
+        KV, G = self.kv_heads_held, self.heads_held // self.kv_heads_held
+        with jax.named_scope('gqa_attention'):
+            q, k, v, gate = self._qkvg(x, pos)           # (B, H | KV, d)
+            slot = pos % n_rows
+            with jax.named_scope('state_update'):
+                seq = jnp.arange(B)
+                ck = ck.at[seq, slot].set(k.reshape(B, -1))
+                cv = cv.at[seq, slot].set(v.reshape(B, -1))
+            # a circle that has gone round holds the window_size positions
+            # up to this one; before that, rows 0..pos
+            seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
+            if self.kind == 'sliding':
+                seen = seen | (pos[:, None] >= n_rows)
+            rows = lambda c: c.reshape(B, n_rows, KV, self.head_dim)
+            s = self.head_dim ** -0.5 * jnp.einsum(
+                'bkgd,brkd->bkgr', q.reshape(B, KV, G, -1), rows(ck),
+                preferred_element_type=f32)
+            prob = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG),
+                                  axis=-1).astype(cv.dtype)
+            y = jnp.einsum('bkgr,brkd->bkgd', prob, rows(cv),
+                           preferred_element_type=f32).astype(self.dtype)
+            x = self._after_attention(x, self._out(y.reshape(B, -1), gate))
+        x, _counts, _dropped = self._mlp(x)
+        return x, (ck, cv)
+
+
+@register('TrinityNet')
+class TrinityNet(nn.Module):
+    """The trunk with its untied head read as a policy over the ids held and
+    a value row. Observations are int32 ids. The defaults are the published
+    counts, at which every layer IS the published layer; the depth, the
+    heads, the experts and the slice of the vocabulary held are the
+    deployment's cut (ISSUE 38)."""
+    hidden_size: int = 2048
+    layer_types: Tuple[str, ...] = PUBLISHED_LAYERS
+    dense_layers: int = 2
+    heads_held: int = 32
+    kv_heads_held: int = 4
+    head_dim: int = 128
+    mlp_size: int = 6144
+    expert_size: int = 1024
+    experts_published: int = 128
+    experts_held: Optional[Tuple[int, ...]] = None     # None: all of them
+    experts_per_token: int = 8
+    route_scale: float = 2.826
+    bias_update_rate: float = 0.001
+    vocab: int = 200192
+    window_size: int = 2048
+    max_positions: int = 8192
+    rope_theta: float = 1e4
+    norm_eps: float = 1e-5
+    query_block: int = 512
+    # rows at or under which every held expert takes every row (a decode
+    # ply: 32 rows, 2 an expert): the weights are read once either way, and
+    # a grouped product over a handful of rows is all tile padding
+    dense_rows: int = 128
+    # every matrix is STORED at ``param_scale`` times its value and each
+    # product's result divided by it (a power of two: the same bits), so
+    # the learner's one learning rate moves a weight by 1 / param_scale of
+    # what it would; 1 is the plain parametrisation (module docstring)
+    param_scale: float = 1.0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @property
+    def held(self):
+        return (tuple(range(self.experts_published))
+                if self.experts_held is None else tuple(self.experts_held))
+
+    @property
+    def expert_layers(self):
+        return tuple(range(self.dense_layers, len(self.layer_types)))
+
+    def setup(self):
+        init = nn.initializers.normal(0.02 * self.param_scale)
+        self.embed = self.param('embed', init, (self.vocab, self.hidden_size))
+        self.blocks = [TrinityBlock(
+            self.hidden_size, self.heads_held, self.kv_heads_held,
+            self.head_dim, kind, self.window_size, self.rope_theta,
+            self.norm_eps, self.mlp_size if i < self.dense_layers else 0,
+            self.expert_size, self.experts_published, self.held,
+            self.experts_per_token, self.route_scale, self.query_block,
+            self.dense_rows, self.param_scale, self.dtype,
+            name='layer_%d' % i)
+            for i, kind in enumerate(self.layer_types)]
+        self.norm_out = self.param('norm_out', nn.initializers.ones,
+                                   (self.hidden_size,))
+        self.head = self.param('head', init, (self.hidden_size, self.vocab))
+        self.value = self.param('value', init, (self.hidden_size, 1))
+
+    @property
+    def actor_param_dtype(self):
+        """The actor's copy of the parameters is kept in the compute dtype
+        (train.py ``actor_refresh``): rollout reads every weight each ply."""
+        return self.dtype
+
+    # -- the cache -----------------------------------------------------------
+    def init_hidden(self, batch_shape=()):
+        lead = tuple(batch_shape)
+
+        def rows():
+            return tuple(jnp.zeros(
+                lead + (self.window_size if kind == 'sliding'
+                        else self.max_positions,
+                        self.kv_heads_held * self.head_dim), self.dtype)
+                for kind in self.layer_types)
+        return {'k': rows(), 'v': rows(), 'pos': jnp.zeros(lead, jnp.int32)}
+
+    @staticmethod
+    def reset_hidden(hidden, done):
+        """A finished game resets its sequences' counters, not their
+        buffers: what a counter has not reached is masked."""
+        pos = hidden['pos']
+        done = done.reshape(done.shape + (1,) * (pos.ndim - done.ndim))
+        return dict(hidden, pos=jnp.where(done, 0, pos))
+
+    # -- inputs and outputs --------------------------------------------------
+    def _embed(self, ids):
+        return self.embed[ids].astype(f32) * (self.hidden_size ** 0.5
+                                              / self.param_scale)
+
+    def _features(self, x):
+        return _rms_norm(x, self.norm_out, self.norm_eps, self.dtype)
+
+    def _value(self, features):
+        return jnp.tanh(_dot(features, self.value, self.dtype, out=f32)
+                        / self.param_scale)
+
+    def policy_logits(self, features):
+        """The head over the ids held, float32: features (..., D)."""
+        return _dot(features, self.head, self.dtype, out=f32) \
+            / self.param_scale
+
+    def __call__(self, obs, hidden, train: bool = False):
+        """One position a sequence: obs (B,) int32 ids."""
+        if hidden is None:
+            hidden = self.init_hidden(obs.shape)
+        pos = hidden['pos']
+        x = self._embed(obs)
+        ks, vs = [], []
+        for i, block in enumerate(self.blocks):
+            x, (k, v) = block.step(x, pos, (hidden['k'][i], hidden['v'][i]))
+            ks.append(k)
+            vs.append(v)
+        h = self._features(x)
+        return {'policy': self.policy_logits(h),
+                'value': self._value(h),
+                'hidden': {'k': tuple(ks), 'v': tuple(vs), 'pos': pos + 1}}
+
+    def sequence(self, ids, first_position, valid, no_grad_prefix: int = 0):
+        """T positions a sequence in one causal forward. ids (B, T) int32,
+        first_position (B,), valid (B, T) bool. Returns ``policy_features``
+        (B, T, D) in ``dtype`` (``policy_logits`` of them are the policy:
+        the loss takes the head a block of positions at a time), ``value``
+        (B, T, 1) float32 and ``aux``: the sums the forward pass hands to
+        the epoch record and to ``post_update``."""
+        positions = first_position[:, None] + jnp.arange(ids.shape[1])
+        x = self._embed(ids)
+        counts, dropped = [], jnp.int32(0)
+        for block in self.blocks:
+            # one layer rematerialised at a time, as models/evabyte.py
+            x, c, d = nn.remat(TrinityBlock.sequence, static_argnums=(4,))(
+                block, x, positions, valid, no_grad_prefix)
+            if c is not None:
+                counts.append(c)
+                dropped = dropped + d
+        h = self._features(x)
+        out = {'policy_features': h, 'value': self._value(h)}
+        if counts:
+            counts = jnp.stack(counts)                       # (layers, E)
+            ours = counts[:, jnp.asarray(self.held)]
+            out['aux'] = {
+                'moe_counts': counts,
+                'moe_rows_held': ours.sum().astype(f32),
+                'moe_rows_routed': counts.sum().astype(f32),
+                'moe_rows_fullest': ours.max().astype(f32),
+                'moe_rows_dropped': dropped.astype(f32)}
+        return out
+
+    def attention_part(self, layer: int, x, positions, valid):
+        return self.blocks[layer].attention_part(x, positions, valid)
+
+    def mlp_branch(self, layer: int, x, shared: bool = True):
+        """Layer ``layer``'s ``f(m)`` for (n, D) inputs, before the branch's
+        norm (the expert-share test sums eight of these)."""
+        return self.blocks[layer].mlp_branch(x, shared)[0]
+
+    # -- after the optimizer ---------------------------------------------------
+    def post_update(self, before, after, aux):
+        """What follows an update step, on the parameter trees: the router
+        stays as it was (it takes no gradient, and Adam's weight decay
+        would still move it); ``b`` is Adam's to leave alone and moves by
+        the source's rule, ``rate * (sign(mean(c) - c)`` centred to mean
+        zero), from the tokens each expert was chosen for in this step."""
+        params = dict(after['params'])
+        for n, i in enumerate(self.expert_layers):
+            name = 'layer_%d' % i
+            c = aux['moe_counts'][n].astype(f32)
+            delta = jnp.sign(c.mean() - c)
+            old = before['params'][name]
+            params[name] = dict(
+                params[name], router=old['router'],
+                router_bias=old['router_bias'] + self.bias_update_rate
+                * (delta - delta.mean()))
+        return dict(after, params=params)
+
+    def epoch_dynamics(self, sums):
+        """The epoch record's keys from the epoch's ``diag_*`` sums."""
+        held = sums.get('diag_moe_rows_held')
+        if not held:
+            return {}
+        slots = len(self.held) * len(self.expert_layers)
+        return {'moe_rows_held_share':
+                100.0 * held / sums['diag_moe_rows_routed'],
+                'moe_load_max_over_mean':
+                sums['diag_moe_rows_fullest'] * slots / held,
+                'moe_rows_dropped': sums.get('diag_moe_rows_dropped', 0.0)}

@@ -97,6 +97,16 @@ def _mask_rows(x, fill, cond):
     return jnp.where(cond.reshape((-1,) + (1,) * (x.ndim - 1)), x, fill)
 
 
+def _padded_mask(amask, cond):
+    """The illegal-action mask's rows (T, 1, ...) in the dtype the game's
+    twin recorded it in, every id illegal where ``cond`` (T,) is false:
+    +1e32 in a float mask, all bits set in a ``uint8`` one (ops/maskbits.py),
+    which stays ``uint8`` into the ring."""
+    if amask.dtype == jnp.uint8:
+        return _mask_rows(amask, jnp.uint8(255), cond)
+    return _mask_rows(amask, 1e32, cond).astype(jnp.float32)
+
+
 def _masked_obs(x, cond):
     """Observation rows (T, ...) given a player axis, zero where ``cond``
     (T,) is false: 0.0 for boards, the id 0 for integer observations (a
@@ -124,7 +134,7 @@ def _window_solo(take, S, ts_w, seat_w, outcome, fs: int, bi: int, L: int,
         lambda x: _masked_obs(x, valid), take('obs', idxm))
     prob = jnp.where(valid, take('prob', idxm), 1.0)
     act = jnp.where(valid, take('action', idxm), 0)
-    amask = _mask_rows(take('amask', idxm)[:, None], 1e32, valid)
+    amask = _padded_mask(take('amask', idxm)[:, None], valid)
     val = jnp.where(valid, take('value', idxm)[:, 0],
                     jnp.where(tail, outcome[seat_w], 0.0))
     if has_reward:
@@ -139,7 +149,7 @@ def _window_solo(take, S, ts_w, seat_w, outcome, fs: int, bi: int, L: int,
         'observation': obs,
         'selected_prob': prob.astype(f32)[:, None, None],
         'action': act.astype(jnp.int32)[:, None, None],
-        'action_mask': amask.astype(f32),
+        'action_mask': amask,
         'value': val.astype(f32)[:, None, None],
         'reward': rew.astype(f32)[:, None, None],
         'return': ret.astype(f32)[:, None, None],

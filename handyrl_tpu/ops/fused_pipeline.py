@@ -261,6 +261,10 @@ class FusedPipeline:
         self.window_positions_host = 0
         self.window_padded_host = 0
         self.chunks_host = 0
+        # every ``diag_*`` sum the fetch carries, added up over the fetched
+        # chunks under its own name less the prefix (the ``host_block`` span
+        # carries them beside the counters above)
+        self.diag_host: Dict[str, float] = {}
         self.state_cache_bytes = sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.hidden))
         self.state_resets = hasattr(wrapper.module, 'reset_hidden')
@@ -388,6 +392,12 @@ class FusedPipeline:
                 telemetry.counter('window_positions_total').inc(positions)
                 telemetry.counter('window_padded_positions_total').inc(
                     padded)
+            if has_metrics:
+                for k, v in zip(keys, rest[3:]):
+                    if k.startswith('diag_'):
+                        self.diag_host[k[5:]] = (
+                            self.diag_host.get(k[5:], 0.0) + float(v))
+            span.set(**self.diag_host)
             span.set(plies=self.plies_host,
                      chunks=self.chunks_host,
                      window_positions=self.window_positions_host,

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .maskbits import as_float
 from .targets import compute_target
 
 tmap = jax.tree_util.tree_map
@@ -105,12 +106,17 @@ def _sequence_prediction(sequence_fn, params, batch: Dict[str, Any],
     B, T, P_obs = ids.shape[:3]
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape((B * P_obs, T))
     first = jnp.repeat(batch['first_position'].reshape(B), P_obs)
-    out = sequence_fn(params, fold(ids), first,
-                      fold(batch['episode_mask'][..., 0]
-                           * jnp.ones((1, 1, P_obs))) > 0,
-                      cfg.burn_in_steps)
-    return {k: jnp.moveaxis(v.reshape((B, P_obs, T) + v.shape[2:]), 1, 2)
-            for k, v in out.items()}
+    out = dict(sequence_fn(params, fold(ids), first,
+                           fold(batch['episode_mask'][..., 0]
+                                * jnp.ones((1, 1, P_obs))) > 0,
+                           cfg.burn_in_steps))
+    # sums the forward pass hands on (``compute_loss``), not outputs
+    aux = out.pop('aux', None)
+    outputs = {k: jnp.moveaxis(v.reshape((B, P_obs, T) + v.shape[2:]), 1, 2)
+               for k, v in out.items()}
+    if aux is not None:
+        outputs['aux'] = aux
+    return outputs
 
 
 def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
@@ -200,12 +206,14 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
 
     masked = {}
     for k, o in outputs.items():
-        if k == 'policy':
+        if k == 'aux':
+            masked[k] = o
+        elif k == 'policy':
             o = o * batch['turn_mask']
             if o.shape[2] > 1 and P_obs == 1:
                 # turn-alternating batch: gather the acting player's row
                 o = o.sum(axis=2, keepdims=True)
-            masked[k] = o - batch['action_mask']
+            masked[k] = o - as_float(batch['action_mask'], o.shape[-1])
         elif o.ndim > batch['observation_mask'].ndim:   # (B, T, P, n, A)
             masked[k] = o * batch['observation_mask'][..., None]
         else:
@@ -227,8 +235,12 @@ def compose_losses(outputs: Dict[str, jnp.ndarray],
                    log_selected_policies: jnp.ndarray,
                    total_advantages: jnp.ndarray,
                    targets: Dict[str, Optional[jnp.ndarray]],
-                   batch: Dict[str, Any], cfg: LossConfig
+                   batch: Dict[str, Any], cfg: LossConfig,
+                   policy_entropy: Optional[jnp.ndarray] = None
                    ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+    """``policy_entropy`` (B, T, P): the policy's entropy where the head
+    was taken in blocks (``_policy_in_blocks``) and ``outputs`` has no
+    ``policy``."""
     tmasks = batch['turn_mask']
     omasks = batch['observation_mask']
 
@@ -242,7 +254,9 @@ def compose_losses(outputs: Dict[str, jnp.ndarray],
         huber = optax_huber(outputs['return'], targets['return'])
         losses['r'] = (huber * omasks).sum()
 
-    entropy = _entropy(outputs['policy']) * tmasks.sum(axis=-1)
+    if policy_entropy is None:
+        policy_entropy = _entropy(outputs['policy'])
+    entropy = policy_entropy * tmasks.sum(axis=-1)
     losses['ent'] = entropy.sum()
 
     base = losses['p'] + losses.get('v', 0) + losses.get('r', 0)
@@ -278,6 +292,38 @@ def _further_heads_loss(heads: jnp.ndarray, batch: Dict[str, Any]
     return total
 
 
+# positions whose logits are live at once where a net hands over
+# ``policy_features`` and not ``policy`` (a head of 25,024 ids over 8,192
+# positions is 820 MB in float32 for each live copy)
+POLICY_BLOCK = 1024
+
+
+def _policy_in_blocks(policy_fn, params, features, batch):
+    """The head, its log-softmax and its entropy ``POLICY_BLOCK`` positions
+    at a time, each block rematerialised in the backward pass: the taken
+    action's log-probability (B, T, P, 1) and the entropy (B, T, P) of the
+    masked policy ``policy_fn(params, features) * turn_mask - action_mask``,
+    what the whole-array path computes."""
+    lead = features.shape[:3]
+    n = lead[0] * lead[1] * lead[2]
+    block = min(POLICY_BLOCK, n)
+    assert n % block == 0, (n, block)
+    rows = lambda x: x.reshape((n // block, block) + x.shape[3:])
+
+    @jax.checkpoint
+    def one(args):
+        feats, turn, mask, action = args
+        logits = policy_fn(params, feats)
+        logp = jax.nn.log_softmax(
+            logits * turn - as_float(mask, logits.shape[-1]), axis=-1)
+        return (jnp.take_along_axis(logp, action, axis=-1),
+                -(jnp.exp(logp) * logp).sum(axis=-1))
+    picked, entropy = lax.map(one, (
+        rows(features), rows(batch['turn_mask']), rows(batch['action_mask']),
+        rows(batch['action'])))
+    return picked.reshape(lead + (1,)), entropy.reshape(lead)
+
+
 def optax_huber(pred: jnp.ndarray, target: jnp.ndarray, delta: float = 1.0
                 ) -> jnp.ndarray:
     """Smooth-L1 (huber, delta=1), elementwise."""
@@ -289,7 +335,8 @@ def optax_huber(pred: jnp.ndarray, target: jnp.ndarray, delta: float = 1.0
 
 def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
                  cfg: LossConfig, batch_stats=None, target_params=None,
-                 sequence_fn=None) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+                 sequence_fn=None, policy_fn=None
+                 ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """Full pipeline: forward, targets, advantages, composed losses.
 
     Returns (total_loss, aux) where aux carries per-term sums and the data
@@ -307,6 +354,12 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     policy that moves once per ``target_sync_epochs`` instead of every
     SGD step, which is what keeps high-lag chunks trainable. The policy
     gradient itself still differentiates the CURRENT policy's log-prob.
+
+    A sequence net MAY hand over ``policy_features`` in place of ``policy``
+    (``policy_fn(params, features)`` gives the logits; the head is then
+    taken in blocks of positions, ``_policy_in_blocks``) and MAY return
+    ``aux``, sums of its forward pass: its scalars join ``diag`` and the
+    whole of it rides back as ``aux['sequence_aux']``.
     """
     if batch_stats is None:
         params, batch_stats = split_batch_stats(params)
@@ -315,6 +368,7 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     new_bs = None
     if batch_stats is not None:
         outputs, new_bs = outputs
+    sequence_aux = outputs.pop('aux', None)
 
     use_target = target_params is not None and cfg.target_clip > 0
     tgt_outputs = None
@@ -343,8 +397,15 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     clip_rho, clip_c = 1.0, 1.0
 
     log_b = jnp.log(jnp.clip(batch['selected_prob'], 1e-16, 1)) * emasks
-    logp = jax.nn.log_softmax(outputs['policy'], axis=-1)
-    log_t = jnp.take_along_axis(logp, actions, axis=-1) * emasks
+    policy_entropy = None
+    if 'policy' in outputs:
+        logp = jax.nn.log_softmax(outputs['policy'], axis=-1)
+        log_t = jnp.take_along_axis(logp, actions, axis=-1) * emasks
+    else:
+        assert not use_target, 'no target network beside a head in blocks'
+        picked, policy_entropy = _policy_in_blocks(
+            policy_fn, params, outputs.pop('policy_features'), batch)
+        log_t = picked * emasks
 
     log_rhos = lax.stop_gradient(log_t) - log_b
     rhos = jnp.exp(log_rhos)
@@ -391,7 +452,7 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     total_advantages = clipped_rhos * sum(advantages.values())
 
     losses, dcnt = compose_losses(outputs, log_t, total_advantages, targets,
-                                  batch, cfg)
+                                  batch, cfg, policy_entropy)
     # off-policy health diagnostics, summed over acting (step, player)
     # pairs like every loss term so the host normalizes by data_count:
     # V-Trace rho/c clip fractions and the importance-ratio first/second
@@ -414,6 +475,9 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
         diag['target_gap_sum'] = ((lax.stop_gradient(log_t) - log_tgt)
                                   * tmask).sum()
     aux = {'losses': losses, 'data_count': dcnt, 'diag': diag}
+    if sequence_aux is not None:
+        diag.update((k, v) for k, v in sequence_aux.items() if v.ndim == 0)
+        aux['sequence_aux'] = sequence_aux
     if new_bs is not None:
         aux['batch_stats'] = new_bs
     return losses['total'], aux

@@ -67,6 +67,16 @@ def _update_core(module, cfg: LossConfig, optimizer, axis_name=None):
         def sequence_fn(params, *args):
             return module.apply(params, *args, method=module.sequence)
 
+    # such a net MAY hand the loss ``policy_features`` for its
+    # ``policy_logits`` (the head in blocks of positions), MAY return sums
+    # of its forward pass and MAY define ``post_update(before, after,
+    # aux)``, which runs on the parameter trees after the optimizer
+    policy_fn = None
+    if hasattr(module, 'policy_logits'):
+        def policy_fn(params, features):
+            return module.apply(params, features,
+                                method=module.policy_logits)
+
     def init_hidden_for(batch):
         if sequence_fn is not None or not hasattr(module, 'init_hidden'):
             return None
@@ -84,7 +94,7 @@ def _update_core(module, cfg: LossConfig, optimizer, axis_name=None):
             return compute_loss(apply_fn, params, init_hidden, batch, cfg,
                                 batch_stats=batch_stats,
                                 target_params=target_params,
-                                sequence_fn=sequence_fn)
+                                sequence_fn=sequence_fn, policy_fn=policy_fn)
 
         (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(trainable)
         new_bs = aux.pop('batch_stats', None)
@@ -114,14 +124,23 @@ def _update_core(module, cfg: LossConfig, optimizer, axis_name=None):
         ok = (jnp.isfinite(lr)
               & jnp.isfinite(aux['losses']['total'])
               & jnp.isfinite(grad_norm))
-        updates, opt_state = optimizer.update(grads, state.opt_state, trainable)
-        updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
-        params = optax.apply_updates(trainable, updates)
+        sequence_aux = aux.pop('sequence_aux', None)
 
         def keep(new, old):
             return jnp.where(ok, new, old)
-        params = jax.tree_util.tree_map(keep, params, trainable)
-        opt_state = jax.tree_util.tree_map(keep, opt_state, state.opt_state)
+        # the scope holds the selects too: the compiler fuses a leaf's
+        # moments, its update and its select into one operation and names it
+        # after the last
+        with jax.named_scope('optimizer'):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  trainable)
+            updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
+            params = optax.apply_updates(trainable, updates)
+            if hasattr(module, 'post_update'):
+                params = module.post_update(trainable, params, sequence_aux)
+            params = jax.tree_util.tree_map(keep, params, trainable)
+            opt_state = jax.tree_util.tree_map(keep, opt_state,
+                                               state.opt_state)
         if new_bs is not None:
             params = {**dict(params),
                       'batch_stats': jax.tree_util.tree_map(

@@ -923,6 +923,11 @@ class Trainer:
             out['target_gap_mean'] = d.get('diag_target_gap_sum', 0.0) / dc
         if 'diag_grad_norm' in d:
             out['grad_norm'] = d['diag_grad_norm'] / nu
+        # a net MAY reduce the sums of its own forward pass (the ``aux`` of
+        # its ``sequence``) to record keys of its own
+        net_dynamics = getattr(self.wrapper.module, 'epoch_dynamics', None)
+        if net_dynamics is not None:
+            out.update(net_dynamics(d))
         out = {k: round(float(v), 6) for k, v in out.items()}
         for k, v in out.items():
             telemetry.gauge(k).set(v)

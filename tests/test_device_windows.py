@@ -621,3 +621,129 @@ def test_no_geister_game_outlasts_the_declared_bound():
                                np.flatnonzero(lane))]
     assert max(lengths) == _declared_max_steps(env) == env.MAX_PLIES + 2
     assert lengths.count(max(lengths)) > 10
+
+
+# -- the legal set as bits (ops/maskbits.py; ROADMAP M4) -----------------------
+def _solo_history(bits, S=9, P=2, A=40, seed=3):
+    from handyrl_tpu.ops import maskbits
+    rng = np.random.RandomState(seed)
+    illegal = rng.rand(L, P, A) < 0.3
+    amask = (maskbits.pack(jnp.asarray(illegal)) if bits
+             else jnp.asarray(np.where(illegal, 1e32, 0).astype(np.float32)))
+    hist = {'obs': jnp.asarray(rng.randint(0, A, (L, P)), jnp.int32),
+            'action': jnp.asarray(rng.randint(0, A, (L, P)), jnp.int32),
+            'prob': jnp.asarray(rng.uniform(0.1, 1, (L, P)), jnp.float32),
+            'amask': amask,
+            'value': jnp.asarray(rng.uniform(-1, 1, (L, P, 1)), jnp.float32),
+            'acting': jnp.ones((L, P), bool)}
+    return hist, illegal, S
+
+
+def test_a_bit_mask_goes_into_the_window_as_bits_padded_with_ones():
+    """The windower keeps the dtype the game's twin recorded: a ``uint8``
+    mask stays ``uint8`` (an eighth of a byte an id where the float mask is
+    four), its padding rows all ones, and it unpacks to the float window's
+    mask exactly."""
+    from handyrl_tpu.ops import maskbits
+    ts, seat = jnp.asarray([0, 3, 7]), jnp.asarray([0, 1, 1])
+    outcome = jnp.asarray([1.0, -1.0])
+    wins = {}
+    for bits in (False, True):
+        hist, _illegal, S = _solo_history(bits)
+        wins[bits] = build_windows_solo(hist, jnp.int32(S), ts, seat, outcome,
+                                        FS, BI, L, first_position=True)
+    packed, plain = wins[True]['action_mask'], wins[False]['action_mask']
+    assert packed.dtype == jnp.uint8 and packed.shape == (3, FS + BI, 1, 5)
+    assert plain.dtype == jnp.float32 and plain.shape == (3, FS + BI, 1, 40)
+    np.testing.assert_array_equal(maskbits.as_float(packed, 40), plain)
+    # the first window starts BI plies before the game: padding, all bits set
+    assert (np.asarray(packed)[0, :BI] == 255).all()
+    for key in wins[False]:
+        if key != 'action_mask':
+            np.testing.assert_array_equal(wins[True][key], wins[False][key])
+
+
+@pytest.mark.parametrize('net_name', ['EvaByteNet', 'TrinityNet'])
+def test_a_bit_mask_in_the_ring_trains_as_the_float_mask(net_name):
+    """``compute_loss`` on windows whose ``action_mask`` is bits against the
+    same windows with the float mask: the same loss and the same gradient,
+    bit for bit, on the whole-array path (a net that returns ``policy``) and
+    on the path that takes the head in blocks (``policy_features``)."""
+    from handyrl_tpu import models
+    from handyrl_tpu.ops import maskbits
+    from handyrl_tpu.ops.losses import LossConfig, compute_loss
+    T, A = 16, 40
+    widths = {
+        'EvaByteNet': dict(hidden_size=32, layers=1, heads_held=2,
+                           heads_published=2, head_dim=8, mlp_size=48,
+                           vocab=A, chunk_size=4, window_size=8,
+                           max_positions=32, query_block=8, pred_heads=2),
+        'TrinityNet': dict(hidden_size=32, layer_types=('sliding', 'full'),
+                           dense_layers=1, heads_held=2, kv_heads_held=1,
+                           head_dim=8, mlp_size=48, expert_size=16,
+                           experts_published=8, experts_held=(0, 1, 2),
+                           experts_per_token=2, vocab=A, window_size=8,
+                           max_positions=32, query_block=8, dense_rows=2)}
+    net = models.build(net_name, dtype=jnp.float32, **widths[net_name])
+    variables = net.init(jax.random.PRNGKey(0), jnp.zeros((1,), jnp.int32),
+                         None)
+    rng = np.random.RandomState(1)
+    valid = (np.arange(T)[None, :] < np.asarray([[T], [11]])).astype(
+        np.float32)
+    illegal = (rng.rand(2, T, 1, A) < 0.3) | (valid[..., None, None] == 0)
+    illegal[..., 0] = valid[..., None] == 0       # id 0 legal in the game
+    col = lambda x: jnp.asarray(x, jnp.float32)[..., None, None]
+    batch = {
+        'observation': jnp.asarray(rng.randint(0, A, (2, T, 1)), jnp.int32),
+        'selected_prob': col(rng.uniform(0.05, 0.5, (2, T))),
+        'action': jnp.zeros((2, T, 1, 1), jnp.int32),
+        'value': col(rng.uniform(-0.1, 0.1, (2, T))),
+        'reward': col(np.zeros((2, T))), 'return': col(np.zeros((2, T))),
+        'outcome': jnp.asarray([1.0, -1.0]).reshape(2, 1, 1, 1),
+        'episode_mask': col(valid), 'turn_mask': col(valid),
+        'observation_mask': col(valid),
+        'progress': jnp.asarray(np.linspace(0, 1, T)[None, :, None]
+                                * np.ones((2, 1, 1)), jnp.float32),
+        'first_position': jnp.zeros((2, 1, 1, 1), jnp.int32)}
+    masks = {'float': jnp.asarray(np.where(illegal, 1e32, 0), jnp.float32),
+             'bits': maskbits.pack(jnp.asarray(illegal))}
+    assert masks['bits'].dtype == jnp.uint8 and masks['bits'].shape[-1] == 5
+    cfg = LossConfig(turn_based_training=False, observation=True,
+                     policy_target='VTRACE', value_target='VTRACE')
+    sequence = lambda p, *a: net.apply(p, *a, method=net.sequence)
+    policy = None
+    if hasattr(net, 'policy_logits'):
+        policy = lambda p, f: net.apply(p, f, method=net.policy_logits)
+
+    def loss_and_grad(mask):
+        return jax.jit(jax.value_and_grad(lambda p: compute_loss(
+            net.apply, p, None, dict(batch, action_mask=mask), cfg,
+            sequence_fn=sequence, policy_fn=policy)[0]))(variables)
+    (want, want_grad), (got, got_grad) = (loss_and_grad(masks[k])
+                                          for k in ('float', 'bits'))
+    assert np.isfinite(float(want)) and float(want) == float(got)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, want_grad, got_grad)
+
+
+def test_a_head_taken_in_blocks_is_the_whole_head(monkeypatch):
+    """``_policy_in_blocks`` over four blocks of positions against one: the
+    same log-probabilities and entropies, so the same loss."""
+    from handyrl_tpu.ops import losses
+    rng = np.random.RandomState(2)
+    B, T, D, A = 2, 16, 8, 24
+    features = jnp.asarray(rng.randn(B, T, 1, D), jnp.float32)
+    head = jnp.asarray(rng.randn(D, A), jnp.float32)
+    batch = {'turn_mask': jnp.ones((B, T, 1, 1)),
+             'action_mask': jnp.asarray(
+                 np.where(rng.rand(B, T, 1, A) < 0.3, 1e32, 0), jnp.float32),
+             'action': jnp.asarray(rng.randint(0, A, (B, T, 1, 1)), jnp.int32)}
+    policy = lambda w, f: f @ w
+    whole = losses._policy_in_blocks(policy, head, features, batch)
+    monkeypatch.setattr(losses, 'POLICY_BLOCK', 8)
+    blocks = losses._policy_in_blocks(policy, head, features, batch)
+    for a, b in zip(whole, blocks):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+    logp = jax.nn.log_softmax(features @ head - batch['action_mask'])
+    np.testing.assert_allclose(
+        whole[0], jnp.take_along_axis(logp, batch['action'], axis=-1),
+        rtol=1e-6, atol=1e-6)

@@ -192,14 +192,21 @@ ENV_ARGS = {'env': 'ByteGame', 'min_steps': 5, 'max_steps': 12,
             'net': WIDTHS}
 
 
+@pytest.mark.parametrize('ids', [320, 25024])
 @pytest.mark.parametrize('seed', [0, 1, 2])
-def test_the_twin_plays_the_host_envs_games(seed):
+def test_the_twin_plays_the_host_envs_games(seed, ids):
     """Same lengths, salts and actions: the same observations, legal ids,
-    ends and outcomes, ply for ply, through two games a lane."""
-    twin = make_jax_env(ENV_ARGS)
+    ends and outcomes, ply for ply, through two games a lane; over the
+    byte game's own 320 ids (the defaults, as before) and over 25,024, of
+    which 64 are legal on the first ply only."""
+    env_args = ENV_ARGS if ids == 320 else dict(ENV_ARGS, ids=ids,
+                                                first_ply_ids=64)
+    twin = make_jax_env(env_args)
+    assert (twin.N_ACTIONS, twin.BOS, twin.MASK_AS_BITS) \
+        == (ids, ids - 64, ids > 4096)
     n = 3
     state = twin.init_state(n, seed)
-    envs = [make_env(ENV_ARGS) for _ in range(n)]
+    envs = [make_env(env_args) for _ in range(n)]
     for i, env in enumerate(envs):
         env.reset({'length': int(state.length[i]), 'salt': int(state.salt[i])})
     rng = np.random.default_rng(seed)
@@ -208,6 +215,7 @@ def test_the_twin_plays_the_host_envs_games(seed):
         obs = np.asarray(twin.observe(state))
         legal = np.asarray(twin.legal_mask(state))
         assert obs.dtype == np.int32 and twin.acting(state).all()
+        assert legal.shape[-1] == ids and (0 <= obs).all() and (obs < ids).all()
         actions = np.zeros((n, 2), np.int32)
         for i, env in enumerate(envs):
             for p in env.players():
