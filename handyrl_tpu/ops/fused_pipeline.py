@@ -167,7 +167,9 @@ class FusedPipeline:
                 # ring rows are stored flat and padded
                 # (device_windows.init_ring); restore the (B, T, P, ...)
                 # window shape after the gather and rebuild the batch
-                # pytree (dotted keys -> nested obs)
+                # pytree (dotted keys -> nested obs). A feed-forward net
+                # then reads it folded time-major (losses._fold_bt), which
+                # keeps these B rows' axis in the lanes
                 with jax.named_scope('sample'):
                     batch = windower.unflatten_rows(
                         {k: ring[k][slots] for k in ring})

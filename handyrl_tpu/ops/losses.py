@@ -5,8 +5,8 @@ Numerical parity targets: the reference training pipeline
 two-player value symmetrization and terminal bootstrap — rebuilt as a single
 XLA program:
 
-  * feed-forward nets: (B, T, P) folded into one batch dim — one big MXU
-    matmul stream instead of T small ones;
+  * feed-forward nets: (B, T, P) folded into one batch dim, time-major
+    (``_fold_bt``) — one big MXU matmul stream instead of T small ones;
   * recurrent nets: ``lax.scan`` over time with observation-mask-gated
     hidden carry; burn-in steps run in a separate scan whose carry passes
     through ``stop_gradient`` (the reference's no_grad replay,
@@ -72,8 +72,18 @@ class LossConfig(NamedTuple):
 
 
 def _fold_bt(x):
-    """(B, T, P, ...) -> (B*T*P, ...)"""
+    """(B, T, P, ...) -> (T*B*P, ...), time-major: the folded index is
+    ``(t*B + b)*P + p``, so a batch gathered as B ring rows reaches the
+    net's first operand by a transposition of whole rows; folded
+    window-major, every ply was relaid (docs/observability.md, "Reading a
+    compiled program's layouts")."""
+    x = jnp.moveaxis(x, 1, 0)
     return x.reshape((-1,) + x.shape[3:])
+
+
+def _unfold_bt(x, B, T, P):
+    """``_fold_bt``'s inverse on a net's output: (T*B*P, ...) -> (B, T, P, ...)"""
+    return jnp.moveaxis(x.reshape((T, B, P) + x.shape[1:]), 0, 1)
 
 
 def split_batch_stats(variables):
@@ -152,7 +162,7 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
     elif hidden is None:
         obs = tmap(_fold_bt, observations)
         outputs, new_bs = net(batch_stats, obs, None)
-        outputs = {k: v.reshape((B, T, P_obs) + v.shape[1:])
+        outputs = {k: _unfold_bt(v, B, T, P_obs)
                    for k, v in outputs.items() if k != 'hidden' and v is not None}
     else:
         obs_tm = tmap(lambda o: jnp.moveaxis(o, 1, 0), observations)   # (T, B, P_obs, ...)

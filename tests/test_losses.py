@@ -7,15 +7,21 @@ import jax
 import jax.numpy as jnp
 import pytest
 from flax import linen as nn
+from jax.flatten_util import ravel_pytree
 
 from handyrl_tpu.model import ModelWrapper
+from handyrl_tpu.models.geese import GeeseNet
 from handyrl_tpu.models.tictactoe import SimpleConv2dModel
 from handyrl_tpu.ops.batch import make_batch
-from handyrl_tpu.ops.losses import LossConfig, compute_loss, forward_prediction
+from handyrl_tpu.ops.losses import (LossConfig, _fold_bt, _unfold_bt,
+                                    compute_loss, forward_prediction,
+                                    split_batch_stats)
 from handyrl_tpu.ops.train_step import build_update_step, init_train_state
 from handyrl_tpu.parallel.mesh import make_mesh, shard_batch
 
 from helpers import turn_based_episode, train_args, window
+
+tmap = jax.tree_util.tree_map
 
 
 def _ttt_batch(B=4, steps=5, fs=4):
@@ -260,3 +266,114 @@ def test_update_step_target_network_on_mesh():
     _, metrics1 = step1(state, batch, lr, target)
     np.testing.assert_allclose(float(metrics['total']),
                                float(metrics1['total']), rtol=2e-3)
+
+
+# --- the feed-forward fold is time-major (PR 39) -------------------------
+#
+# `_fold_bt` hands the net (T*B*P, ...) rows and `_unfold_bt` brings its
+# outputs back to (B, T, P, ...): every feed-forward net is a function of one
+# row at a time, so the order of the fold must not show in any output.
+
+class TinyDictNet(nn.Module):
+    """Feed-forward net over Geister's observation pytree: a (7, 6, 6)
+    board and 18 scalars, each leaf folded on its own by ``tmap``."""
+
+    @nn.compact
+    def __call__(self, obs, hidden=None):
+        x = jnp.concatenate(
+            [obs['board'].reshape(obs['board'].shape[:-3] + (-1,)),
+             obs['scalar']], axis=-1)
+        h = jnp.tanh(nn.Dense(16)(x))
+        return {'policy': nn.Dense(5)(h), 'value': jnp.tanh(nn.Dense(1)(h))}
+
+
+def _random_ff_batch(obs_shapes, B, T, P, n_actions, dtype, seed=0):
+    """A random (B, T, P, ...) batch of 0/1 planes (what the games' nets
+    read) with every mask open, so the masked outputs of
+    ``forward_prediction`` ARE the net's outputs."""
+    rng = np.random.RandomState(seed)
+    draw = lambda shape: jnp.asarray(
+        rng.random_sample((B, T, P) + shape) < 0.2, dtype)
+    if isinstance(obs_shapes, dict):
+        obs = {k: draw(s) for k, s in obs_shapes.items()}
+    else:
+        obs = draw(obs_shapes)
+    return {'observation': obs,
+            'action': jnp.zeros((B, T, P, 1), jnp.int32),
+            'turn_mask': jnp.ones((B, T, P, 1), dtype),
+            'observation_mask': jnp.ones((B, T, P, 1), dtype),
+            'action_mask': jnp.zeros((B, T, P, n_actions), dtype)}
+
+
+# name -> (module, observation shapes, actions, dtype). BatchNorm's variance
+# is E[x^2] - E[x]^2 and the net divides by its root, which carries the
+# float32 rounding of a sum taken in another order to 1e-5 of an output:
+# that case runs in float64, where "the same but for rounding" reads 1e-13
+_FF_NETS = {
+    'geese_group': (lambda dt: GeeseNet(filters=8, layers=2, dtype=dt),
+                    (17, 7, 11), 4, 'float32'),
+    'geese_batch': (lambda dt: GeeseNet(filters=8, layers=2, dtype=dt,
+                                        norm_kind='batch'),
+                    (17, 7, 11), 4, 'float64'),
+    'dict_obs': (lambda dt: TinyDictNet(),
+                 {'board': (7, 6, 6), 'scalar': (18,)}, 5, 'float32'),
+}
+
+
+@pytest.mark.parametrize('p_obs', [1, 4])
+@pytest.mark.parametrize('net', sorted(_FF_NETS))
+def test_forward_prediction_is_the_net_window_by_window(net, p_obs):
+    """Folded time-major or not, row (b, t, p) of every output is the net on
+    observation (b, t, p). Nets without statistics across rows are applied
+    one window at a time; with ``norm_kind='batch'`` the statistics span the
+    whole fold (the reference's flattened forward), so the comparison is the
+    net on the window-major fold: same rows, same statistics, and the same
+    new ``batch_stats``, up to the rounding of a sum taken in another
+    order."""
+    make, obs_shapes, n_actions, dtype = _FF_NETS[net]
+    B, T = 3, 5
+    with jax.enable_x64(dtype == 'float64'):
+        dtype = jnp.dtype(dtype)
+        batch = _random_ff_batch(obs_shapes, B, T, p_obs, n_actions, dtype)
+        obs = batch['observation']
+        module = make(dtype)
+        variables = tmap(lambda v: v.astype(dtype), module.init(
+            jax.random.PRNGKey(2), tmap(lambda o: o[0, 0], obs), None))
+        params, stats = split_batch_stats(variables)
+        got = forward_prediction(module.apply, params, None, batch,
+                                 LossConfig(), batch_stats=stats)
+        if stats is None:
+            per_window = [module.apply(params, tmap(
+                lambda o: o[b].reshape((T * p_obs,) + o.shape[3:]), obs),
+                None) for b in range(B)]
+            want = {k: jnp.stack([w[k] for w in per_window])
+                    for k in ('policy', 'value')}
+        else:
+            got, new_stats = got
+            want, mutated = module.apply(
+                variables, tmap(lambda o: o.reshape((-1,) + o.shape[3:]), obs),
+                None, train=True, mutable=['batch_stats'])
+            got['batch_stats'] = ravel_pytree(new_stats)[0]
+            want['batch_stats'] = ravel_pytree(mutated['batch_stats'])[0]
+        for k, w in want.items():
+            assert got[k].dtype == dtype, (k, got[k].dtype)
+            np.testing.assert_allclose(
+                np.asarray(got[k]), np.asarray(w).reshape(got[k].shape),
+                rtol=1e-6, atol=1e-6, err_msg=k)
+        assert got['policy'].shape == (B, T, p_obs, n_actions)
+        assert got['value'].shape == (B, T, p_obs, 1)
+
+
+@pytest.mark.parametrize('shape', [(3, 5, 1), (2, 4, 4, 17, 7, 11),
+                                   (4, 2, 2, 6)])
+def test_fold_then_unfold_is_the_identity(shape):
+    B, T, P = shape[:3]
+    x = jnp.arange(int(np.prod(shape))).reshape(shape)
+    folded = _fold_bt(x)
+    assert folded.shape == (T * B * P,) + shape[3:]
+    # time-major: the first B*P rows are ply 0 of every window
+    np.testing.assert_array_equal(
+        np.asarray(folded[:B * P]),
+        np.asarray(x[:, 0].reshape((B * P,) + shape[3:])))
+    np.testing.assert_array_equal(np.asarray(_unfold_bt(folded, B, T, P)),
+                                  np.asarray(x))
