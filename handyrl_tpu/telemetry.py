@@ -1959,16 +1959,30 @@ def set_utilization_proxy(value):
 
 # -- the fused loop's per-chunk record and stall event
 
-# a chunk's split: the loop's spans under the names the stall event and the
-# epoch record's ``fused`` block give them. The first two lie at the head of
-# the iteration that sees the chunk complete, the rest at the tail of the
-# iteration before. A boundary's ``state_fetch`` waits for the chunk in
-# flight, so its time is booked under ``wait`` and taken out of ``epoch``:
-# the pieces are disjoint parts of the interval and ``wait`` is ALL the time
-# the loop was blocked on device results in it
-CHUNK_HEAD = (('enqueue', 'dispatch'), ('wait', 'host_block'))
-CHUNK_TAIL = (('account', 'chunk_account'), ('eval', 'eval_share'),
-              ('epoch', 'epoch_boundary'))
+# a chunk's split: the loop's spans (direct children of ``fused_iter`` or of
+# its ``epoch_boundary``) under the keys the stall event, the epoch record's
+# ``fused`` block and the ``fused_iter`` span's counters give them. ONE rule
+# books an interval, wherever its step was made: each piece is the seconds of
+# those spans that lie inside it, and ``epoch`` is the rest (the
+# boundary's and the loop's glue, under no child span). A boundary's
+# ``state_fetch`` waits for device work (the pack and its transfer; the chunk
+# in flight too where the boundary keeps fetch-then-enqueue), so it is booked
+# under ``wait``: ``wait`` is ALL the time the loop was blocked on device
+# results in the interval
+CHUNK_PIECES = {
+    'dispatch': 'enqueue', 'host_block': 'wait', 'state_fetch': 'wait',
+    'chunk_account': 'account', 'eval_share': 'eval',
+    'epoch_report': 'report', 'state_pack': 'pack',
+    'actor_refresh': 'refresh', 'checkpoint_wait': 'ckpt_wait',
+    'epoch_advance': 'advance', 'metrics_write': 'record',
+    'checkpoint_submit': 'submit', 'snapshot_release': 'release'}
+# the boundary's own host work: what the loop thread does there that waits
+# for no write (``ckpt_wait`` is the wait for the writer and stands apart;
+# ``release`` is where the writer's serialisation takes the interpreter
+# lock from the loop)
+BOUNDARY_KEYS = ('report', 'pack', 'refresh', 'advance', 'record', 'submit',
+                 'release', 'epoch')
+CHUNK_KEYS = tuple(dict.fromkeys(CHUNK_PIECES.values())) + ('epoch',)
 
 
 def _cpu_usage() -> Tuple[float, float, int]:
@@ -1979,15 +1993,27 @@ def _cpu_usage() -> Tuple[float, float, int]:
 
 
 class ChunkMonitor:
-    """What the fused loop keeps of each chunk, and the stall detector.
+    """What the fused loop keeps of each chunk, the verdict on who set its
+    pace, and the stall detector.
 
     A chunk is complete when the loop's blocking fetch of its packed result
     returns (the end of a ``host_block`` span). The INTERVAL between two
-    completions is one chunk, whole: the tail of the iteration that saw the
-    first (accounting, eval share, an epoch boundary with its own wait for
-    the device) and the head of the next (enqueue, wait). ``fetched`` books
-    it from the open ``fused_iter`` span right after the step; ``closed``
-    keeps the finished iteration's tail for the next interval.
+    completions is one chunk, whole, split by ``CHUNK_PIECES``: ``fetched``
+    books it from the open ``fused_iter`` span (and the open
+    ``epoch_boundary``, where the boundary made the step) right after the
+    step; ``closed`` keeps what a finished iteration held after its last
+    completion for the next interval.
+
+    The interval less its ``wait`` is the loop's TURNAROUND: the host's own
+    time from one completion to the next blocking fetch, with one program's
+    worth of device work queued. Where the turnaround outlasts that program
+    the device runs dry and the fetch that completes the chunk finds its
+    result ready: a chunk whose completing ``host_block`` took under
+    ``HOST_BOUND_FETCH`` of the running median interval is HOST-BOUND (the
+    result's transfer takes 0.5-0.9 ms on the chip: zero is not the test;
+    a boundary's ``state_fetch`` of 2-3 ms is ``wait`` but no part of the
+    test, which it blurs: PERF.md section 5, PR 40). ``totals`` keeps the
+    sums since the loop began, for every ``fused_iter`` span to carry.
 
     A running median over the last ``WINDOW`` intervals is kept; an interval
     over ``RATIO`` times that median and at least ``FLOOR_S`` over it is a
@@ -2003,6 +2029,8 @@ class ChunkMonitor:
 
     WINDOW, MIN_SAMPLES = 64, 8
     RATIO, FLOOR_S = 2.0, 0.5
+    # set once against the device trace (PERF.md section 5, PR 40)
+    HOST_BOUND_FETCH = 0.075
 
     def __init__(self):
         self._recent: deque = deque(maxlen=self.WINDOW)
@@ -2011,67 +2039,71 @@ class ChunkMonitor:
         self._pending: Optional[Dict[str, Any]] = None
         self._usage = _cpu_usage()
         self._done: Optional[float] = None     # the last completion
-        self._tail: Dict[str, float] = {}      # the last iteration's tail
+        self._tail: Dict[str, float] = {}      # pieces since, in closed spans
+        self._epoch_hb = 0                     # this epoch's host-bound chunks
+        self._epoch_hb_split: Dict[str, float] = {}
+        self.totals: Dict[str, Any] = dict(
+            {'chunks': 0, 'host_bound_chunks': 0, 'interval_s': 0.0,
+             'turnaround_s': 0.0, 'hb_turnaround_s': 0.0,
+             'hb_boundary_s': 0.0},
+            **{'hb_%s_s' % key: 0.0 for key in CHUNK_KEYS})
+
+    def _collect(self, *scopes):
+        """Add to the tail the pieces under ``scopes`` (an iteration, and its
+        open boundary) that ended after the last completion (no piece
+        straddles one: a completion IS the end of a piece). A finished
+        boundary's children count as its iteration's."""
+        since = self._done
+        for scope in scopes:
+            for child in scope.children:
+                for piece in (child.children
+                              if child.name == 'epoch_boundary' else (child,)):
+                    key = CHUNK_PIECES.get(piece.name)
+                    if key is not None and (since is None
+                                            or piece.t1 > since):
+                        self._tail[key] = (self._tail.get(key, 0.0)
+                                           + piece.t1 - piece.t0)
 
     def fetched(self, dispatch: int, span,
                 boundary=None) -> Optional[Dict[str, Any]]:
         """The step of iteration ``span`` (still open) has returned: if it
         fetched a chunk, book the interval that chunk's completion ends.
         ``boundary`` is the iteration's open ``epoch_boundary`` span where
-        it made the NEXT iteration's step itself, ahead of its state fetch:
-        the interval then lies inside this one iteration, from its own
-        step's completion through its accounting, its eval share and the
-        boundary so far."""
-        scope = span if boundary is None else boundary
-        blocks = [c for c in scope.children if c.name == 'host_block']
+        it made the NEXT iteration's step itself, ahead of its state
+        fetch."""
+        scopes = (span,) if boundary is None else (span, boundary)
+        blocks = [c for c in scopes[-1].children if c.name == 'host_block']
         if not blocks:
             return None
+        self._collect(*scopes)
         done, before = blocks[-1].t1, self._done
         self._done = done
-        split, self._tail = self._tail, {}
+        pieces, self._tail = self._tail, {}
         if before is None:
             return None
-        for key, stage in CHUNK_HEAD:
-            split[key] = split.get(key, 0.0) + scope.child_seconds(stage)
-        if boundary is not None:
-            for key, stage in CHUNK_TAIL[:2]:
-                split[key] = split.get(key, 0.0) + span.child_seconds(stage)
-            split['epoch'] = (split.get('epoch', 0.0) + done - boundary.t0
-                              - sum(scope.child_seconds(stage)
-                                    for _key, stage in CHUNK_HEAD))
-        return self.observe(dispatch, done - before, split)
+        split = {key: pieces.get(key, 0.0) for key in CHUNK_KEYS}
+        split['epoch'] = done - before - sum(split.values())
+        return self.observe(dispatch, done - before, split,
+                            fetch_s=done - blocks[-1].t0)
 
     def closed(self, span):
-        """Iteration ``span`` has finished: its tail opens the next
-        interval. A boundary's packed state fetch waits for the chunk in
-        flight: that part of the boundary is the next interval's wait. Of a
-        boundary that made the next step itself (``fetched`` has booked the
-        iteration up to that step's completion) the rest is the tail."""
-        tail = {key: span.child_seconds(stage) for key, stage in CHUNK_TAIL}
-        tail['wait'] = 0.0
-        for boundary in span.children:
-            if boundary.name != 'epoch_boundary':
-                continue
-            stepped = [c for c in boundary.children
-                       if c.name == 'host_block']
-            if stepped:
-                tail = {'epoch': boundary.t1 - stepped[-1].t1}
-            tail['wait'] = boundary.child_seconds('state_fetch')
-            tail['epoch'] -= tail['wait']
-        # (added, not set: the iteration after such a boundary has no step
-        # of its own, and its tail joins what is left of the boundary's)
-        for key, seconds in tail.items():
-            self._tail[key] = self._tail.get(key, 0.0) + seconds
+        """Iteration ``span`` has finished: what it held after its last
+        completion (all of it, where a boundary had made its step) opens
+        the next interval."""
+        self._collect(span)
 
     def observe(self, dispatch: int, interval_s: float,
-                split: Dict[str, float]) -> Optional[Dict[str, Any]]:
-        """Book one interval; returns the stall record this call emitted
-        (the PREVIOUS stall, now complete), if any."""
+                split: Dict[str, float],
+                fetch_s: Optional[float] = None) -> Optional[Dict[str, Any]]:
+        """Book one interval; ``fetch_s`` is the completing fetch's own
+        seconds (the whole ``wait`` where not given). Returns the stall
+        record this call emitted (the PREVIOUS stall, now complete), if
+        any."""
         usage, before = _cpu_usage(), self._usage
         self._usage = usage
         emitted = self._emit(split.get('wait'))
-        median = (statistics.median(self._recent)
-                  if len(self._recent) >= self.MIN_SAMPLES else None)
+        pace = statistics.median(self._recent) if self._recent else None
+        median = pace if len(self._recent) >= self.MIN_SAMPLES else None
         if median is not None and interval_s > max(
                 self.RATIO * median, median + self.FLOOR_S):
             self._pending = {
@@ -2084,9 +2116,40 @@ class ChunkMonitor:
                 'involuntary_switches': usage[2] - before[2],
                 'loadavg': [round(x, 2) for x in os.getloadavg()],
                 'next_host_block_s': None}
+        fetch_s = split.get('wait', 0.0) if fetch_s is None else fetch_s
+        # (the first interval of a loop is measured against itself)
+        self._book_turnaround(
+            interval_s, split, fetch_s < self.HOST_BOUND_FETCH * (
+                interval_s if pace is None else pace))
         self._recent.append(interval_s)
         self._chunks.append((interval_s, split))
         return emitted
+
+    def _book_turnaround(self, interval_s: float, split: Dict[str, float],
+                         host_bound: bool):
+        """The interval's turnaround and its verdict into the running
+        sums."""
+        wait = split.get('wait', 0.0)
+        totals = self.totals
+        totals['chunks'] += 1
+        totals['interval_s'] += interval_s
+        totals['turnaround_s'] += interval_s - wait
+        counter('fused_chunks_total').inc()
+        # (an inc of 0 registers the counter: a loop that was never
+        # host-bound reads 0, not "no such counter")
+        counter('fused_chunks_host_bound_total').inc(int(host_bound))
+        if not host_bound:
+            return
+        self._epoch_hb += 1
+        totals['host_bound_chunks'] += 1
+        totals['hb_turnaround_s'] += interval_s - wait
+        for key, seconds in split.items():
+            totals['hb_%s_s' % key] = (totals.get('hb_%s_s' % key, 0.0)
+                                       + seconds)
+            self._epoch_hb_split[key] = (self._epoch_hb_split.get(key, 0.0)
+                                         + seconds)
+        totals['hb_boundary_s'] += sum(split.get(key, 0.0)
+                                       for key in BOUNDARY_KEYS)
 
     def _emit(self, next_wait_s: Optional[float]):
         stall, self._pending = self._pending, None
@@ -2111,18 +2174,24 @@ class ChunkMonitor:
         block: Dict[str, Any] = {'chunks': len(chunks), 'stalls': stalls}
         if chunks:
             intervals = [c[0] for c in chunks]
+            waits = [c[1].get('wait', 0.0) for c in chunks]
             block['interval_median_s'] = round(
                 statistics.median(intervals), 6)
             block['interval_max_s'] = round(max(intervals), 6)
             for key in ('enqueue', 'wait', 'account', 'eval'):
                 block[key + '_median_s'] = round(statistics.median(
                     c[1].get(key, 0.0) for c in chunks), 6)
+            block['turnaround_median_s'] = round(statistics.median(
+                i - w for i, w in zip(intervals, waits)), 6)
+            block['host_bound_chunks'] = self._epoch_hb
+            block['host_bound_split'] = {
+                k: round(v, 6) for k, v in self._epoch_hb_split.items()}
             # the share of the epoch's chunk intervals that the loop spent
-            # blocked on device results. Each wait lies inside its interval,
-            # so a value over 1 is a fault of the booking and is left to show
-            block['utilization'] = round(
-                sum(c[1].get('wait', 0.0) for c in chunks) / sum(intervals),
-                6)
+            # blocked on device results (1 - turnaround / interval). Each
+            # wait lies inside its interval, so a value over 1 is a fault
+            # of the booking and is left to show
+            block['utilization'] = round(sum(waits) / sum(intervals), 6)
+        self._epoch_hb, self._epoch_hb_split = 0, {}
         return block
 
 

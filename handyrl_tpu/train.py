@@ -1752,8 +1752,10 @@ class Learner:
             waited = self._collect_checkpoint()
         if steps is None:
             steps = self.trainer.steps
-        return self._advance_epoch(host_state.params, steps,
-                                   state=host_state, bump=bump), waited
+        with telemetry.trace_span('epoch_advance'):
+            job = self._advance_epoch(host_state.params, steps,
+                                      state=host_state, bump=bump)
+        return job, waited
 
     def _collect_checkpoint(self, block: bool = True) -> float:
         """Announce the fused loop's checkpoint once it is on disk; with
@@ -3123,7 +3125,10 @@ class Learner:
                     epoch_t0 = time.time()
                     if self._past_epoch_budget():
                         self.shutdown_flag = True
-                it.set(dispatch=chunk, warm=int(warm))
+                # the monitor's sums since the loop began ride every
+                # iteration's span, a step-less one too: a reader takes
+                # their growth between any two records
+                it.set(dispatch=chunk, warm=int(warm), **monitor.totals)
             monitor.closed(it)
         if ahead is not None:
             # the loop ended between a boundary and the iteration it had
@@ -3151,6 +3156,105 @@ class Learner:
         (that chunk enqueued, the chunk in flight collected; False where it
         must not). Returns whether the boundary enqueued that chunk BEFORE
         it fetched the train state."""
+        tr = self.trainer
+        with telemetry.trace_span('epoch_report',
+                                  chunks=len(pending_metrics)):
+            self._fused_epoch_report(pending_metrics, epoch_steps,
+                                     epoch_wall, fp)
+
+        # What a checkpoint costs the loop is the fetch of the train state:
+        # it waits for the chunk in flight, and the next dispatch donates
+        # tr.state. The fetch is two steps (utils/fetch.py): a pack of the
+        # leaves into ONE new device buffer, enqueued behind that chunk, and
+        # the blocking transfer of it. The buffer is no argument of the
+        # fused program, so where every leaf packs it is a snapshot that
+        # outlives the donation: the boundary packs, bumps the epoch,
+        # MAKES THE NEXT ITERATION'S STEP (`enqueue_next`: the refreshed
+        # actor, the new epoch's tag, the new ema: what that iteration
+        # would give it; the step collects the chunk in flight as ever, so
+        # the loop stays one dispatch deep) and only then fetches, with the
+        # device at work on the new chunk through the transfer, the record
+        # and the hand-over. A state with a leaf over LARGE_LEAF_BYTES (a
+        # trunk of gigabytes, fetched leaf by leaf because a second device
+        # copy has no room) keeps fetch-then-enqueue. Serialisation and the
+        # fsynced writes run on the writer thread (PERF.md section 6, PRs 32
+        # and 35). With checkpoint_interval > 1, intermediate epochs skip
+        # the host round trip entirely — the actor/eval params refresh
+        # device-to-device in the fused loop, so nothing here needs host
+        # bytes.
+        interval = int(self.args.get('checkpoint_interval') or 1)
+        final = 0 <= self.args['epochs'] <= self.model_epoch + 1
+        # what the record and the checkpoint say of THIS epoch's end: a
+        # step made ahead books the next chunk's SGD steps and dispatch
+        steps, dispatches = tr.steps, fp.dispatches
+        enqueued_first = False
+        snapshot = host_state = None
+        telemetry.counter('epoch_boundaries_total').inc()
+        if interval <= 1 or (self.model_epoch + 1) % interval == 0 or final:
+            from .utils import fetch
+            with telemetry.trace_span('state_pack'):
+                if fetch.packs_whole(tr.state):
+                    snapshot = fetch.pack_tree(tr.state, detach=True)
+                    self._bump_epoch()
+            if snapshot is not None:
+                enqueued_first = enqueue_next()
+            # ONE packed transfer for params + optimizer state, not one
+            # blocking np.asarray per leaf
+            with telemetry.trace_span('state_fetch') as span:
+                host_state = (fetch.fetch_tree(tr.state) if snapshot is None
+                              else fetch.fetch_packed(snapshot))
+                span.set(bytes=sum(
+                    leaf.nbytes for leaf in
+                    jax.tree_util.tree_leaves(host_state)))
+            job, waited = self._hand_over_checkpoint(
+                host_state, steps, bump=snapshot is None)
+            fused_block['ckpt_wait_s'] = round(waited, 6)
+        else:
+            job = None
+            with telemetry.trace_span('epoch_advance'):
+                self.update_model(None, steps, write_files=False)
+        # (an inc of 0 registers the counter: its share of the boundaries
+        # reads 0, not "no such counter", where the order is always kept)
+        telemetry.counter('epoch_boundaries_enqueued_first_total').inc(
+            int(enqueued_first))
+        fused_block['enqueued_first'] = enqueued_first
+        try:
+            telemetry.set_utilization_proxy(fused_block.get('utilization'))
+            rec_extra = {'dispatches_gen': dispatches,
+                         'dispatches_eval': getattr(evaluator, 'dispatches',
+                                                    0),
+                         'fused': fused_block}
+            with telemetry.trace_span('metrics_write'):
+                self._write_metrics(steps, rec_extra)
+            with telemetry.trace_span('checkpoint_submit'):
+                self._maybe_profile()
+                self.flags = set()
+                # the writer starts LAST: its serialisation takes the
+                # interpreter lock from what the loop still has to do
+                # before the device has its next chunk (PERF.md section 6,
+                # PR 32)
+                if job is not None:
+                    self._ckpt_writer.submit(job)
+                    job = None
+            # the boundary's references to the fetched state go HERE, under
+            # a span, as they went at the function's end before: freeing
+            # the packed snapshot's device buffers releases the interpreter
+            # lock, the writer thread takes it for its serialisation, and
+            # the loop waits 2-3 ms for it (PERF.md section 6, PR 40)
+            with telemetry.trace_span('snapshot_release'):
+                snapshot = host_state = None
+        finally:
+            # (a record that failed must not lose the epoch's checkpoint)
+            if job is not None:
+                self._ckpt_writer.submit(job)
+        return enqueued_first
+
+    def _fused_epoch_report(self, pending_metrics, epoch_steps, epoch_wall,
+                            fp):
+        """What a fused epoch's close prints and sums on the host (span
+        ``epoch_report``): the reference-format lines, the loss and
+        ``diag_`` sums of the epoch's chunks, the lr EMA, the dynamics and
+        the replay counters. No device fetch."""
         tr = self.trainer
         print()
         print('epoch %d' % self.model_epoch)
@@ -3190,77 +3294,6 @@ class Learner:
             tr.replay_stats['windows_ingested'] = max(
                 tr.replay_stats['windows_ingested'],
                 fp.windows_ingested_host)
-
-        # What a checkpoint costs the loop is the fetch of the train state:
-        # it waits for the chunk in flight, and the next dispatch donates
-        # tr.state. The fetch is two steps (utils/fetch.py): a pack of the
-        # leaves into ONE new device buffer, enqueued behind that chunk, and
-        # the blocking transfer of it. The buffer is no argument of the
-        # fused program, so where every leaf packs it is a snapshot that
-        # outlives the donation: the boundary packs, bumps the epoch,
-        # MAKES THE NEXT ITERATION'S STEP (`enqueue_next`: the refreshed
-        # actor, the new epoch's tag, the new ema: what that iteration
-        # would give it; the step collects the chunk in flight as ever, so
-        # the loop stays one dispatch deep) and only then fetches, with the
-        # device at work on the new chunk through the transfer, the record
-        # and the hand-over. A state with a leaf over LARGE_LEAF_BYTES (a
-        # trunk of gigabytes, fetched leaf by leaf because a second device
-        # copy has no room) keeps fetch-then-enqueue. Serialisation and the
-        # fsynced writes run on the writer thread (PERF.md section 6, PRs 32
-        # and 35). With checkpoint_interval > 1, intermediate epochs skip
-        # the host round trip entirely — the actor/eval params refresh
-        # device-to-device in the fused loop, so nothing here needs host
-        # bytes.
-        interval = int(self.args.get('checkpoint_interval') or 1)
-        final = 0 <= self.args['epochs'] <= self.model_epoch + 1
-        # what the record and the checkpoint say of THIS epoch's end: a
-        # step made ahead books the next chunk's SGD steps and dispatch
-        steps, dispatches = tr.steps, fp.dispatches
-        enqueued_first = False
-        telemetry.counter('epoch_boundaries_total').inc()
-        if interval <= 1 or (self.model_epoch + 1) % interval == 0 or final:
-            from .utils import fetch
-            snapshot = None
-            if fetch.packs_whole(tr.state):
-                snapshot = fetch.pack_tree(tr.state, detach=True)
-                self._bump_epoch()
-                enqueued_first = enqueue_next()
-            # ONE packed transfer for params + optimizer state, not one
-            # blocking np.asarray per leaf
-            with telemetry.trace_span('state_fetch') as span:
-                host_state = (fetch.fetch_tree(tr.state) if snapshot is None
-                              else fetch.fetch_packed(snapshot))
-                span.set(bytes=sum(
-                    leaf.nbytes for leaf in
-                    jax.tree_util.tree_leaves(host_state)))
-            job, waited = self._hand_over_checkpoint(
-                host_state, steps, bump=snapshot is None)
-            fused_block['ckpt_wait_s'] = round(waited, 6)
-        else:
-            job = None
-            self.update_model(None, steps, write_files=False)
-        # (an inc of 0 registers the counter: its share of the boundaries
-        # reads 0, not "no such counter", where the order is always kept)
-        telemetry.counter('epoch_boundaries_enqueued_first_total').inc(
-            int(enqueued_first))
-        fused_block['enqueued_first'] = enqueued_first
-        try:
-            telemetry.set_utilization_proxy(fused_block.get('utilization'))
-            rec_extra = {'dispatches_gen': dispatches,
-                         'dispatches_eval': getattr(evaluator, 'dispatches',
-                                                    0),
-                         'fused': fused_block}
-            with telemetry.trace_span('metrics_write'):
-                self._write_metrics(steps, rec_extra)
-            self._maybe_profile()
-            self.flags = set()
-        finally:
-            # the writer starts LAST: its serialisation takes the
-            # interpreter lock from what the loop still has to do before
-            # the device has its next chunk (PERF.md section 6, PR 32)
-            if job is not None:
-                self._ckpt_writer.submit(job)
-        return enqueued_first
 
     def _print_eval_stats(self):
         if self.model_epoch not in self.results:

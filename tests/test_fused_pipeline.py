@@ -191,9 +191,9 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
     booked = []
     observe = telemetry.ChunkMonitor.observe
 
-    def remember_chunk(self, dispatch, interval_s, split):
-        booked.append(dict(split, interval=interval_s))
-        return observe(self, dispatch, interval_s, split)
+    def remember_chunk(self, dispatch, interval_s, split, fetch_s=None):
+        booked.append(dict(split, interval=interval_s, fetch=fetch_s))
+        return observe(self, dispatch, interval_s, split, fetch_s)
     monkeypatch.setattr(telemetry.ChunkMonitor, 'observe', remember_chunk)
 
     t_start = time.perf_counter()
@@ -291,7 +291,8 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
     from benchmark.readers import program_span
     from benchmark.record import Run, quantile
     assert len(booked) == fp.dispatches - 2
-    assert all(0 <= chunk['wait'] <= chunk['interval'] for chunk in booked)
+    assert all(0 <= chunk['fetch'] <= chunk['wait'] <= chunk['interval']
+               for chunk in booked)
     window = (blocks[0]['t1'], iters[-1]['t1'])   # the chunks the loop booked
     run = Run(cell={'name': 'c'}, config={}, traffic={}, train_args={},
               spans={}, window=window)
@@ -315,6 +316,91 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
         assert 'jit(fused_pipeline_train)/%s/' % scope in text, scope
     # the SGD scan's body is a function of its own: its scopes are relative
     assert 'loc("sample/gather"' in text and 'loc("update/' in text
+
+
+@pytest.mark.timeout(600)
+def test_a_boundary_is_covered_by_its_children_and_the_sums_ride_every_iteration(  # noqa: E501
+        tmp_path):
+    """A few epochs of the real loop: every checkpointing boundary holds each
+    of ``epoch_report``, ``state_pack``, ``epoch_advance``,
+    ``checkpoint_submit`` and ``snapshot_release`` once; what a boundary
+    holds under no child span is glue (under a fifth of the boundaries' seconds here, where a boundary is
+    a few milliseconds; PERF.md has the chip's number); and every
+    ``fused_iter`` carries the monitor's sums, which only grow and end at
+    what the monitor booked."""
+    import time
+
+    from handyrl_tpu import telemetry
+    t_start = time.perf_counter()
+    before = {name: telemetry.counter(name).value for name in (
+        'fused_chunks_total', 'fused_chunks_host_bound_total')}
+    learner = Learner(args=apply_defaults(_ttt_raw(tmp_path, epochs=4)))
+    learner.run()
+    recs = telemetry.spans(since=t_start)
+    children = {}
+    for rec in recs:
+        children.setdefault(rec['parent_id'], []).append(rec)
+    boundaries = [r for r in recs if r['name'] == 'epoch_boundary']
+    assert len(boundaries) == 4
+    inside = outside = 0.0
+    for boundary in boundaries:
+        held = children[boundary['span_id']]
+        names = [r['name'] for r in held]
+        for name in ('epoch_report', 'state_pack', 'epoch_advance',
+                     'checkpoint_submit', 'snapshot_release', 'state_fetch',
+                     'checkpoint_wait', 'metrics_write'):
+            assert names.count(name) == 1, name
+        assert names.index('epoch_report') < names.index('state_pack') \
+            < names.index('state_fetch') < names.index('checkpoint_wait') \
+            < names.index('epoch_advance') < names.index('metrics_write') \
+            < names.index('checkpoint_submit') \
+            < names.index('snapshot_release')
+        # every child is a piece the monitor books
+        assert set(names) <= set(telemetry.CHUNK_PIECES)
+        inside += sum(r['t1'] - r['t0'] for r in held)
+        outside += boundary['t1'] - boundary['t0']
+    assert 0 <= outside - inside < 0.2 * outside
+    report = [r for r in recs if r['name'] == 'epoch_report']
+    # (the first boundary closes the warm-up: no training chunk to sum)
+    assert [r['attrs']['chunks'] >= 1 for r in report] == [False] + [True] * 3
+
+    iters = [r['attrs'] for r in recs if r['name'] == 'fused_iter']
+    keys = [key for key in iters[-1] if key not in ('dispatch', 'warm')]
+    assert set(keys) == {'chunks', 'host_bound_chunks', 'interval_s',
+                         'turnaround_s', 'hb_turnaround_s', 'hb_boundary_s'
+                         } | {'hb_%s_s' % k for k in telemetry.CHUNK_KEYS}
+    for key in keys:
+        series = [attrs[key] for attrs in iters]
+        assert series == sorted(series), key
+    last = iters[-1]
+    # one interval a completion after the first; the last chunk is the
+    # loop's drain, which the monitor does not see
+    blocks = sum(r['name'] == 'host_block' for r in recs)
+    assert last['chunks'] == blocks - 2
+    assert 0 <= last['host_bound_chunks'] <= last['chunks']
+    assert 0 < last['turnaround_s'] < last['interval_s']
+    assert last['hb_turnaround_s'] <= last['turnaround_s']
+    assert (last['hb_ckpt_wait_s'] + last['hb_boundary_s'] + last['hb_eval_s']
+            + last['hb_account_s'] + last['hb_enqueue_s']) == pytest.approx(
+                last['hb_turnaround_s'])
+    assert telemetry.counter('fused_chunks_total').value \
+        - before['fused_chunks_total'] == last['chunks']
+    assert telemetry.counter('fused_chunks_host_bound_total').value \
+        - before['fused_chunks_host_bound_total'] == last['host_bound_chunks']
+    rows = [telemetry.validate_metrics_line(line) for line in
+            (tmp_path / 'metrics.jsonl').read_text().splitlines()]
+    blocks = [row['fused'] for row in rows if row['fused']['chunks']]
+    assert blocks
+    for block in blocks:
+        assert 0 <= block['host_bound_chunks'] <= block['chunks']
+        assert 0 < block['turnaround_median_s'] <= block['interval_max_s']
+        assert set(block['host_bound_split']) <= set(telemetry.CHUNK_KEYS)
+        assert bool(block['host_bound_split']) == bool(
+            block['host_bound_chunks'])
+    # the record is cut inside the boundary: its chunks are those booked
+    # before it, so the records together hold all but the last boundary's
+    assert sum(b['host_bound_chunks'] for b in blocks) \
+        <= last['host_bound_chunks']
 
 
 def test_fused_block_must_be_well_formed():
@@ -497,6 +583,250 @@ def test_chunk_intervals_run_from_completion_to_completion():
     assert monitor.fetched(7, telemetry._NULL_SPAN) is None
     monitor.closed(telemetry._NULL_SPAN)
     assert monitor.epoch_block() == {'chunks': 0, 'stalls': []}
+
+
+def _laid_out(name, t, pieces, children=()):
+    """A span ``name`` that starts at ``t`` and holds ``pieces`` one after the
+    other: (span name, seconds), or (None, seconds) for time under no span.
+    Returns the span (``children`` first) and its end."""
+    held, at = list(children), t
+    for piece, seconds in pieces:
+        if piece is not None:
+            held.append(_FakeSpan(piece, at, at + seconds))
+        at += seconds
+    return _FakeSpan(name, t, at, held), at
+
+
+# a checkpointing boundary's host work, before and after the place of its
+# step (ms): 0.2 of glue at its head and 0.4 at its end lie under no span
+_BEFORE_STEP = [(None, 0.0002), ('epoch_report', 0.001),
+                ('state_pack', 0.0005)]
+_STEP = [('actor_refresh', 0.0003), ('dispatch', 0.002)]
+_AFTER_STEP = [('state_fetch', 0.005), ('checkpoint_wait', 0.004),
+               ('epoch_advance', 0.002), ('metrics_write', 0.003),
+               ('checkpoint_submit', 0.0001), ('snapshot_release', 0.0025),
+               (None, 0.0004)]
+_TAIL = [('chunk_account', 0.003), ('eval_share', 0.001)]
+_PIECES = {'account': 0.003, 'eval': 0.001, 'report': 0.001, 'pack': 0.0005,
+           'refresh': 0.0003, 'enqueue': 0.002, 'ckpt_wait': 0.004,
+           'advance': 0.002, 'record': 0.003, 'submit': 0.0001,
+           'release': 0.0025, 'epoch': 0.0006}
+
+
+@pytest.mark.parametrize('path', ['head', 'boundary'])
+def test_an_interval_is_its_pieces_and_epoch_is_the_rest(path):
+    """ONE rule books an interval wherever its step was made: each piece is
+    the seconds of the loop's spans that lie in it, ``epoch`` what is left.
+    A whole boundary (its state fetch booked as ``wait``) and one step lie
+    between the two completions on both paths, so both give the same keys
+    and the same seconds: at the head of the next iteration (the boundary
+    kept fetch-then-enqueue), or inside the boundary (enqueue-first: the
+    interval ends in the boundary, the rest of it opens the next)."""
+    from handyrl_tpu import telemetry
+    monitor = telemetry.ChunkMonitor()
+    wait = 0.0123
+
+    def head_step(t, refresh):
+        pieces = (_STEP if refresh else _STEP[1:]) + [('host_block', wait)]
+        return _laid_out('fused_iter', t, pieces)
+
+    def stepping_boundary(t, it_children):
+        """The boundary up to its own step's completion, ``fetched`` as the
+        loop does, then the rest; returns the closed iteration's children."""
+        boundary, t = _laid_out('epoch_boundary', t, _BEFORE_STEP + _STEP
+                                + [('host_block', wait)])
+        boundary.t1 = None
+        monitor.fetched(2, _FakeSpan('fused_iter', 0.0, None, it_children),
+                        boundary)
+        whole, t = _laid_out('epoch_boundary', boundary.t0, [(
+            None, t - boundary.t0)] + _AFTER_STEP, boundary.children)
+        return it_children + [whole], t
+
+    # the first completion: an iteration's own step
+    it, t = head_step(10.0, refresh=False)
+    assert monitor.fetched(1, it) is None and not monitor._chunks
+    tail, t = _laid_out('tail', t, _TAIL)
+    if path == 'head':
+        boundary, t = _laid_out('epoch_boundary', t,
+                                _BEFORE_STEP + _AFTER_STEP)
+        monitor.closed(_FakeSpan('fused_iter', 10.0, t, it.children
+                                 + tail.children + [boundary]))
+        nxt, t = head_step(t, refresh=True)
+        monitor.fetched(2, nxt)
+        (interval, split), = monitor._chunks
+        expected = dict(_PIECES, wait=0.005 + wait)
+    else:
+        held, t = stepping_boundary(t, it.children + tail.children)
+        monitor.closed(_FakeSpan('fused_iter', 10.0, t, held))
+        # the iteration after it has no step of its own; its boundary steps
+        tail, t = _laid_out('tail', t, _TAIL)
+        held, t = stepping_boundary(t, tail.children)
+        monitor.closed(_FakeSpan('fused_iter', 10.0, t, held))
+        (first, before), (interval, split) = monitor._chunks
+        # the first interval ends inside its boundary: no state fetch, no
+        # hand-over yet, 0.2 ms of glue
+        assert before == pytest.approx(dict(
+            dict.fromkeys(telemetry.CHUNK_KEYS, 0.0), account=0.003,
+            eval=0.001, report=0.001, pack=0.0005, refresh=0.0003,
+            enqueue=0.002, wait=wait, epoch=0.0002))
+        assert sum(before.values()) == pytest.approx(first)
+        expected = dict(_PIECES, wait=0.005 + wait)
+    assert list(split) == list(telemetry.CHUNK_KEYS)
+    assert split == pytest.approx(expected)
+    assert sum(split.values()) == pytest.approx(interval)
+    assert interval == pytest.approx(sum(expected.values()))
+    # the boundary's own host work is one counter's worth of keys
+    assert set(telemetry.BOUNDARY_KEYS) == set(expected) - {
+        'enqueue', 'wait', 'account', 'eval', 'ckpt_wait'}
+
+
+def _booked(monitor, waits, boundary_every=2):
+    """Book intervals with the given waits through ``observe``: 20 ms each,
+    every ``boundary_every``-th one holds a boundary (4 ms of writer wait,
+    6 ms of the boundary's own work) in place of device wait. Returns the
+    splits."""
+    splits = []
+    for n, wait in enumerate(waits, 1):
+        split = dict.fromkeys(telemetry_keys(), 0.0)
+        split.update(enqueue=0.0015, account=0.001, eval=0.0005, wait=wait)
+        if n % boundary_every == 0:
+            split.update(ckpt_wait=0.004, report=0.002, advance=0.001,
+                         record=0.002, submit=0.0005)
+        split['epoch'] = 0.020 - sum(split.values())
+        monitor.observe(n, 0.020, split)
+        splits.append(split)
+    return splits
+
+
+def telemetry_keys():
+    from handyrl_tpu import telemetry
+    return telemetry.CHUNK_KEYS
+
+
+def test_the_host_bound_verdict_and_its_sums():
+    """A chunk whose completing fetch found its result ready (it took under
+    ``HOST_BOUND_FETCH`` of the running median interval; the whole wait
+    where the caller names no fetch) is host-bound; the
+    sums keep, for those chunks alone, the turnaround and each piece of it;
+    the epoch's block and the two counters say the same."""
+    from handyrl_tpu import telemetry
+    chunks = telemetry.counter('fused_chunks_total')
+    bound = telemetry.counter('fused_chunks_host_bound_total')
+    before = chunks.value, bound.value
+    monitor = telemetry.ChunkMonitor()
+    limit = monitor.HOST_BOUND_FETCH * 0.020
+    assert 0.0002 < limit < 0.008       # the waits below stand clear of it
+    #        device-bound: 15 and 8 ms of wait; host-bound: 0.1 and 0.2 ms
+    waits = [0.015, 0.0001, 0.008, 0.0002, 0.015, 0.0002, 0.015, 0.015]
+    splits = _booked(monitor, waits)
+    called = [wait < limit for wait in waits]
+    assert called == [False, True, False, True, False, True, False, False]
+    totals = monitor.totals
+    assert totals['chunks'] == 8 and totals['host_bound_chunks'] == 3
+    assert (chunks.value - before[0], bound.value - before[1]) == (8, 3)
+    assert totals['interval_s'] == pytest.approx(8 * 0.020)
+    assert totals['turnaround_s'] == pytest.approx(8 * 0.020 - sum(waits))
+    hb = [split for split, yes in zip(splits, called) if yes]
+    assert totals['hb_turnaround_s'] == pytest.approx(
+        3 * 0.020 - 0.0001 - 0.0002 - 0.0002)
+    for key in telemetry.CHUNK_KEYS:
+        assert totals['hb_%s_s' % key] == pytest.approx(
+            sum(split[key] for split in hb)), key
+    assert totals['hb_ckpt_wait_s'] == pytest.approx(3 * 0.004)
+    assert totals['hb_boundary_s'] == pytest.approx(sum(
+        totals['hb_%s_s' % key] for key in telemetry.BOUNDARY_KEYS))
+    # the turnaround of the host-bound chunks is the writer's wait, the
+    # boundary's own work and the loop's three pieces, and nothing else
+    assert (totals['hb_ckpt_wait_s'] + totals['hb_boundary_s']
+            + totals['hb_eval_s'] + totals['hb_account_s']
+            + totals['hb_enqueue_s']) == pytest.approx(
+                totals['hb_turnaround_s'])
+    block = monitor.epoch_block()
+    assert block['chunks'] == 8 and block['host_bound_chunks'] == 3
+    assert block['turnaround_median_s'] == pytest.approx(
+        0.020 - 0.5 * (0.008 + 0.015), abs=1e-6)
+    assert block['host_bound_split']['ckpt_wait'] == pytest.approx(0.012)
+    assert sum(block['host_bound_split'].values()) == pytest.approx(
+        3 * 0.020, abs=1e-5)
+    assert block['utilization'] == pytest.approx(
+        1 - totals['turnaround_s'] / totals['interval_s'], abs=1e-6)
+    # the next epoch's block starts from nothing; the sums go on
+    monitor.observe(9, 0.020, splits[1])
+    block = monitor.epoch_block()
+    assert block['chunks'] == block['host_bound_chunks'] == 1
+    assert block['host_bound_split']['ckpt_wait'] == pytest.approx(0.004)
+    assert monitor.totals['host_bound_chunks'] == 4
+    assert monitor.epoch_block() == {'chunks': 0, 'stalls': []}
+    # the test is the completing fetch's own time: a boundary's state fetch
+    # of 3 ms is wait, and the chunk whose result was ready is host-bound
+    # all the same; one whose fetch itself blocked 3 ms is not
+    blocked = dict(splits[1], wait=0.0035, epoch=splits[1]['epoch'] - 0.0034)
+    monitor.observe(10, 0.020, blocked, fetch_s=0.0005)
+    assert monitor.totals['host_bound_chunks'] == 5
+    monitor.observe(11, 0.020, blocked, fetch_s=0.003)
+    monitor.observe(12, 0.020, blocked)
+    assert monitor.totals['host_bound_chunks'] == 5
+    assert monitor.totals['chunks'] == 12
+
+
+@pytest.mark.parametrize('metric, expected', [
+    ('host_bound_chunk_share', 100 * 2 / 5),
+    ('turnaround_ms', 1e3 * (5 * 0.020 - 0.0001 - 0.008 - 0.0002
+                             - 2 * 0.015) / 5),
+    ('host_bound_ckpt_wait_share', 100 * 0.008 / (0.040 - 0.0003)),
+    # (a host-bound boundary chunk: 5.5 ms under the boundary's spans and
+    # what the 20 ms hold under none, 7.5 ms less the wait)
+    ('host_bound_boundary_share', 100 * (2 * 0.013 - 0.0003)
+     / (0.040 - 0.0003)),
+])
+def test_the_counters_on_fused_iter_grow_and_the_reader_takes_their_growth(
+        monkeypatch, metric, expected):
+    """Every ``fused_iter`` record carries the monitor's sums as they stood
+    when it closed, a step-less iteration's too; ``program_counter_ratio``
+    with the metric file's arguments reads the growth between the records
+    that bound a window, here the five chunks booked between the records
+    that ended at 11 and at 18 s."""
+    from benchmark.readers import program_counter_ratio
+    from benchmark.record import Run
+    from handyrl_tpu import telemetry
+    monitor = telemetry.ChunkMonitor()
+    records = []
+
+    def close(n, t1):
+        records.append({'name': 'fused_iter', 't0': t1 - 0.5, 't1': t1,
+                        'span_id': n, 'parent_id': None,
+                        'attrs': dict(monitor.totals, dispatch=n)})
+    waits = [0.015, 0.0001, 0.015, 0.0001, 0.008, 0.0002, 0.015, 0.015,
+             0.0002, 0.015]
+    booked = 0
+    for n in range(10, 20):
+        # iterations 13 and 16 are step-less (the boundary before made their
+        # step): they book nothing and still carry the sums
+        if n not in (13, 16):
+            _booked(monitor, [waits[booked]],
+                    boundary_every=1 if booked % 2 else 99)
+            booked += 1
+        close(n, float(n))
+    grown = [r['attrs'] for r in records]
+    for key in monitor.totals:
+        series = [attrs[key] for attrs in grown]
+        assert series == sorted(series), key
+    assert [a['chunks'] for a in grown] == [1, 2, 3, 3, 4, 5, 5, 6, 7, 8]
+    monkeypatch.setattr(telemetry, 'spans',
+                        lambda name=None, since=None: list(records))
+    run = Run(cell={'name': 'c'}, config={}, traffic={}, train_args={},
+              spans={}, window=(11.5, 18.5))
+    # waits[2:7]: two of the five under the limit, each with a boundary
+    assert program_counter_ratio.read(
+        run, **telemetry_metric_args(metric)) == pytest.approx(expected)
+    # a window in which no chunk was host-bound leaves the two shares out
+    run.window = (17.5, 18.5)
+    got = program_counter_ratio.read(run, **telemetry_metric_args(metric))
+    if metric in ('host_bound_ckpt_wait_share', 'host_bound_boundary_share'):
+        assert got is None
+    else:
+        assert got == pytest.approx(
+            {'host_bound_chunk_share': 0.0, 'turnaround_ms': 5.0}[metric])
 
 
 @pytest.mark.parametrize('series', [
