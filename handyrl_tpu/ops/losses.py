@@ -26,6 +26,7 @@ data count, exactly like the reference.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -148,7 +149,7 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
     observations = batch['observation']
     B, T, P_obs = batch['action'].shape[:3]
 
-    def net(bs, obs_in, h_in):
+    def net(bs, obs_in, h_in, params=params):
         """One apply in the right mode; returns (out_dict, new_bs)."""
         if bs is None:
             return dict(apply_fn(params, obs_in, h_in)), None
@@ -168,7 +169,7 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
         obs_tm = tmap(lambda o: jnp.moveaxis(o, 1, 0), observations)   # (T, B, P_obs, ...)
         omask_tm = jnp.moveaxis(batch['observation_mask'], 1, 0)       # (T, B, P, 1)
 
-        def step(carry, x):
+        def step(params, carry, x):
             h_carry, bs = carry
             obs_t, omask_t = x
             # gate each player's hidden by whether they observed this step
@@ -184,7 +185,7 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
             else:
                 h_in = tmap(lambda h: h.reshape((-1,) + h.shape[2:]), gated)
                 obs_in = tmap(lambda o: o.reshape((-1,) + o.shape[2:]), obs_t)
-            out, bs = net(bs, obs_in, h_in)
+            out, bs = net(bs, obs_in, h_in, params)
             next_h = out.pop('hidden')
             out = {k: v.reshape((B, P_obs) + v.shape[1:])
                    for k, v in out.items() if v is not None}
@@ -196,15 +197,25 @@ def forward_prediction(apply_fn, params, hidden, batch: Dict[str, Any],
             h_carry = tmap(merge, h_carry, next_h)
             return (h_carry, bs), out
 
+        # The backward pass recomputes a ply from what entered it: the scan
+        # then stacks its carry and nothing else. Stacked, a ply's residuals
+        # are written in the forward pass's layout and relaid one by one for
+        # the backward pass, which costs more than the ply's second forward
+        # (PERF.md section 6, PR 41). Recomputing costs a trace more passes
+        # over the ply, so the burn-in takes the same traced ply, and its
+        # parameters as constants: its carry is cut, and nothing need be
+        # linearised to find its gradient zero.
+        ply = jax.checkpoint(step)
         bi = cfg.burn_in_steps
         if bi > 0:
             xs_burn = (tmap(lambda o: o[:bi], obs_tm), omask_tm[:bi])
             (hidden, batch_stats), _ = lax.scan(
-                step, (hidden, batch_stats), xs_burn)
+                functools.partial(ply, lax.stop_gradient(params)),
+                (hidden, batch_stats), xs_burn)
             hidden = lax.stop_gradient(hidden)
         xs_main = (tmap(lambda o: o[bi:], obs_tm), omask_tm[bi:])
-        (_, new_bs), outputs_tm = lax.scan(step, (hidden, batch_stats),
-                                           xs_main)
+        (_, new_bs), outputs_tm = lax.scan(functools.partial(ply, params),
+                                           (hidden, batch_stats), xs_main)
         outputs = {k: jnp.moveaxis(v, 0, 1) for k, v in outputs_tm.items()}
 
         # re-attach zero outputs for burn-in steps so downstream slicing is

@@ -377,3 +377,25 @@ def test_fold_then_unfold_is_the_identity(shape):
         np.asarray(x[:, 0].reshape((B * P,) + shape[3:])))
     np.testing.assert_array_equal(np.asarray(_unfold_bt(folded, B, T, P)),
                                   np.asarray(x))
+
+
+@pytest.mark.parametrize('burn_in', [0, 2])
+def test_rnn_scan_recomputes_plies_without_changing_a_gradient(burn_in,
+                                                               monkeypatch):
+    """The recurrent scan's body runs under ``jax.checkpoint``; with it
+    taken away (plain autodiff, every residual stacked) the turn-based
+    window's loss and gradients are the same numbers."""
+    module, params, hidden, batch, args = _rnn_setup(burn_in=burn_in, fs=3)
+    cfg = LossConfig.from_args(args)
+
+    def total_and_grads():
+        return jax.value_and_grad(lambda p: compute_loss(
+            module.apply, p, hidden, batch, cfg)[0])(params)
+    loss, grads = total_and_grads()
+    monkeypatch.setattr(jax, 'checkpoint', lambda f, **kw: f)
+    loss_ref, grads_ref = total_and_grads()
+    assert float(loss) == pytest.approx(float(loss_ref), rel=1e-6)
+    g, g_ref = ravel_pytree(grads)[0], ravel_pytree(grads_ref)[0]
+    assert float(jnp.abs(g_ref).max()) > 0
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), rtol=1e-5,
+                               atol=1e-6 * float(jnp.abs(g_ref).max()))
