@@ -1,17 +1,18 @@
 #!/usr/bin/env python3
-"""A manifest root whose ``BENCHMARK.json`` also names the per-layer metrics
-that a configuration's file lists under ``pending_metrics``.
+"""RETIRED by PR 42, and kept for one reason: ``docs/observability.md`` still
+names this path, ``tests/test_repo_references.py`` holds that document to
+files the tree has, and a ``benchmark`` PR may edit neither. Delete this file
+in the first ``benchmark`` PR after that paragraph of the document is gone
+(PERF.md section 7, "Left by PR 42").
 
-A metric is pending where its file (``metrics/<name>.json``) and its reader
-are in the checkout but its entry cannot join ``BENCHMARK.json`` yet: a PR
-that changes the program may only APPEND to ``per_layer``, and
-``tests/benchmark/test_bench_evabyte.py`` pins the list's LAST thirteen
-names by position, so an appended entry fails an accepted test and an
-inserted one changes the accepted list (PERF.md section 7). Until a
-``benchmark`` PR pins those names by name, the root built here is how the
-pending metrics are read on the chip: the checkout's entries, then one entry
-a pending metric, made from the metric's own file and listing the cells of
-the configuration that names it. Everything else is a link to the checkout.
+Nothing is pending any more: ``per_layer`` is pinned by name and takes
+appended entries, the entries that waited here (PR 38's and PR 40's) are in
+``BENCHMARK.json``, no configuration's file has a ``pending_metrics`` key, and
+``tests/benchmark/contracts.py`` refuses a metric file without an entry. What
+the code below still does, unchanged from PR 38: build a manifest root whose
+``BENCHMARK.json`` is the checkout's plus one entry for each name a
+configuration's file lists under ``pending_metrics`` (today: none, so the
+root's list is the checkout's), everything else a link to the checkout.
 
     python3 benchmark/pending_root.py <dest>
     python3 benchmark/run.py --root <dest> --workload <cell> --trace 1 ...

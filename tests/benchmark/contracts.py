@@ -2,14 +2,24 @@
 the pins on what the checkout ships, as functions of a ``Manifest`` too.
 
 The contracts hold for any number of configurations and cells. The pins name
-what they pin (four cells, two configurations, nine metrics) and reach no
-further: they hold on a root that has MORE than the checkout, so a later PR
-that appends entries and adds files edits none of them. tests/benchmark runs
-both on the checkout and on ``fixture/make_root.build()``'s root, which has a
-fifth cell of a seeded configuration with checks, a FLOP count, a traffic
-mix, a hook, a reader, a metric and a rehearsal overlay of its own."""
+what they pin (four cells, two configurations, nine metrics, what each trunk
+cell brought of its own, the turnaround four) BY NAME and reach no further:
+none indexes ``configs``, ``workloads`` or ``per_layer`` by position past the
+first four cells, so they hold on a root that has MORE than the checkout and
+a later PR that appends entries and adds files edits none of them
+(``test_bench_open_for_additions.py`` is the guard). tests/benchmark runs both
+on the checkout and on ``fixture/make_root.build()``'s root, which has one
+more cell of a seeded configuration with checks, a FLOP count, a traffic mix,
+a hook, a reader, a metric and a rehearsal overlay of its own.
+
+A reading that every cell's program gives (a scope of the module every cell
+runs, a span every fused iteration opens, the whole step's share of the peak)
+has ONE entry that lists no cells: ``Manifest.cells_of`` gives it to every
+cell, those a later PR adds too. An entry lists cells only where the reading
+exists or always reads in those alone."""
 
 import importlib
+import json
 import os
 
 from benchmark import hooks
@@ -24,7 +34,9 @@ HARNESS_VERDICTS = ['all_updates_finite', 'replay_ratio_as_configured',
 SIX = HARNESS_VERDICTS + ['forward_matches_reference',
                           'vtrace_matches_reference']
 # (source, layer, reader) of the nine metrics that read the program's own
-# measurement, and the cells each lists: those four, by name
+# measurement. The seven that read what every cell's program gives list no
+# cells (``SHARED`` less ``train_mfu``); the other two list those four, by
+# name: a trunk cell's window may hold no epoch boundary to read them from
 NINE = {
     'rollout_ms': ('device_trace', 'rollout', 'trace_scope_time'),
     'ingest_ms': ('device_trace', 'ingest', 'trace_scope_time'),
@@ -37,6 +49,40 @@ NINE = {
                             'program_span'),
     'ingest_builder_ply_share': ('program_counter', 'ingest',
                                  'program_counter_ratio'),
+}
+# one name a shared reading: no ``workloads`` key, so every cell reports them
+SHARED = ['rollout_ms', 'ingest_ms', 'sgd_ms', 'unscoped_ms',
+          'dispatch_enqueue_ms', 'fetch_wait_ms', 'host_busy_ms', 'train_mfu']
+# what each trunk cell brought that no entry read before: the entries that
+# list that cell alone, in the file's order
+OWN = {
+    'evabyte.selfplay_4k': [
+        'eva_attention_ms', 'eva_attention_roofline', 'window_padding_share',
+        'state_cache_gib', 'trunk_eval_share_ms'],
+    'trinity_mini.moe_selfplay_4k': [
+        'moe_experts_ms', 'moe_experts_roofline', 'moe_route_ms',
+        'moe_rows_held_share', 'moe_load_max_over_mean', 'gqa_attention_ms',
+        'gqa_attention_roofline', 'trinity_optimizer_ms'],
+}
+# the loop's turnaround (PR 40), counters on ``fused_iter``: (unit, layer,
+# numerator, denominator, scale, the cells it lists). The two shares divide
+# by ``hb_turnaround_s``, which is zero where no chunk of the window was
+# host-bound: they list the cells whose windows hold such chunks by the
+# hundred (2-33% of ~3,000), not the ``sgd_heavy`` cells' 0-3 of ~600, where
+# one traced run in three of ``geese.sgd_heavy`` read none (PERF.md section
+# 6, PR 42)
+HOST_BOUND = ['geese.rollout_heavy', 'geese_lstm.rollout_heavy']
+TURNAROUND = {
+    'host_bound_chunk_share': ('%', 'entry, orchestration',
+                               'host_bound_chunks', 'chunks', 100, FOUR),
+    'turnaround_ms': ('ms', 'entry, orchestration',
+                      'turnaround_s', 'chunks', 1000, FOUR),
+    'host_bound_ckpt_wait_share': ('%', 'param publish, checkpoint',
+                                   'hb_ckpt_wait_s', 'hb_turnaround_s', 100,
+                                   HOST_BOUND),
+    'host_bound_boundary_share': ('%', 'entry, orchestration',
+                                  'hb_boundary_s', 'hb_turnaround_s', 100,
+                                  HOST_BOUND),
 }
 
 
@@ -165,11 +211,37 @@ def every_named_file_exists_and_agrees(manifest):
     assert all('source' in row for row in peaks.values())
 
 
+def every_metric_file_has_an_entry_and_every_entry_a_file(manifest):
+    """A file without an entry is a metric nobody reads (a pending one: the
+    ledger never sees it); an entry without a file fails every run."""
+    folder = os.path.join(manifest.root, 'benchmark', 'metrics')
+    files = {name[:-5] for name in os.listdir(folder)
+             if name.endswith('.json')}
+    assert files == set(manifest.metrics), sorted(
+        files ^ set(manifest.metrics))
+
+
+def no_two_entries_are_twins(manifest):
+    """Two entries with the same ``reader`` and ``args`` are one reading
+    under two names: a twin where their cells are disjoint (drop the list of
+    the first and the second entry), a duplicate where they are not."""
+    seen = {}
+    for name in manifest.metrics:
+        spec = manifest.load_metric(name)
+        key = json.dumps([spec['reader'], spec.get('args', {})],
+                         sort_keys=True)
+        assert key not in seen, '%s reads what %s reads: %s' % (
+            name, seen[key], key)
+        seen[key] = name
+
+
 CONTRACTS = [keys_and_limits, entries_have_just_the_contracts_keys,
              a_layer_metric_moves_what_its_cells_report,
              every_cell_reports_setup_one_more_and_a_layer_metric,
              four_chip_cells_are_a_quarter_at_most,
-             every_named_file_exists_and_agrees]
+             every_named_file_exists_and_agrees,
+             every_metric_file_has_an_entry_and_every_entry_a_file,
+             no_two_entries_are_twins]
 
 
 # ---------------------------------------------------------------------------
@@ -198,14 +270,17 @@ def the_pair_states_all_three_explicitly(manifest, name):
 
 
 def one_of_the_nine_agrees_with_its_entry(manifest, name):
-    import json
     source, layer, reader = NINE[name]
     entry = manifest.metrics[name]
     spec = manifest.load_metric(name)           # raises where they disagree
     assert (entry['source'], entry['layer'], spec['reader']) == \
         (source, layer, reader)
     assert entry['moves'] == 'train_windows_per_s'
-    assert entry['workloads'] == FOUR
+    if name in SHARED:
+        assert 'workloads' not in entry
+        assert manifest.cells_of(name) == tuple(manifest.cells)
+    else:
+        assert entry['workloads'] == FOUR
     with open(os.path.join(manifest.root, 'benchmark', 'metrics',
                            name + '.json')) as f:
         raw = json.load(f)
@@ -238,10 +313,80 @@ def a_rehearsal_line(manifest, workload, line):
     assert 'metrics' not in line and 'device' not in line
 
 
+def the_whole_steps_share_is_every_cells(manifest):
+    """``train_mfu``: one ``derived`` expression on ``flops.train_window``,
+    which every configuration's file must state, and no list of cells: each
+    cell has a share of the whole step under a name that holds ``mfu``."""
+    entry = manifest.metrics['train_mfu']
+    assert 'workloads' not in entry
+    spec = manifest.load_metric('train_mfu')
+    assert spec['reader'] == 'derived'
+    assert 'flops.train_window' in spec['args']['expr']
+    assert 'lower bound' in spec['what'].lower()   # float32 activations
+    for cell in manifest.cells:
+        assert 'train_mfu' in manifest.metrics_of(cell, 'per_layer')
+
+
+def a_cells_own_metrics_are_its_entries(manifest, cell):
+    """By NAME: the entries that list this cell alone, in the file's order,
+    wherever in ``per_layer`` they stand; a later PR may append more of
+    them behind these."""
+    alone = [entry['name'] for entry in manifest.raw['per_layer']
+             if entry.get('workloads') == [cell]]
+    assert alone[:len(OWN[cell])] == OWN[cell]
+    assert manifest.metrics_of(cell, 'end_to_end') \
+        == ['train_windows_per_s', 'setup_s']
+    reported = manifest.metrics_of(cell, 'per_layer')
+    for name in SHARED + OWN[cell]:
+        assert name in reported, name
+    for other, names in OWN.items():
+        if other != cell:
+            assert not set(names) & set(reported)
+
+
+def a_cells_own_metric(manifest, cell, name):
+    entry = manifest.metrics[name]
+    assert entry['workloads'] == [cell]
+    assert entry['moves'] == 'train_windows_per_s'
+    spec = manifest.load_metric(name)           # raises where they disagree
+    module = importlib.import_module('benchmark.readers.' + spec['reader'])
+    assert callable(module.read)
+    assert spec['what']
+
+
+def a_turnaround_metric(manifest, name):
+    unit, layer, top, bottom, scale, cells = TURNAROUND[name]
+    spec = manifest.load_metric(name)     # raises where file and entry differ
+    entry = manifest.metrics[name]
+    assert (entry['unit'], entry['better'], entry['source'], entry['layer'],
+            entry['moves']) == (unit, 'lower', 'program_counter', layer,
+                                'train_windows_per_s')
+    assert entry['workloads'] == cells
+    assert spec['reader'] == 'program_counter_ratio'
+    assert spec['args'] == {'stage': 'fused_iter', 'numerator': top,
+                            'denominator': bottom, 'scale': scale}
+    # the text names the span and the counters it reads
+    for word in ('fused_iter', top, bottom, 'ChunkMonitor'):
+        assert word in spec['what'], word
+
+
+# every pin as (function, further arguments): one case each wherever a test
+# is parametrised by pin
+PINS = ([(the_first_four_cells, ()), (the_whole_steps_share_is_every_cells, ())]
+        + [(the_pair_states_all_three_explicitly, (name,)) for name in PAIR]
+        + [(one_of_the_nine_agrees_with_its_entry, (name,)) for name in NINE]
+        + [(a_cells_own_metrics_are_its_entries, (cell,)) for cell in OWN]
+        + [(a_cells_own_metric, (cell, name))
+           for cell, names in OWN.items() for name in names]
+        + [(a_turnaround_metric, (name,)) for name in TURNAROUND])
+
+
+def pin_id(pin):
+    fn, args = pin
+    return '-'.join((fn.__name__,) + args)
+
+
 def pins(manifest):
     """Every pin, on one root."""
-    the_first_four_cells(manifest)
-    for name in PAIR:
-        the_pair_states_all_three_explicitly(manifest, name)
-    for name in NINE:
-        one_of_the_nine_agrees_with_its_entry(manifest, name)
+    for fn, args in PINS:
+        fn(manifest, *args)

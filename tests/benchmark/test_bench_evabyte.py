@@ -16,6 +16,8 @@ from benchmark import checks_evabyte, flops_evabyte, rehearse
 from benchmark.manifest import Manifest, ROOT
 from benchmark.readers import trace_inner_scope_time
 
+from tests.benchmark import contracts
+
 CELL = 'evabyte.selfplay_4k'
 # EvaByte/EvaByte config.json, the numbers of it: what may not differ
 PUBLISHED = {'hidden_size': 4096, 'intermediate_size': 11008,
@@ -139,39 +141,31 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
         > flops_evabyte.train_window_flops(model, args)
 
 
-def test_each_new_metric_names_a_reader_and_its_cell(cell):
+def test_the_cells_own_metrics_are_pinned_by_name(cell):
+    """What the cell brought that no entry read before, found by NAME (the
+    entries that list this cell alone), wherever in ``per_layer`` they
+    stand: a later PR appends behind them and fails nothing here. The
+    shared path's readings (``sgd_ms``, ``rollout_ms``, ``fetch_wait_ms``,
+    ``train_mfu``, ...) list no cells and are this cell's under their one
+    name; the eight ``trunk_*`` twins of PR 34 are gone."""
     manifest, _config, _traffic, _args = cell
-    new = ['trunk_sgd_ms', 'trunk_rollout_ms', 'eva_attention_ms',
-           'eva_attention_roofline', 'trunk_train_mfu',
-           'window_padding_share', 'state_cache_gib',
-           # REVIEW of PR 34: what an iteration holds beside the program
-           'trunk_fetch_wait_ms', 'trunk_host_busy_ms', 'trunk_ingest_ms',
-           'trunk_unscoped_ms', 'trunk_dispatch_enqueue_ms',
-           'trunk_eval_share_ms']
-    assert [e['name'] for e in manifest.raw['per_layer'][-13:]] == new
-    for name in new:
-        assert manifest.metrics[name]['workloads'] == [CELL]
-        manifest.load_metric(name)
-    # the seven shipped metrics that list no cells are the new cell's too
+    contracts.a_cells_own_metrics_are_its_entries(manifest, CELL)
     reported = manifest.metrics_of(CELL, 'per_layer')
+    # the seven shipped metrics that never listed cells are the cell's too
     for name in ('fused_program_ms', 'env_steps_per_s', 'episodes_per_s',
                  'plies_per_episode', 'chunk_max_ms', 'device_idle',
                  'hbm_peak_gib'):
         assert name in reported
-    assert manifest.metrics_of(CELL, 'end_to_end') \
-        == ['train_windows_per_s', 'setup_s']
-    # and the same scopes and spans read under both names (a shipped
-    # metric lists its cells by name, so the new cell's is a twin)
-    for ours, theirs in (('trunk_sgd_ms', 'sgd_ms'),
-                         ('trunk_rollout_ms', 'rollout_ms'),
-                         ('trunk_ingest_ms', 'ingest_ms'),
-                         ('trunk_unscoped_ms', 'unscoped_ms'),
-                         ('trunk_fetch_wait_ms', 'fetch_wait_ms'),
-                         ('trunk_host_busy_ms', 'host_busy_ms'),
-                         ('trunk_dispatch_enqueue_ms',
-                          'dispatch_enqueue_ms')):
-        assert manifest.load_metric(ours)['args'] \
-            == manifest.load_metric(theirs)['args']
+    for twin in ('sgd_ms', 'rollout_ms', 'ingest_ms', 'unscoped_ms',
+                 'fetch_wait_ms', 'host_busy_ms', 'dispatch_enqueue_ms',
+                 'train_mfu'):
+        assert 'trunk_' + twin not in manifest.metrics
+        assert twin in reported
+
+
+@pytest.mark.parametrize('name', contracts.OWN[CELL])
+def test_each_own_metric_names_a_reader_and_the_cell(cell, name):
+    contracts.a_cells_own_metric(cell[0], CELL, name)
 
 
 def test_self_times_take_the_children_out():

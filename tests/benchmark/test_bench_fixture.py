@@ -108,6 +108,11 @@ def test_the_cells_own_hook_reader_and_metric_are_found_by_name(rehearsed):
     _proc, line, _written = rehearsed
     assert make_root.HOOK in line['spans']
     assert make_root.METRIC in line['metrics_read']
+    # the shared path's metrics list no cells, so the toy cell reads them
+    # under their one name (those a CPU run has something to read for: the
+    # loop's spans and the arithmetic; names only, never a number)
+    assert {'dispatch_enqueue_ms', 'fetch_wait_ms', 'train_mfu'} \
+        <= set(line['metrics_read'])
     assert line['counts']['epochs_in_window'] >= 1
 
 

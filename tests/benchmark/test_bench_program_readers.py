@@ -297,8 +297,9 @@ def test_scope_decoder_reads_the_trace_recorded_on_a_v5e():
 
 @pytest.mark.parametrize('name', sorted(NEW_METRICS))
 def test_metric_file_agrees_with_its_entry(name):
-    """Each lists the four shipped cells, by name: a fifth cell lists
-    itself in the entries it brings and edits none of these."""
+    """The seven that read what every cell's program gives list no cells
+    (a new cell reports them under their one name); the two that need an
+    epoch boundary in the window list the four conv cells, by name."""
     contracts.one_of_the_nine_agrees_with_its_entry(Manifest(), name)
 
 

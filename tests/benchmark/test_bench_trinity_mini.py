@@ -6,16 +6,17 @@ give them, each new metric's reader and cell, and a planted fault for each
 new check at the rehearsal's size. (That the cell rehearses with ``correct``
 true is test_bench_rehearsal's, which runs every cell of the manifest.)
 
-The eleven new per-layer metrics are PENDING: their files and readers are in
-the checkout, their entries only in the root ``benchmark/pending_root.py``
-builds (its docstring says why), so what is said of them here is said on
-that root (fixture ``pending``)."""
+The cell's eight per-layer metrics of its own are entries of the checkout's
+``BENCHMARK.json`` since PR 42 (they waited in a root of their own from PR
+38 on); what every cell's program gives (``sgd_ms``, ``rollout_ms``,
+``train_mfu``, ...) it reports under the shared names, and the three twins
+PR 38 had for them are gone."""
 
 import numpy as np
 import pytest
 
 from benchmark import checks_trinity_mini as ct
-from benchmark import flops_trinity_mini, pending_root, rehearse
+from benchmark import flops_trinity_mini, rehearse
 from benchmark.manifest import Manifest
 
 from tests.benchmark import contracts
@@ -33,10 +34,7 @@ PUBLISHED = {'hidden_size': 2048, 'intermediate_size': 6144,
 CUT = {'num_hidden_layers': (32, 5), 'num_dense_layers': (2, 1),
        'num_experts': (128, 16), 'num_attention_heads': (32, 8),
        'num_key_value_heads': (4, 1), 'vocab_size': (200192, 25024)}
-NEW = ['moe_experts_ms', 'moe_experts_roofline', 'moe_route_ms',
-       'moe_rows_held_share', 'moe_load_max_over_mean', 'gqa_attention_ms',
-       'gqa_attention_roofline', 'trinity_optimizer_ms', 'trinity_sgd_ms',
-       'trinity_rollout_ms', 'trinity_train_mfu']
+NEW = contracts.OWN[CELL]
 
 
 @pytest.fixture(scope='module')
@@ -48,44 +46,16 @@ def cell():
     return manifest, config, traffic, train_args
 
 
-@pytest.fixture(scope='module')
-def pending(tmp_path_factory):
-    """The checkout with the pending metrics' entries appended."""
-    return Manifest(pending_root.build(
-        str(tmp_path_factory.mktemp('pending') / 'root')))
-
-
 @pytest.mark.parametrize('contract', contracts.CONTRACTS,
                          ids=lambda fn: fn.__name__)
 def test_contract_holds_on_the_checkout_with_the_sixth_cell(contract, cell):
     manifest = cell[0]
-    assert list(manifest.cells)[-1] == CELL and len(manifest.cells) >= 6
+    assert CELL in manifest.cells and len(manifest.cells) >= 6
     contract(manifest)
 
 
-@pytest.mark.parametrize('contract', contracts.CONTRACTS,
-                         ids=lambda fn: fn.__name__)
-def test_contract_holds_with_the_pending_metrics_appended(contract, pending):
-    contract(pending)
-
-
-@pytest.mark.parametrize('root', ['checkout', 'pending'])
-def test_the_pins_hold_with_the_sixth_cell(root, cell, pending):
-    contracts.pins(cell[0] if root == 'checkout' else pending)
-
-
-def test_the_pending_root_appends_and_changes_nothing_else(cell, pending):
-    shipped, raw = cell[0].raw, pending.raw
-    for key in shipped:
-        if key != 'per_layer':
-            assert raw[key] == shipped[key]
-    held = len(shipped['per_layer'])
-    assert raw['per_layer'][:held] == shipped['per_layer']
-    assert [e['name'] for e in raw['per_layer'][held:]] == NEW \
-        == cell[1]['pending_metrics']['names']
-    # the checkout names none of them, so the accepted list stands as it was
-    assert not set(NEW) & set(cell[0].metrics)
-    assert pending_root.pending_entries() == raw['per_layer'][held:]
+def test_the_pins_hold_with_the_sixth_cell(cell):
+    contracts.pins(cell[0])
 
 
 def test_the_file_keeps_every_published_width_and_lists_each_cut(cell):
@@ -239,29 +209,22 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
     assert flops_trinity_mini.train_window_flops(model, burn) > window
 
 
-def test_each_new_metric_names_a_reader_and_the_cell(cell, pending):
-    manifest = pending
-    # in the checkout the cell reports the seven metrics that list no cells
-    assert cell[0].metrics_of(CELL, 'per_layer') == [
-        'fused_program_ms', 'env_steps_per_s', 'episodes_per_s',
-        'plies_per_episode', 'chunk_max_ms', 'device_idle', 'hbm_peak_gib']
+def test_each_new_metric_names_a_reader_and_the_cell(cell):
+    manifest = cell[0]
+    contracts.a_cells_own_metrics_are_its_entries(manifest, CELL)
+    assert len(NEW) == 8
     for name in NEW:
-        assert manifest.metrics[name]['workloads'] == [CELL]
-        assert manifest.metrics[name]['moves'] == 'train_windows_per_s'
-        manifest.load_metric(name)
+        contracts.a_cells_own_metric(manifest, CELL, name)
     reported = manifest.metrics_of(CELL, 'per_layer')
     for name in ('fused_program_ms', 'env_steps_per_s', 'episodes_per_s',
                  'plies_per_episode', 'chunk_max_ms', 'device_idle',
                  'hbm_peak_gib'):
         assert name in reported
     assert not [name for name in reported if name.startswith('trunk_')]
-    assert manifest.metrics_of(CELL, 'end_to_end') \
-        == ['train_windows_per_s', 'setup_s']
-    for ours, theirs in (('trinity_sgd_ms', 'sgd_ms'),
-                         ('trinity_rollout_ms', 'rollout_ms'),
-                         ('trinity_train_mfu', 'trunk_train_mfu')):
-        assert manifest.load_metric(ours)['args'] \
-            == manifest.load_metric(theirs)['args']
+    # one name a shared reading: no twin of the cell's own is left
+    for twin in ('sgd_ms', 'rollout_ms', 'train_mfu'):
+        assert 'trinity_' + twin not in manifest.metrics
+        assert twin in reported
     for name, scope in (('moe_experts_ms', 'moe_experts'),
                         ('moe_route_ms', 'moe_route'),
                         ('gqa_attention_ms', 'gqa_attention'),
@@ -336,14 +299,12 @@ def test_the_experts_time_holds_the_grouped_products_the_scope_lost(
         Run, module, 'moe_experts', ['ragged-dot']) is None
 
 
-def test_the_counter_ratios_read_the_pipelines_sums(cell, pending,
-                                                    monkeypatch):
+def test_the_counter_ratios_read_the_pipelines_sums(cell, monkeypatch):
     """``program_counter_ratio`` with the new metrics' arguments over two
     records of the ``host_block`` span as ``FusedPipeline._parse`` sets it."""
     from benchmark.readers import program_counter_ratio
     from benchmark.record import Run
-    _checkout, config, traffic, args = cell
-    manifest = pending
+    manifest, config, traffic, args = cell
     attrs = lambda k: {'moe_rows_held': 1000.0 * k,
                        'moe_rows_routed': 8000.0 * k,
                        'moe_rows_fullest': 40.0 * k}

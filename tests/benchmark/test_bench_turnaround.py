@@ -2,99 +2,38 @@
 reader the benchmark already has, ``program_counter_ratio`` over the sums
 that ``telemetry.ChunkMonitor`` puts on every ``fused_iter`` span.
 
-Their entries AWAIT a ``benchmark`` PR (``test_bench_evabyte.py`` pins the
-last thirteen entries of ``per_layer`` by position; PERF.md section 7 (9)),
-so what is said of them here is said on a root built in ``tmp_path``: the
-checkout's ``BENCHMARK.json`` with the four entries appended, for the four
-conv cells, and everything else a link to the checkout's file."""
-
-import json
-import os
+Entries of the checkout's ``BENCHMARK.json`` since PR 42 (they waited on a
+root of their own from PR 40 on). An entry lists a cell only where the
+metric read a number in every traced run of that cell: the two shares of the
+host-bound turnaround divide by ``hb_turnaround_s``, which is zero in a
+window with no host-bound chunk (``contracts.TURNAROUND`` has the lists)."""
 
 import pytest
 
-from benchmark.manifest import ROOT, Manifest
 from benchmark.readers import program_counter_ratio
 from benchmark.record import Run
 
 from tests.benchmark import contracts
-from tests.benchmark.fixture import make_root
 
-NEW = {
-    'host_bound_chunk_share': ('%', 'entry, orchestration',
-                               'host_bound_chunks', 'chunks', 100),
-    'turnaround_ms': ('ms', 'entry, orchestration',
-                      'turnaround_s', 'chunks', 1000),
-    'host_bound_ckpt_wait_share': ('%', 'param publish, checkpoint',
-                                   'hb_ckpt_wait_s', 'hb_turnaround_s', 100),
-    'host_bound_boundary_share': ('%', 'entry, orchestration',
-                                  'hb_boundary_s', 'hb_turnaround_s', 100),
-}
-ENTRY_KEYS = ('name', 'unit', 'better', 'source', 'layer', 'moves')
+NEW = contracts.TURNAROUND
 
 
-def entries():
-    """The ``per_layer`` entries the ``benchmark`` PR is to append."""
-    out = []
-    for name in NEW:
-        with open(os.path.join(ROOT, 'benchmark', 'metrics',
-                               name + '.json')) as f:
-            spec = json.load(f)
-        out.append(dict({key: spec[key] for key in ENTRY_KEYS},
-                        workloads=list(contracts.FOUR)))
-    return out
-
-
-@pytest.fixture(scope='module')
-def appended(tmp_path_factory):
-    dest = str(tmp_path_factory.mktemp('turnaround') / 'root')
-    make_root._link_shipped(dest)
-    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
-        raw = json.load(f)
-    raw['per_layer'] += entries()
-    with open(os.path.join(dest, 'BENCHMARK.json'), 'w') as f:
-        json.dump(raw, f, indent=1)
-    return Manifest(dest)
-
-
-@pytest.mark.parametrize('contract', contracts.CONTRACTS,
-                         ids=lambda fn: fn.__name__)
-def test_contract_holds_with_the_four_entries_appended(contract, appended):
-    contract(appended)
-
-
-def test_the_pins_hold_and_nothing_shipped_changes(appended, shipped):
-    contracts.pins(appended)
-    for key, value in shipped.raw.items():
-        if key != 'per_layer':
-            assert appended.raw[key] == value
-    held = len(shipped.raw['per_layer'])
-    assert appended.raw['per_layer'][:held] == shipped.raw['per_layer']
-    assert [e['name'] for e in appended.raw['per_layer'][held:]] == list(NEW)
-    # the checkout names none of them: the accepted list stands as it was
-    assert not set(NEW) & set(shipped.metrics)
-    for cell in contracts.FOUR:
-        assert appended.metrics_of(cell, 'per_layer')[-4:] == list(NEW)
-    for cell in set(appended.cells) - set(contracts.FOUR):
-        assert not set(NEW) & set(appended.metrics_of(cell))
+def test_the_four_are_entries_of_the_checkout_for_the_cells_that_read_them(
+        shipped):
+    names = [e['name'] for e in shipped.raw['per_layer']]
+    assert [name for name in names if name in NEW] == list(NEW)
+    for name, (*_rest, cells) in NEW.items():
+        assert set(cells) <= set(contracts.FOUR)
+        for cell in shipped.cells:
+            assert (name in shipped.metrics_of(cell, 'per_layer')) \
+                == (cell in cells)
 
 
 @pytest.mark.parametrize('name', NEW)
-def test_a_metric_file_agrees_with_its_entry(name, appended):
-    unit, layer, top, bottom, scale = NEW[name]
-    spec = appended.load_metric(name)     # raises where file and entry differ
-    entry = appended.metrics[name]
-    assert (entry['unit'], entry['better'], entry['source'], entry['layer'],
-            entry['moves']) == (unit, 'lower', 'program_counter', layer,
-                                'train_windows_per_s')
-    assert entry['layer'] in {e['layer'] for e in
-                              Manifest().raw['per_layer']}
-    assert spec['reader'] == 'program_counter_ratio'
-    assert spec['args'] == {'stage': 'fused_iter', 'numerator': top,
-                            'denominator': bottom, 'scale': scale}
-    # the text names the span and the counters it reads
-    for word in ('fused_iter', top, bottom, 'ChunkMonitor'):
-        assert word in spec['what'], word
+def test_a_metric_file_agrees_with_its_entry(name, shipped):
+    contracts.a_turnaround_metric(shipped, name)
+    assert shipped.metrics[name]['layer'] in {
+        e['layer'] for e in shipped.raw['per_layer'] if e['name'] not in NEW}
 
 
 def _ring(host_bound):
@@ -139,10 +78,10 @@ def _read(monkeypatch, manifest, records, window):
 
 
 def test_the_shares_of_the_host_bound_turnaround_on_a_hand_made_ring(
-        monkeypatch, appended):
+        monkeypatch, shipped):
     # the window's chunks are those of records 12 .. 17; 13, 14 and 16 were
     # host-bound (record 11's, host-bound too, lies before the window)
-    got = _read(monkeypatch, appended, _ring({11, 13, 14, 16}), (11.5, 17.5))
+    got = _read(monkeypatch, shipped, _ring({11, 13, 14, 16}), (11.5, 17.5))
     assert got['host_bound_chunk_share'] == pytest.approx(100 * 3 / 6)
     assert got['turnaround_ms'] == pytest.approx(
         1e3 * (6 * 0.004 + 3 * 0.014) / 6)
@@ -156,8 +95,8 @@ def test_the_shares_of_the_host_bound_turnaround_on_a_hand_made_ring(
             + 100 * 0.004 / 0.018) == pytest.approx(100)
 
 
-def test_no_host_bound_chunk_leaves_the_two_shares_out(monkeypatch, appended):
-    got = _read(monkeypatch, appended, _ring({11}), (11.5, 17.5))
+def test_no_host_bound_chunk_leaves_the_two_shares_out(monkeypatch, shipped):
+    got = _read(monkeypatch, shipped, _ring({11}), (11.5, 17.5))
     assert got['host_bound_chunk_share'] == 0.0
     assert got['turnaround_ms'] == pytest.approx(4.0)
     assert got['host_bound_ckpt_wait_share'] is None
@@ -165,9 +104,9 @@ def test_no_host_bound_chunk_leaves_the_two_shares_out(monkeypatch, appended):
 
 
 def test_a_program_without_the_counters_leaves_all_four_out(monkeypatch,
-                                                           appended):
+                                                           shipped):
     """The parent's ``fused_iter`` carries ``dispatch`` and ``warm`` alone."""
     records = [dict(r, attrs={'dispatch': r['span_id'], 'warm': 0})
                for r in _ring(set())]
-    got = _read(monkeypatch, appended, records, (11.5, 17.5))
+    got = _read(monkeypatch, shipped, records, (11.5, 17.5))
     assert got == dict.fromkeys(NEW)
