@@ -1,0 +1,94 @@
+"""Grouped-query attention of one kind a layer, as functions of arrays: a
+whole sequence in blocks of queries, and one position through a cache of K
+and V rows. A layer either sees everything before a query (``window`` None)
+or the ``window`` keys up to and with the query's own. What comes before
+(projections, norms, phases) and after (gates, ``W_o``) is the net's, as are
+the scopes these run under (``models/trinity.py``, ``models/smallthinker.py``).
+"""
+
+import jax
+import jax.numpy as jnp
+
+NEG = -1e30
+f32 = jnp.float32
+
+
+def sequence_attention(q, k, v, positions, valid, window, query_block):
+    """One sequence. q (T, H, d), k, v (T, KV, d) -> (T, H * d); query head
+    ``n`` reads KV head ``n // (H / KV)``; query ``i`` sees key ``j`` iff
+    ``j <= i`` by position, the key is valid and, with a ``window``,
+    ``i - window < j``."""
+    T, H, d = q.shape
+    KV = k.shape[1]
+    G = H // KV
+    q = q.reshape(T, KV, G, d).transpose(1, 2, 0, 3)           # (KV, G, T, d)
+    k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)        # (KV, T, d)
+    scale = d ** -0.5
+    bq = min(query_block, T)
+    assert T % bq == 0, (T, bq)
+
+    @jax.checkpoint
+    def block(args):
+        qb, pq = args                                 # (KV, G, bq, d), (bq,)
+        seen = (positions[None, :] <= pq[:, None]) & valid[None, :]
+        if window is not None:
+            seen = seen & (positions[None, :] > pq[:, None] - window)
+        s = scale * jnp.einsum('kgqd,ktd->kgqt', qb, k,
+                               preferred_element_type=f32)
+        prob = jax.nn.softmax(jnp.where(seen[None, None], s, NEG),
+                              axis=-1).astype(v.dtype)
+        return jnp.einsum('kgqt,ktd->kgqd', prob, v,
+                          preferred_element_type=f32).astype(v.dtype)
+
+    qs = q.reshape(KV, G, T // bq, bq, d).transpose(2, 0, 1, 3, 4)
+    out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
+    return out.transpose(0, 3, 1, 2, 4).reshape(T, H * d)
+
+
+def init_cache(batch_shape, rows, width, dtype):
+    """K and V of every layer, ``rows[i]`` rows of ``width`` on layer i, and
+    ONE counter a sequence."""
+    lead = tuple(batch_shape)
+    zeros = lambda: tuple(jnp.zeros(lead + (n, width), dtype) for n in rows)
+    return {'k': zeros(), 'v': zeros(), 'pos': jnp.zeros(lead, jnp.int32)}
+
+
+def reset_cache(hidden, done):
+    """A finished game resets its sequences' counters, not their buffers:
+    what a counter has not reached is masked."""
+    pos = hidden['pos']
+    done = done.reshape(done.shape + (1,) * (pos.ndim - done.ndim))
+    return dict(hidden, pos=jnp.where(done, 0, pos))
+
+
+def cache_write(ck, cv, k, v, pos):
+    """A position's k, v (B, KV, d) into each sequence's row ``pos % rows``
+    of ck, cv (B, rows, KV * d): a row is the KV heads side by side (with a
+    head axis of its own a lone KV head of 128 would be padded to a tile of
+    8). A buffer shorter than the game is written round and round."""
+    slot = pos % ck.shape[1]
+    seq = jnp.arange(ck.shape[0])
+    return (ck.at[seq, slot].set(k.reshape(k.shape[0], -1)),
+            cv.at[seq, slot].set(v.reshape(v.shape[0], -1)))
+
+
+def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):
+    """q (B, H, d) at each sequence's own position ``pos`` (B,) over the
+    rows written so far -> (B, H * d). Nothing is ever cleared: rows the
+    counter has not reached are masked; a ``circle`` that has gone round
+    holds the ``rows`` positions up to this one, before that rows 0..pos
+    (keys are stored already turned, so a row needs no position)."""
+    B, n_rows = ck.shape[:2]
+    H, d = q.shape[1:]
+    seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
+    if circle:
+        seen = seen | (pos[:, None] >= n_rows)
+    rows = lambda c: c.reshape(B, n_rows, kv_heads, d)
+    s = d ** -0.5 * jnp.einsum(
+        'bkgd,brkd->bkgr', q.reshape(B, kv_heads, H // kv_heads, -1),
+        rows(ck), preferred_element_type=f32)
+    prob = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG),
+                          axis=-1).astype(cv.dtype)
+    y = jnp.einsum('bkgr,brkd->bkgd', prob, rows(cv),
+                   preferred_element_type=f32).astype(dtype)
+    return y.reshape(B, -1)

@@ -66,11 +66,10 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 
-from . import register
-from .evabyte import NEG, _dot, _rotary, f32
+from . import attention, experts, register
+from .evabyte import _dot, _rotary, f32
 
 PUBLISHED_LAYERS = ('sliding', 'sliding', 'sliding', 'full') * 8
 
@@ -186,37 +185,10 @@ class TrinityBlock(nn.Module):
                 None, :, None, None]
             k = jnp.where(keep, k, jax.lax.stop_gradient(k))
             v = jnp.where(keep, v, jax.lax.stop_gradient(v))
-        y = jax.vmap(self._sequence_attention)(q, k, v, positions, valid)
+        window = self.window_size if self.kind == 'sliding' else None
+        y = jax.vmap(lambda *seq: attention.sequence_attention(
+            *seq, window, self.query_block))(q, k, v, positions, valid)
         return self._out(y, gate)
-
-    def _sequence_attention(self, q, k, v, positions, valid):
-        """One sequence. q (T, H, d), k, v (T, KV, d) -> (T, H * d)."""
-        T, H, d = q.shape
-        KV = k.shape[1]
-        G, W = H // KV, self.window_size
-        sliding = self.kind == 'sliding'
-        q = q.reshape(T, KV, G, d).transpose(1, 2, 0, 3)       # (KV, G, T, d)
-        k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)    # (KV, T, d)
-        scale = d ** -0.5
-        bq = min(self.query_block, T)
-        assert T % bq == 0, (T, bq)
-
-        @jax.checkpoint
-        def block(args):
-            qb, pq = args                             # (KV, G, bq, d), (bq,)
-            seen = (positions[None, :] <= pq[:, None]) & valid[None, :]
-            if sliding:
-                seen = seen & (positions[None, :] > pq[:, None] - W)
-            s = scale * jnp.einsum('kgqd,ktd->kgqt', qb, k,
-                                   preferred_element_type=f32)
-            prob = jax.nn.softmax(jnp.where(seen[None, None], s, NEG),
-                                  axis=-1).astype(v.dtype)
-            return jnp.einsum('kgqt,ktd->kgqd', prob, v,
-                              preferred_element_type=f32).astype(v.dtype)
-
-        qs = q.reshape(KV, G, T // bq, bq, d).transpose(2, 0, 1, 3, 4)
-        out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
-        return out.transpose(0, 3, 1, 2, 4).reshape(T, H * d)
 
     # -- the MLP: dense, or the experts held -------------------------------
     def _route(self, m32):
@@ -234,84 +206,35 @@ class TrinityBlock(nn.Module):
         picked = jnp.take_along_axis(s, ids, axis=1)
         w = self.route_scale * picked / (
             picked.sum(axis=1, keepdims=True) + 1e-20)
-        counts = (ids[..., None] == jnp.arange(self.experts_published)).sum(
-            axis=(0, 1), dtype=jnp.int32)
+        counts = experts.chosen_counts(ids, self.experts_published)
         return ids, jax.lax.stop_gradient(w), counts
-
-    def _held_slot(self, ids):
-        """Published expert ids -> slots among the experts held here; an
-        expert that lies on another chip gets the slot ``len(held)``."""
-        held = len(self.experts_held)
-        # a host constant: as a device scatter of arange into a constant the
-        # v5e's compiler aborts inside a scan (one constant, two operands)
-        slot = np.full((self.experts_published,), held, np.int32)
-        slot[list(self.experts_held)] = np.arange(held)
-        return jnp.asarray(slot)[ids]
 
     def _experts_every_row(self, m, slot, w):
         """A few rows: every held expert on every row, weighted by ``w_e``
         or by 0."""
-        held = len(self.experts_held)
         with jax.named_scope('moe_route'):
-            gate = ((slot[..., None] == jnp.arange(held)) * w[..., None]
-                    ).sum(axis=1)                               # (n, held)
+            gate = experts.every_row_gate(slot, w, len(self.experts_held))
         with jax.named_scope('moe_experts'):
-            cast, inv = (lambda p: p.astype(self.dtype)), self.inv
-            act = (jax.nn.silu(jnp.einsum('nd,edf->enf', m,
-                                          cast(self.experts_gate)) * inv)
-                   * (jnp.einsum('nd,edf->enf', m, cast(self.experts_up))
-                      * inv))
-            # in ``dtype``, as the grouped product's rows are. The weighted
-            # sum stays in this scope and ends it: the compiler names a
-            # fusion after its last operation, and without the barrier the
-            # product that reads ``experts_down`` is fused on into what
-            # follows the scope and timed there
-            y = jnp.einsum('enf,efd->end', act,
-                           cast(self.experts_down)) * inv
-            return jax.lax.optimization_barrier(jnp.einsum(
-                'end,ne->nd', y.astype(f32), gate)), jnp.int32(0)
+            return experts.every_row_products(
+                m, gate, self.experts_gate, self.experts_up,
+                self.experts_down, jax.nn.silu, self.dtype,
+                self.inv), jnp.int32(0)
 
     def _experts_grouped(self, m, slot, w):
-        """Dropless under any imbalance: the (row, choice) pairs sorted by
-        the expert's slot (pairs of absent experts last), ONE grouped
-        product over the experts held, whose work follows the rows that
-        came and not the buffer's size, and the weighted sum back by row."""
-        n, K = slot.shape
-        held, M = len(self.experts_held), n * K
+        """Dropless under any imbalance (``models/experts.py``): the (row,
+        choice) pairs sorted by the expert's slot, ONE grouped product over
+        the experts held, and the weighted sum back by row."""
+        held = len(self.experts_held)
         with jax.named_scope('moe_route'):
-            flat = slot.reshape(M)
-            onehot = flat[:, None] == jnp.arange(held + 1)      # (M, held+1)
-            rank = (jnp.cumsum(onehot, axis=0, dtype=jnp.int32)
-                    * onehot).sum(axis=1) - 1
-            sizes = onehot.sum(axis=0, dtype=jnp.int32)
-            dest = (jnp.cumsum(sizes) - sizes)[flat] + rank     # (M,)
-            source = jnp.zeros((M,), jnp.int32).at[dest].set(
-                jnp.arange(M, dtype=jnp.int32))
-            # both gathers are by permutations of the pairs, and say so:
-            # their transposes are then plain scatters, not scatter-adds
-            rows = jnp.repeat(m, K, axis=0).at[source].get(
-                unique_indices=True)                            # (M, D)
-            groups = sizes[:held]
-            # rows past the last group belong to no expert held here: the
-            # product leaves whatever was there, forward AND backward, so
-            # nothing of them is read and no cotangent of theirs passes
-            in_group = (jnp.arange(M) < groups.sum())[:, None]
-            rows = jnp.where(in_group, rows, 0)
-            # by construction 0: the buffer holds every pair
-            dropped = jnp.sum((flat < held) & (dest >= M), dtype=jnp.int32)
+            plan = experts.sort_plan(slot, held)
+            rows = experts.to_expert_order(m, plan)
         with jax.named_scope('moe_experts'):
-            cast = lambda p: p.astype(self.dtype)
-            grouped = lambda x, p: jax.lax.ragged_dot(
-                x, cast(p), groups, preferred_element_type=self.dtype
-            ) * self.inv
-            act = (jax.nn.silu(grouped(rows, self.experts_gate))
-                   * grouped(rows, self.experts_up))
-            y = grouped(act, self.experts_down)                 # (M, D)
+            y = experts.grouped_products(
+                rows, plan.groups, self.experts_gate, self.experts_up,
+                self.experts_down, jax.nn.silu, self.dtype, self.inv)
         with jax.named_scope('moe_route'):
-            y = jnp.where(in_group, y, 0)
-            y = y.at[dest].get(unique_indices=True).reshape(n, K, -1)
-            return jnp.einsum('nkd,nk->nd', y, (w * (slot < held)).astype(
-                y.dtype), preferred_element_type=f32), dropped
+            return experts.weighted_sum_back(y, plan, slot, w,
+                                             held), plan.dropped
 
     def mlp_branch(self, x, shared: bool = True):
         """x (n, D) float32 -> ``f(N_pre_mlp(x))`` before the branch's norm
@@ -326,10 +249,11 @@ class TrinityBlock(nn.Module):
                                self.dtype, self.inv), None, None
         with jax.named_scope('moe_route'):
             ids, w, counts = self._route(m32)
-            slot = self._held_slot(ids)
-        experts = (self._experts_every_row if m.shape[0] <= self.dense_rows
-                   else self._experts_grouped)
-        f, dropped = experts(m, slot, w)
+            slot = experts.held_slot(ids, self.experts_held,
+                                     self.experts_published)
+        branch = (self._experts_every_row if m.shape[0] <= self.dense_rows
+                  else self._experts_grouped)
+        f, dropped = branch(m, slot, w)
         if shared:
             with jax.named_scope('moe_shared'):
                 f = f + _swiglu(m, self.shared_gate, self.shared_up,
@@ -358,29 +282,15 @@ class TrinityBlock(nn.Module):
         (a row is the KV heads side by side: with a head axis of its own a
         lone KV head of 128 would be padded to a tile of 8)."""
         ck, cv = cache
-        B, n_rows = x.shape[0], ck.shape[1]
-        KV, G = self.kv_heads_held, self.heads_held // self.kv_heads_held
+        KV = self.kv_heads_held
         with jax.named_scope('gqa_attention'):
             q, k, v, gate = self._qkvg(x, pos)           # (B, H | KV, d)
-            slot = pos % n_rows
             with jax.named_scope('state_update'):
-                seq = jnp.arange(B)
-                ck = ck.at[seq, slot].set(k.reshape(B, -1))
-                cv = cv.at[seq, slot].set(v.reshape(B, -1))
-            # a circle that has gone round holds the window_size positions
-            # up to this one; before that, rows 0..pos
-            seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
-            if self.kind == 'sliding':
-                seen = seen | (pos[:, None] >= n_rows)
-            rows = lambda c: c.reshape(B, n_rows, KV, self.head_dim)
-            s = self.head_dim ** -0.5 * jnp.einsum(
-                'bkgd,brkd->bkgr', q.reshape(B, KV, G, -1), rows(ck),
-                preferred_element_type=f32)
-            prob = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG),
-                                  axis=-1).astype(cv.dtype)
-            y = jnp.einsum('bkgr,brkd->bkgd', prob, rows(cv),
-                           preferred_element_type=f32).astype(self.dtype)
-            x = self._after_attention(x, self._out(y.reshape(B, -1), gate))
+                ck, cv = attention.cache_write(ck, cv, k, v, pos)
+            y = attention.cache_attention(q, ck, cv, pos,
+                                          self.kind == 'sliding', KV,
+                                          self.dtype)
+            x = self._after_attention(x, self._out(y, gate))
         x, _counts, _dropped = self._mlp(x)
         return x, (ck, cv)
 
@@ -456,23 +366,13 @@ class TrinityNet(nn.Module):
 
     # -- the cache -----------------------------------------------------------
     def init_hidden(self, batch_shape=()):
-        lead = tuple(batch_shape)
+        return attention.init_cache(
+            batch_shape, [self.window_size if kind == 'sliding'
+                          else self.max_positions
+                          for kind in self.layer_types],
+            self.kv_heads_held * self.head_dim, self.dtype)
 
-        def rows():
-            return tuple(jnp.zeros(
-                lead + (self.window_size if kind == 'sliding'
-                        else self.max_positions,
-                        self.kv_heads_held * self.head_dim), self.dtype)
-                for kind in self.layer_types)
-        return {'k': rows(), 'v': rows(), 'pos': jnp.zeros(lead, jnp.int32)}
-
-    @staticmethod
-    def reset_hidden(hidden, done):
-        """A finished game resets its sequences' counters, not their
-        buffers: what a counter has not reached is masked."""
-        pos = hidden['pos']
-        done = done.reshape(done.shape + (1,) * (pos.ndim - done.ndim))
-        return dict(hidden, pos=jnp.where(done, 0, pos))
+    reset_hidden = staticmethod(attention.reset_cache)
 
     # -- inputs and outputs --------------------------------------------------
     def _embed(self, ids):
@@ -527,14 +427,8 @@ class TrinityNet(nn.Module):
         h = self._features(x)
         out = {'policy_features': h, 'value': self._value(h)}
         if counts:
-            counts = jnp.stack(counts)                       # (layers, E)
-            ours = counts[:, jnp.asarray(self.held)]
-            out['aux'] = {
-                'moe_counts': counts,
-                'moe_rows_held': ours.sum().astype(f32),
-                'moe_rows_routed': counts.sum().astype(f32),
-                'moe_rows_fullest': ours.max().astype(f32),
-                'moe_rows_dropped': dropped.astype(f32)}
+            out['aux'] = experts.rows_aux(jnp.stack(counts), self.held,
+                                          dropped)
         return out
 
     def attention_part(self, layer: int, x, positions, valid):
@@ -566,12 +460,5 @@ class TrinityNet(nn.Module):
 
     def epoch_dynamics(self, sums):
         """The epoch record's keys from the epoch's ``diag_*`` sums."""
-        held = sums.get('diag_moe_rows_held')
-        if not held:
-            return {}
-        slots = len(self.held) * len(self.expert_layers)
-        return {'moe_rows_held_share':
-                100.0 * held / sums['diag_moe_rows_routed'],
-                'moe_load_max_over_mean':
-                sums['diag_moe_rows_fullest'] * slots / held,
-                'moe_rows_dropped': sums.get('diag_moe_rows_dropped', 0.0)}
+        return experts.rows_dynamics(
+            sums, len(self.held) * len(self.expert_layers))
