@@ -137,26 +137,29 @@ class SmallThinkerBlock(nn.Module):
         held = len(self.experts_held)
         if a32.shape[0] <= self.dense_rows:
             return slot, w, counts, experts.every_row_gate(slot, w, held)
-        return slot, w, counts, experts.sort_plan(slot, held)
+        return slot, w, counts, experts.sort_plan(slot, held,
+                                                  self.experts_published)
 
     def experts_part(self, m, routing):
         """m (n, D) in ``dtype``, ``N_post(h)`` -> this chip's experts' part
-        of the layer's sum (n, D) float32, and the rows dropped (0)."""
+        of the layer's sum (n, D) float32, and the dispatch's tally
+        (``experts.SortPlan.tally``; 0 for a few rows, which take no
+        buffer)."""
         slot, w, _counts, plan = routing
-        held = len(self.experts_held)
-        matrices = (self.experts_gate, self.experts_up, self.experts_down,
-                    self.activation, self.dtype, self.inv)
+        matrices = (self.experts_gate, self.experts_up, self.experts_down)
+        how = (self.activation, self.dtype, self.inv)
         if not isinstance(plan, experts.SortPlan):
             with jax.named_scope('reglu_experts'):
-                return experts.every_row_products(m, plan, *matrices), \
+                return experts.every_row_products(m, plan, *matrices, *how), \
                     jnp.int32(0)
-        with jax.named_scope('expert_dispatch'):
-            rows = experts.to_expert_order(m, plan)
-        with jax.named_scope('reglu_experts'):
-            y = experts.grouped_products(rows, plan.groups, *matrices)
-        with jax.named_scope('expert_dispatch'):
-            return experts.weighted_sum_back(y, plan, slot, w,
-                                             held), plan.dropped
+
+        def products(rows, groups, *matrices):
+            with jax.named_scope('reglu_experts'):
+                return experts.grouped_products(rows, groups, *matrices,
+                                                *how)
+        f = experts.dispatched_sum(m, plan, slot, w, self.experts_published,
+                                   products, matrices, 'expert_dispatch')
+        return f, plan.tally
 
     # -- attention -----------------------------------------------------------
     def _qkv(self, a, positions):
@@ -198,8 +201,8 @@ class SmallThinkerBlock(nn.Module):
 
     def _after_attention(self, x, routing):
         m = _rms_norm(x, self.norm_post, self.norm_eps, self.dtype)
-        f, dropped = self.experts_part(m, routing)
-        return x + f, dropped
+        f, tally = self.experts_part(m, routing)
+        return x + f, tally
 
     # -- a whole window ------------------------------------------------------
     def sequence(self, x, positions, valid, no_grad_prefix=0):
@@ -209,8 +212,8 @@ class SmallThinkerBlock(nn.Module):
             routing = self.pre_route(a32.reshape(B * T, D))
         with jax.named_scope(self.attention_scope):
             x = x + self._attention(a, positions, valid, no_grad_prefix)
-        x, dropped = self._after_attention(x.reshape(B * T, D), routing)
-        return x.reshape(B, T, D), routing[2], dropped
+        x, tally = self._after_attention(x.reshape(B * T, D), routing)
+        return x.reshape(B, T, D), routing[2], tally
 
     # -- one position through the cache --------------------------------------
     def step(self, x, pos, cache):
@@ -230,7 +233,7 @@ class SmallThinkerBlock(nn.Module):
                                           self.kind == 'window',
                                           self.kv_heads_held, self.dtype)
             x = x + self._out(y)
-        x, _dropped = self._after_attention(x, routing)
+        x, _tally = self._after_attention(x, routing)
         return x, (ck, cv)
 
 
@@ -351,21 +354,21 @@ class SmallThinkerNet(nn.Module):
         T = ids.shape[1]
         positions = first_position[:, None] + jnp.arange(T)
         x = self._embed(ids)
-        counts, dropped = [], jnp.int32(0)
+        counts, tally = [], jnp.zeros((2,), jnp.int32)
         for block in self.blocks:
             # one layer rematerialised at a time, as models/evabyte.py
-            x, c, d = nn.remat(SmallThinkerBlock.sequence,
+            x, c, t = nn.remat(SmallThinkerBlock.sequence,
                                static_argnums=(4,))(
                 block, x, positions, valid, no_grad_prefix)
             counts.append(c)
-            dropped = dropped + d
+            tally = tally + t
         h = self._features(x)
         # the sequence starts with an empty cache at its first position:
         # from ``window_size`` positions on a window layer hides a key
         trained = valid & (jnp.arange(T) >= no_grad_prefix)
         hidden = trained & (jnp.arange(T) >= self.window_size)
         return {'policy_features': h, 'value': self._value(h), 'aux': dict(
-            experts.rows_aux(jnp.stack(counts), self.held, dropped),
+            experts.rows_aux(jnp.stack(counts), self.held, tally),
             window_positions_valid=trained.sum().astype(f32),
             window_positions_hidden=hidden.sum().astype(f32))}
 

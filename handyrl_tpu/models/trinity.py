@@ -222,24 +222,31 @@ class TrinityBlock(nn.Module):
 
     def _experts_grouped(self, m, slot, w):
         """Dropless under any imbalance (``models/experts.py``): the (row,
-        choice) pairs sorted by the expert's slot, ONE grouped product over
-        the experts held, and the weighted sum back by row."""
-        held = len(self.experts_held)
+        choice) pairs sorted by the expert's slot, the pairs held here
+        through the short buffer or every pair through the long one, ONE
+        grouped product over the experts held, and the weighted sum back by
+        row. Also the plan's tally (the rows dropped, 0, and whether the
+        short buffer was taken)."""
         with jax.named_scope('moe_route'):
-            plan = experts.sort_plan(slot, held)
-            rows = experts.to_expert_order(m, plan)
-        with jax.named_scope('moe_experts'):
-            y = experts.grouped_products(
-                rows, plan.groups, self.experts_gate, self.experts_up,
-                self.experts_down, jax.nn.silu, self.dtype, self.inv)
-        with jax.named_scope('moe_route'):
-            return experts.weighted_sum_back(y, plan, slot, w,
-                                             held), plan.dropped
+            plan = experts.sort_plan(slot, len(self.experts_held),
+                                     self.experts_published)
+
+        def products(rows, groups, *matrices):
+            with jax.named_scope('moe_experts'):
+                return experts.grouped_products(
+                    rows, groups, *matrices, jax.nn.silu, self.dtype,
+                    self.inv)
+        f = experts.dispatched_sum(
+            m, plan, slot, w, self.experts_published, products,
+            (self.experts_gate, self.experts_up, self.experts_down),
+            'moe_route')
+        return f, plan.tally
 
     def mlp_branch(self, x, shared: bool = True):
         """x (n, D) float32 -> ``f(N_pre_mlp(x))`` before the branch's norm
         and, on an expert layer, the tokens each published expert was chosen
-        for (E,) and the rows dropped (0). ``shared=False`` leaves the
+        for (E,) and the dispatch's tally (``experts.SortPlan.tally``; 0 for
+        a few rows, which take no buffer). ``shared=False`` leaves the
         shared expert out (the share test counts it once)."""
         m32 = _rms_norm(x, self.norm_pre_mlp, self.norm_eps, f32)
         m = m32.astype(self.dtype)
@@ -253,17 +260,17 @@ class TrinityBlock(nn.Module):
                                      self.experts_published)
         branch = (self._experts_every_row if m.shape[0] <= self.dense_rows
                   else self._experts_grouped)
-        f, dropped = branch(m, slot, w)
+        f, tally = branch(m, slot, w)
         if shared:
             with jax.named_scope('moe_shared'):
                 f = f + _swiglu(m, self.shared_gate, self.shared_up,
                                 self.shared_down, self.dtype, self.inv)
-        return f, counts, dropped
+        return f, counts, tally
 
     def _mlp(self, x):
-        f, counts, dropped = self.mlp_branch(x)
+        f, counts, tally = self.mlp_branch(x)
         x = x + _rms_norm(f, self.norm_post_mlp, self.norm_eps, f32)
-        return x, counts, dropped
+        return x, counts, tally
 
     # -- a whole window ------------------------------------------------------
     def sequence(self, x, positions, valid, no_grad_prefix=0):
@@ -271,8 +278,8 @@ class TrinityBlock(nn.Module):
         with jax.named_scope('gqa_attention'):
             x = self._after_attention(x, self.attention_part(
                 x, positions, valid, no_grad_prefix))
-        x, counts, dropped = self._mlp(x.reshape(B * T, D))
-        return x.reshape(B, T, D), counts, dropped
+        x, counts, tally = self._mlp(x.reshape(B * T, D))
+        return x.reshape(B, T, D), counts, tally
 
     # -- one position through the cache --------------------------------------
     def step(self, x, pos, cache):
@@ -291,7 +298,7 @@ class TrinityBlock(nn.Module):
                                           self.kind == 'sliding', KV,
                                           self.dtype)
             x = self._after_attention(x, self._out(y, gate))
-        x, _counts, _dropped = self._mlp(x)
+        x, _counts, _tally = self._mlp(x)
         return x, (ck, cv)
 
 
@@ -416,19 +423,19 @@ class TrinityNet(nn.Module):
         the epoch record and to ``post_update``."""
         positions = first_position[:, None] + jnp.arange(ids.shape[1])
         x = self._embed(ids)
-        counts, dropped = [], jnp.int32(0)
+        counts, tally = [], jnp.zeros((2,), jnp.int32)
         for block in self.blocks:
             # one layer rematerialised at a time, as models/evabyte.py
-            x, c, d = nn.remat(TrinityBlock.sequence, static_argnums=(4,))(
+            x, c, t = nn.remat(TrinityBlock.sequence, static_argnums=(4,))(
                 block, x, positions, valid, no_grad_prefix)
             if c is not None:
                 counts.append(c)
-                dropped = dropped + d
+                tally = tally + t
         h = self._features(x)
         out = {'policy_features': h, 'value': self._value(h)}
         if counts:
             out['aux'] = experts.rows_aux(jnp.stack(counts), self.held,
-                                          dropped)
+                                          tally)
         return out
 
     def attention_part(self, layer: int, x, positions, valid):

@@ -371,15 +371,17 @@ def test_the_routing_of_a_block_depends_on_the_normed_input_alone():
 
 def test_the_sort_plan_is_traced_before_attention():
     """In the block's jaxpr every operation of the routing and of the sort
-    plan (the top-k, the plan's scatter) stands before the first operation
-    that only attention has (the cosine of a window layer's phases)."""
+    plan (the top-k, the plan's scatter: the only plain ``scatter``, the
+    short buffer's way back is a ``scatter-add``) stands before the first
+    operation that only attention has (the cosine of a window layer's
+    phases)."""
     net, variables = _net_and_variables(layer_types=('window',))
     ids = _ids(1, 2)
     text = str(jax.make_jaxpr(lambda v: net.apply(
         v, ids, jnp.zeros((1,), jnp.int32), jnp.ones((1, T), bool),
         method=net.sequence)['policy_features'])(variables))
     assert text.count('top_k') == 1
-    top_k, scatter = text.index('top_k'), text.rindex('scatter')
+    top_k, scatter = text.index('top_k'), text.rindex(' scatter[')
     assert top_k < scatter < text.index(' cos ')
 
 
@@ -414,7 +416,7 @@ def test_one_group_takes_every_row_and_three_none():
     0, the other three groups empty, pairs of absent experts behind them."""
     n, K, held, D, F = 12, 3, 4, 8, 6
     slot = jnp.tile(jnp.asarray([[0, held, held]], jnp.int32), (n, 1))
-    plan = experts.sort_plan(slot, held)
+    plan = experts.sort_plan(slot, held, 16)
     assert plan.groups.tolist() == [n, 0, 0, 0]
     assert int(plan.dropped) == 0 and int(plan.in_group.sum()) == n
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -477,7 +479,7 @@ def test_plan_then_dispatch_is_the_old_one_call_path_bit_for_bit(activation,
 
     @jax.jit
     def in_parts(m, slot, w):
-        plan = experts.sort_plan(slot, held)      # from the routing alone
+        plan = experts.sort_plan(slot, held, E)   # from the routing alone
         y = experts.grouped_products(experts.to_expert_order(m, plan),
                                      plan.groups, *args)
         return experts.weighted_sum_back(y, plan, slot, w, held), plan
