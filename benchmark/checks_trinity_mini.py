@@ -32,20 +32,28 @@ very weights the learner starts from.
 Statistics are RMS errors relative to the RMS of the reference; each limit
 sits in the configuration's ``tolerance`` with the readings it was set from
 (``tolerance_trinity_mini.py`` reads them and the negative controls).
+
+Nothing below ``REFERENCE`` names a net: ``_Plain``, ``forward_errors``,
+``rollout_compare``, ``step_errors`` and the two checks that only wrap them
+take the reference they compare with as the argument ``ref`` (a
+``Reference``: the two plain modules and the few facts about the net that
+the comparison needs beside them), this file's own where none is given. A
+second expert trunk's checks (``checks_smallthinker.py``) hand in theirs and
+copy nothing.
 """
 
 import functools
+import inspect
+import time
+import typing
 
 import numpy as np
 
 from . import checks
 from .checks_evabyte import (_adder, _count, _items, _leaves_by_name, _limit,
                              _verdict)
-from .reference import trinity_mini as reference
-from .reference import trinity_mini_loss as reference_loss
+from .reference import trinity_mini, trinity_mini_loss
 
-GROUPS = ('attention', 'experts', 'router', 'shared', 'mlp', 'norms', 'embed',
-          'readout')
 LR = 1e-3       # the step check's learning rate: any value, both sides use it
 CONTROLS = {'one_layer_left_out': {'skip_layer': 2},
             'experts_left_out': {'use_experts': False},
@@ -64,17 +72,61 @@ def reference_config(config):
     return cfg
 
 
+def group_of(path):
+    """A parameter's group, by its name in the tree."""
+    name = path[-1]
+    if name in ('wq', 'wk', 'wv', 'wg', 'wo', 'q_norm', 'k_norm'):
+        return 'attention'
+    if name.startswith('experts_'):
+        return 'experts'
+    if name.startswith('router'):
+        return 'router'
+    if name.startswith('shared_'):
+        return 'shared'
+    if name in ('w_gate', 'w_up', 'w_down'):
+        return 'mlp'
+    if name.startswith('norm'):
+        return 'norms'
+    return 'embed' if name == 'embed' else 'readout'
+
+
+class Reference(typing.NamedTuple):
+    """What the comparisons read of one trunk's plain reference."""
+    net: typing.Any        # the module: embed, layer, layer_kinds, readout
+    loss: typing.Any       # the module: targets, loss_of_outputs,
+    #                        first_adam_step, ADAM_B1
+    config: typing.Any     # the configuration's file -> the reference's cfg
+    embed_scale: typing.Any   # (cfg, hidden width) -> what ``net.embed``
+    #                           multiplies a looked-up row by
+    groups: tuple          # the parameters' groups, in reporting order
+    group_of: typing.Any   # a leaf's path -> its group; ``router`` is the
+    #                        group that follows rules of its own
+
+
+REFERENCE = Reference(
+    net=trinity_mini, loss=trinity_mini_loss, config=reference_config,
+    embed_scale=lambda cfg, width: width ** 0.5 / cfg['param_scale'],
+    groups=('attention', 'experts', 'router', 'shared', 'mlp', 'norms',
+            'embed', 'readout'),
+    group_of=group_of)
+
+
 class _Plain:
     """The reference as small programs, each jitted once a process and a
     kind of layer: ``layer`` (one block at ``highest`` precision), its
     vector-Jacobian product, the readout, and the loss's gradient at the
     readout. Everything a seed decides is an ARGUMENT."""
 
-    def __init__(self, cfg, skip_layer=None, use_experts=True,
-                 use_window=True, rotary_on_full=False):
+    def __init__(self, ref, cfg, skip_layer=None, **controls):
         import jax
-        self.cfg, self.skip_layer = cfg, skip_layer
-        self.kinds = reference.layer_kinds(cfg, use_window, rotary_on_full)
+        reference, reference_loss = ref.net, ref.loss
+        self.ref, self.cfg, self.skip_layer = ref, cfg, skip_layer
+        # a control is either ``layer_kinds``' (which kind of attention a
+        # layer runs) or the layer's own
+        of_kinds = set(inspect.signature(reference.layer_kinds).parameters)
+        self.kinds = reference.layer_kinds(cfg, **{
+            k: v for k, v in controls.items() if k in of_kinds})
+        layer_args = {k: v for k, v in controls.items() if k not in of_kinds}
 
         def highest(fn, **jit_args):
             def wrapped(*args, **kwargs):
@@ -84,7 +136,7 @@ class _Plain:
 
         def layer(p_layer, x, positions, valid, kind):
             return reference.layer(p_layer, x, positions, valid, cfg, kind,
-                                   use_experts)
+                                   **layer_args)
         self.layer = highest(layer, static_argnums=(4,))
         self.layer_vjp = highest(
             lambda p_layer, x, positions, valid, kind, ct: jax.vjp(
@@ -100,7 +152,7 @@ class _Plain:
             head_loss, argnums=(0, 1), has_aux=True))
         self.embed_add = jax.jit(
             lambda g, ids, ct: g.at[ids].add(
-                ct * g.shape[1] ** 0.5 / cfg['param_scale']),
+                ct * ref.embed_scale(cfg, g.shape[1])),
             donate_argnums=(0,))
 
     def kept(self):
@@ -118,7 +170,7 @@ class _Plain:
         import jax.numpy as jnp
         p = variables['params']
         positions = first + jnp.arange(ids.shape[0])
-        xs, routes = [reference.embed(p, ids, self.cfg)], []
+        xs, routes = [self.ref.net.embed(p, ids, self.cfg)], []
         for i, kind in self.kept():
             x, chosen = self.layer(p['layer_%d' % i], xs[-1], positions,
                                    valid, kind)
@@ -154,12 +206,12 @@ class _Plain:
 
 
 @functools.lru_cache(maxsize=None)
-def _plain(cfg_items, args_items=()):
-    return _Plain(dict(cfg_items), **dict(args_items))
+def _plain(ref, cfg_items, args_items=()):
+    return _Plain(ref, dict(cfg_items), **dict(args_items))
 
 
-def plain(config, reference_args):
-    return _plain(_items(reference_config(config)), _items(reference_args))
+def plain(ref, config, reference_args):
+    return _plain(ref, _items(ref.config(config)), _items(reference_args))
 
 
 def _sq(x):
@@ -204,7 +256,7 @@ def program_sequence(module):
 
 
 def forward_errors(config, module, variables, seed, program_variables=None,
-                   **reference_args):
+                   ref=REFERENCE, **reference_args):
     """The RMS errors of the program's ``sequence`` against the reference
     over the seeded windows' valid positions, and the share of the router's
     choices that agree. ``program_variables`` and ``reference_args`` are
@@ -214,7 +266,7 @@ def forward_errors(config, module, variables, seed, program_variables=None,
     ids, first, valid = seeded_windows(
         config, seed, int(config['forward_windows']),
         int(config['forward_positions']))
-    want_of = plain(config, reference_args).forward
+    want_of = plain(ref, config, reference_args).forward
     logits, value, routes = program_sequence(module)(
         variables if program_variables is None else program_variables,
         jnp.asarray(ids), jnp.asarray(first), jnp.asarray(valid))
@@ -252,16 +304,18 @@ FORWARD_LIMITS = (('logits_rms_rel_to_logit_rms', '<='), ('value_rms', '<='),
                   ('routing_agreement_share', '>='))
 
 
-def forward_check(config, variables, seed, train_args):
+def forward_check(config, variables, seed, train_args, ref=REFERENCE):
+    began = time.perf_counter()
     module = checks.build_module(config, train_args)
-    stats = forward_errors(config, module, variables, seed)
+    stats = forward_errors(config, module, variables, seed, ref=ref)
     n_params = _count(variables)
     compared = [['parameters', n_params, '==', config['model']['parameters']]]
     for name, op in FORWARD_LIMITS:
         limit = config.get('tolerance', {}).get(
             'forward_' + name, float('inf') if op == '<=' else 0.0)
         compared.append([name, stats[name], op, limit])
-    return _verdict(compared, n_params, **stats)
+    return _verdict(compared, n_params, **stats,
+                    seconds=time.perf_counter() - began)
 
 
 # -- rollout through the cache -----------------------------------------------
@@ -326,15 +380,39 @@ def rollout_records(config, module, variables, seed, train_args,
             'done': np.concatenate(done)}
 
 
-def rollout_compare(config, records, variables, **reference_args):
+def host_game_sums(got, want, window, after_reset):
+    """One game and seat, on the host in float64: the squared errors of its
+    plies' logits and values and the reference's squared logits, summed
+    over all plies, over those at positions from ``window`` on and (a game
+    begun after a reset) over all of them again. ``got`` is a ply's
+    ``[value, logits]``; ``want`` the reference's forward over the game's
+    ids, padded to whole blocks."""
+    n = len(got)
+    logits = np.asarray(want['logits'], np.float32)[:n]
+    d_logit = got[:, 1:] - logits
+    d_value = got[:, 0] - np.asarray(want['value'], np.float32)[:n]
+    square = lambda x: float(np.square(x, dtype=np.float64).sum())
+    return {name: {'logit': square(logits[keep]),
+                   'd_logit': square(d_logit[keep]),
+                   'd_value': square(d_value[keep]),
+                   'plies': len(d_value[keep])}
+            for name, keep in (
+                ('', slice(None)), ('wrapped_', slice(window, None)),
+                ('after_reset_', slice(None) if after_reset else slice(0, 0)))}
+
+
+def rollout_compare(config, records, variables, ref=REFERENCE,
+                    game_sums=host_game_sums, **reference_args):
     """Every ply's policy logits and value against the reference's full
     forward over each game's ids (``variables``: float32, what the actor's
     copy was cast from), over all plies and over two parts of them: the
     plies at positions from ``window_size`` on (``wrapped_``) and the plies
-    of games that began after a reset (``after_reset_``)."""
+    of games that began after a reset (``after_reset_``). ``game_sums`` adds
+    up one game's errors (``host_game_sums``; a net whose logits a game are
+    too many to fetch hands in one that stays on the device)."""
     import jax.numpy as jnp
     obs, out, done = records['obs'], records['out'], records['done']
-    want_of = plain(config, reference_args).forward
+    want_of = plain(ref, config, reference_args).forward
     window = config['model']['window_size']
     # one length for every game: the forward check's, so that the same
     # compiled layers serve both (causal: the padded tail is unseen)
@@ -357,22 +435,10 @@ def rollout_compare(config, records, variables, **reference_args):
                 ids[:b - a] = obs[a:b, n, seat]   # causal: the tail is unseen
                 want = want_of(variables, jnp.asarray(ids), jnp.int32(0),
                                jnp.ones(ids.shape, bool))
-                logits = np.asarray(want['logits'], np.float32)[:b - a]
-                d_logit = out[a:b, n, seat, 1:] - logits
-                d_value = out[a:b, n, seat, 0] - np.asarray(
-                    want['value'], np.float32)[:b - a]
-                for name, keep in (('', slice(None)),
-                                   ('wrapped_', slice(window, None)),
-                                   ('after_reset_',
-                                    slice(None) if a else slice(0, 0))):
-                    part = parts[name]
-                    part['logit'] += float(np.square(
-                        logits[keep], dtype=np.float64).sum())
-                    part['d_logit'] += float(np.square(
-                        d_logit[keep], dtype=np.float64).sum())
-                    part['d_value'] += float(np.square(
-                        d_value[keep], dtype=np.float64).sum())
-                    part['plies'] += len(d_value[keep])
+                sums = game_sums(out[a:b, n, seat], want, window, a > 0)
+                for name, part in parts.items():
+                    for key in part:
+                        part[key] += float(sums[name][key])
     stats = {'plies': int(len(done)), 'games': games,
              'sequences': int(obs.shape[1] * obs.shape[2]),
              'resets': int(done.sum()),
@@ -391,14 +457,15 @@ def rollout_compare(config, records, variables, **reference_args):
 
 
 def rollout_errors(config, module, variables, seed, train_args,
-                   actor_dtype=None, reference_variables=None,
-                   **reference_args):
+                   actor_dtype=None, reference_variables=None, **compare_args):
+    """``compare_args``: ``rollout_compare``'s own (``ref``, ``game_sums``)
+    and the reference's negative controls."""
     records = rollout_records(config, module, variables, seed, train_args,
                               actor_dtype)
     return rollout_compare(
         config, records,
         variables if reference_variables is None else reference_variables,
-        **reference_args)
+        **compare_args)
 
 
 ROLLOUT_LIMITS = ('logits_rms_rel_to_logit_rms', 'value_rms',
@@ -407,9 +474,11 @@ ROLLOUT_LIMITS = ('logits_rms_rel_to_logit_rms', 'value_rms',
                   'after_reset_value_rms')
 
 
-def rollout_check(config, variables, seed, train_args):
+def rollout_check(config, variables, seed, train_args, **compare_args):
+    began = time.perf_counter()
     module = checks.build_module(config, train_args)
-    stats = rollout_errors(config, module, variables, seed, train_args)
+    stats = rollout_errors(config, module, variables, seed, train_args,
+                           **compare_args)
     compared = [[name, stats[name], '<=', _limit(config, 'rollout_' + name)]
                 for name in ROLLOUT_LIMITS]
     lanes = int(config['rollout_envs'])
@@ -419,28 +488,11 @@ def rollout_check(config, variables, seed, train_args):
         ['distinct_counters', stats['distinct_counters'], '>=',
          min(lanes, 4)],
         ['wrapped_plies', stats['wrapped_plies'], '>=', 2]]
-    return _verdict(compared, _count(variables), **stats)
+    return _verdict(compared, _count(variables), **stats,
+                    seconds=time.perf_counter() - began)
 
 
 # -- one update ----------------------------------------------------------------
-def group_of(path):
-    """A parameter's group, by its name in the tree."""
-    name = path[-1]
-    if name in ('wq', 'wk', 'wv', 'wg', 'wo', 'q_norm', 'k_norm'):
-        return 'attention'
-    if name.startswith('experts_'):
-        return 'experts'
-    if name.startswith('router'):
-        return 'router'
-    if name.startswith('shared_'):
-        return 'shared'
-    if name in ('w_gate', 'w_up', 'w_down'):
-        return 'mlp'
-    if name.startswith('norm'):
-        return 'norms'
-    return 'embed' if name == 'embed' else 'readout'
-
-
 def seeded_batch(config, seed, train_args):
     """``batch_size`` solo-layout windows of the token game as the windower
     stores them, the legal set as bits, from seeded ids, actions and
@@ -492,7 +544,7 @@ def seeded_batch(config, seed, train_args):
     return batch, windows
 
 
-def _leaf_sums(change, moment, grads, params, lr, norm):
+def _leaf_sums(reference_loss, change, moment, grads, params, lr, norm):
     """A leaf: the squared error and the squared size of the gradient (the
     program's, read from Adam's first moment) and of the change, and how
     many elements moved the other way. Scalars only leave the program."""
@@ -515,7 +567,16 @@ def _leaf_sums(change, moment, grads, params, lr, norm):
 
 
 def step_errors(config, module, variables, seed, train_args,
-                program_variables=None, **reference_args):
+                program_variables=None, ref=REFERENCE, park_on_host=False,
+                **reference_args):
+    """One update of the program's own step against the reference's
+    gradient and first Adam step. What a net has beyond the shared numbers
+    is read where the program gives it: a router's bias (``router_bias``
+    leaves) against the reference's rule, the step's counters of positions
+    past an attention window (``diag_window_positions_*``). ``park_on_host``
+    moves the program's change and first moment to the host while the
+    reference works (a long window's vector-Jacobian product needs the
+    room)."""
     import jax
     import jax.numpy as jnp
     from handyrl_tpu.config import apply_defaults
@@ -544,22 +605,29 @@ def step_errors(config, module, variables, seed, train_args,
     bias_after = {name: np.asarray(layer['router_bias'], np.float64)
                   for name, layer in state.params['params'].items()
                   if isinstance(layer, dict) and 'router_bias' in layer}
+    router_moved = max(
+        float(jnp.abs(layer['router']).max())
+        for layer in change['params'].values()
+        if isinstance(layer, dict) and 'router' in layer)
     del state
+    if park_on_host:
+        change, moment = jax.device_get((change, moment))
 
     # the reference: numpy targets, then jax.vjp of the plain loss, summed
     # over the batch's windows
-    ref = plain(config, reference_args)
+    plain_ref = plain(ref, config, reference_args)
     total, terms, routes = 0.0, {}, []
     grads = jax.tree_util.tree_map(jnp.zeros_like, variables['params'])
     for window in windows:
         win = {k: jnp.asarray(v) for k, v in window.items()}
-        out = ref.forward(variables, win['ids'], win['first_position'],
-                          win['valid'] > 0)
+        out = plain_ref.forward(variables, win['ids'], win['first_position'],
+                                win['valid'] > 0)
         routes.append(np.stack([np.asarray(r) for r in out['routes']]))
-        value_target, advantage = reference_loss.targets(
+        value_target, advantage = ref.loss.targets(
             {'logits': out['logits'], 'value': out['value']}, window,
             cfg.lmb)
-        one, its_terms, grads = ref.loss_and_grad(
+        del out
+        one, its_terms, grads = plain_ref.loss_and_grad(
             variables, win, jnp.asarray(value_target, jnp.float32),
             jnp.asarray(advantage, jnp.float32),
             jnp.float32(cfg.entropy_regularization),
@@ -570,36 +638,79 @@ def step_errors(config, module, variables, seed, train_args,
     grads = {'params': grads}
     norm = float(sum(float(_sq(g)) for g in
                      jax.tree_util.tree_leaves(grads))) ** 0.5
-    sums = jax.jit(_leaf_sums)(change, moment, grads, variables,
-                               jnp.float32(LR), jnp.float32(norm))
-    router_moved = max(
-        float(jnp.abs(layer['router']).max())
-        for layer in change['params'].values()
-        if isinstance(layer, dict) and 'router' in layer)
+    sums = jax.jit(functools.partial(_leaf_sums, ref.loss))(
+        change, moment, grads, variables, jnp.float32(LR), jnp.float32(norm))
     del grads, change, moment
     leaves = {name: {k: float(v) for k, v in leaf.items()}
               for name, leaf in _leaves_by_name(sums).items()}
-    # the router's two leaves follow rules of their own, held below
-    ruled = [n for n in leaves if group_of(n.split('/')) == 'router']
-    adam = {n: leaf for n, leaf in leaves.items() if n not in ruled}
+    # the router's leaves follow rules of their own, held below
+    adam = {n: leaf for n, leaf in leaves.items()
+            if ref.group_of(n.split('/')) != 'router'}
     small = [n for n in adam if adam[n]['small']]
 
     def rel(err, refkey, names=None):
         picked = [adam[n] for n in (adam if names is None else names)]
         return (sum(x[err] for x in picked)
                 / max(sum(x[refkey] for x in picked), 1e-30)) ** 0.5
-    groups = {g: [n for n in adam if group_of(n.split('/')) == g]
-              for g in GROUPS}
+    groups = {g: [n for n in adam if ref.group_of(n.split('/')) == g]
+              for g in ref.groups}
     worst_grad = max(adam, key=lambda n: rel('grad_err', 'grad', [n]))
     worst_change = max(adam, key=lambda n: rel('change_err', 'change', [n]))
+    stats = {
+        'loss_rel_err': abs(metrics['total'] - total)
+        / max(abs(total), 1e-9),
+        'grad_norm_rel_err': abs(metrics['diag_grad_norm'] - norm)
+        / max(norm, 1e-9),
+        'grad_err_rel_to_grad': rel('grad_err', 'grad'),
+        'grad_err_worst_leaf': rel('grad_err', 'grad', [worst_grad]),
+        'change_err_rel_to_change': rel('change_err', 'change'),
+        'change_err_worst_leaf': rel('change_err', 'change', [worst_change]),
+        'small_grad_err_rel_to_grad': rel('grad_err', 'grad', small),
+        'small_change_err_rel_to_change': rel('change_err', 'change', small),
+        'small_moved_rel_to_change': rel('moved', 'change', small),
+        'readout_change_err_rel_to_change': rel('change_err', 'change',
+                                                groups['readout']),
+        'router_moved_max_abs': router_moved,
+        'rows_held_share': metrics['diag_moe_rows_held']
+        / max(metrics['diag_moe_rows_routed'], 1.0),
+        'rows_dropped': metrics['diag_moe_rows_dropped'],
+        'worst_leaves': {'grad': worst_grad, 'change': worst_change},
+        'small_leaves': len(small),
+        'loss': metrics['total'], 'reference_loss': total,
+        'terms': {k: [metrics.get(k), v] for k, v in terms.items()},
+        'grad_norm': metrics['diag_grad_norm'], 'reference_grad_norm': norm,
+        'grad_err_rel_by_group': {
+            g: rel('grad_err', 'grad', groups[g]) for g in ref.groups
+            if groups[g]},
+        'change_err_rel_by_group': {
+            g: rel('change_err', 'change', groups[g]) for g in ref.groups
+            if groups[g]},
+        'nonfinite': metrics['nonfinite'],
+        'windows': len(windows),
+        'positions': [int(w['valid'].sum()) for w in windows],
+        'change_sign_flipped_share': sum(
+            x['flipped'] for x in adam.values())
+        / sum(x['size'] for x in adam.values()),
+    }
+    if 'diag_window_positions_valid' in metrics:
+        stats['positions_hidden_share'] = (
+            metrics['diag_window_positions_hidden']
+            / max(metrics['diag_window_positions_valid'], 1.0))
+    if bias_after:
+        stats.update(_bias_against_the_rule(
+            ref.loss, model, start['params'], bias_after, routes))
+    return stats
 
-    # ``b``: its change is the rule's for SOME vector of signs (exact: the
-    # program's choices differ from the reference's on ~1% of the pairs, so
-    # an expert whose count lies at the mean may take either sign), and
-    # that vector is the reference's wherever the reference's count is
-    # clear of the mean
+
+def _bias_against_the_rule(reference_loss, model, start_p, bias_after,
+                           routes):
+    """``b``: its change is the rule's for SOME vector of signs (exact: the
+    program's choices differ from the reference's on ~1% of the pairs, so
+    an expert whose count lies at the mean may take either sign), and that
+    vector is the reference's wherever the reference's count is clear of
+    the mean."""
     rate = model['bias_update_rate']
-    start_p, ref_counts = start['params'], None
+    ref_counts = None
     if len(routes[0]) == len(bias_after):   # no layer left out (a control)
         ref_counts = reference_loss.expert_counts(
             np.concatenate(routes, axis=1), model['experts_published'])
@@ -616,46 +727,11 @@ def step_errors(config, module, variables, seed, train_args,
             want = np.sign(c.mean() - c)
             wrong_signs += int((signs != want)[clear].sum())
             sign_agree.append(np.mean(signs == want))
-    stats = {
-        'loss_rel_err': abs(metrics['total'] - total)
-        / max(abs(total), 1e-9),
-        'grad_norm_rel_err': abs(metrics['diag_grad_norm'] - norm)
-        / max(norm, 1e-9),
-        'grad_err_rel_to_grad': rel('grad_err', 'grad'),
-        'grad_err_worst_leaf': rel('grad_err', 'grad', [worst_grad]),
-        'change_err_rel_to_change': rel('change_err', 'change'),
-        'change_err_worst_leaf': rel('change_err', 'change', [worst_change]),
-        'small_grad_err_rel_to_grad': rel('grad_err', 'grad', small),
-        'small_change_err_rel_to_change': rel('change_err', 'change', small),
-        'small_moved_rel_to_change': rel('moved', 'change', small),
-        'router_moved_max_abs': router_moved,
-        'bias_err_max_abs': bias_err,
-        'bias_signs_against_reference': wrong_signs,
-        'bias_sign_agrees_with_reference_share':
-            float(np.mean(sign_agree)) if sign_agree else None,
-        'bias_layers': len(bias_after),
-        'rows_held_share': metrics['diag_moe_rows_held']
-        / max(metrics['diag_moe_rows_routed'], 1.0),
-        'rows_dropped': metrics['diag_moe_rows_dropped'],
-        'worst_leaves': {'grad': worst_grad, 'change': worst_change},
-        'small_leaves': len(small),
-        'loss': metrics['total'], 'reference_loss': total,
-        'terms': {k: [metrics.get(k), v] for k, v in terms.items()},
-        'grad_norm': metrics['diag_grad_norm'], 'reference_grad_norm': norm,
-        'grad_err_rel_by_group': {
-            g: rel('grad_err', 'grad', groups[g]) for g in GROUPS
-            if groups[g]},
-        'change_err_rel_by_group': {
-            g: rel('change_err', 'change', groups[g]) for g in GROUPS
-            if groups[g]},
-        'nonfinite': metrics['nonfinite'],
-        'windows': len(windows),
-        'positions': [int(w['valid'].sum()) for w in windows],
-        'change_sign_flipped_share': sum(
-            x['flipped'] for x in adam.values())
-        / sum(x['size'] for x in adam.values()),
-    }
-    return stats
+    return {'bias_err_max_abs': bias_err,
+            'bias_signs_against_reference': wrong_signs,
+            'bias_sign_agrees_with_reference_share':
+                float(np.mean(sign_agree)) if sign_agree else None,
+            'bias_layers': len(bias_after)}
 
 
 STEP_LIMITS = ('loss_rel_err', 'grad_norm_rel_err', 'grad_err_rel_to_grad',
@@ -665,6 +741,7 @@ STEP_LIMITS = ('loss_rel_err', 'grad_norm_rel_err', 'grad_err_rel_to_grad',
 
 
 def step_check(config, variables, seed, train_args):
+    began = time.perf_counter()
     module = checks.build_module(config, train_args)
     stats = step_errors(config, module, variables, seed, train_args)
     compared = [[name, stats[name], '<=', _limit(config, 'step_' + name)]
@@ -680,4 +757,5 @@ def step_check(config, variables, seed, train_args):
         ['rows_dropped', stats['rows_dropped'], '==', 0.0],
         ['nonfinite', stats['nonfinite'], '==', 0.0],
         ['windows', stats['windows'], '==', int(train_args['batch_size'])]]
-    return _verdict(compared, _count(variables), **stats)
+    return _verdict(compared, _count(variables), **stats,
+                    seconds=time.perf_counter() - began)

@@ -3,14 +3,23 @@ the pins on what the checkout ships, as functions of a ``Manifest`` too.
 
 The contracts hold for any number of configurations and cells. The pins name
 what they pin (four cells, two configurations, nine metrics, what each trunk
-cell brought of its own, the turnaround four) BY NAME and reach no further:
-none indexes ``configs``, ``workloads`` or ``per_layer`` by position past the
-first four cells, so they hold on a root that has MORE than the checkout and
-a later PR that appends entries and adds files edits none of them
-(``test_bench_open_for_additions.py`` is the guard). tests/benchmark runs both
-on the checkout and on ``fixture/make_root.build()``'s root, which has one
-more cell of a seeded configuration with checks, a FLOP count, a traffic mix,
-a hook, a reader, a metric and a rehearsal overlay of its own.
+cell brought of its own, what the two expert cells share, the turnaround
+four) BY NAME and reach no further: none indexes ``configs``, ``workloads``
+or ``per_layer`` by position past a prefix (a prefix never moves under
+appending), and none from the END, so they hold on a root that has MORE than
+the checkout and a later PR that appends entries and adds files edits none
+of them (``test_bench_open_for_additions.py`` is the guard; ``conftest.py``'s
+``either_root`` takes every configuration's own tests there too).
+tests/benchmark runs both on the checkout and on ``fixture/make_root``'s
+roots, which have one more cell of a seeded configuration with checks, a
+FLOP count, a traffic mix, a hook, a reader, a metric and a rehearsal overlay
+of its own.
+
+An entry that a later PR may extend to a further cell is pinned as "lists
+these cells, in this order" (a prefix of its ``workloads``), not as "lists
+them alone": ``EXPERT_SHARED``. An entry that reads what ONE net's program
+names (a scope of its own layers, a counter of its own) lists that cell
+alone: ``OWN``.
 
 A reading that every cell's program gives (a scope of the module every cell
 runs, a span every fused iteration opens, the whole step's share of the peak)
@@ -61,8 +70,30 @@ OWN = {
         'state_cache_gib', 'trunk_eval_share_ms'],
     'trinity_mini.moe_selfplay_4k': [
         'moe_experts_ms', 'moe_experts_roofline', 'moe_route_ms',
-        'moe_rows_held_share', 'moe_load_max_over_mean', 'gqa_attention_ms',
-        'gqa_attention_roofline', 'trinity_optimizer_ms'],
+        'gqa_attention_ms', 'gqa_attention_roofline'],
+    'smallthinker.moe_selfplay_8k': [
+        'pre_route_ms', 'expert_dispatch_ms', 'reglu_experts_ms',
+        'reglu_experts_roofline', 'window_attention_ms',
+        'window_attention_roofline', 'global_attention_ms',
+        'global_attention_roofline', 'window_hidden_position_share'],
+}
+# the first seven cells and five configurations, in the file's order: a
+# prefix, which appending never moves
+SEVEN = FOUR + list(OWN)
+FIVE = PAIR + ['evabyte', 'trinity_mini', 'smallthinker']
+# what both expert cells' programs give under one name (``models/experts.py``'
+# counters on ``host_block``, the scope ``optimizer`` of ``ops/train_step.py``):
+# (reader, the argument that says what it reads). Each lists both cells, in
+# this order; a third expert cell may be appended behind them
+EXPERT_CELLS = ['trinity_mini.moe_selfplay_4k', 'smallthinker.moe_selfplay_8k']
+EXPERT_SHARED = {
+    'moe_rows_held_share': ('program_counter_ratio',
+                            ('numerator', 'moe_rows_held')),
+    'moe_load_max_over_mean': ('program_counter_ratio',
+                               ('denominator', 'moe_rows_held')),
+    'optimizer_ms': ('trace_inner_scope_time', ('scope', 'optimizer')),
+    'expert_short_buffer_share': ('program_counter_ratio',
+                                  ('numerator', 'moe_dispatches_short')),
 }
 # the loop's turnaround (PR 40), counters on ``fused_iter``: (unit, layer,
 # numerator, denominator, scale, the cells it lists). The two shares divide
@@ -254,6 +285,16 @@ def the_first_four_cells(manifest):
         assert manifest.cells[name]['chips'] == 1
 
 
+def the_first_seven_cells_and_five_configurations(manifest):
+    """Prefixes of ``workloads`` and ``configs``: what stands behind them is
+    a later PR's and no concern of this pin."""
+    assert list(manifest.cells)[:7] == SEVEN
+    assert list(manifest.configs)[:5] == FIVE
+    for name in SEVEN:
+        assert manifest.cells[name]['chips'] == 1
+        assert manifest.cells[name]['config'] == name.split('.')[0]
+
+
 def the_pair_states_all_three_explicitly(manifest, name):
     assert name in PAIR
     config = manifest.load_config(name)
@@ -354,6 +395,25 @@ def a_cells_own_metric(manifest, cell, name):
     assert spec['what']
 
 
+def an_expert_cells_shared_metric(manifest, name):
+    """Lists both expert cells, in this order, and maybe more behind them;
+    named for no net, and read where both programs put it."""
+    reader, (key, value) = EXPERT_SHARED[name]
+    entry = manifest.metrics[name]
+    assert entry['workloads'][:len(EXPERT_CELLS)] == EXPERT_CELLS
+    assert (entry['layer'], entry['moves']) == ('update step',
+                                                'train_windows_per_s')
+    spec = manifest.load_metric(name)           # raises where they disagree
+    assert spec['reader'] == reader and spec['args'][key] == value
+    assert spec['what']
+    assert not name.startswith(tuple(
+        cell.split('.')[0] for cell in EXPERT_CELLS))
+    for cell in EXPERT_CELLS:
+        assert name in manifest.metrics_of(cell, 'per_layer')
+    for cell in FOUR + ['evabyte.selfplay_4k']:
+        assert name not in manifest.metrics_of(cell)
+
+
 def a_turnaround_metric(manifest, name):
     unit, layer, top, bottom, scale, cells = TURNAROUND[name]
     spec = manifest.load_metric(name)     # raises where file and entry differ
@@ -372,12 +432,15 @@ def a_turnaround_metric(manifest, name):
 
 # every pin as (function, further arguments): one case each wherever a test
 # is parametrised by pin
-PINS = ([(the_first_four_cells, ()), (the_whole_steps_share_is_every_cells, ())]
+PINS = ([(the_first_four_cells, ()),
+         (the_first_seven_cells_and_five_configurations, ()),
+         (the_whole_steps_share_is_every_cells, ())]
         + [(the_pair_states_all_three_explicitly, (name,)) for name in PAIR]
         + [(one_of_the_nine_agrees_with_its_entry, (name,)) for name in NINE]
         + [(a_cells_own_metrics_are_its_entries, (cell,)) for cell in OWN]
         + [(a_cells_own_metric, (cell, name))
            for cell, names in OWN.items() for name in names]
+        + [(an_expert_cells_shared_metric, (name,)) for name in EXPERT_SHARED]
         + [(a_turnaround_metric, (name,)) for name in TURNAROUND])
 
 

@@ -9,6 +9,14 @@ of its own), the traffic mix ``fixture_mix``, the cell of the two, the hook
 rehearsal overlay ``rehearsal/seeded_toy.json``. No shipped entry, list or
 file is changed: tests/benchmark holds every contract on this root too.
 
+``grow`` puts one more metric file and entry behind that, for a cell the
+checkout already HAS: the root a checkout becomes once later PRs have added
+one of each kind. ``conftest.py`` hands it to every test that takes a
+manifest (``grown``, and ``either_root`` beside the checkout), so a pin that
+holds only while nothing stands behind it fails in the PR that writes it.
+This fixture's own tests (``test_bench_fixture.py``) are the template for a
+configuration's test file.
+
     python3 tests/benchmark/fixture/make_root.py <dest> [--third-check-fails]
 
 then ``benchmark/rehearse.py --root <dest> --workload seeded_toy.fixture_mix``
@@ -25,6 +33,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(HERE)))
 CONFIG, TRAFFIC = 'seeded_toy', 'fixture_mix'
 CELL = CONFIG + '.' + TRAFFIC
 HOOK, READER, METRIC = 'fixture_probe', 'fixture_count', 'fixture_probe_calls'
+# ``grow``'s one more entry, for a cell the checkout HAS, as a program PR that
+# adds a counter to a shipped cell would append it
+SECOND, ITS_CELL = 'toy_boundaries_in_a_shipped_cell', 'evabyte.selfplay_4k'
 # what the overlay sets that tiny.json does not, each to a value of its own
 OVERLAY = {'model': {'flops_per_window': 77},
            'config': {'reference_envs': 3, 'reference_plies': 2},
@@ -104,6 +115,28 @@ def build(dest, third_check_ok=True):
 
     _write(raw, dest, 'BENCHMARK.json')
     return dest
+
+
+def append_entry(root, entry):
+    raw = _read(root, 'BENCHMARK.json')
+    raw['per_layer'].append(entry)
+    _write(raw, root, 'BENCHMARK.json')
+
+
+def grow(dest):
+    """``build``'s root (a configuration, its cell, a traffic file, a hook,
+    a reader, a metric file and its entry, all appended) and, behind that,
+    one more metric file and entry that lists a shipped cell alone."""
+    root = build(dest)
+    entry = {'name': SECOND, 'unit': 'calls', 'better': 'higher',
+             'source': 'program_counter',
+             'layer': 'param publish, checkpoint',
+             'moves': 'train_windows_per_s', 'workloads': [ITS_CELL]}
+    _write(dict(entry, reader=READER, args={'span': 'epoch_boundary'},
+                what='guard: the boundaries that ended in the window'),
+           root, 'benchmark', 'metrics', SECOND + '.json')
+    append_entry(root, entry)
+    return root
 
 
 if __name__ == '__main__':

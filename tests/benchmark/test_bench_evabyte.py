@@ -4,7 +4,14 @@ count by brute force, the numbers a derived metric reads against the
 function that gives them, the reader of a scope inside a loop, and its
 checks' negative controls at the rehearsal's size. (That the cell rehearses
 with ``correct`` true is test_bench_rehearsal's, which runs every cell of
-the manifest.)"""
+the manifest.)
+
+Every test that takes ``cell`` runs twice: on the checkout and on the root
+later PRs will have grown it into (``conftest.py``'s ``either_root``), so a
+pin that indexes a list from its end (``raw['per_layer'][-13:]`` stood here
+from PR 34 to PR 41) fails in the PR that writes it. On the checkout alone:
+the tests that take ``tiny`` (they lay out a rehearsal root of the checkout
+and run the checks at its size) and those that take neither fixture."""
 
 import json
 import os
@@ -29,8 +36,8 @@ CUT = {'num_hidden_layers': (32, 4), 'num_attention_heads': (32, 8),
 
 
 @pytest.fixture(scope='module')
-def cell():
-    manifest = Manifest()
+def cell(either_root):
+    manifest = either_root
     config = manifest.load_config('evabyte')
     traffic = manifest.load_traffic('selfplay_4k')
     train_args = dict(traffic['train_args'], **config['train_args'])

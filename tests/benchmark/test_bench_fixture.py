@@ -3,7 +3,17 @@ held on a fixture configuration that is no entry of ``BENCHMARK.json``
 (``fixture/seeded_toy.json``: seeded weights, a FLOP count of its own and a
 third check) in a cell that brings its own traffic mix, hook, reader, metric
 and rehearsal overlay (``fixture/make_root.py``), run through the harness's
-one path on the CPU rehearsal, and on the loader's refusals."""
+one path on the CPU rehearsal, and on the loader's refusals.
+
+THE TEMPLATE for a configuration's own test file (benchmark/README.md,
+"Adding a configuration and its cell"): a test that reads a manifest takes
+it from ``conftest.py``'s ``either_root`` (the checkout, then the root later
+PRs will have grown it into) or from a root this fixture builds, and never
+from ``Manifest()`` alone; what it pins it names (``contracts.PAIR``,
+``make_root.CONFIG``) or finds by its kind (``_with_a_checkpoint``), and
+nothing here indexes ``configs``, ``workloads`` or ``per_layer`` from the
+end. On the checkout alone: what starts a subprocess (``rehearsed`` and the
+two runs with something broken) and the checkpoint's bytes."""
 
 import json
 import os
@@ -213,13 +223,15 @@ def test_weights_are_a_checkpoint_or_seeded_and_not_both(tmp_path, weights):
 
 
 @pytest.mark.parametrize('name', PAIR)
-def test_shipped_configurations_state_all_three_explicitly(name):
-    contracts.the_pair_states_all_three_explicitly(Manifest(), name)
+def test_shipped_configurations_state_all_three_explicitly(name,
+                                                           either_root):
+    contracts.the_pair_states_all_three_explicitly(either_root, name)
 
 
 @pytest.mark.parametrize('name', list(Manifest().configs))
-def test_any_configuration_states_its_weights_checks_and_flops(name):
-    contracts.any_configuration(Manifest(), name)
+def test_any_configuration_states_its_weights_checks_and_flops(name,
+                                                               either_root):
+    contracts.any_configuration(either_root, name)
 
 
 def test_a_seeded_configuration_states_them_too(fifth):
@@ -233,8 +245,10 @@ def _with_a_checkpoint(manifest):
             if 'checkpoint' in manifest.load_config(name)['weights']]
 
 
-def test_the_configurations_with_a_checkpoint_are_found_by_the_kind(fifth):
-    assert _with_a_checkpoint(fifth) == PAIR == _with_a_checkpoint(Manifest())
+def test_the_configurations_with_a_checkpoint_are_found_by_the_kind(
+        fifth, either_root):
+    assert _with_a_checkpoint(fifth) == PAIR == _with_a_checkpoint(
+        either_root)
 
 
 @pytest.mark.parametrize('name', _with_a_checkpoint(Manifest()))

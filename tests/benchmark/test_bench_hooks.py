@@ -57,9 +57,29 @@ def test_every_shipped_hook_resolves():
     specs = Manifest().load_hooks(spans)
     assert {'train_dispatch', 'warm_dispatch', 'chunk_account',
             'chunk_fetch', 'epoch_boundary', 'update_model',
-            'state_fetch'} <= set(specs)
+            'eval_share'} <= set(specs)
     for span, spec in specs.items():
         hooks.resolve(spec['target'])
+
+
+def test_no_hook_is_installed_that_nothing_reads():
+    """Every hook file is named by some cell's window or metric. The hook
+    ``state_fetch`` went with PR 45: it wrapped ``utils.fetch:fetch_tree``,
+    which an enqueue-first boundary has not called since PR 35, and no
+    metric read its records; ``fetch_wait_ms`` reads the PROGRAM's span of
+    that name by ``stage``, for which the harness installs nothing."""
+    manifest = Manifest()
+    folder = os.path.join(ROOT, 'benchmark', 'hooks')
+    files = {f[:-5] for f in os.listdir(folder) if f.endswith('.json')}
+    named = set()
+    for workload, cell in manifest.cells.items():
+        window = manifest.load_traffic(cell['traffic'])['window']
+        named |= set(spans_of(manifest, workload, window))
+    assert files == named
+    assert 'state_fetch' not in files
+    spec = manifest.load_metric('fetch_wait_ms')
+    assert spec['args']['also'] == ['state_fetch']
+    assert not {'span', 'inner'} & set(spec['args'])
 
 
 @pytest.mark.parametrize('workload', list(Manifest().cells))

@@ -4,14 +4,23 @@ files with one more configuration, cell, traffic file, metric file and
 ``per_layer`` entry APPENDED, the way ``benchmark/README.md``'s "whole list"
 tells a later PR to (``fixture/make_root.build`` does exactly that, and adds
 a hook, a reader and a rehearsal overlay besides), one more entry for a cell
-the checkout already has behind that, and on it every contract,
-every pin of ``contracts.PINS`` (the first four cells, the pair, the nine,
-what each trunk cell brought of its own, the turnaround four) one case each.
+the checkout already has behind that (``fixture/make_root.grow``; the root
+is ``conftest.py``'s ``grown``), and on it every contract and every pin of
+``contracts.PINS`` (the first four cells, the pair, the nine, what each
+trunk cell brought of its own, what the two expert cells share, the
+turnaround four) one case each.
 
-A pin that indexes a list by position past the first four cells fails here:
+A pin that indexes a list by position past a prefix fails here:
 ``manifest.raw['per_layer'][-13:]`` in ``test_bench_evabyte.py`` did, from PR
 34 to PR 41, and no program PR could add a per-layer metric (CHANGES.md, PR
-42, shows this guard failing with that line put back)."""
+42, shows this guard failing with that line put back). It reached only the
+pins of ``contracts.py``, though: PR 43 wrote ``raw['per_layer'][-9:]`` into
+``test_bench_smallthinker.py``, which ran on the checkout alone, saw it pass,
+and PR 44 could not append its entry. Since PR 45 every configuration's own
+manifest tests take their manifest from ``conftest.py``'s ``either_root``,
+the checkout and then THIS root, so such a pin fails in the PR that writes
+it (CHANGES.md, PR 45, shows that run). The tests of this file check what
+``grow`` itself appended, so they alone may index from the end."""
 
 import json
 import os
@@ -22,47 +31,7 @@ from benchmark.manifest import Manifest
 
 from tests.benchmark import contracts
 from tests.benchmark.fixture import make_root
-
-# one more entry for a cell the checkout HAS, as a program PR that adds a
-# counter to a shipped cell would append it
-SECOND, ITS_CELL = 'toy_boundaries_in_a_shipped_cell', 'evabyte.selfplay_4k'
-
-
-def append_entry(root, entry):
-    path = os.path.join(root, 'BENCHMARK.json')
-    with open(path) as f:
-        raw = json.load(f)
-    raw['per_layer'].append(entry)
-    with open(path, 'w') as f:
-        json.dump(raw, f, indent=1)
-
-
-def grow(dest):
-    """``make_root.build``'s root (a configuration, its cell, a traffic
-    file, a hook, a reader, a metric file and its entry, all appended) and,
-    behind that, one more metric file and entry that lists a shipped cell
-    alone."""
-    root = make_root.build(dest)
-    entry = {'name': SECOND, 'unit': 'calls', 'better': 'higher',
-             'source': 'program_counter',
-             'layer': 'param publish, checkpoint',
-             'moves': 'train_windows_per_s', 'workloads': [ITS_CELL]}
-    with open(os.path.join(root, 'benchmark', 'metrics',
-                           SECOND + '.json'), 'w') as f:
-        json.dump(dict(entry, reader=make_root.READER,
-                       args={'span': 'epoch_boundary'},
-                       what='guard: the boundaries that ended in the window'),
-                  f)
-    append_entry(root, entry)
-    return root
-
-
-@pytest.fixture(scope='module')
-def grown(tmp_path_factory):
-    """The checkout as later PRs would leave it: everything it has, and one
-    of each kind of addition behind it."""
-    return Manifest(grow(
-        str(tmp_path_factory.mktemp('open_for_additions') / 'root')))
+from tests.benchmark.fixture.make_root import ITS_CELL, SECOND, append_entry
 
 
 def test_the_root_is_the_checkout_with_one_of_each_appended(grown, shipped):

@@ -1,11 +1,20 @@
 """The ``smallthinker`` configuration's own files: what its file states
 against the catalog's ``config`` and against the program, the manifest's
-contracts and pins on the checkout with the seventh cell, its FLOP and byte
-counts against a count by brute force and against the numbers the metrics
-read, each new metric's reader on a synthetic trace or span ring, and a
-planted fault or control for each new check at the rehearsal's size. (That
-the cell rehearses with ``correct`` true is test_bench_rehearsal's, which
-runs every cell of the manifest.)"""
+contracts and pins with the seventh cell, its FLOP and byte counts against a
+count by brute force and against the numbers the metrics read, each new
+metric's reader on a synthetic trace or span ring, and a planted fault or
+control for each new check at the rehearsal's size. (That the cell rehearses
+with ``correct`` true is test_bench_rehearsal's, which runs every cell of
+the manifest.)
+
+Every test that takes ``cell`` runs twice: on the checkout and on the root
+later PRs will have grown it into (``conftest.py``'s ``either_root``). What
+this configuration brought is pinned by NAME or as a PREFIX, never from the
+end of a list: ``test_the_entries_are_appended_and_nothing_before_them_is_
+edited`` as PR 43 wrote it (``raw['per_layer'][-9:]``) passes on the first
+root and fails on the second, which is the point. On the checkout alone:
+the tests that take ``tiny`` (they lay out a rehearsal root of the checkout
+and run the checks at its size) and those that take neither fixture."""
 
 import json
 import math
@@ -41,15 +50,12 @@ CATALOG = {'head_dim': 128, 'hidden_size': 2560,
 CUT = {'num_hidden_layers': (52, 4), 'moe_num_primary_experts': (64, 16),
        'num_attention_heads': (28, 7), 'num_key_value_heads': (4, 1),
        'vocab_size': (151936, 37984)}
-NEW = ['pre_route_ms', 'expert_dispatch_ms', 'reglu_experts_ms',
-       'reglu_experts_roofline', 'window_attention_ms',
-       'window_attention_roofline', 'global_attention_ms',
-       'global_attention_roofline', 'window_hidden_position_share']
+NEW = contracts.OWN[CELL]
 
 
 @pytest.fixture(scope='module')
-def cell():
-    manifest = Manifest()
+def cell(either_root):
+    manifest = either_root
     config = manifest.load_config('smallthinker')
     traffic = manifest.load_traffic('moe_selfplay_8k')
     train_args = dict(traffic['train_args'], **config['train_args'])
@@ -62,7 +68,6 @@ def test_contract_holds_on_the_checkout_with_the_seventh_cell(contract, cell):
     manifest = cell[0]
     assert list(manifest.cells)[6] == CELL and len(manifest.cells) >= 7
     assert manifest.cells[CELL]['chips'] == 1
-    assert not [c for c in manifest.cells.values() if c['chips'] != 1]
     contract(manifest)
 
 
@@ -73,22 +78,28 @@ def test_pin_holds_with_the_seventh_cell(pin, cell):
 
 
 def test_the_entries_are_appended_and_nothing_before_them_is_edited(cell):
+    """By name and by prefix: what PR 43 appended is found wherever it
+    stands, and what stood before it stands there still."""
     manifest = cell[0]
     raw = manifest.raw
-    assert raw['configs'][-1]['name'] == 'smallthinker'
-    assert raw['configs'][-1]['source'] == SOURCE
-    assert [c['name'] for c in raw['configs'][:4]] == [
-        'geese', 'geese_lstm', 'evabyte', 'trinity_mini']
-    assert raw['workloads'][-1] == dict(
-        raw['workloads'][-1], name=CELL, config='smallthinker',
+    assert manifest.configs['smallthinker']['source'] == SOURCE
+    contracts.the_first_seven_cells_and_five_configurations(manifest)
+    assert manifest.cells[CELL] == dict(
+        manifest.cells[CELL], name=CELL, config='smallthinker',
         traffic='moe_selfplay_8k', chips=1)
-    assert [e['name'] for e in raw['per_layer'][-len(NEW):]] == NEW
-    for entry in raw['per_layer'][-len(NEW):]:
-        assert entry['workloads'] == [CELL]
-    # the entries that list the other trunk cells list them still, alone
-    for other, names in contracts.OWN.items():
-        for name in names:
+    # the nine stand in ``per_layer`` in this order (a subsequence, not a
+    # tail), each listing this cell alone
+    names = [entry['name'] for entry in raw['per_layer']]
+    assert [name for name in names if name in NEW] == NEW
+    for name in NEW:
+        assert manifest.metrics[name]['workloads'] == [CELL]
+    # the entries that list the other trunk cells alone list them still, and
+    # what the two expert cells share lists both
+    for other, theirs in contracts.OWN.items():
+        for name in theirs:
             assert manifest.metrics[name]['workloads'] == [other]
+    for name in contracts.EXPERT_SHARED:
+        assert CELL in manifest.metrics[name]['workloads']
 
 
 def test_the_file_holds_the_catalogs_config_and_lists_each_cut(cell):
@@ -321,8 +332,10 @@ def test_each_new_metric_names_a_reader_and_the_cell(cell):
     for name in contracts.SHARED + ['fused_program_ms', 'device_idle',
                                     'hbm_peak_gib', 'env_steps_per_s']:
         assert name in reported
-    for names in contracts.OWN.values():
-        assert not set(names) & set(reported)
+    for name in contracts.EXPERT_SHARED:
+        assert name in reported
+    for other, names in contracts.OWN.items():
+        assert other == CELL or not set(names) & set(reported)
     for name, scope in (('pre_route_ms', 'pre_route'),
                         ('expert_dispatch_ms', 'expert_dispatch'),
                         ('window_attention_ms', 'window_attention'),
@@ -436,7 +449,13 @@ def test_the_rooflines_and_the_share_read_their_numbers(cell, monkeypatch):
     attrs = lambda k: {'window_positions_valid': 6000.0 * k,
                        'window_positions_hidden': 1800.0 * k,
                        # the host's own count, padding and all: not ours
-                       'window_positions': 16384 * k}
+                       'window_positions': 16384 * k,
+                       # what both expert cells' programs count
+                       'moe_rows_held': 3000.0 * k,
+                       'moe_rows_routed': 12000.0 * k,
+                       'moe_rows_fullest': 300.0 * k,
+                       'moe_dispatches': 8.0 * k,
+                       'moe_dispatches_short': 8.0 * k}
     ring = [{'name': 'host_block', 't1': 1.0, 'attrs': attrs(1)},
             {'name': 'host_block', 't1': 2.0, 'attrs': attrs(3)}]
     monkeypatch.setattr(program_counter_ratio, 'ring', lambda: ring)
@@ -446,6 +465,12 @@ def test_the_rooflines_and_the_share_read_their_numbers(cell, monkeypatch):
                      if k != 'source'})
     share = manifest.load_metric('window_hidden_position_share')
     assert program_counter_ratio.read(run, **share['args']) == 30.0
+    # the three counters' metrics this cell lists beside ``trinity_mini``'s
+    shared = lambda name: program_counter_ratio.read(
+        run, **manifest.load_metric(name)['args'])
+    assert shared('moe_rows_held_share') == 25.0       # the even share
+    assert shared('moe_load_max_over_mean') == 600.0 * 64 / 6000.0
+    assert shared('expert_short_buffer_share') == 100.0
     model = config['model']
     for scope in ('reglu_experts', 'window_attention', 'global_attention'):
         spec = manifest.load_metric(scope + '_roofline')
@@ -458,6 +483,7 @@ def test_the_rooflines_and_the_share_read_their_numbers(cell, monkeypatch):
     for record in ring:
         record['attrs'] = {'plies': 1}
     assert program_counter_ratio.read(run, **share['args']) is None
+    assert shared('expert_short_buffer_share') is None
 
 
 # -- the checks and a planted fault for each, at the rehearsal's size ----------

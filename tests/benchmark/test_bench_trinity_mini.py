@@ -6,11 +6,21 @@ give them, each new metric's reader and cell, and a planted fault for each
 new check at the rehearsal's size. (That the cell rehearses with ``correct``
 true is test_bench_rehearsal's, which runs every cell of the manifest.)
 
-The cell's eight per-layer metrics of its own are entries of the checkout's
+The cell's per-layer metrics are entries of the checkout's
 ``BENCHMARK.json`` since PR 42 (they waited in a root of their own from PR
-38 on); what every cell's program gives (``sgd_ms``, ``rollout_ms``,
-``train_mfu``, ...) it reports under the shared names, and the three twins
-PR 38 had for them are gone."""
+38 on): five read what only this net's program names (``contracts.OWN``),
+four read what both expert cells' programs give and list both under one
+name since PR 45 (``contracts.EXPERT_SHARED``; ``trinity_optimizer_ms`` is
+``optimizer_ms`` now); what every cell's program gives (``sgd_ms``,
+``rollout_ms``, ``train_mfu``, ...) it reports under the shared names, and
+the three twins PR 38 had for them are gone.
+
+Every test that takes ``cell`` runs twice: on the checkout and on the root
+later PRs will have grown it into (``conftest.py``'s ``either_root``), so a
+pin that indexes a list from its end fails here, in the PR that writes it.
+On the checkout alone: the tests that take ``tiny`` (they lay out a
+rehearsal root of the checkout and run the checks at its size) and those
+that take neither fixture."""
 
 import numpy as np
 import pytest
@@ -35,11 +45,12 @@ CUT = {'num_hidden_layers': (32, 5), 'num_dense_layers': (2, 1),
        'num_experts': (128, 16), 'num_attention_heads': (32, 8),
        'num_key_value_heads': (4, 1), 'vocab_size': (200192, 25024)}
 NEW = contracts.OWN[CELL]
+SHARED_BY_THE_EXPERT_CELLS = list(contracts.EXPERT_SHARED)
 
 
 @pytest.fixture(scope='module')
-def cell():
-    manifest = Manifest()
+def cell(either_root):
+    manifest = either_root
     config = manifest.load_config('trinity_mini')
     traffic = manifest.load_traffic('moe_selfplay_4k')
     train_args = dict(traffic['train_args'], **config['train_args'])
@@ -212,10 +223,13 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
 def test_each_new_metric_names_a_reader_and_the_cell(cell):
     manifest = cell[0]
     contracts.a_cells_own_metrics_are_its_entries(manifest, CELL)
-    assert len(NEW) == 8
+    assert len(NEW) == 5 and len(SHARED_BY_THE_EXPERT_CELLS) == 4
     for name in NEW:
         contracts.a_cells_own_metric(manifest, CELL, name)
+    for name in SHARED_BY_THE_EXPERT_CELLS:
+        contracts.an_expert_cells_shared_metric(manifest, name)
     reported = manifest.metrics_of(CELL, 'per_layer')
+    assert 'trinity_optimizer_ms' not in manifest.metrics
     for name in ('fused_program_ms', 'env_steps_per_s', 'episodes_per_s',
                  'plies_per_episode', 'chunk_max_ms', 'device_idle',
                  'hbm_peak_gib'):
@@ -228,7 +242,7 @@ def test_each_new_metric_names_a_reader_and_the_cell(cell):
     for name, scope in (('moe_experts_ms', 'moe_experts'),
                         ('moe_route_ms', 'moe_route'),
                         ('gqa_attention_ms', 'gqa_attention'),
-                        ('trinity_optimizer_ms', 'optimizer')):
+                        ('optimizer_ms', 'optimizer')):
         spec = manifest.load_metric(name)
         # the grouped products reach the trace under the compiler's own
         # names and without a scope path: the experts' reader counts both
@@ -238,8 +252,12 @@ def test_each_new_metric_names_a_reader_and_the_cell(cell):
         assert spec['args']['scope'] == scope
     assert manifest.load_metric('moe_experts_ms')['args']['kernels'] \
         == ['ragged-dot']
-    for name in ('moe_rows_held_share', 'moe_load_max_over_mean'):
+    for name in ('moe_rows_held_share', 'moe_load_max_over_mean',
+                 'expert_short_buffer_share'):
         assert manifest.load_metric(name)['reader'] == 'program_counter_ratio'
+    # named for no net, and no parameter count in its text: both cells' is it
+    what = manifest.load_metric('optimizer_ms')['what']
+    assert 'optimizer' in what and '603' not in what and '594' not in what
 
 
 # one execution of the module, 0..100 us: a while that holds a fusion under
@@ -307,7 +325,9 @@ def test_the_counter_ratios_read_the_pipelines_sums(cell, monkeypatch):
     manifest, config, traffic, args = cell
     attrs = lambda k: {'moe_rows_held': 1000.0 * k,
                        'moe_rows_routed': 8000.0 * k,
-                       'moe_rows_fullest': 40.0 * k}
+                       'moe_rows_fullest': 40.0 * k,
+                       'moe_dispatches': 8.0 * k,
+                       'moe_dispatches_short': 8.0 * k - (k > 1)}
     ring = [{'name': 'host_block', 't1': 1.0, 'attrs': attrs(1)},
             {'name': 'host_block', 't1': 2.0, 'attrs': attrs(3)}]
     monkeypatch.setattr(program_counter_ratio, 'ring', lambda: ring)
@@ -316,10 +336,13 @@ def test_the_counter_ratios_read_the_pipelines_sums(cell, monkeypatch):
         run, **manifest.load_metric(name)['args'])
     assert read('moe_rows_held_share') == 12.5
     assert read('moe_load_max_over_mean') == 80.0 * 64 / 2000.0
+    # 16 layer-steps in the window, one through the every-pair buffer
+    assert read('expert_short_buffer_share') == 100 * 15.0 / 16.0
     # a program without the sums (the parent's): nothing to read, no error
     for record in ring:
         record['attrs'] = {'plies': 1}
     assert read('moe_rows_held_share') is None
+    assert read('expert_short_buffer_share') is None
 
 
 # -- the checks and a planted fault for each, at the rehearsal's size ----------
