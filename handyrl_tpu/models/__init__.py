@@ -22,7 +22,8 @@ def build(name: str, **kwargs):
     if name not in _REGISTRY:
         # lazily import the built-in model modules, which self-register
         from . import (tictactoe, geister, geese, transformer,  # noqa: F401
-                       connect_four, evabyte, trinity, smallthinker)
+                       connect_four, evabyte, trinity, smallthinker,
+                       ouro)
     return _REGISTRY[name](**kwargs)
 
 

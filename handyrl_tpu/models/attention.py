@@ -4,6 +4,8 @@ and V rows. A layer either sees everything before a query (``window`` None)
 or the ``window`` keys up to and with the query's own. What comes before
 (projections, norms, phases) and after (gates, ``W_o``) is the net's, as are
 the scopes these run under (``models/trinity.py``, ``models/smallthinker.py``).
+A net that runs its layers several times (``models/ouro.py``) keeps K and V
+of every (pass, layer): ``init_pass_cache``, ``pass_write``, ``pass_rows``.
 """
 
 import jax
@@ -92,3 +94,51 @@ def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):
     y = jnp.einsum('bkgr,brkd->bkgd', prob, rows(cv),
                    preferred_element_type=f32).astype(dtype)
     return y.reshape(B, -1)
+
+
+def init_pass_cache(batch_shape, passes, rows, width, dtype):
+    """K and V of every (pass, layer) of a net that runs its layers
+    ``passes`` times: layer i's buffer holds the passes' ``rows[i]`` rows one
+    behind the other (pass t's are rows ``t * rows[i]`` and on), so a layer
+    and sequence stay ONE buffer whatever the pass; ONE counter a sequence,
+    reset as ``reset_cache`` resets it."""
+    return init_cache(batch_shape, [passes * n for n in rows], width, dtype)
+
+
+def pass_write(ck, cv, k, v, pos, t, rows):
+    """``cache_write`` into pass ``t``'s rows of a layer's buffers: the
+    counter never reaches ``rows`` (a full-length cache)."""
+    return cache_write(ck, cv, k, v, t * rows + pos)
+
+
+def pass_rows(c, t, rows):
+    """Pass ``t``'s rows of a layer's buffer (B, passes * rows, width): what
+    ``cache_attention`` reads in that pass, (B, rows, width)."""
+    return jax.lax.dynamic_slice_in_dim(c, t * rows, rows, axis=1)
+
+
+def side_by_side_attention(q, ck, cv, pos, dtype):
+    """``cache_attention`` for a layer WITHOUT grouping (one query head a KV
+    head) and no circle, on the buffers as they lie: q (B, H, d) over ck, cv
+    (B, rows, H * d) -> (B, H * d). With one query row a head the score is a
+    matrix-vector product, which the chip's compiler takes apart into float32
+    multiplies and sums over a float32 copy of the rows (9 passes over HBM
+    for one, PERF.md, PR 46). So the H queries go as ONE matrix against a
+    sequence's rows: row h holds ``q[h]`` at head h's columns and zeros at
+    the others', padded to 8 rows, and head h's output is block h of row h.
+    The rows are read once, in bfloat16, with no relayout."""
+    B, H, d = q.shape
+    n_rows = ck.shape[1]
+    assert ck.shape[2] == H * d, (q.shape, ck.shape)
+    m = -(-H // 8) * 8
+    wide = jnp.einsum('mh,bhd->bmhd', jnp.eye(m, H, dtype=q.dtype),
+                      q).reshape(B, m, H * d)
+    s = d ** -0.5 * jnp.einsum('bmc,brc->bmr', wide, ck,
+                               preferred_element_type=f32)
+    seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
+    prob = jax.nn.softmax(jnp.where(seen[:, None], s, NEG),
+                          axis=-1).astype(cv.dtype)
+    out = jnp.einsum('bmr,brc->bmc', prob, cv, preferred_element_type=f32)
+    heads = jnp.arange(H)
+    return out.reshape(B, m, H, d)[:, heads, heads].astype(dtype).reshape(
+        B, H * d)

@@ -14,7 +14,9 @@ XLA program:
   * nets that declare ``sequence`` (models/evabyte.py): the window's T
     positions of each (window, seat) in ONE causal forward at the window's
     absolute positions, the burn-in prefix in the same call with no
-    gradient through its keys and values;
+    gradient through its keys and values; a net that runs its layers
+    several times hands over every pass's outputs and the gate that weighs
+    the passes' losses (``_exit_weighted_losses``);
   * turn-alternating batches (P_obs=1, P=2): the acting player's policy row
     is gathered by multiplying with turn_mask and summing the player axis
     (train.py:179-180); per-player hidden state is gated by
@@ -26,7 +28,9 @@ data count, exactly like the reference.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -123,6 +127,10 @@ def _sequence_prediction(sequence_fn, params, batch: Dict[str, Any],
                            cfg.burn_in_steps))
     # sums the forward pass hands on (``compute_loss``), not outputs
     aux = out.pop('aux', None)
+    if PASS_GATE in out:
+        # a LEADING pass axis on every output: behind the positions', where
+        # further heads of prediction have theirs, (B, T, P, passes, ...)
+        out = {k: jnp.moveaxis(v, 0, 2) for k, v in out.items()}
     outputs = {k: jnp.moveaxis(v.reshape((B, P_obs, T) + v.shape[2:]), 1, 2)
                for k, v in out.items()}
     if aux is not None:
@@ -345,6 +353,90 @@ def _policy_in_blocks(policy_fn, params, features, batch):
     return picked.reshape(lead + (1,)), entropy.reshape(lead)
 
 
+# A sequence net that runs its layers several times hands over, beside
+# ``policy_features`` and ``value`` of EVERY pass, this output: the logit of
+# leaving after each pass. Its presence is what says "a pass axis".
+PASS_GATE = 'exit_gate'
+# weight of the exit distribution's entropy in such a net's loss (the
+# source's beta: its config holds none; benchmark/configs/ouro.json
+# ``assumed``)
+EXIT_ENTROPY_COEF = 0.1
+
+
+def exit_distribution(gate_logits):
+    """gate_logits (passes, ...) -> ``p`` (passes, ...), the probability of
+    leaving after each pass, and its entropy (...) in nats: with ``g_t`` the
+    sigmoid of pass t's logit, ``p_t = g_t prod_{j<t} (1 - g_j)`` and the
+    last pass takes what is left (its own logit is not read)."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gate_logits[:-1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay], axis=0)
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(gate_logits[:-1]) + before[:-1], before[-1:]],
+        axis=0)
+    p = jnp.exp(log_p)
+    return p, -(p * log_p).sum(axis=0)
+
+
+@contextlib.contextmanager
+def _pass_readout_scope():
+    """The scope ``pass_readout`` where ``compute_loss`` opens it. jax writes
+    its transforms around the FIRST scope named under them
+    (``transpose(jvp(...))``, one step of an operation's path), and here
+    nothing is named above: a scope of the seam's own takes the transforms,
+    so that ``pass_readout`` stands in a trace as it is, backward pass too
+    (as ``models/experts.py`` ``dispatched_sum``)."""
+    with jax.named_scope('exit_weighted'), jax.named_scope('pass_readout'):
+        yield
+
+
+def _passes_in_blocks(policy_fn, params, features, batch):
+    """``_policy_in_blocks`` a pass, the passes one after the other:
+    features (B, T, P, passes, D) -> the taken action's log-probability
+    (passes, B, T, P, 1) and the entropy (passes, B, T, P)."""
+    return lax.map(
+        lambda feats: _policy_in_blocks(policy_fn, params, feats, batch),
+        jnp.moveaxis(features, 3, 0))
+
+
+def _exit_weighted_losses(log_selected, entropies, values, gate_logits,
+                          total_advantages, targets, batch, cfg):
+    """``compose_losses`` for a net with a pass axis: each pass's loss a
+    position from today's three terms (policy gradient on ITS log-probability
+    ``log_selected`` (passes, B, T, P, 1), regression of ITS value (passes,
+    B, T, P, 1), ITS policy's entropy (passes, B, T, P)) against the SHARED
+    advantages and targets, weighted by the exit distribution ``p`` of
+    ``gate_logits`` (passes, B, T, P, 1), less ``EXIT_ENTROPY_COEF`` times
+    ``p``'s entropy, summed over the acting positions. ``p`` carries its
+    gradient: the gate trains through both terms. Beside the losses and the
+    count of acting positions, the sums the epoch record reads the gate
+    from (they join ``diag``, and ``FusedPipeline._parse`` adds them up onto
+    ``host_block``): the count again under the name a sequence net's
+    ``aux`` gives it, ``p``'s entropy and its largest possible value, and
+    each pass's mass."""
+    tmasks = batch['turn_mask']
+    acting = tmasks.sum(axis=-1)
+    p, exit_entropy = exit_distribution(gate_logits[..., 0])
+    weigh = lambda term: (p * term).sum()
+    losses = {
+        'p': weigh((-log_selected * total_advantages * tmasks)[..., 0]),
+        'v': weigh((((values - targets['value']) ** 2)
+                    * batch['observation_mask'])[..., 0]) / 2,
+        'ent': weigh(entropies * acting),
+        'exit_ent': (exit_entropy * acting).sum()}
+    decay = 1 - batch['progress'] * (1 - cfg.entropy_regularization_decay)
+    losses['total'] = (
+        losses['p'] + losses['v']
+        - cfg.entropy_regularization * weigh(entropies * acting * decay)
+        - EXIT_ENTROPY_COEF * losses['exit_ent'])
+    count = tmasks.sum()
+    sums = {'window_positions_valid': count,
+            'exit_entropy_nats': losses['exit_ent'],
+            'exit_entropy_max_nats': count * math.log(p.shape[0])}
+    for t in range(p.shape[0]):
+        sums['exit_mass_pass_%d' % (t + 1)] = (p[t] * acting).sum()
+    return losses, count, sums
+
+
 def optax_huber(pred: jnp.ndarray, target: jnp.ndarray, delta: float = 1.0
                 ) -> jnp.ndarray:
     """Smooth-L1 (huber, delta=1), elementwise."""
@@ -380,7 +472,11 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     (``policy_fn(params, features)`` gives the logits; the head is then
     taken in blocks of positions, ``_policy_in_blocks``) and MAY return
     ``aux``, sums of its forward pass: its scalars join ``diag`` and the
-    whole of it rides back as ``aux['sequence_aux']``.
+    whole of it rides back as ``aux['sequence_aux']``. With ``PASS_GATE``
+    among its outputs the features, values and gate logits have a pass axis:
+    the head is taken a pass, V-trace's ratios, targets and advantages come
+    ONCE, from the last pass (the policy the actor played), and the loss is
+    ``_exit_weighted_losses``.
     """
     if batch_stats is None:
         params, batch_stats = split_batch_stats(params)
@@ -418,14 +514,25 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
     clip_rho, clip_c = 1.0, 1.0
 
     log_b = jnp.log(jnp.clip(batch['selected_prob'], 1e-16, 1)) * emasks
-    policy_entropy = None
+    policy_entropy = passes = None
     if 'policy' in outputs:
         logp = jax.nn.log_softmax(outputs['policy'], axis=-1)
         log_t = jnp.take_along_axis(logp, actions, axis=-1) * emasks
     else:
         assert not use_target, 'no target network beside a head in blocks'
-        picked, policy_entropy = _policy_in_blocks(
-            policy_fn, params, outputs.pop('policy_features'), batch)
+        features = outputs.pop('policy_features')
+        if PASS_GATE in outputs:
+            with _pass_readout_scope():
+                picked, entropies = _passes_in_blocks(policy_fn, params,
+                                                      features, batch)
+            passes = (picked * emasks, entropies,
+                      jnp.moveaxis(outputs.pop('value'), 3, 0),
+                      jnp.moveaxis(outputs.pop(PASS_GATE), 3, 0))
+            # what follows reads the pass the actor played from
+            picked, outputs['value'] = picked[-1], passes[2][-1]
+        else:
+            picked, policy_entropy = _policy_in_blocks(policy_fn, params,
+                                                       features, batch)
         log_t = picked * emasks
 
     log_rhos = lax.stop_gradient(log_t) - log_b
@@ -472,8 +579,14 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
 
     total_advantages = clipped_rhos * sum(advantages.values())
 
-    losses, dcnt = compose_losses(outputs, log_t, total_advantages, targets,
-                                  batch, cfg, policy_entropy)
+    exit_sums = {}
+    if passes is None:
+        losses, dcnt = compose_losses(outputs, log_t, total_advantages,
+                                      targets, batch, cfg, policy_entropy)
+    else:
+        with _pass_readout_scope():
+            losses, dcnt, exit_sums = _exit_weighted_losses(
+                *passes, total_advantages, targets, batch, cfg)
     # off-policy health diagnostics, summed over acting (step, player)
     # pairs like every loss term so the host normalizes by data_count:
     # V-Trace rho/c clip fractions and the importance-ratio first/second
@@ -495,6 +608,7 @@ def compute_loss(apply_fn, params, init_hidden, batch: Dict[str, Any],
         diag['target_ratio_sum'] = (rhos_tgt * tmask).sum()
         diag['target_gap_sum'] = ((lax.stop_gradient(log_t) - log_tgt)
                                   * tmask).sum()
+    diag.update(exit_sums)
     aux = {'losses': losses, 'data_count': dcnt, 'diag': diag}
     if sequence_aux is not None:
         diag.update((k, v) for k, v in sequence_aux.items() if v.ndim == 0)
