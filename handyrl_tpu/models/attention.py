@@ -15,11 +15,26 @@ NEG = -1e30
 f32 = jnp.float32
 
 
-def sequence_attention(q, k, v, positions, valid, window, query_block):
-    """One sequence. q (T, H, d), k, v (T, KV, d) -> (T, H * d); query head
-    ``n`` reads KV head ``n // (H / KV)``; query ``i`` sees key ``j`` iff
-    ``j <= i`` by position, the key is valid and, with a ``window``,
-    ``i - window < j``."""
+def block_keys(T, window, query_block):
+    """How many keys each block of ``query_block`` queries multiplies, from
+    the shapes alone: with a window the ``window + query_block`` keys that
+    end with the block's own last query (from key 0 while fewer lie before
+    it), every key where that reaches ``T`` and where there is no window."""
+    bq = min(query_block, T)
+    assert T % bq == 0, (T, bq)
+    return T if window is None else min(window + bq, T)
+
+
+def key_share(T, windows, query_block):
+    """The (query, key) pairs ``sequence_attention`` multiplies as a share of
+    all ``T x T``, the mean over layers whose windows (None: a layer that
+    sees everything) are ``windows``. 1.0: nothing is left out."""
+    return sum(block_keys(T, window, query_block)
+               for window in windows) / (len(windows) * T)
+
+
+def _sequence_attention(q, k, v, positions, valid, window, query_block):
+    """``sequence_attention``, traced where it is called."""
     T, H, d = q.shape
     KV = k.shape[1]
     G = H // KV
@@ -27,24 +42,64 @@ def sequence_attention(q, k, v, positions, valid, window, query_block):
     k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)        # (KV, T, d)
     scale = d ** -0.5
     bq = min(query_block, T)
-    assert T % bq == 0, (T, bq)
+    n_keys = block_keys(T, window, query_block)
+    if n_keys < T:
+        # a key that is padding stands one past every query: ONE array a
+        # key to slice beside K and V
+        pk = jnp.where(valid, positions, jnp.iinfo(positions.dtype).max)
 
     @jax.checkpoint
     def block(args):
-        qb, pq = args                                 # (KV, G, bq, d), (bq,)
-        seen = (positions[None, :] <= pq[:, None]) & valid[None, :]
+        qb, pq, *b = args                      # (KV, G, bq, d), (bq,), [()]
+        if b:
+            start = jnp.maximum((b[0] + 1) * bq - n_keys, 0)
+            kb = jax.lax.dynamic_slice_in_dim(k, start, n_keys, 1)
+            vb = jax.lax.dynamic_slice_in_dim(v, start, n_keys, 1)
+            pb = jax.lax.dynamic_slice_in_dim(pk, start, n_keys, 0)
+            seen = pb[None, :] <= pq[:, None]
+        else:
+            kb, vb, pb = k, v, positions
+            seen = (pb[None, :] <= pq[:, None]) & valid[None, :]
         if window is not None:
-            seen = seen & (positions[None, :] > pq[:, None] - window)
-        s = scale * jnp.einsum('kgqd,ktd->kgqt', qb, k,
+            seen = seen & (pb[None, :] > pq[:, None] - window)
+        s = scale * jnp.einsum('kgqd,ktd->kgqt', qb, kb,
                                preferred_element_type=f32)
         prob = jax.nn.softmax(jnp.where(seen[None, None], s, NEG),
                               axis=-1).astype(v.dtype)
-        return jnp.einsum('kgqt,ktd->kgqd', prob, v,
+        return jnp.einsum('kgqt,ktd->kgqd', prob, vb,
                           preferred_element_type=f32).astype(v.dtype)
 
     qs = q.reshape(KV, G, T // bq, bq, d).transpose(2, 0, 1, 3, 4)
-    out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
+    # only a block that takes a span of the keys needs its index
+    index = (jnp.arange(T // bq),) if n_keys < T else ()
+    out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)) + index)
     return out.transpose(0, 3, 1, 2, 4).reshape(T, H * d)
+
+
+_shared_attention = jax.jit(_sequence_attention,
+                            static_argnames=('window', 'query_block'))
+
+
+def sequence_attention(q, k, v, positions, valid, window, query_block):
+    """One sequence. q (T, H, d), k, v (T, KV, d) -> (T, H * d); query head
+    ``n`` reads KV head ``n // (H / KV)``; query ``i`` sees key ``j`` iff
+    ``j <= i`` by position, the key is valid and, with a ``window``,
+    ``i - window < j``. ``positions`` must rise by one an index (the nets
+    pass ``first_position + arange(T)``), so what a query sees is bounded by
+    INDEX too, and a block of queries multiplies only the ``block_keys`` keys
+    that end with its own last query; inside them the mask is the one above,
+    by position. A query that sees no key at all (padding further than a
+    window past the last valid key) gets the mean of the values its block
+    was handed: nothing reads it.
+
+    A layer whose blocks take a span is one function however many layers of
+    its shape a net has: traced, differentiated and lowered ONCE a program
+    (a block's slices cost the host more to trace than they save the chip
+    otherwise: PERF.md, PR 48). A layer that takes every key is traced
+    where it stands, the program it always was."""
+    span = block_keys(q.shape[0], window, query_block) < q.shape[0]
+    return (_shared_attention if span else _sequence_attention)(
+        q, k, v, positions, valid, window=window, query_block=query_block)
 
 
 def init_cache(batch_shape, rows, width, dtype):

@@ -394,6 +394,13 @@ class SmallThinkerNet(nn.Module):
                                 router=before['params'][name]['router'])
         return dict(after, params=params)
 
+    def attention_key_share(self, T):
+        """Of the layers' ``T x T`` (query, key) pairs, the share a window
+        of ``T`` positions multiplies (1.0: all of them)."""
+        return attention.key_share(
+            T, [self.window_size if kind == 'window' else None
+                for kind in self.layer_types], self.query_block)
+
     def epoch_dynamics(self, sums):
         """The epoch record's keys from the epoch's ``diag_*`` sums."""
         dynamics = experts.rows_dynamics(

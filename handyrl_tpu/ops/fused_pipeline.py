@@ -49,7 +49,8 @@ class FusedPipeline:
     def __init__(self, env_mod, wrapper, cfg: LossConfig, windower,
                  args: Dict[str, Any], n_envs: int, chunk_steps: int,
                  sgd_steps: int, batch_size: int,
-                 default_lr: float = 3e-8, seed: int = 0, mesh=None):
+                 default_lr: float = 3e-8, seed: int = 0, mesh=None,
+                 attention_key_share=None):
         self.chunk_steps = chunk_steps
         self.sgd_steps = sgd_steps
         self.mesh = mesh
@@ -270,6 +271,11 @@ class FusedPipeline:
         self.state_cache_bytes = sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.hidden))
         self.state_resets = hasattr(wrapper.module, 'reset_hidden')
+        # of all (query, key) pairs of a window, the share the update step's
+        # attention multiplies, where the learner knows it (train.py
+        # ``Trainer.attention_key_share``): the shapes fix it, and the
+        # ``host_block`` span carries it as it is
+        self.attention_key_share = attention_key_share
         telemetry.gauge('state_cache_bytes').set(self.state_cache_bytes)
 
     # -- multi-chip construction -------------------------------------------
@@ -414,6 +420,8 @@ class FusedPipeline:
                      windows_ingested=self.windows_ingested_host,
                      ring_size=self.ring_size_host,
                      sgd_steps=self.sgd_steps if has_metrics else 0)
+            if self.attention_key_share is not None:
+                span.set(attention_key_share=self.attention_key_share)
         metrics = None
         if has_metrics:
             metrics = {k: float(v)

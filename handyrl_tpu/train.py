@@ -494,6 +494,14 @@ class Trainer:
         # ``last_dynamics`` (metrics_jsonl + gauges + the TIMING line)
         self._diag_sum: Dict[str, float] = {}
         self.last_dynamics: Dict[str, float] = {}
+        # a net whose attention multiplies only the keys a block of queries
+        # can see (models/attention.py) MAY say what share of all (query,
+        # key) pairs that is at the trained length: the shapes fix it, and
+        # every epoch's record carries it (``_epoch_dynamics``), as does
+        # the fused loop's ``host_block`` span
+        key_share = getattr(wrapper.module, 'attention_key_share', None)
+        self.attention_key_share = None if key_share is None else key_share(
+            args['burn_in_steps'] + args['forward_steps'])
         self.shutdown_flag = False
         self.failed = False
         self.failed_reason = ''
@@ -928,6 +936,8 @@ class Trainer:
         net_dynamics = getattr(self.wrapper.module, 'epoch_dynamics', None)
         if net_dynamics is not None:
             out.update(net_dynamics(d))
+        if self.attention_key_share is not None:
+            out['attention_key_share'] = self.attention_key_share
         out = {k: round(float(v), 6) for k, v in out.items()}
         for k, v in out.items():
             telemetry.gauge(k).set(v)
@@ -2942,7 +2952,7 @@ class Learner:
             chunk_steps=int(args.get('device_chunk_steps') or 16),
             sgd_steps=sgd_steps, batch_size=args['batch_size'],
             default_lr=tr.default_lr, seed=args.get('seed', 0),
-            mesh=tr.mesh)
+            mesh=tr.mesh, attention_key_share=tr.attention_key_share)
 
         cadence = _EpochCadence(args)
         actor_epoch = self.model_epoch

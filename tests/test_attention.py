@@ -1,0 +1,274 @@
+"""``models/attention.py`` ``sequence_attention`` on its own: a block of
+queries multiplies only the ``block_keys`` keys that end with its own, what comes out is what
+every block against EVERY key gives (the function as it stood before, kept
+here as the plain reference), value and gradient, and the program it lowers
+to stays near that one's size (the set-up seconds of every run, PR 47)."""
+
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from handyrl_tpu.models import attention
+
+D = 4
+f32 = jnp.float32
+
+
+def all_keys_attention(q, k, v, positions, valid, window, query_block):
+    """``sequence_attention`` before PR 48: every block of queries against
+    all ``T`` keys, most of them masked away."""
+    T, H, d = q.shape
+    KV = k.shape[1]
+    G = H // KV
+    q = q.reshape(T, KV, G, d).transpose(1, 2, 0, 3)
+    k, v = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
+    scale = d ** -0.5
+    bq = min(query_block, T)
+    assert T % bq == 0, (T, bq)
+
+    @jax.checkpoint
+    def block(args):
+        qb, pq = args
+        seen = (positions[None, :] <= pq[:, None]) & valid[None, :]
+        if window is not None:
+            seen = seen & (positions[None, :] > pq[:, None] - window)
+        s = scale * jnp.einsum('kgqd,ktd->kgqt', qb, k,
+                               preferred_element_type=f32)
+        prob = jax.nn.softmax(jnp.where(seen[None, None], s, attention.NEG),
+                              axis=-1).astype(v.dtype)
+        return jnp.einsum('kgqt,ktd->kgqd', prob, v,
+                          preferred_element_type=f32).astype(v.dtype)
+
+    qs = q.reshape(KV, G, T // bq, bq, d).transpose(2, 0, 1, 3, 4)
+    out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
+    return out.transpose(0, 3, 1, 2, 4).reshape(T, H * d)
+
+
+def _seen(positions, valid, window):
+    seen = (positions[None, :] <= positions[:, None]) & valid[None, :]
+    if window is not None:
+        seen = seen & (positions[None, :] > positions[:, None] - window)
+    return np.asarray(seen)
+
+
+BQ = 8
+# blocks a sequence x the window (None: everything before; 3 and 13: shorter
+# than any sequence of two blocks; 'reaches': ``window + bq == T``; 100:
+# longer than the sequence) x (query heads, KV heads) x (first position,
+# padding at the tail)
+BLOCKS = (1, 2, 8)
+WINDOWS = (None, 3, 13, 'reaches', 100)
+HEADS = ((2, 2), (7, 1))
+TAILS = ((0, 0), (1000, 5))
+CASES = [(n, window, heads, tail)
+         for n, window, (heads, tail) in itertools.product(
+             BLOCKS, WINDOWS, zip(HEADS, TAILS))]
+CASES += [(8, 13, (4, 2), (7, 30)), (8, None, (4, 2), (0, 11)),
+          (3, None, (2, 1), (3, 0)), (5, 13, (2, 2), (0, 9))]
+
+
+def _case_id(case):
+    n, window, (H, KV), (first, tail) = case
+    return '%dx%d-w%s-h%dkv%d-p%d-tail%d' % (n, BQ, window, H, KV, first,
+                                             tail)
+
+
+@pytest.mark.parametrize('case', CASES, ids=_case_id)
+def test_sequence_attention_is_the_all_keys_function(case):
+    n, window, (H, KV), (first, tail) = case
+    T = n * BQ
+    if window == 'reaches':
+        window = max(T - BQ, 1)
+    ks = jax.random.split(jax.random.PRNGKey(T + H), 4)
+    q = jax.random.normal(ks[0], (T, H, D), f32)
+    k = jax.random.normal(ks[1], (T, KV, D), f32)
+    v = jax.random.normal(ks[2], (T, KV, D), f32)
+    positions = first + jnp.arange(T)
+    valid = jnp.arange(T) < T - tail
+    # padding further than a window past the last valid key sees nothing and
+    # averages whatever keys it was handed, here as there; nothing reads it,
+    # so it carries no cotangent either. Every VALID row sees itself.
+    sees = _seen(positions, valid, window).any(axis=1)
+    assert sees[:T - tail].all()
+    cot = jax.random.normal(ks[3], (T, H * D), f32) * sees[:, None]
+
+    def run(f):
+        out = lambda *a: f(*a, positions, valid, window, BQ)
+        return jax.jit(out)(q, k, v), jax.jit(jax.grad(
+            lambda *a: (out(*a) * cot).sum(), (0, 1, 2)))(q, k, v)
+    got, grads = run(attention.sequence_attention)
+    want, wants = run(all_keys_attention)
+    np.testing.assert_allclose(got[sees], want[sees], rtol=2e-5, atol=2e-5)
+    for g, w in zip(grads, wants):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=2e-5)
+
+
+SHAPES = [(8192, 4096, 128), (8192, None, 128), (4096, 2048, 512),
+          (4096, None, 512), (64, 13, 8), (64, 56, 8), (64, 100, 8),
+          (24, None, 8), (16, None, 512), (16, 4, 512), (88, None, 8)]
+
+
+@pytest.mark.parametrize('T,window,bq', SHAPES)
+def test_the_plan_leaves_no_visible_key_out_and_the_share_counts_it(
+        T, window, bq):
+    """By brute force: every (query, key) pair a block hands to a product,
+    against the pairs the mask lets through and against ``key_share``."""
+    bq_ = min(bq, T)
+    n_keys = attention.block_keys(T, window, bq)
+    multiplied = np.zeros((T, T), bool)
+    for b in range(T // bq_):
+        start = max((b + 1) * bq_ - n_keys, 0)
+        multiplied[b * bq_:(b + 1) * bq_, start:start + n_keys] = True
+    positions = 17 + np.arange(T)
+    visible = _seen(positions, np.ones(T, bool), window)
+    assert not (visible & ~multiplied).any()
+    assert attention.key_share(T, [window], bq) == multiplied.mean()
+    assert attention.key_share(T, [window, None], bq) == pytest.approx(
+        (multiplied.mean() + attention.key_share(T, [None], bq)) / 2)
+
+
+def _key_extents(T, window, bq, H=2, KV=1, d=8, batch=None):
+    """The key extents of every ``dot_general`` in the jaxpr of
+    ``sequence_attention`` at these shapes (no arrays: shapes alone), the
+    loop bodies it holds and its gathers."""
+    lead = () if batch is None else (batch,)
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(lead + s,
+                                                                dtype)
+    f = lambda *seq: attention.sequence_attention(*seq, window, bq)
+    if batch is not None:
+        f = jax.vmap(f)
+    jaxpr = jax.make_jaxpr(f)(
+        shape(T, H, d), shape(T, KV, d), shape(T, KV, d),
+        shape(T, dtype=jnp.int32), shape(T, dtype=jnp.bool_))
+    extents, counts = [], {'scan': 0, 'gather': 0}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == 'dot_general':
+                # scores: (.., bq, d) x (.., keys, d); values: (.., bq, keys)
+                # x (.., keys, d): the keys are the second operand's axis -2
+                extents.append(eqn.invars[1].aval.shape[-2])
+            if eqn.primitive.name in counts:
+                counts[eqn.primitive.name] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    return extents, counts['scan'], counts['gather']
+
+
+def test_a_block_multiplies_only_its_own_keys_in_one_loop_body():
+    # a window layer at ``smallthinker``'s shapes: ONE loop body, no product
+    # over all 8,192 keys, under the nets' ``vmap`` over windows or without
+    assert _key_extents(8192, 4096, 128) == ([4224, 4224], 1, 0)
+    assert _key_extents(8192, 4096, 128, batch=2)[:2] == ([4224, 4224], 1)
+    assert attention.block_keys(8192, 4096, 128) == 4224
+    # ``trinity_mini``'s sliding layers
+    assert _key_extents(4096, 2048, 512)[:2] == ([2560, 2560], 1)
+    # a window that reaches the sequence, a layer that sees everything, one
+    # block: every key, one body
+    assert _key_extents(64, 56, 8) == ([64, 64], 1, 0)
+    assert _key_extents(8192, None, 128, batch=2) == ([8192, 8192], 1, 0)
+    assert _key_extents(16, 4, 512) == ([16, 16], 1, 0)
+    assert attention.block_keys(16, 4, 512) == 16
+    assert attention.key_share(4096, [None], 512) == 1.0
+
+
+@pytest.mark.parametrize('T,window,bq', [
+    (4096, None, 512), (64, 56, 8), (16, 4, 512), (16, None, 512)])
+def test_blocks_that_take_every_key_run_the_all_keys_program(T, window, bq):
+    """No slice, no index, the mask as it was: the text it lowers to is the
+    all-keys function's to the character, so a net of such layers alone
+    (``models/ouro.py``) builds the programs it built before PR 48."""
+    shape = jax.ShapeDtypeStruct
+    args = (shape((T, 4, D), jnp.bfloat16), shape((T, 2, D), jnp.bfloat16),
+            shape((T, 2, D), jnp.bfloat16), shape((T,), jnp.int32),
+            shape((T,), jnp.bool_))
+    text = lambda f: jax.jit(jax.grad(lambda *a: f(*a, window, bq).astype(
+        f32).sum(), (0, 1, 2))).lower(*args).as_text()
+    assert text(attention.sequence_attention) == text(all_keys_attention)
+
+
+def test_a_blocks_slice_stays_a_slice_under_vmap():
+    """The slice's start is the block's index alone, the same for every
+    window of a batch: ``vmap`` writes it as a ``gather`` of one index, which
+    compiles to the ``dynamic-slice`` it is."""
+    f = jax.vmap(lambda *seq: attention.sequence_attention(*seq, 13, 8))
+    shape = jax.ShapeDtypeStruct
+    text = jax.jit(f).lower(
+        shape((2, 64, 2, D), f32), shape((2, 64, 1, D), f32),
+        shape((2, 64, 1, D), f32), shape((2, 64), jnp.int32),
+        shape((2, 64), jnp.bool_)).compile().as_text()
+    assert ' gather(' not in text and 'dynamic-slice(' in text
+
+
+def _lowered_characters(f, T, window, bq, layers=4, B=2, H=8, d=128):
+    """The text ``jax.grad`` of a stack of ``layers`` layers of ``f`` lowers
+    to, each under ``vmap`` over windows and ``jax.checkpoint`` as the nets
+    run it (shapes alone: nothing is compiled or run)."""
+    def loss(q, k, v, positions, valid):
+        x = q
+        for _ in range(layers):
+            x = x + jax.checkpoint(lambda x: jax.vmap(
+                lambda *seq: f(*seq, window, bq))(
+                    x, k, v, positions, valid).reshape(x.shape))(x)
+        return (x.astype(f32) ** 2).sum()
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct((B,) + s,
+                                                                dtype)
+    return len(jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shape(T, H, d), shape(T, 1, d), shape(T, 1, d),
+        shape(T, dtype=jnp.int32), shape(T, dtype=jnp.bool_)).as_text())
+
+
+# what the final form read when it was written (PR 48) plus a tenth: a full
+# layer is the all-keys program itself (1.0 x); a window layer's body (one
+# ``lax.map`` of ``window + bq`` keys a block) reads 1.34 x traced where it
+# is called, and the four layers of the stack share ONE function: 0.43 x. Two
+# groups a full layer read 1.96 x, PR 47's eight 7.8 x, a chunk loop under a
+# ``cond`` with a running soft-max 3.5 x, and set-up paid for each
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize('f,T,window,bq,most', [
+    ('sequence_attention', 4096, None, 512, 1.1),
+    ('sequence_attention', 8192, 4096, 128, 0.53),
+    ('_sequence_attention', 8192, 4096, 128, 1.45)])
+def test_the_update_program_stays_near_the_all_keys_programs_size(
+        f, T, window, bq, most):
+    ours = _lowered_characters(getattr(attention, f), T, window, bq)
+    theirs = _lowered_characters(all_keys_attention, T, window, bq)
+    assert most <= 1.5 and ours <= most * theirs, (ours, theirs)
+
+
+def test_window_layers_of_one_shape_share_one_lowered_function():
+    """However many layers: the body's ``dynamic_slice``s stand once in the
+    forward function and once in each function ``jax.grad`` makes of it."""
+    slices = lambda layers: jax.jit(jax.grad(lambda q, k, v, p, ok: sum(
+        attention.sequence_attention(q + i, k, v, p, ok, 13, 8).sum()
+        for i in range(layers)))).lower(
+            *(jax.ShapeDtypeStruct(s, d) for s, d in (
+                ((64, 2, D), f32), ((64, 1, D), f32), ((64, 1, D), f32),
+                ((64,), jnp.int32), ((64,), jnp.bool_)))
+        ).as_text().count('dynamic_slice')
+    assert slices(4) == slices(1) > 0
+
+
+def test_the_epoch_record_and_the_gauge_carry_what_the_learner_holds():
+    """``Trainer._epoch_dynamics`` hands the share the learner read from the
+    net's hook at start to every epoch's record and its gauge; a learner
+    whose net has no hook holds None and records nothing (the fused loop's
+    span and a whole run's records: tests/test_fused_pipeline.py)."""
+    import types
+
+    from handyrl_tpu import telemetry, train
+
+    def record(share):
+        trainer = types.SimpleNamespace(
+            _diag_sum={'diag_grad_norm': 3.0}, attention_key_share=share,
+            wrapper=types.SimpleNamespace(module=object()))
+        return train.Trainer._epoch_dynamics(trainer, {}, 1, 1)
+    want = attention.key_share(64, [16, None], 8)
+    assert want == (24 / 64 + 1) / 2
+    assert record(want) == {'grad_norm': 3.0, 'attention_key_share': want}
+    assert telemetry.gauge('attention_key_share').value == want
+    assert record(None) == {'grad_norm': 3.0}

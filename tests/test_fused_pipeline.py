@@ -173,14 +173,20 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
     """A short fused run leaves, per iteration, one ``fused_iter`` span with
     ``dispatch`` and ``host_block`` children; the ``host_block`` counters
     agree with the pipeline's own; the epoch records carry the ``fused``
-    block; and the fused program's phases carry their names."""
+    block; and the fused program's phases carry their names. A net that
+    says what share of a window's (query, key) pairs its attention
+    multiplies (the hook ``attention_key_share``, called at the trained
+    length) has it on the span and in every record."""
     import time
 
     import jax
     import jax.numpy as jnp
 
     from handyrl_tpu import telemetry
+    from handyrl_tpu.models.tictactoe import SimpleConv2dModel
     from handyrl_tpu.ops.fused_pipeline import FusedPipeline
+    monkeypatch.setattr(SimpleConv2dModel, 'attention_key_share',
+                        lambda self, T: T / 16, raising=False)
     built = []
     init = FusedPipeline.__init__
 
@@ -275,11 +281,14 @@ def test_fused_loop_spans_counters_and_named_phases(tmp_path, monkeypatch):
     assert last['builder_plies'] <= last['episodes']
     assert blocks[0]['attrs']['sgd_steps'] == 0          # a warm-up chunk
     assert last['sgd_steps'] == fp.sgd_steps == 4
+    assert [b['attrs']['attention_key_share'] for b in blocks] == \
+        [0.25] * len(blocks)                              # forward_steps 4
 
     # the epoch records carry the per-chunk block
     lines = (tmp_path / 'metrics.jsonl').read_text().splitlines()
     rows = [telemetry.validate_metrics_line(line) for line in lines]
     assert sum(row['fused']['chunks'] for row in rows) <= fp.dispatches
+    assert [row['attention_key_share'] for row in rows] == [0.25] * len(rows)
     block = rows[-1]['fused']
     assert block['chunks'] > 0 and block['stalls'] == []
     assert 0 < block['interval_median_s'] <= block['interval_max_s']

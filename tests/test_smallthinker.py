@@ -100,6 +100,23 @@ def test_the_cut_has_the_parameter_count_the_configuration_states():
     assert 21.4e9 < count(SmallThinkerNet()) < 21.6e9
 
 
+def test_the_key_share_is_by_hand_at_the_cells_shapes():
+    """What the learner's gauge ``attention_key_share`` reads for the cell's
+    cut at its 8,192-position windows: three window layers at 4,224 of 8,192
+    keys a block of 128, the global layer every key."""
+    cut = SmallThinkerNet(layer_types=PUBLISHED_LAYERS[:4], heads_held=7,
+                          kv_heads_held=1, experts_held=tuple(range(16)),
+                          vocab=37984)
+    assert PUBLISHED_LAYERS[:4] == ('global', 'window', 'window', 'window')
+    assert cut.attention_key_share(8192) == (
+        3 * 4224 / 8192 + 1) / 4 == 0.63671875
+    # a window no longer than the attention's window and a block: every key
+    assert cut.attention_key_share(4096) == 1.0
+    # five blocks of 8 under a window of 16: 24 of 40 keys a block
+    assert SmallThinkerNet(**WIDTHS).attention_key_share(T) == pytest.approx(
+        (2 * 24 / 40 + 1) / 3)
+
+
 # -- each kind of layer, and the whole net, against the reference ---------------
 @pytest.mark.parametrize('kind', ['global', 'window'])
 def test_one_layer_matches_the_plain_reference(kind):

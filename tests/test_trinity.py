@@ -98,6 +98,21 @@ def test_the_cut_has_the_parameter_count_the_configuration_states():
     assert 26.0e9 < count(TrinityNet()) < 26.2e9
 
 
+def test_the_key_share_is_by_hand_at_the_cells_shapes():
+    """What the learner's gauge ``attention_key_share`` reads for the cell's
+    cut at its 4,096-position windows: four sliding layers at 2,560 of 4,096
+    keys a block of 512, the full layer every key."""
+    cut = TrinityNet(layer_types=('sliding',) * 4 + ('full',), heads_held=8,
+                     kv_heads_held=1, experts_held=tuple(range(16)),
+                     vocab=25024)
+    assert cut.attention_key_share(4096) == (4 * 2560 / 4096 + 1) / 5 == 0.7
+    # windows of 2,048 positions: nothing to leave out
+    assert cut.attention_key_share(2048) == 1.0
+    # five blocks of 8 under a window of 16: 24 of 40 keys a block
+    assert TrinityNet(**WIDTHS).attention_key_share(T) == pytest.approx(
+        (2 * 24 / 40 + 1) / 3)
+
+
 # -- each kind of layer, and the whole net, against the reference ---------------
 @pytest.mark.parametrize('kinds,dense', [
     (('sliding',), 1), (('sliding',), 0), (('full',), 0)],
