@@ -6,6 +6,9 @@ or the ``window`` keys up to and with the query's own. What comes before
 the scopes these run under (``models/trinity.py``, ``models/smallthinker.py``).
 A net that runs its layers several times (``models/ouro.py``) keeps K and V
 of every (pass, layer): ``init_pass_cache``, ``pass_write``, ``pass_rows``.
+A layer WITHOUT grouping decodes with its heads' queries side by side
+(``heads_side_by_side`` and the products over a set of rows; over one set
+``side_by_side_attention``, over two under one soft-max ``models/evabyte.py``).
 """
 
 import jax
@@ -172,28 +175,55 @@ def pass_rows(c, t, rows):
     return jax.lax.dynamic_slice_in_dim(c, t * rows, rows, axis=1)
 
 
+def heads_side_by_side(q):
+    """q (B, H, d), one query head a KV head -> (B, m, H * d), m = H rounded
+    up to 8: row h holds ``q[h]`` at head h's columns and zeros at the
+    others'. With one query row a head a score is a matrix-vector product,
+    which the chip's compiler takes apart into float32 multiplies and sums
+    over a float32 copy of the rows (9 passes over HBM for one, PERF.md,
+    PR 46); laid so, the H queries are ONE matrix against a sequence's rows
+    as they lie (B, rows, H * d), on the matrix unit."""
+    B, H, d = q.shape
+    m = -(-H // 8) * 8
+    return jnp.einsum('mh,bhd->bmhd', jnp.eye(m, H, dtype=q.dtype),
+                      q).reshape(B, m, H * d)
+
+
+def side_by_side_scores(wide, rows, d):
+    """Every head's scaled scores over one set of rows: ``wide`` (B, m,
+    H * d) against rows (B, n, H * d) -> (B, m, n) float32, row h head h's
+    (a row past H scores 0 everywhere)."""
+    assert rows.shape[2] == wide.shape[2], (wide.shape, rows.shape)
+    return d ** -0.5 * jnp.einsum('bmc,brc->bmr', wide, rows,
+                                  preferred_element_type=f32)
+
+
+def side_by_side_values(prob, rows):
+    """prob (B, m, n) in the rows' dtype over rows (B, n, H * d) -> (B, m,
+    H * d) float32: head h's weighted values are block h of row h
+    (``own_blocks``), the other blocks are other heads' values under head
+    h's weights and are dropped. Sets of rows under one soft-max add up
+    here, before the blocks are taken."""
+    return jnp.einsum('bmr,brc->bmc', prob, rows, preferred_element_type=f32)
+
+
+def own_blocks(out, H):
+    """Block h of row h of ``side_by_side_values``' (B, m, H * d): (B, H, d)."""
+    B, m = out.shape[:2]
+    heads = jnp.arange(H)
+    return out.reshape(B, m, H, -1)[:, heads, heads]
+
+
 def side_by_side_attention(q, ck, cv, pos, dtype):
     """``cache_attention`` for a layer WITHOUT grouping (one query head a KV
     head) and no circle, on the buffers as they lie: q (B, H, d) over ck, cv
-    (B, rows, H * d) -> (B, H * d). With one query row a head the score is a
-    matrix-vector product, which the chip's compiler takes apart into float32
-    multiplies and sums over a float32 copy of the rows (9 passes over HBM
-    for one, PERF.md, PR 46). So the H queries go as ONE matrix against a
-    sequence's rows: row h holds ``q[h]`` at head h's columns and zeros at
-    the others', padded to 8 rows, and head h's output is block h of row h.
-    The rows are read once, in bfloat16, with no relayout."""
+    (B, rows, H * d) -> (B, H * d), the side-by-side product over ONE set of
+    rows. The rows are read once, in bfloat16, with no relayout."""
     B, H, d = q.shape
     n_rows = ck.shape[1]
-    assert ck.shape[2] == H * d, (q.shape, ck.shape)
-    m = -(-H // 8) * 8
-    wide = jnp.einsum('mh,bhd->bmhd', jnp.eye(m, H, dtype=q.dtype),
-                      q).reshape(B, m, H * d)
-    s = d ** -0.5 * jnp.einsum('bmc,brc->bmr', wide, ck,
-                               preferred_element_type=f32)
+    s = side_by_side_scores(heads_side_by_side(q), ck, d)
     seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
     prob = jax.nn.softmax(jnp.where(seen[:, None], s, NEG),
                           axis=-1).astype(cv.dtype)
-    out = jnp.einsum('bmr,brc->bmc', prob, cv, preferred_element_type=f32)
-    heads = jnp.arange(H)
-    return out.reshape(B, m, H, d)[:, heads, heads].astype(dtype).reshape(
-        B, H * d)
+    out = side_by_side_values(prob, cv)
+    return own_blocks(out, H).astype(dtype).reshape(B, H * d)

@@ -21,9 +21,13 @@ Two entries over one set of parameters:
 * ``sequence(ids, first_position, valid)``: T positions of each sequence in
   one causal forward (the learner's window, ops/losses.py; the checks);
 * ``__call__(id, hidden)``: one position through the cache (rollout, eval,
-  the serving engine). ``hidden`` holds, a layer, the current window's K and
-  V (window, heads, d), the summaries of the whole game (max_positions /
-  chunk, heads, d), and ONE position counter a sequence. A row is written at the
+  the serving engine). ``hidden`` holds, a layer, ONE buffer of K and one of
+  V (window + max_positions / chunk, heads * d): the current window's rows,
+  then the summaries of the whole game; and ONE position counter a
+  sequence. A row is the heads side by side, so the step's two products
+  read a buffer as it lies (``models/attention.py`` ``heads_side_by_side``;
+  summaries in buffers of their own are fetched whole into fast memory and
+  written back every ply, PERF.md, PR 50). A row is written at the
   sequence's own counter; nothing is ever cleared: what a counter does not
   reach is masked, so a new game resets the counter alone (``reset_hidden``).
 
@@ -43,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from . import register
+from . import attention, register
 
 NEG = -1e30
 f32 = jnp.float32
@@ -203,50 +207,51 @@ class EvaBlock(nn.Module):
     # -- one position through the cache -------------------------------------
     def step(self, x, pos, cache):
         """x (B, D) float32 at each sequence's own position ``pos`` (B,);
-        cache = (k, v (B, window, H, d), sk, sv (B, chunks, H, d))."""
-        ck, cv, csk, csv = cache
+        cache = (k, v (B, window + chunks, H * d)): the window's rows, then
+        the game's summaries; a row is the heads side by side, as the
+        products read it."""
+        ck, cv = cache
         W, chunk = self.window_size, self.chunk_size
+        H, d = self.heads_held, self.head_dim
         B = x.shape[0]
         rows = jnp.arange(B)
         with jax.named_scope('eva_attention'):
             q, k, v = self._qkv(x, pos)                        # (B, H, d)
             slot = pos % W
             with jax.named_scope('state_update'):
-                ck = ck.at[rows, slot].set(k)
-                cv = cv.at[rows, slot].set(v)
+                ck, cv = attention.cache_write(ck, cv, k, v, slot)
             # the chunk this position lies in, summarised over its members
             # so far; its slot is read only once its window is over, by
             # which time it is whole
             start = (slot // chunk) * chunk
             inside = start[:, None] + jnp.arange(chunk)[None, :]   # (B, chunk)
             member = inside <= slot[:, None]
+            heads_first = lambda c: jnp.swapaxes(               # (H, chunk, d)
+                c.reshape(chunk, H, d), 0, 1)
             sk, sv = jax.vmap(
                 lambda kc, vc, m: _summarise(
-                    jnp.swapaxes(kc, 0, 1), jnp.swapaxes(vc, 0, 1),
+                    heads_first(kc), heads_first(vc),
                     self.mu, self.phi, m[None]))(
                 ck[rows[:, None], inside], cv[rows[:, None], inside],
                 member)                                        # (B, H, 1, d)
             with jax.named_scope('state_update'):
-                csk = csk.at[rows, pos // chunk].set(sk[:, :, 0])
-                csv = csv.at[rows, pos // chunk].set(sv[:, :, 0])
-            scale = self.head_dim ** -0.5
-            local = jnp.arange(W)[None, :] <= slot[:, None]    # (B, W)
-            remote = (jnp.arange(csk.shape[1])[None, :]
-                      < (pos // W * (W // chunk))[:, None])    # (B, chunks)
-            s_local = scale * jnp.einsum('bhd,bwhd->bhw', q, ck,
-                                         preferred_element_type=f32)
-            s_remote = scale * jnp.einsum('bhd,bchd->bhc', q, csk,
-                                          preferred_element_type=f32)
-            scores = jnp.concatenate(
-                [jnp.where(local[:, None], s_local, NEG),
-                 jnp.where(remote[:, None], s_remote, NEG)], axis=-1)
-            prob = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
-            y = (jnp.einsum('bhw,bwhd->bhd', prob[..., :W], cv,
-                            preferred_element_type=f32)
-                 + jnp.einsum('bhc,bchd->bhd', prob[..., W:], csv,
-                              preferred_element_type=f32))
+                ck = ck.at[rows, W + pos // chunk].set(sk.reshape(B, H * d))
+                cv = cv.at[rows, W + pos // chunk].set(sv.reshape(B, H * d))
+            # a window row up to this position's own, a summary of the
+            # windows before this one
+            row = jnp.arange(ck.shape[1])[None, :]
+            seen = jnp.where(row < W, row <= slot[:, None],
+                             row - W < (pos // W * (W // chunk))[:, None])
+            # one query row a head: the heads' queries side by side as ONE
+            # matrix against the rows, one soft-max over rows and summaries
+            scores = attention.side_by_side_scores(
+                attention.heads_side_by_side(q), ck, d)
+            prob = jax.nn.softmax(jnp.where(seen[:, None], scores, NEG),
+                                  axis=-1).astype(cv.dtype)
+            y = attention.own_blocks(
+                attention.side_by_side_values(prob, cv), H)
             x = x + _dot(y.reshape(B, -1), self.wo, self.dtype, out=f32)
-        return self.mlp(x), (ck, cv, csk, csv)
+        return self.mlp(x), (ck, cv)
 
 
 @register('EvaByteNet')
@@ -293,24 +298,16 @@ class EvaByteNet(nn.Module):
 
     # -- the cache -----------------------------------------------------------
     def init_hidden(self, batch_shape=()):
-        lead = tuple(batch_shape)
-        H, d = self.heads_held, self.head_dim
+        """A layer's K and V: the window's rows, then a summary a chunk of
+        the longest game, in ONE buffer each."""
+        rows = self.window_size + self.max_positions // self.chunk_size
+        return attention.init_cache(
+            batch_shape, [rows] * self.layers,
+            self.heads_held * self.head_dim, self.dtype)
 
-        def zeros(n):
-            return tuple(jnp.zeros(lead + (n, H, d), self.dtype)
-                         for _ in range(self.layers))
-        chunks = self.max_positions // self.chunk_size
-        return {'k': zeros(self.window_size), 'v': zeros(self.window_size),
-                'sk': zeros(chunks), 'sv': zeros(chunks),
-                'pos': jnp.zeros(lead, jnp.int32)}
-
-    @staticmethod
-    def reset_hidden(hidden, done):
-        """A finished game resets its sequences' counters, not their
-        buffers: what a counter has not reached is masked."""
-        pos = hidden['pos']
-        done = done.reshape(done.shape + (1,) * (pos.ndim - done.ndim))
-        return dict(hidden, pos=jnp.where(done, 0, pos))
+    # a finished game resets its sequences' counters, not their buffers:
+    # what a counter has not reached is masked
+    reset_hidden = staticmethod(attention.reset_cache)
 
     # -- outputs ---------------------------------------------------------------
     def _readout(self, x):
@@ -328,16 +325,14 @@ class EvaByteNet(nn.Module):
             hidden = self.init_hidden(obs.shape)
         pos = hidden['pos']
         x = self.embed[obs].astype(f32)
-        new = {'k': [], 'v': [], 'sk': [], 'sv': []}
+        ks, vs = [], []
         for i, block in enumerate(self.blocks):
-            x, cache = block.step(
-                x, pos, tuple(hidden[key][i] for key in ('k', 'v', 'sk', 'sv')))
-            for key, leaf in zip(('k', 'v', 'sk', 'sv'), cache):
-                new[key].append(leaf)
+            x, (k, v) = block.step(x, pos, (hidden['k'][i], hidden['v'][i]))
+            ks.append(k)
+            vs.append(v)
         out = self._readout(x)
         out.pop('heads')    # acting reads head 0 alone
-        out['hidden'] = dict({key: tuple(v) for key, v in new.items()},
-                             pos=pos + 1)
+        out['hidden'] = {'k': tuple(ks), 'v': tuple(vs), 'pos': pos + 1}
         return out
 
     def sequence(self, ids, first_position, valid, no_grad_prefix: int = 0):

@@ -5,6 +5,7 @@ here as the plain reference), value and gradient, and the program it lowers
 to stays near that one's size (the set-up seconds of every run, PR 47)."""
 
 import itertools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -251,6 +252,64 @@ def test_window_layers_of_one_shape_share_one_lowered_function():
                 ((64,), jnp.int32), ((64,), jnp.bool_)))
         ).as_text().count('dynamic_slice')
     assert slices(4) == slices(1) > 0
+
+
+# -- one query row a head: the heads side by side (PR 46, PR 50) ---------------
+@pytest.mark.parametrize('heads', [2, 8, 9])
+def test_the_side_by_side_halves_over_two_row_sets_are_one_soft_max_over_both(
+        heads):
+    """The queries laid side by side once, the scores of each set of rows,
+    ONE soft-max, the values of each set added before the heads' own blocks
+    are taken (``models/evabyte.py``'s window rows and summaries): what
+    ``cache_attention`` without groups reads over the rows concatenated."""
+    keys = jax.random.split(jax.random.PRNGKey(heads), 5)
+    q = jax.random.normal(keys[0], (3, heads, 8))
+    ka, va, kb, vb = (jax.random.normal(key, (3, n, heads * 8))
+                      for key, n in zip(keys[1:], (12, 12, 8, 8)))
+    pos = jnp.asarray([0, 11, 17])
+    want = attention.cache_attention(
+        q, jnp.concatenate([ka, kb], axis=1), jnp.concatenate([va, vb], 1),
+        pos, False, heads, f32)
+    wide = attention.heads_side_by_side(q)
+    assert wide.shape == (3, -(-heads // 8) * 8, heads * 8)
+    seen = jnp.arange(20)[None, :] <= pos[:, None]
+    s = jnp.concatenate([attention.side_by_side_scores(wide, ka, 8),
+                         attention.side_by_side_scores(wide, kb, 8)], axis=-1)
+    prob = jax.nn.softmax(jnp.where(seen[:, None], s, attention.NEG), axis=-1)
+    out = (attention.side_by_side_values(prob[..., :12], va)
+           + attention.side_by_side_values(prob[..., 12:], vb))
+    got = attention.own_blocks(out, heads).reshape(3, -1)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_the_evabyte_step_multiplies_its_buffers_as_they_lie():
+    """The guard against the decode ply's products coming back as one query
+    row a head (which the chip's compiler takes apart into float32 multiplies
+    and sums over the rows, PERF.md, PRs 46 and 50): in the text
+    ``EvaBlock.step`` lowers to, each of a layer's two buffers (K and V: the
+    window's rows, then the summaries) is the operand of ONE product whose
+    only batch axis is the sequence, and no float32 array as large as a
+    buffer is made."""
+    from handyrl_tpu.models.evabyte import EvaBlock
+    B, W, H, d, chunks = 2, 64, 8, 16, 32
+    block = EvaBlock(64, H, d, 96, 4, W, 1e5, 1e-5, 8, jnp.bfloat16)
+    shape = jax.ShapeDtypeStruct
+    cache = (shape((B, W + chunks, H * d), jnp.bfloat16),) * 2
+    args = (shape((B, 64), f32), shape((B,), jnp.int32), cache)
+    variables = jax.tree_util.tree_map(
+        lambda p: shape(p.shape, jnp.bfloat16), jax.eval_shape(
+            lambda *a: block.init(jax.random.PRNGKey(0), *a,
+                                  method=EvaBlock.step), *args))
+    text = jax.jit(lambda v, *a: block.apply(
+        v, *a, method=EvaBlock.step)).lower(variables, *args).as_text()
+    products = re.findall(
+        r'stablehlo\.dot_general [^\n]*batching_dims = (\[[\d, ]*\] x '
+        r'\[[\d, ]*\])[^\n]*tensor<%dx%dx%dxbf16>\) ->'
+        % (B, W + chunks, H * d), text)
+    assert products == ['[0] x [0]'] * 2
+    sizes = [int(np.prod([int(n) for n in dims.split('x')]))
+             for dims in re.findall(r'tensor<([\dx]+)xf32>', text)]
+    assert sizes and max(sizes) < B * (W + chunks) * H * d
 
 
 def test_the_epoch_record_and_the_gauge_carry_what_the_learner_holds():

@@ -135,7 +135,7 @@ def test_a_new_game_resets_counters_and_leaves_the_buffers():
         hidden = step(variables, ids[:, t], hidden)['hidden']
     reset = net.reset_hidden(hidden, jnp.asarray([True, False]))
     assert list(np.asarray(reset['pos'])) == [0, 24]
-    for key in ('k', 'v', 'sk', 'sv'):
+    for key in ('k', 'v'):
         for a, b in zip(reset[key], hidden[key]):
             assert a is b
     fresh = net.init_hidden((2,))
@@ -145,6 +145,90 @@ def test_a_new_game_resets_counters_and_leaves_the_buffers():
         reset, fresh = out_stale['hidden'], out_fresh['hidden']
         np.testing.assert_array_equal(out_stale['policy'][0],
                                       out_fresh['policy'][0])
+
+
+# -- the decode step's products against the formula it replaced ---------------
+def _plain_step(block, x, pos, cache):
+    """``EvaBlock.step`` as it stood before PR 50, in plain einsums over the
+    buffers with a head axis: one query row a head against the window's rows
+    and the summaries, ONE soft-max over both, the two value sums."""
+    from handyrl_tpu.models.evabyte import NEG, _dot, _summarise, f32
+    ck, cv, csk, csv = cache                    # (B, rows, H, d)
+    W, chunk = block.window_size, block.chunk_size
+    B = x.shape[0]
+    rows = jnp.arange(B)
+    q, k, v = block._qkv(x, pos)
+    slot = pos % W
+    ck, cv = ck.at[rows, slot].set(k), cv.at[rows, slot].set(v)
+    inside = ((slot // chunk) * chunk)[:, None] + jnp.arange(chunk)[None, :]
+    sk, sv = jax.vmap(lambda kc, vc, m: _summarise(
+        jnp.swapaxes(kc, 0, 1), jnp.swapaxes(vc, 0, 1), block.mu, block.phi,
+        m[None]))(ck[rows[:, None], inside], cv[rows[:, None], inside],
+                  inside <= slot[:, None])
+    csk = csk.at[rows, pos // chunk].set(sk[:, :, 0])
+    csv = csv.at[rows, pos // chunk].set(sv[:, :, 0])
+    local = jnp.arange(W)[None, :] <= slot[:, None]
+    remote = (jnp.arange(csk.shape[1])[None, :]
+              < (pos // W * (W // chunk))[:, None])
+    scale = block.head_dim ** -0.5
+    s_local = scale * jnp.einsum('bhd,bwhd->bhw', q, ck,
+                                 preferred_element_type=f32)
+    s_remote = scale * jnp.einsum('bhd,bchd->bhc', q, csk,
+                                  preferred_element_type=f32)
+    prob = jax.nn.softmax(jnp.concatenate(
+        [jnp.where(local[:, None], s_local, NEG),
+         jnp.where(remote[:, None], s_remote, NEG)], axis=-1),
+        axis=-1).astype(cv.dtype)
+    y = (jnp.einsum('bhw,bwhd->bhd', prob[..., :W], cv,
+                    preferred_element_type=f32)
+         + jnp.einsum('bhc,bchd->bhd', prob[..., W:], csv,
+                      preferred_element_type=f32))
+    x = x + _dot(y.reshape(B, -1), block.wo, block.dtype, out=f32)
+    return block.mlp(x), (ck, cv, csk, csv)
+
+
+# counters a sequence (window 16, chunk 4, 16 summaries); every buffer is
+# full of another game's rows, so whatever a mask lets through shows
+COUNTERS = {
+    'before_the_window_wraps': [0, 3, 15],
+    'after_it_has_wrapped': [16, 17, 30],      # slot < pos: stale rows above
+    'summaries_of_three_windows': [48, 55, 63],
+    'just_reset_beside_one_that_is_not': [0, 37, 0],
+}
+
+
+@pytest.mark.parametrize('heads', [8, 3])
+@pytest.mark.parametrize('case', sorted(COUNTERS))
+def test_the_step_side_by_side_is_the_plain_step(case, heads):
+    """The heads' queries as ONE matrix against a buffer as it lies, the
+    window's rows and then the summaries (``models/attention.py``; at 3
+    heads the matrix is padded to 8 rows), give what one query row a head
+    gave over buffers of their own: the layer's output and every row."""
+    import flax.linen as nn
+    from handyrl_tpu.models.evabyte import EvaBlock
+    block = EvaBlock(64, heads, 16, 96, 4, 16, 1e5, 1e-5, 8, jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(heads), 7)
+    pos = jnp.asarray(COUNTERS[case])
+    x = jax.random.normal(keys[0], (3, 64))
+    # a layer's K and V: 16 window rows, then 16 summaries
+    flat = tuple(jax.random.normal(key, (3, 32, heads * 16))
+                 for key in keys[1:3])
+    variables = jax.tree_util.tree_map(
+        lambda p: p * 8 if p.ndim >= 2
+        else 0.3 * jax.random.normal(keys[5], p.shape),
+        block.init(keys[6], x, pos, flat, method=EvaBlock.step))
+    got, got_cache = block.apply(variables, x, pos, flat,
+                                 method=EvaBlock.step)
+    with_heads = lambda c: c.reshape(3, 16, heads, 16)
+    want, (ck, cv, csk, csv) = nn.apply(_plain_step, block)(
+        variables, x, pos,
+        (with_heads(flat[0][:, :16]), with_heads(flat[1][:, :16]),
+         with_heads(flat[0][:, 16:]), with_heads(flat[1][:, 16:])))
+    assert float(jnp.abs(want - x).max()) > 0.1
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for a, b in zip(got_cache, ((ck, csk), (cv, csv))):
+        np.testing.assert_array_equal(
+            a, jnp.concatenate(b, axis=1).reshape(a.shape))
 
 
 def test_the_four_head_shares_sum_to_the_uncut_layer():
