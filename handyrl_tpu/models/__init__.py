@@ -4,6 +4,36 @@ Registry maps architecture names to constructors so model snapshots can be
 shipped over the wire as (name, flat params) instead of pickled code objects
 (the reference pickles whole nn.Modules — train.py:615; we deliberately
 don't).
+
+What the learner reads off a net beside ``__call__(obs, hidden, train) ->
+{'policy', 'value', 'hidden', ...}``, each by ``hasattr``; in brackets what a
+net without the name gets. The trunk nets (``evabyte``, ``trinity``,
+``smallthinker``, ``ouro``) take most of them from ``models/shell.py``, and
+tests/test_models.py holds the four to these signatures.
+
+* ``init_hidden(batch_shape=()) -> hidden``: the state one ``__call__`` hands
+  the next (``model.py``, ``device_generation.py``; ``ops/train_step.py``
+  scans a window through it unless the net has ``sequence``). [None]
+* ``reset_hidden(hidden, done) -> hidden``: what a finished game resets, for
+  a state that is a cache kept by counters (``device_generation.py``,
+  ``ops/fused_pipeline.py``). [the whole tree is zero-filled]
+* ``sequence(ids, first_position, valid, no_grad_prefix=0) -> outputs``: a
+  training window as ONE causal forward (``ops/train_step.py``,
+  ``ops/losses.py`` ``_sequence_prediction``; ``train.py`` then stores each
+  window's ``first_position``). Outputs (B, T, ...): ``policy`` or
+  ``policy_features``, ``value``, further heads; with ``exit_gate`` each has
+  a LEADING pass axis; ``aux`` is a dict of sums. [the window is scanned]
+* ``policy_logits(features) -> logits``: the head over ``policy_features``,
+  which the loss takes a block of positions at a time
+  (``ops/train_step.py``). [``sequence`` returns ``policy``]
+* ``post_update(before, after, aux) -> params``: runs on the parameter trees
+  after the optimizer, ``aux`` the ``sequence``'s (``ops/train_step.py``).
+* ``attention_key_share(T) -> float``: the share of all (query, key) pairs a
+  window of ``T`` multiplies, in every epoch's record (``train.py``).
+* ``epoch_dynamics(sums) -> dict``: record keys of the net's own from the
+  epoch's ``diag_*`` sums (``train.py`` ``_epoch_dynamics``).
+* ``actor_param_dtype``: what the actor's copy of the parameters is cast to
+  (``train.py`` ``_run_fused``). [a float32 copy]
 """
 
 from typing import Callable, Dict

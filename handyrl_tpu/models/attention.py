@@ -6,16 +6,16 @@ or the ``window`` keys up to and with the query's own. What comes before
 the scopes these run under (``models/trinity.py``, ``models/smallthinker.py``).
 A net that runs its layers several times (``models/ouro.py``) keeps K and V
 of every (pass, layer): ``init_pass_cache``, ``pass_write``, ``pass_rows``.
-A layer WITHOUT grouping decodes with its heads' queries side by side
-(``heads_side_by_side`` and the products over a set of rows; over one set
-``side_by_side_attention``, over two under one soft-max ``models/evabyte.py``).
+``cache_attention`` is the ONE decode attention: grouped, or for a layer
+WITHOUT grouping the heads' queries side by side, by the shapes it is handed
+(``heads_side_by_side`` and the products over a set of rows, which
+``models/evabyte.py`` composes over two sets under one soft-max).
 """
 
 import jax
 import jax.numpy as jnp
 
-NEG = -1e30
-f32 = jnp.float32
+from .trunk import NEG, f32
 
 
 def block_keys(T, window, query_block):
@@ -132,17 +132,42 @@ def cache_write(ck, cv, k, v, pos):
             cv.at[seq, slot].set(v.reshape(v.shape[0], -1)))
 
 
+def rows_seen(n_rows, pos, circle):
+    """(B, n_rows): the rows each sequence's counter ``pos`` (B,) has
+    reached. Nothing is ever cleared: a ``circle`` that has gone round holds
+    the ``n_rows`` positions up to this one, before that (and in a buffer as
+    long as the game) rows 0..pos count."""
+    seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
+    return seen | (pos[:, None] >= n_rows) if circle else seen
+
+
 def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):
-    """q (B, H, d) at each sequence's own position ``pos`` (B,) over the
-    rows written so far -> (B, H * d). Nothing is ever cleared: rows the
-    counter has not reached are masked; a ``circle`` that has gone round
-    holds the ``rows`` positions up to this one, before that rows 0..pos
-    (keys are stored already turned, so a row needs no position)."""
+    """The decode ply's attention, the one a layer's ``step`` calls: q (B,
+    H, d) at each sequence's own position ``pos`` (B,) over the rows written
+    so far of ck, cv (B, rows, kv_heads * d) -> (B, H * d); rows not
+    ``rows_seen`` are masked (keys are stored already turned, so a row needs
+    no position). With one query head a KV head the grouped form is the
+    slow one (``heads_side_by_side``), so the shapes say which to take:
+    without groups the side-by-side product over ONE set of rows, read once
+    as they lie, with no relayout."""
+    B, H, d = q.shape
+    if H != kv_heads:
+        return grouped_cache_attention(q, ck, cv, pos, circle, kv_heads,
+                                       dtype)
+    s = side_by_side_scores(heads_side_by_side(q), ck, d)
+    seen = rows_seen(ck.shape[1], pos, circle)
+    prob = jax.nn.softmax(jnp.where(seen[:, None], s, NEG),
+                          axis=-1).astype(cv.dtype)
+    out = side_by_side_values(prob, cv)
+    return own_blocks(out, H).astype(dtype).reshape(B, H * d)
+
+
+def grouped_cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):
+    """``cache_attention`` by KV head: the ``H / kv_heads`` query heads of a
+    group as one matrix against their KV head's rows."""
     B, n_rows = ck.shape[:2]
     H, d = q.shape[1:]
-    seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
-    if circle:
-        seen = seen | (pos[:, None] >= n_rows)
+    seen = rows_seen(n_rows, pos, circle)
     rows = lambda c: c.reshape(B, n_rows, kv_heads, d)
     s = d ** -0.5 * jnp.einsum(
         'bkgd,brkd->bkgr', q.reshape(B, kv_heads, H // kv_heads, -1),
@@ -212,18 +237,3 @@ def own_blocks(out, H):
     B, m = out.shape[:2]
     heads = jnp.arange(H)
     return out.reshape(B, m, H, -1)[:, heads, heads]
-
-
-def side_by_side_attention(q, ck, cv, pos, dtype):
-    """``cache_attention`` for a layer WITHOUT grouping (one query head a KV
-    head) and no circle, on the buffers as they lie: q (B, H, d) over ck, cv
-    (B, rows, H * d) -> (B, H * d), the side-by-side product over ONE set of
-    rows. The rows are read once, in bfloat16, with no relayout."""
-    B, H, d = q.shape
-    n_rows = ck.shape[1]
-    s = side_by_side_scores(heads_side_by_side(q), ck, d)
-    seen = jnp.arange(n_rows)[None, :] <= pos[:, None]
-    prob = jax.nn.softmax(jnp.where(seen[:, None], s, NEG),
-                          axis=-1).astype(cv.dtype)
-    out = side_by_side_values(prob, cv)
-    return own_blocks(out, H).astype(dtype).reshape(B, H * d)

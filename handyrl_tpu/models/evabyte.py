@@ -48,33 +48,11 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from . import attention, register
+from .shell import TrunkNet
+from .trunk import NEG, burn_in_as_state, dot, f32, rms_norm, rotary
 
-NEG = -1e30
-f32 = jnp.float32
-
-
-def _rms_norm(x, g, eps, dtype):
-    """Float32 in, ``dtype`` out; the published norm's unit offset."""
-    x = x.astype(f32)
-    y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return (y * (1.0 + g.astype(f32))).astype(dtype)
-
-
-def _rotary(x, positions, theta):
-    """x (..., d) at absolute ``positions`` (broadcast over x's leading
-    axes): the pair (i, i + d/2) turned by p * theta^(-2i/d), in float32."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=f32) / half)
-    angle = positions.astype(f32)[..., None] * freq
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    a, b = x[..., :half].astype(f32), x[..., half:].astype(f32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _dot(x, w, dtype, out=None):
-    return jnp.dot(x.astype(dtype), w.astype(dtype),
-                   preferred_element_type=out or dtype)
+# the published norm multiplies by one plus its weight
+_rms_norm = functools.partial(rms_norm, unit_offset=True)
 
 
 def _summarise(k, v, mu, phi, member):
@@ -130,19 +108,19 @@ class EvaBlock(nn.Module):
         turned by their positions' phases."""
         h = _rms_norm(x, self.norm_attn, self.norm_eps, self.dtype)
         shape = x.shape[:-1] + (self.heads_held, self.head_dim)
-        q = _dot(h, self.wq, self.dtype).reshape(shape)
-        k = _dot(h, self.wk, self.dtype).reshape(shape)
-        v = _dot(h, self.wv, self.dtype).reshape(shape)
+        q = dot(h, self.wq, self.dtype).reshape(shape)
+        k = dot(h, self.wk, self.dtype).reshape(shape)
+        v = dot(h, self.wv, self.dtype).reshape(shape)
         pos = positions[..., None]
-        return (_rotary(q, pos, self.rope_theta),
-                _rotary(k, pos, self.rope_theta), v)
+        return (rotary(q, pos, self.rope_theta),
+                rotary(k, pos, self.rope_theta), v)
 
     def mlp(self, x):
         with jax.named_scope('trunk_mlp'):
             h = _rms_norm(x, self.norm_mlp, self.norm_eps, self.dtype)
-            act = (jax.nn.silu(_dot(h, self.w_gate, self.dtype))
-                   * _dot(h, self.w_up, self.dtype))
-            return x + _dot(act, self.w_down, self.dtype, out=f32)
+            act = (jax.nn.silu(dot(h, self.w_gate, self.dtype))
+                   * dot(h, self.w_up, self.dtype))
+            return x + dot(act, self.w_down, self.dtype, out=f32)
 
     # -- a whole window -----------------------------------------------------
     def attention_part(self, x, positions, valid, no_grad_prefix=0):
@@ -150,15 +128,9 @@ class EvaBlock(nn.Module):
         inputs at absolute ``positions`` (B, T): (B, T, D) float32."""
         with jax.named_scope('eva_attention'):
             q, k, v = self._qkv(x, positions)
-            if no_grad_prefix:
-                # the burn-in's positions are state, not trained: their
-                # keys and values (and so their summaries) carry no gradient
-                keep = (jnp.arange(x.shape[1]) >= no_grad_prefix)[
-                    None, :, None, None]
-                k = jnp.where(keep, k, jax.lax.stop_gradient(k))
-                v = jnp.where(keep, v, jax.lax.stop_gradient(v))
+            k, v = burn_in_as_state(k, v, no_grad_prefix)
             y = jax.vmap(self._sequence_attention)(q, k, v, positions, valid)
-            return _dot(y, self.wo, self.dtype, out=f32)
+            return dot(y, self.wo, self.dtype, out=f32)
 
     def _sequence_attention(self, q, k, v, positions, valid):
         """One sequence. q, k, v (T, H, d) -> (T, H * d)."""
@@ -250,12 +222,12 @@ class EvaBlock(nn.Module):
                                   axis=-1).astype(cv.dtype)
             y = attention.own_blocks(
                 attention.side_by_side_values(prob, cv), H)
-            x = x + _dot(y.reshape(B, -1), self.wo, self.dtype, out=f32)
+            x = x + dot(y.reshape(B, -1), self.wo, self.dtype, out=f32)
         return self.mlp(x), (ck, cv)
 
 
 @register('EvaByteNet')
-class EvaByteNet(nn.Module):
+class EvaByteNet(TrunkNet):
     """The trunk with eight heads of prediction (head 0 is the policy over
     the ids, heads 1-7 the published multi-byte objective) and a value row.
     Observations are int32 ids. The published widths are the defaults; the
@@ -290,12 +262,6 @@ class EvaByteNet(nn.Module):
             'heads', init, (self.pred_heads, self.hidden_size, self.vocab))
         self.value = self.param('value', init, (self.hidden_size, 1))
 
-    @property
-    def actor_param_dtype(self):
-        """The actor's copy of the parameters is kept in the compute dtype
-        (train.py ``actor_refresh``): rollout reads every weight each ply."""
-        return self.dtype
-
     # -- the cache -----------------------------------------------------------
     def init_hidden(self, batch_shape=()):
         """A layer's K and V: the window's rows, then a summary a chunk of
@@ -305,34 +271,22 @@ class EvaByteNet(nn.Module):
             batch_shape, [rows] * self.layers,
             self.heads_held * self.head_dim, self.dtype)
 
-    # a finished game resets its sequences' counters, not their buffers:
-    # what a counter has not reached is masked
-    reset_hidden = staticmethod(attention.reset_cache)
+    # -- inputs and outputs ----------------------------------------------------
+    def _embed(self, ids):
+        return self.embed[ids].astype(f32)
 
-    # -- outputs ---------------------------------------------------------------
-    def _readout(self, x):
+    def _window_readout(self, x):
         h = _rms_norm(x, self.norm_out, self.norm_eps, self.dtype)
         logits = jnp.einsum('...d,ndv->...nv', h,
                             self.heads.astype(self.dtype),
                             preferred_element_type=f32)
-        value = jnp.tanh(_dot(h, self.value, self.dtype, out=f32))
+        value = jnp.tanh(dot(h, self.value, self.dtype, out=f32))
         return {'policy': logits[..., 0, :], 'value': value,
                 'heads': logits[..., 1:, :]}
 
-    def __call__(self, obs, hidden, train: bool = False):
-        """One position a sequence: obs (B,) int32 ids."""
-        if hidden is None:
-            hidden = self.init_hidden(obs.shape)
-        pos = hidden['pos']
-        x = self.embed[obs].astype(f32)
-        ks, vs = [], []
-        for i, block in enumerate(self.blocks):
-            x, (k, v) = block.step(x, pos, (hidden['k'][i], hidden['v'][i]))
-            ks.append(k)
-            vs.append(v)
-        out = self._readout(x)
+    def _readout(self, x):
+        out = self._window_readout(x)
         out.pop('heads')    # acting reads head 0 alone
-        out['hidden'] = {'k': tuple(ks), 'v': tuple(vs), 'pos': pos + 1}
         return out
 
     def sequence(self, ids, first_position, valid, no_grad_prefix: int = 0):
@@ -341,15 +295,10 @@ class EvaByteNet(nn.Module):
         element, valid (B, T) bool. Returns policy (B, T, vocab), value
         (B, T, 1), heads (B, T, pred_heads - 1, vocab), all float32."""
         positions = first_position[:, None] + jnp.arange(ids.shape[1])
-        x = self.embed[ids].astype(f32)
+        x = self._embed(ids)
         for block in self.blocks:
             # one layer rematerialised at a time: the backward pass keeps
             # each layer's input and recomputes the rest
             x = nn.remat(EvaBlock.sequence, static_argnums=(4,))(
                 block, x, positions, valid, no_grad_prefix)
-        return self._readout(x)
-
-    def attention_part(self, layer: int, x, positions, valid):
-        """Layer ``layer``'s attention output for this chip's heads alone
-        (the head-share test sums four of these against the uncut layer)."""
-        return self.blocks[layer].attention_part(x, positions, valid)
+        return self._window_readout(x)

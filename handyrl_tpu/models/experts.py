@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-f32 = jnp.float32
+from .trunk import f32
 
 
 def held_slot(ids, experts_held, experts_published):

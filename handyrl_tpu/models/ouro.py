@@ -53,8 +53,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from . import attention, register
-from .evabyte import _dot, _rotary, f32
-from .trinity import _rms_norm
+from .shell import ScaledTrunkNet
+from .trunk import burn_in_as_state, dot, f32, heads_of, rms_norm, rotary
 
 LAYER_LEAVES = ('wq', 'wk', 'wv', 'wo', 'w_gate', 'w_up', 'w_down',
                 'norm_1', 'norm_2', 'norm_3', 'norm_4')
@@ -101,47 +101,40 @@ class OuroBlock(nn.Module):
 def _qkv(spec, p, n, positions):
     """n (..., D) in ``dtype`` at ``positions`` (...,) -> q (..., H, d), k, v
     (..., KV, d), q and k turned by their positions' phases."""
-    lead, d = n.shape[:-1], spec.head_dim
-    q = (_dot(n, p['wq'], spec.dtype) * spec.inv).reshape(
-        lead + (spec.heads, d))
-    k = (_dot(n, p['wk'], spec.dtype) * spec.inv).reshape(
-        lead + (spec.kv_heads, d))
-    v = (_dot(n, p['wv'], spec.dtype) * spec.inv).reshape(
-        lead + (spec.kv_heads, d))
+    q = heads_of(n, p['wq'], spec.heads, spec.dtype, spec.inv)
+    k = heads_of(n, p['wk'], spec.kv_heads, spec.dtype, spec.inv)
+    v = heads_of(n, p['wv'], spec.kv_heads, spec.dtype, spec.inv)
     pos = positions[..., None]
-    return (_rotary(q, pos, spec.rope_theta),
-            _rotary(k, pos, spec.rope_theta), v)
+    return (rotary(q, pos, spec.rope_theta),
+            rotary(k, pos, spec.rope_theta), v)
 
 
 def _attention_part(spec, p, x, positions, valid, no_grad_prefix=0):
     """This chip's heads' part of ``W_o``'s sum for (B, T, D) float32 inputs:
     (B, T, D) float32, before the branch's norm."""
-    n = _rms_norm(x, p['norm_1'], spec.norm_eps, spec.dtype)
+    n = rms_norm(x, p['norm_1'], spec.norm_eps, spec.dtype)
     q, k, v = _qkv(spec, p, n, positions)
-    if no_grad_prefix:      # the burn-in's state carries no gradient
-        keep = (jnp.arange(x.shape[1]) >= no_grad_prefix)[None, :, None, None]
-        k = jnp.where(keep, k, jax.lax.stop_gradient(k))
-        v = jnp.where(keep, v, jax.lax.stop_gradient(v))
+    k, v = burn_in_as_state(k, v, no_grad_prefix)
     y = jax.vmap(lambda *seq: attention.sequence_attention(
         *seq, None, spec.query_block))(q, k, v, positions, valid)
-    return _dot(y, p['wo'], spec.dtype, out=f32) * spec.inv
+    return dot(y, p['wo'], spec.dtype, out=f32) * spec.inv
 
 
 def _mlp(spec, p, a):
     """``a + N4(MLP(N3(a)))`` on the float32 residual."""
     with jax.named_scope('trunk_mlp'):
-        n = _rms_norm(a, p['norm_3'], spec.norm_eps, spec.dtype)
-        act = (jax.nn.silu(_dot(n, p['w_gate'], spec.dtype) * spec.inv)
-               * (_dot(n, p['w_up'], spec.dtype) * spec.inv))
-        m = _dot(act, p['w_down'], spec.dtype, out=f32) * spec.inv
-        return a + _rms_norm(m, p['norm_4'], spec.norm_eps, f32)
+        n = rms_norm(a, p['norm_3'], spec.norm_eps, spec.dtype)
+        act = (jax.nn.silu(dot(n, p['w_gate'], spec.dtype) * spec.inv)
+               * (dot(n, p['w_up'], spec.dtype) * spec.inv))
+        m = dot(act, p['w_down'], spec.dtype, out=f32) * spec.inv
+        return a + rms_norm(m, p['norm_4'], spec.norm_eps, f32)
 
 
 def _layer_sequence(spec, no_grad_prefix, p, x, positions, valid):
     """One layer over a whole window: (B, T, D) float32 -> the same."""
     with jax.named_scope('loop_attention'):
         part = _attention_part(spec, p, x, positions, valid, no_grad_prefix)
-        a = x + _rms_norm(part, p['norm_2'], spec.norm_eps, f32)
+        a = x + rms_norm(part, p['norm_2'], spec.norm_eps, f32)
     return _mlp(spec, p, a)
 
 
@@ -149,20 +142,21 @@ def _layer_step(spec, p, x, pos, t, rows, ck, cv):
     """One layer, one position a sequence, in pass ``t``: x (B, D) float32 at
     each sequence's own ``pos`` (B,); ck, cv (B, passes * rows, KV * d)."""
     with jax.named_scope('loop_attention'):
-        n = _rms_norm(x, p['norm_1'], spec.norm_eps, spec.dtype)
+        n = rms_norm(x, p['norm_1'], spec.norm_eps, spec.dtype)
         q, k, v = _qkv(spec, p, n, pos)                  # (B, H | KV, d)
         with jax.named_scope('state_update'):
             ck, cv = attention.pass_write(ck, cv, k, v, pos, t, rows)
-        y = attention.side_by_side_attention(
+        y = attention.cache_attention(
             q, attention.pass_rows(ck, t, rows),
-            attention.pass_rows(cv, t, rows), pos, spec.dtype)
-        part = _dot(y, p['wo'], spec.dtype, out=f32) * spec.inv
-        a = x + _rms_norm(part, p['norm_2'], spec.norm_eps, f32)
+            attention.pass_rows(cv, t, rows), pos, False, spec.kv_heads,
+            spec.dtype)
+        part = dot(y, p['wo'], spec.dtype, out=f32) * spec.inv
+        a = x + rms_norm(part, p['norm_2'], spec.norm_eps, f32)
     return _mlp(spec, p, a), ck, cv
 
 
 @register('OuroNet')
-class OuroNet(nn.Module):
+class OuroNet(ScaledTrunkNet):
     """The looped trunk with its untied head read as a policy over the ids
     held, a value row and the exit gate's row. Observations are int32 ids.
     The defaults are the published counts; the depth, the heads and the
@@ -188,10 +182,6 @@ class OuroNet(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
 
     def setup(self):
-        # the published layer has no groups, and the decode ply's attention
-        # (``side_by_side_attention``) is written for none
-        assert self.heads_held == self.kv_heads_held, (
-            self.heads_held, self.kv_heads_held)
         init = nn.initializers.normal(0.02 * self.param_scale)
         D = self.hidden_size
         self.embed = self.param('embed', init, (self.vocab, D))
@@ -211,31 +201,13 @@ class OuroNet(nn.Module):
                      self.rope_theta, self.norm_eps, self.query_block,
                      1 / self.param_scale, self.dtype)
 
-    @property
-    def actor_param_dtype(self):
-        """The actor's copy of the parameters is kept in the compute dtype
-        (train.py ``actor_refresh``): rollout reads every weight each pass."""
-        return self.dtype
-
     # -- the cache -----------------------------------------------------------
     def init_hidden(self, batch_shape=()):
         return attention.init_pass_cache(
             batch_shape, self.passes, [self.max_positions] * self.layers,
             self.kv_heads_held * self.head_dim, self.dtype)
 
-    reset_hidden = staticmethod(attention.reset_cache)
-
     # -- inputs and outputs --------------------------------------------------
-    def _embed(self, ids):
-        return self.embed[ids].astype(f32) / self.param_scale
-
-    def _row(self, features, w):
-        return _dot(features, w, self.dtype, out=f32) / self.param_scale
-
-    def policy_logits(self, features):
-        """The head over the ids held, float32: features (..., D)."""
-        return self._row(features, self.head)
-
     def __call__(self, obs, hidden, train: bool = False):
         """One position a sequence: obs (B,) int32 ids. Every pass runs; the
         head and the value row read the last."""
@@ -251,14 +223,13 @@ class OuroNet(nn.Module):
             for i, p in enumerate(weights):
                 x, ks[i], vs[i] = _layer_step(spec, p, x, pos, t, rows,
                                               ks[i], vs[i])
-            x = _rms_norm(x, norm_out, spec.norm_eps, f32)
+            x = rms_norm(x, norm_out, spec.norm_eps, f32)
             return (x, tuple(ks), tuple(vs)), None
         (x, ks, vs), _ = jax.lax.scan(
             one_pass, (self._embed(obs), hidden['k'], hidden['v']),
             jnp.arange(self.passes))
         h = x.astype(self.dtype)
-        return {'policy': self.policy_logits(h),
-                'value': jnp.tanh(self._row(h, self.value)),
+        return {'policy': self.policy_logits(h), 'value': self._value(h),
                 'hidden': {'k': ks, 'v': vs, 'pos': pos + 1}}
 
     def sequence(self, ids, first_position, valid, no_grad_prefix: int = 0):
@@ -280,20 +251,17 @@ class OuroNet(nn.Module):
             for p in weights:
                 x = layer(p, x, positions, valid)
             with jax.named_scope('pass_readout'):
-                x = _rms_norm(x, norm_out, spec.norm_eps, f32)
+                x = rms_norm(x, norm_out, spec.norm_eps, f32)
             return x, x.astype(spec.dtype)
         _, features = jax.lax.scan(one_pass, self._embed(ids), None,
                                    length=self.passes)
         with jax.named_scope('pass_readout'):
-            value = jnp.tanh(self._row(features, self.value))
+            value = self._value(features)
             gate = self._row(features, self.gate) + self.gate_bias
         return {'policy_features': features, 'value': value,
                 'exit_gate': gate}
 
     def attention_part(self, layer: int, x, positions, valid):
-        """Layer ``layer``'s attention output for this chip's heads alone,
-        before the branch's norm (the head-share test sums four of these
-        against the uncut layer)."""
         return _attention_part(self.spec, self.blocks[layer].weights(), x,
                                positions, valid)
 

@@ -58,8 +58,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from . import attention, experts, register
-from .evabyte import _dot, _rotary, f32
-from .trinity import _rms_norm
+from .shell import ExpertTrunkNet
+from .trunk import burn_in_as_state, dot, f32, heads_of, rotary
+# under this name tests/benchmark plants a fault through it
+from .trunk import rms_norm as _rms_norm
 
 PUBLISHED_LAYERS = ('global', 'window', 'window', 'window') * 13
 
@@ -165,30 +167,22 @@ class SmallThinkerBlock(nn.Module):
     def _qkv(self, a, positions):
         """a (..., D) in ``dtype`` at ``positions`` (...,) -> q (..., H, d),
         k, v (..., KV, d)."""
-        lead, d, inv = a.shape[:-1], self.head_dim, self.inv
-        q = (_dot(a, self.wq, self.dtype) * inv).reshape(
-            lead + (self.heads_held, d))
-        k = (_dot(a, self.wk, self.dtype) * inv).reshape(
-            lead + (self.kv_heads_held, d))
-        v = (_dot(a, self.wv, self.dtype) * inv).reshape(
-            lead + (self.kv_heads_held, d))
+        q = heads_of(a, self.wq, self.heads_held, self.dtype, self.inv)
+        k = heads_of(a, self.wk, self.kv_heads_held, self.dtype, self.inv)
+        v = heads_of(a, self.wv, self.kv_heads_held, self.dtype, self.inv)
         if self.kind == 'window':
             pos = positions[..., None]
-            q = _rotary(q, pos, self.rope_theta)
-            k = _rotary(k, pos, self.rope_theta)
+            q = rotary(q, pos, self.rope_theta)
+            k = rotary(k, pos, self.rope_theta)
         return q, k, v
 
     def _out(self, y):
         """This chip's heads' part of ``W_o``'s sum."""
-        return _dot(y, self.wo, self.dtype, out=f32) * self.inv
+        return dot(y, self.wo, self.dtype, out=f32) * self.inv
 
     def _attention(self, a, positions, valid, no_grad_prefix=0):
         q, k, v = self._qkv(a, positions)
-        if no_grad_prefix:      # the burn-in's state carries no gradient
-            keep = (jnp.arange(a.shape[1]) >= no_grad_prefix)[
-                None, :, None, None]
-            k = jnp.where(keep, k, jax.lax.stop_gradient(k))
-            v = jnp.where(keep, v, jax.lax.stop_gradient(v))
+        k, v = burn_in_as_state(k, v, no_grad_prefix)
         window = self.window_size if self.kind == 'window' else None
         y = jax.vmap(lambda *seq: attention.sequence_attention(
             *seq, window, self.query_block))(q, k, v, positions, valid)
@@ -238,7 +232,7 @@ class SmallThinkerBlock(nn.Module):
 
 
 @register('SmallThinkerNet')
-class SmallThinkerNet(nn.Module):
+class SmallThinkerNet(ExpertTrunkNet):
     """The trunk with its untied head read as a policy over the ids held and
     a value row. Observations are int32 ids. The defaults are the published
     counts, at which every layer IS the published layer; the depth, the
@@ -271,14 +265,7 @@ class SmallThinkerNet(nn.Module):
     param_scale: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
 
-    @property
-    def held(self):
-        return (tuple(range(self.experts_published))
-                if self.experts_held is None else tuple(self.experts_held))
-
-    @property
-    def expert_layers(self):
-        return tuple(range(len(self.layer_types)))
+    windowed_kind = 'window'
 
     def setup(self):
         init = nn.initializers.normal(0.02 * self.param_scale)
@@ -296,84 +283,20 @@ class SmallThinkerNet(nn.Module):
         self.head = self.param('head', init, (self.hidden_size, self.vocab))
         self.value = self.param('value', init, (self.hidden_size, 1))
 
-    @property
-    def actor_param_dtype(self):
-        """The actor's copy of the parameters is kept in the compute dtype
-        (train.py ``actor_refresh``): rollout reads every weight each ply."""
-        return self.dtype
-
-    # -- the cache -----------------------------------------------------------
-    def init_hidden(self, batch_shape=()):
-        return attention.init_cache(
-            batch_shape, [self.window_size if kind == 'window'
-                          else self.max_positions
-                          for kind in self.layer_types],
-            self.kv_heads_held * self.head_dim, self.dtype)
-
-    reset_hidden = staticmethod(attention.reset_cache)
-
-    # -- inputs and outputs --------------------------------------------------
-    def _embed(self, ids):
-        return self.embed[ids].astype(f32) / self.param_scale
-
-    def _features(self, x):
-        return _rms_norm(x, self.norm_out, self.norm_eps, self.dtype)
-
-    def _value(self, features):
-        return jnp.tanh(_dot(features, self.value, self.dtype, out=f32)
-                        / self.param_scale)
-
-    def policy_logits(self, features):
-        """The head over the ids held, float32: features (..., D)."""
-        return _dot(features, self.head, self.dtype, out=f32) \
-            / self.param_scale
-
-    def __call__(self, obs, hidden, train: bool = False):
-        """One position a sequence: obs (B,) int32 ids."""
-        if hidden is None:
-            hidden = self.init_hidden(obs.shape)
-        pos = hidden['pos']
-        x = self._embed(obs)
-        ks, vs = [], []
-        for i, block in enumerate(self.blocks):
-            x, (k, v) = block.step(x, pos, (hidden['k'][i], hidden['v'][i]))
-            ks.append(k)
-            vs.append(v)
-        h = self._features(x)
-        return {'policy': self.policy_logits(h),
-                'value': self._value(h),
-                'hidden': {'k': tuple(ks), 'v': tuple(vs), 'pos': pos + 1}}
-
     def sequence(self, ids, first_position, valid, no_grad_prefix: int = 0):
-        """T positions a sequence in one causal forward. ids (B, T) int32,
-        first_position (B,), valid (B, T) bool. Returns ``policy_features``
-        (B, T, D) in ``dtype`` (``policy_logits`` of them are the policy:
-        the loss takes the head a block of positions at a time), ``value``
-        (B, T, 1) float32 and ``aux``: the sums the forward pass hands to
-        the epoch record and to ``post_update``."""
-        T = ids.shape[1]
-        positions = first_position[:, None] + jnp.arange(T)
-        x = self._embed(ids)
-        counts, tally = [], jnp.zeros((2,), jnp.int32)
-        for block in self.blocks:
-            # one layer rematerialised at a time, as models/evabyte.py
-            x, c, t = nn.remat(SmallThinkerBlock.sequence,
-                               static_argnums=(4,))(
-                block, x, positions, valid, no_grad_prefix)
-            counts.append(c)
-            tally = tally + t
-        h = self._features(x)
+        """The shell's, and in ``aux`` how many of the trained positions a
+        window layer hides a key from."""
+        h, counts, tally = self._layers(ids, first_position, valid,
+                                        no_grad_prefix)
         # the sequence starts with an empty cache at its first position:
         # from ``window_size`` positions on a window layer hides a key
+        T = ids.shape[1]
         trained = valid & (jnp.arange(T) >= no_grad_prefix)
         hidden = trained & (jnp.arange(T) >= self.window_size)
         return {'policy_features': h, 'value': self._value(h), 'aux': dict(
             experts.rows_aux(jnp.stack(counts), self.held, tally),
             window_positions_valid=trained.sum().astype(f32),
             window_positions_hidden=hidden.sum().astype(f32))}
-
-    def attention_part(self, layer: int, x, positions, valid):
-        return self.blocks[layer].attention_part(x, positions, valid)
 
     def experts_part(self, layer: int, a32, m):
         """Layer ``layer``'s experts' sum for (n, D) rows: routed from
@@ -394,17 +317,9 @@ class SmallThinkerNet(nn.Module):
                                 router=before['params'][name]['router'])
         return dict(after, params=params)
 
-    def attention_key_share(self, T):
-        """Of the layers' ``T x T`` (query, key) pairs, the share a window
-        of ``T`` positions multiplies (1.0: all of them)."""
-        return attention.key_share(
-            T, [self.window_size if kind == 'window' else None
-                for kind in self.layer_types], self.query_block)
-
     def epoch_dynamics(self, sums):
         """The epoch record's keys from the epoch's ``diag_*`` sums."""
-        dynamics = experts.rows_dynamics(
-            sums, len(self.held) * len(self.expert_layers))
+        dynamics = super().epoch_dynamics(sums)
         if dynamics:
             dynamics['window_hidden_position_share'] = (
                 100.0 * sums.get('diag_window_positions_hidden', 0.0)

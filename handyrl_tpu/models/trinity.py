@@ -69,23 +69,17 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from . import attention, experts, register
-from .evabyte import _dot, _rotary, f32
+from .shell import ExpertTrunkNet
+from .trunk import burn_in_as_state, dot, f32, heads_of, rms_norm, rotary
 
 PUBLISHED_LAYERS = ('sliding', 'sliding', 'sliding', 'full') * 8
 
 
-def _rms_norm(x, g, eps, dtype):
-    """Float32 in, ``dtype`` out; the weight multiplies (no unit offset)."""
-    x = x.astype(f32)
-    y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return (y * g.astype(f32)).astype(dtype)
-
-
 def _swiglu(x, w_gate, w_up, w_down, dtype, inv):
     """``inv``: 1 / ``param_scale``, taken on each product's result."""
-    act = (jax.nn.silu(_dot(x, w_gate, dtype) * inv)
-           * (_dot(x, w_up, dtype) * inv))
-    return _dot(act, w_down, dtype, out=f32) * inv
+    act = (jax.nn.silu(dot(x, w_gate, dtype) * inv)
+           * (dot(x, w_up, dtype) * inv))
+    return dot(act, w_down, dtype, out=f32) * inv
 
 
 class TrinityBlock(nn.Module):
@@ -152,39 +146,31 @@ class TrinityBlock(nn.Module):
     def _qkvg(self, x, positions):
         """x (..., D) float32 at ``positions`` (...,) -> q (..., H, d), k, v
         (..., KV, d) and the gate (..., H * d), in ``dtype``."""
-        a = _rms_norm(x, self.norm_in, self.norm_eps, self.dtype)
-        lead, d, inv = x.shape[:-1], self.head_dim, self.inv
-        q = (_dot(a, self.wq, self.dtype) * inv).reshape(
-            lead + (self.heads_held, d))
-        k = (_dot(a, self.wk, self.dtype) * inv).reshape(
-            lead + (self.kv_heads_held, d))
-        v = (_dot(a, self.wv, self.dtype) * inv).reshape(
-            lead + (self.kv_heads_held, d))
-        q = _rms_norm(q, self.q_norm, self.norm_eps, self.dtype)
-        k = _rms_norm(k, self.k_norm, self.norm_eps, self.dtype)
+        a = rms_norm(x, self.norm_in, self.norm_eps, self.dtype)
+        q = heads_of(a, self.wq, self.heads_held, self.dtype, self.inv)
+        k = heads_of(a, self.wk, self.kv_heads_held, self.dtype, self.inv)
+        v = heads_of(a, self.wv, self.kv_heads_held, self.dtype, self.inv)
+        q = rms_norm(q, self.q_norm, self.norm_eps, self.dtype)
+        k = rms_norm(k, self.k_norm, self.norm_eps, self.dtype)
         if self.kind == 'sliding':
             pos = positions[..., None]
-            q = _rotary(q, pos, self.rope_theta)
-            k = _rotary(k, pos, self.rope_theta)
-        return q, k, v, _dot(a, self.wg, self.dtype) * inv
+            q = rotary(q, pos, self.rope_theta)
+            k = rotary(k, pos, self.rope_theta)
+        return q, k, v, dot(a, self.wg, self.dtype) * self.inv
 
     def _out(self, y, gate):
         """This chip's part of ``W_o``'s sum, before the branch's norm."""
-        return _dot(y * jax.nn.sigmoid(gate.astype(f32)).astype(y.dtype),
-                    self.wo, self.dtype, out=f32) * self.inv
+        return dot(y * jax.nn.sigmoid(gate.astype(f32)).astype(y.dtype),
+                   self.wo, self.dtype, out=f32) * self.inv
 
     def _after_attention(self, x, part):
-        return x + _rms_norm(part, self.norm_post_attn, self.norm_eps, f32)
+        return x + rms_norm(part, self.norm_post_attn, self.norm_eps, f32)
 
     def attention_part(self, x, positions, valid, no_grad_prefix=0):
         """This chip's part of the attention output, before the branch's
         norm: (B, T, D) float32 (the head-share test sums four of these)."""
         q, k, v, gate = self._qkvg(x, positions)
-        if no_grad_prefix:
-            keep = (jnp.arange(x.shape[1]) >= no_grad_prefix)[
-                None, :, None, None]
-            k = jnp.where(keep, k, jax.lax.stop_gradient(k))
-            v = jnp.where(keep, v, jax.lax.stop_gradient(v))
+        k, v = burn_in_as_state(k, v, no_grad_prefix)
         window = self.window_size if self.kind == 'sliding' else None
         y = jax.vmap(lambda *seq: attention.sequence_attention(
             *seq, window, self.query_block))(q, k, v, positions, valid)
@@ -248,7 +234,7 @@ class TrinityBlock(nn.Module):
         for (E,) and the dispatch's tally (``experts.SortPlan.tally``; 0 for
         a few rows, which take no buffer). ``shared=False`` leaves the
         shared expert out (the share test counts it once)."""
-        m32 = _rms_norm(x, self.norm_pre_mlp, self.norm_eps, f32)
+        m32 = rms_norm(x, self.norm_pre_mlp, self.norm_eps, f32)
         m = m32.astype(self.dtype)
         if self.mlp_size:
             with jax.named_scope('trunk_mlp'):
@@ -269,7 +255,7 @@ class TrinityBlock(nn.Module):
 
     def _mlp(self, x):
         f, counts, tally = self.mlp_branch(x)
-        x = x + _rms_norm(f, self.norm_post_mlp, self.norm_eps, f32)
+        x = x + rms_norm(f, self.norm_post_mlp, self.norm_eps, f32)
         return x, counts, tally
 
     # -- a whole window ------------------------------------------------------
@@ -303,7 +289,7 @@ class TrinityBlock(nn.Module):
 
 
 @register('TrinityNet')
-class TrinityNet(nn.Module):
+class TrinityNet(ExpertTrunkNet):
     """The trunk with its untied head read as a policy over the ids held and
     a value row. Observations are int32 ids. The defaults are the published
     counts, at which every layer IS the published layer; the depth, the
@@ -339,14 +325,7 @@ class TrinityNet(nn.Module):
     param_scale: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
 
-    @property
-    def held(self):
-        return (tuple(range(self.experts_published))
-                if self.experts_held is None else tuple(self.experts_held))
-
-    @property
-    def expert_layers(self):
-        return tuple(range(self.dense_layers, len(self.layer_types)))
+    windowed_kind = 'sliding'
 
     def setup(self):
         init = nn.initializers.normal(0.02 * self.param_scale)
@@ -365,81 +344,9 @@ class TrinityNet(nn.Module):
         self.head = self.param('head', init, (self.hidden_size, self.vocab))
         self.value = self.param('value', init, (self.hidden_size, 1))
 
-    @property
-    def actor_param_dtype(self):
-        """The actor's copy of the parameters is kept in the compute dtype
-        (train.py ``actor_refresh``): rollout reads every weight each ply."""
-        return self.dtype
-
-    # -- the cache -----------------------------------------------------------
-    def init_hidden(self, batch_shape=()):
-        return attention.init_cache(
-            batch_shape, [self.window_size if kind == 'sliding'
-                          else self.max_positions
-                          for kind in self.layer_types],
-            self.kv_heads_held * self.head_dim, self.dtype)
-
-    reset_hidden = staticmethod(attention.reset_cache)
-
-    # -- inputs and outputs --------------------------------------------------
     def _embed(self, ids):
         return self.embed[ids].astype(f32) * (self.hidden_size ** 0.5
                                               / self.param_scale)
-
-    def _features(self, x):
-        return _rms_norm(x, self.norm_out, self.norm_eps, self.dtype)
-
-    def _value(self, features):
-        return jnp.tanh(_dot(features, self.value, self.dtype, out=f32)
-                        / self.param_scale)
-
-    def policy_logits(self, features):
-        """The head over the ids held, float32: features (..., D)."""
-        return _dot(features, self.head, self.dtype, out=f32) \
-            / self.param_scale
-
-    def __call__(self, obs, hidden, train: bool = False):
-        """One position a sequence: obs (B,) int32 ids."""
-        if hidden is None:
-            hidden = self.init_hidden(obs.shape)
-        pos = hidden['pos']
-        x = self._embed(obs)
-        ks, vs = [], []
-        for i, block in enumerate(self.blocks):
-            x, (k, v) = block.step(x, pos, (hidden['k'][i], hidden['v'][i]))
-            ks.append(k)
-            vs.append(v)
-        h = self._features(x)
-        return {'policy': self.policy_logits(h),
-                'value': self._value(h),
-                'hidden': {'k': tuple(ks), 'v': tuple(vs), 'pos': pos + 1}}
-
-    def sequence(self, ids, first_position, valid, no_grad_prefix: int = 0):
-        """T positions a sequence in one causal forward. ids (B, T) int32,
-        first_position (B,), valid (B, T) bool. Returns ``policy_features``
-        (B, T, D) in ``dtype`` (``policy_logits`` of them are the policy:
-        the loss takes the head a block of positions at a time), ``value``
-        (B, T, 1) float32 and ``aux``: the sums the forward pass hands to
-        the epoch record and to ``post_update``."""
-        positions = first_position[:, None] + jnp.arange(ids.shape[1])
-        x = self._embed(ids)
-        counts, tally = [], jnp.zeros((2,), jnp.int32)
-        for block in self.blocks:
-            # one layer rematerialised at a time, as models/evabyte.py
-            x, c, t = nn.remat(TrinityBlock.sequence, static_argnums=(4,))(
-                block, x, positions, valid, no_grad_prefix)
-            if c is not None:
-                counts.append(c)
-                tally = tally + t
-        h = self._features(x)
-        out = {'policy_features': h, 'value': self._value(h)}
-        if counts:
-            out['aux'] = experts.rows_aux(jnp.stack(counts), self.held,
-                                          tally)
-        return out
-
-    def attention_part(self, layer: int, x, positions, valid):
-        return self.blocks[layer].attention_part(x, positions, valid)
 
     def mlp_branch(self, layer: int, x, shared: bool = True):
         """Layer ``layer``'s ``f(m)`` for (n, D) inputs, before the branch's
@@ -464,15 +371,3 @@ class TrinityNet(nn.Module):
                 router_bias=old['router_bias'] + self.bias_update_rate
                 * (delta - delta.mean()))
         return dict(after, params=params)
-
-    def attention_key_share(self, T):
-        """Of the layers' ``T x T`` (query, key) pairs, the share a window
-        of ``T`` positions multiplies (1.0: all of them)."""
-        return attention.key_share(
-            T, [self.window_size if kind == 'sliding' else None
-                for kind in self.layer_types], self.query_block)
-
-    def epoch_dynamics(self, sums):
-        """The epoch record's keys from the epoch's ``diag_*`` sums."""
-        return experts.rows_dynamics(
-            sums, len(self.held) * len(self.expert_layers))

@@ -114,35 +114,17 @@ def vtrace(values: Array, returns: Array, rewards: Optional[Array],
 
 def compute_target(algorithm: str, values: Optional[Array], returns: Array,
                    rewards: Optional[Array], lmb: float, gamma: float,
-                   rhos: Array, cs: Array, masks: Array,
-                   use_pallas: Optional[bool] = None) -> Tuple[Array, Array]:
+                   rhos: Array, cs: Array, masks: Array
+                   ) -> Tuple[Array, Array]:
     """Dispatch on algorithm name; mirrors losses.py:63-78 including the
-    no-baseline Monte-Carlo fallback and the lambda-mask collapse.
-
-    The backward recursion runs as lax.scan by default on every backend
-    (measured faster than the Pallas kernels inside the full update step —
-    ops/pallas_targets.py module docstring); HANDYRL_PALLAS_TARGETS=1 plus
-    a passing on-device probe switches TPU backends to the fused kernels."""
+    no-baseline Monte-Carlo fallback and the lambda-mask collapse. The
+    backward recursion is a ``lax.scan`` on every backend."""
     if values is None:
         return returns, returns
     if algorithm == 'MC':
         return monte_carlo(values, returns)
 
     lambda_ = lmb + (1 - lmb) * (1 - masks)
-
-    if use_pallas is None:
-        from .pallas_targets import use_pallas_targets
-        use_pallas = use_pallas_targets()
-
-    if use_pallas:
-        from . import pallas_targets as pt
-        if algorithm == 'TD':
-            return pt.td_lambda_pallas(values, returns, rewards, lambda_, gamma)
-        if algorithm == 'UPGO':
-            return pt.upgo_pallas(values, returns, rewards, lambda_, gamma)
-        if algorithm == 'VTRACE':
-            return pt.vtrace_pallas(values, returns, rewards, lambda_, gamma,
-                                    rhos, cs)
 
     if algorithm == 'TD':
         return td_lambda(values, returns, rewards, lambda_, gamma)

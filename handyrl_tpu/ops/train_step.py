@@ -186,12 +186,6 @@ def build_update_step(module, cfg: LossConfig, mesh=None, donate: bool = True,
     type the outputs, so the donated state round-trips through every step
     without a reshard.
     """
-    # Resolve the Pallas-vs-scan target path NOW, outside any trace: the
-    # probe compiles and runs a real kernel on the backend, which cannot
-    # happen once tracing of ``update`` has begun.
-    from .pallas_targets import use_pallas_targets
-    use_pallas_targets()
-
     update = _update_core(module, cfg, make_optimizer())
     # name the program so the retrace sentinel (telemetry.py) can report
     # WHICH compiled callable re-lowered after steady state
@@ -237,8 +231,6 @@ def build_replay_update(module, cfg: LossConfig, capacity: int,
     ring is replicated and each sampled batch is sharding-constrained along
     'data', so XLA runs the same data-parallel step as build_update_step.
     """
-    from .pallas_targets import use_pallas_targets
-    use_pallas_targets()
     from .replay import recency_slots
 
     update = _update_core(module, cfg, make_optimizer())

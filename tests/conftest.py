@@ -7,22 +7,11 @@ before ``import jax`` is all it takes; child processes inherit it.
 
 import os
 
-# Opt-in real-device run: HANDYRL_TPU_TESTS=1 keeps whatever backend the
-# environment provides, so device-gated tests (the compiled Pallas kernels
-# in test_pallas_targets.py) exercise real silicon.
-# Only the modules in _TPU_SAFE_FILES run in this mode (see
-# pytest_collection_modifyitems): the rest of the suite assumes the
-# 8-virtual-device CPU mesh (some tests hard-assert it). On the chip it
-# runs through the tool, one pytest process owning the device:
-#   chiprun -- env HANDYRL_TPU_TESTS=1 python -m pytest \
-#       tests/test_pallas_targets.py -q
-_TPU_MODE = os.environ.get('HANDYRL_TPU_TESTS') == '1'
-_TPU_SAFE_FILES = ('test_pallas_targets.py',)
-if not _TPU_MODE:
-    os.environ['JAX_PLATFORMS'] = 'cpu'
-    _flags = os.environ.get('XLA_FLAGS', '')
-    if '--xla_force_host_platform_device_count' not in _flags:
-        os.environ['XLA_FLAGS'] = (_flags + ' --xla_force_host_platform_device_count=8').strip()
+# the suite assumes the 8-virtual-device CPU mesh (some tests hard-assert it)
+os.environ['JAX_PLATFORMS'] = 'cpu'
+_flags = os.environ.get('XLA_FLAGS', '')
+if '--xla_force_host_platform_device_count' not in _flags:
+    os.environ['XLA_FLAGS'] = (_flags + ' --xla_force_host_platform_device_count=8').strip()
 
 
 # ---------------------------------------------------------------------------
@@ -73,14 +62,6 @@ def pytest_collection_modifyitems(config, items):
         for item in items:
             if os.path.basename(str(item.fspath)) in _POSIX_ONLY_FILES:
                 item.add_marker(skip_win)
-    if not _TPU_MODE:
-        return
-    skip = pytest.mark.skip(
-        reason='HANDYRL_TPU_TESTS=1 runs only the real-device-safe modules; '
-               'the rest of the suite needs the 8-virtual-device CPU mesh')
-    for item in items:
-        if os.path.basename(str(item.fspath)) not in _TPU_SAFE_FILES:
-            item.add_marker(skip)
 
 
 @pytest.fixture(autouse=True)

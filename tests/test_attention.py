@@ -254,6 +254,73 @@ def test_window_layers_of_one_shape_share_one_lowered_function():
     assert slices(4) == slices(1) > 0
 
 
+# -- the ONE decode attention picks its product from the shapes (PR 51) --------
+def _rows(heads, n_rows=20, dtype=f32):
+    keys = jax.random.split(jax.random.PRNGKey(heads), 3)
+    return (jax.random.normal(keys[0], (3, heads, 8), dtype),
+            jax.random.normal(keys[1], (3, n_rows, heads * 8), dtype),
+            jax.random.normal(keys[2], (3, n_rows, heads * 8), dtype))
+
+
+@pytest.mark.parametrize('circle', [False, True], ids=['buffer', 'circle'])
+@pytest.mark.parametrize('heads', [2, 4, 9])
+def test_a_group_of_one_is_the_grouped_product_row_for_row(heads, circle):
+    """``cache_attention`` with one query head a KV head (the heads side by
+    side as ONE matrix against the rows as they lie) is the grouped product
+    on the same rows, at counters of its own a sequence: at a buffer's
+    first and last row, and on a circle not yet full, full, and gone round
+    (where the counter is past the rows and every row counts)."""
+    q, ck, cv = _rows(heads)
+    pos = jnp.asarray([0, 19, 47] if circle else [0, 7, 19])
+    want = attention.grouped_cache_attention(q, ck, cv, pos, circle, heads,
+                                             f32)
+    got = attention.cache_attention(q, ck, cv, pos, circle, heads, f32)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    if circle:      # the third sequence sees what a buffer would mask
+        masked = attention.cache_attention(q, ck, cv, pos % 20, False,
+                                           heads, f32)
+        assert float(jnp.abs(got[2] - masked[2]).max()) > 1e-3
+        np.testing.assert_allclose(got[:2], masked[:2], atol=1e-5)
+
+
+def _assert_the_rows_are_multiplied_as_they_lie(text, rows):
+    """Each (B, rows, H * d) bfloat16 buffer is the operand of ONE product
+    whose only batch axis is the sequence, and no float32 array as large as
+    a buffer is made."""
+    products = re.findall(
+        r'stablehlo\.dot_general [^\n]*batching_dims = (\[[\d, ]*\] x '
+        r'\[[\d, ]*\])[^\n]*tensor<%dx%dx%dxbf16>\) ->' % rows, text)
+    assert products == ['[0] x [0]'] * 2
+    sizes = [int(np.prod([int(n) for n in dims.split('x')]))
+             for dims in re.findall(r'tensor<([\dx]+)xf32>', text)]
+    assert sizes and max(sizes) < np.prod(rows)
+
+
+def _lowered_decode(heads, kv_heads, circle):
+    shape = jax.ShapeDtypeStruct
+    rows = shape((2, 96, kv_heads * 16), jnp.bfloat16)
+    return rows, jax.jit(
+        lambda q, ck, cv, pos: attention.cache_attention(
+            q, ck, cv, pos, circle, kv_heads, jnp.bfloat16)).lower(
+        shape((2, heads, 16), jnp.bfloat16), rows, rows,
+        shape((2,), jnp.int32)).as_text()
+
+
+@pytest.mark.parametrize('circle', [False, True], ids=['buffer', 'circle'])
+def test_a_group_of_one_multiplies_the_rows_as_they_lie(circle):
+    """The guard against a net WITHOUT groups reaching the grouped product
+    (62.8 ms a ply against 7.8 on the chip, PERF.md, PR 46), read from the
+    shapes and from no flag: in the text ``cache_attention`` lowers to at
+    ``H == kv_heads`` each buffer is the operand of ONE product whose only
+    batch axis is the sequence, and no float32 array as large as a buffer
+    is made; with groups the rows get a KV-head axis, as they always did."""
+    rows, text = _lowered_decode(8, 8, circle)
+    _assert_the_rows_are_multiplied_as_they_lie(text, rows.shape)
+    _rows_of_groups, grouped = _lowered_decode(8, 2, circle)
+    assert 'tensor<2x96x2x16xbf16>' in grouped
+    assert 'tensor<2x96x2x16xbf16>' not in text
+
+
 # -- one query row a head: the heads side by side (PR 46, PR 50) ---------------
 @pytest.mark.parametrize('heads', [2, 8, 9])
 def test_the_side_by_side_halves_over_two_row_sets_are_one_soft_max_over_both(
@@ -261,13 +328,13 @@ def test_the_side_by_side_halves_over_two_row_sets_are_one_soft_max_over_both(
     """The queries laid side by side once, the scores of each set of rows,
     ONE soft-max, the values of each set added before the heads' own blocks
     are taken (``models/evabyte.py``'s window rows and summaries): what
-    ``cache_attention`` without groups reads over the rows concatenated."""
+    the grouped product without groups reads over the rows concatenated."""
     keys = jax.random.split(jax.random.PRNGKey(heads), 5)
     q = jax.random.normal(keys[0], (3, heads, 8))
     ka, va, kb, vb = (jax.random.normal(key, (3, n, heads * 8))
                       for key, n in zip(keys[1:], (12, 12, 8, 8)))
     pos = jnp.asarray([0, 11, 17])
-    want = attention.cache_attention(
+    want = attention.grouped_cache_attention(
         q, jnp.concatenate([ka, kb], axis=1), jnp.concatenate([va, vb], 1),
         pos, False, heads, f32)
     wide = attention.heads_side_by_side(q)
@@ -302,14 +369,8 @@ def test_the_evabyte_step_multiplies_its_buffers_as_they_lie():
                                   method=EvaBlock.step), *args))
     text = jax.jit(lambda v, *a: block.apply(
         v, *a, method=EvaBlock.step)).lower(variables, *args).as_text()
-    products = re.findall(
-        r'stablehlo\.dot_general [^\n]*batching_dims = (\[[\d, ]*\] x '
-        r'\[[\d, ]*\])[^\n]*tensor<%dx%dx%dxbf16>\) ->'
-        % (B, W + chunks, H * d), text)
-    assert products == ['[0] x [0]'] * 2
-    sizes = [int(np.prod([int(n) for n in dims.split('x')]))
-             for dims in re.findall(r'tensor<([\dx]+)xf32>', text)]
-    assert sizes and max(sizes) < B * (W + chunks) * H * d
+    _assert_the_rows_are_multiplied_as_they_lie(
+        text, (B, W + chunks, H * d))
 
 
 def test_the_epoch_record_and_the_gauge_carry_what_the_learner_holds():

@@ -102,11 +102,12 @@ def test_the_reference_controls_differ_from_the_model(control):
     assert diff.max() > 0.05
 
 
-@pytest.mark.parametrize('dtype,atol', [('float32', 2e-4),
-                                        ('bfloat16', 0.25)])
-def test_step_through_three_windows_matches_sequence(dtype, atol):
+def test_step_through_three_windows_matches_sequence():
     """One position at a time through the cache, 2.5 windows of 16 and ten
-    chunks of 4, against the same ids as one causal forward."""
+    chunks of 4, against the same ids as one causal forward, in the cell's
+    compute dtype (in float32, with the other trunks: tests/test_models.py
+    ``test_a_trunks_sequence_and_its_steps_agree``)."""
+    dtype, atol = 'bfloat16', 0.25
     net, variables = _net_and_variables(dtype)
     ids = _ids(3)
     step = jax.jit(net.apply)
@@ -125,8 +126,8 @@ def test_step_through_three_windows_matches_sequence(dtype, atol):
 
 
 def test_a_new_game_resets_counters_and_leaves_the_buffers():
-    """``reset_hidden`` touches the counter alone, and a sequence that
-    starts over on a stale cache reads none of it."""
+    """A sequence that starts over on a stale cache reads none of it (that
+    ``reset_hidden`` touches the counter alone: tests/test_models.py)."""
     net, variables = _net_and_variables()
     ids = _ids(2)
     step = jax.jit(net.apply)
@@ -135,9 +136,6 @@ def test_a_new_game_resets_counters_and_leaves_the_buffers():
         hidden = step(variables, ids[:, t], hidden)['hidden']
     reset = net.reset_hidden(hidden, jnp.asarray([True, False]))
     assert list(np.asarray(reset['pos'])) == [0, 24]
-    for key in ('k', 'v'):
-        for a, b in zip(reset[key], hidden[key]):
-            assert a is b
     fresh = net.init_hidden((2,))
     for t in range(20):
         out_stale = step(variables, ids[:, t], reset)
@@ -152,7 +150,8 @@ def _plain_step(block, x, pos, cache):
     """``EvaBlock.step`` as it stood before PR 50, in plain einsums over the
     buffers with a head axis: one query row a head against the window's rows
     and the summaries, ONE soft-max over both, the two value sums."""
-    from handyrl_tpu.models.evabyte import NEG, _dot, _summarise, f32
+    from handyrl_tpu.models.evabyte import _summarise
+    from handyrl_tpu.models.trunk import NEG, dot, f32
     ck, cv, csk, csv = cache                    # (B, rows, H, d)
     W, chunk = block.window_size, block.chunk_size
     B = x.shape[0]
@@ -183,7 +182,7 @@ def _plain_step(block, x, pos, cache):
                     preferred_element_type=f32)
          + jnp.einsum('bhc,bchd->bhd', prob[..., W:], csv,
                       preferred_element_type=f32))
-    x = x + _dot(y.reshape(B, -1), block.wo, block.dtype, out=f32)
+    x = x + dot(y.reshape(B, -1), block.wo, block.dtype, out=f32)
     return block.mlp(x), (ck, cv, csk, csv)
 
 

@@ -139,9 +139,10 @@ def test_the_reference_controls_differ_from_the_model(control):
 
 
 # -- one position through the (pass, layer) cache ----------------------------------
-@pytest.mark.parametrize('dtype,atol', [('float32', 3e-4),
-                                        ('bfloat16', 0.12)])
-def test_decode_through_the_cache_matches_sequence(dtype, atol):
+def test_decode_through_the_cache_matches_sequence():
+    """In the cell's compute dtype (in float32, with the other trunks:
+    tests/test_models.py ``test_a_trunks_sequence_and_its_steps_agree``)."""
+    dtype, atol = 'bfloat16', 0.12
     net, variables = _net_and_variables(dtype)
     ids = _ids(4, (3, T))
     logits, value, _gate = _program(
@@ -212,20 +213,23 @@ def test_a_pass_reads_its_own_rows_and_no_other_passes():
     assert cache['pos'].shape == (3,)
 
 
-@pytest.mark.parametrize('heads', [2, 4, 9])
-def test_the_heads_side_by_side_read_what_cache_attention_reads(heads):
-    """One matrix of block-diagonal queries against the rows as they lie is
-    ``cache_attention`` without groups, row for row, at counters of its
-    own a sequence."""
-    keys = jax.random.split(jax.random.PRNGKey(heads), 3)
-    q = jax.random.normal(keys[0], (3, heads, 8))
-    ck = jax.random.normal(keys[1], (3, 20, heads * 8))
-    cv = jax.random.normal(keys[2], (3, 20, heads * 8))
-    pos = jnp.asarray([0, 7, 19])
-    want = attention.cache_attention(q, ck, cv, pos, False, heads,
-                                     jnp.float32)
-    got = attention.side_by_side_attention(q, ck, cv, pos, jnp.float32)
-    np.testing.assert_allclose(got, want, atol=1e-5)
+def test_a_net_with_grouped_heads_decodes_what_its_sequence_computes():
+    """The decode attention is chosen from the shapes, so the net asserts
+    nothing about its heads: two query heads a KV head take the grouped
+    product (the heads' comparison with a group of one:
+    tests/test_attention.py)."""
+    net, variables = _net_and_variables(heads_held=4)
+    ids = _ids(6, (2, T))
+    logits, _value, _gate = _program(
+        net, variables, ids, jnp.zeros((2,), jnp.int32),
+        jnp.ones((2, T), bool))
+    step = jax.jit(net.apply)
+    hidden = net.init_hidden((2,))
+    for t in range(T):
+        out = step(variables, ids[:, t], hidden)
+        hidden = out['hidden']
+        np.testing.assert_allclose(out['policy'], logits[-1][:, t],
+                                   atol=3e-4)
 
 
 def test_the_byte_game_builds_the_net_and_its_rollout_runs_every_pass():
