@@ -11,8 +11,9 @@ V-trace and Adam. The backward pass is twice the forward; recomputation (the
 learner rematerialises a layer at a time) is never counted.
 """
 
+import numpy as np
 
-_BYTES = {'bfloat16': 2, 'float32': 4}
+from .flops_trinity_mini import _BYTES, chunk_bytes, rollout_split
 
 
 def matmul_parameters(model):
@@ -56,13 +57,30 @@ def train_window_flops(model, train_args):
     return 3 * whole - 2 * forward_flops(model, bi)
 
 
+def rows_seen_at(model, kind, ply_index):
+    """The cache rows a decode query must see at the ply indices
+    ``ply_index`` (0 at a game's first ply): the K (or V) rows of its own
+    window up to itself, ``|L_n| = p % window + 1``, and one summary (an RFA
+    pair) for every chunk of the windows before, ``|R_n| = (p // window) x
+    (window / chunk)``. The one kind is ``eva``."""
+    assert kind == 'eva', kind
+    p = np.asarray(ply_index)
+    W, chunk = model['window_size'], model['chunk_size']
+    return p % W + 1 + p // W * (W // chunk)
+
+
 def eva_attention_scope(model, train_args):
     """What the named scope ``eva_attention`` requires in ONE fused dispatch:
     ``sgd_flops``, forward and backward of the projections and of attention
     over the trained windows (compute-bound), and ``rollout_bytes``, what a
     chunk of decode plies must read: the actor's attention weights once a
     ply and layer, every sequence's cache (window K and V and the
-    summaries) once a ply and layer (memory-bound)."""
+    summaries) once a ply and layer (memory-bound). ``rollout`` is that count
+    split (``flops_trinity_mini.rollout_split``): ``chunk_bytes`` of it at a
+    chunk's own ply indices (``rows_seen_at``) is what that chunk required;
+    ``rollout_bytes`` is its value with every row of the cache read, window
+    and summaries whole, which no ply's query needs (before PR 52 the count
+    the roofline took)."""
     fs = int(train_args['forward_steps']) + int(
         train_args.get('burn_in_steps') or 0)
     windows = int(train_args['batch_size']) * int(
@@ -70,11 +88,13 @@ def eva_attention_scope(model, train_args):
     attention, _mlp, _readout = matmul_parameters(model)
     sgd = 3 * windows * (2 * fs * attention + attention_flops(model, fs))
     width = _BYTES[model['actor_param_dtype']]
-    cache = (model['layers'] * model['heads_held'] * model['head_dim'] * 2
-             * (model['window_size']
-                + model['max_positions'] // model['chunk_size'])
-             * _BYTES[model['compute_dtype']])
+    row = (model['layers'] * model['heads_held'] * model['head_dim'] * 2
+           * _BYTES[model['compute_dtype']])
     sequences = int(train_args['generation_envs']) * 2
-    rollout = int(train_args['device_chunk_steps']) * (
-        attention * width + sequences * cache)
-    return {'sgd_flops': sgd, 'rollout_bytes': rollout}
+    split = rollout_split(
+        train_args['device_chunk_steps'], attention * width,
+        {'eva': sequences * row},
+        {'eva': model['window_size']
+         + model['max_positions'] // model['chunk_size']})
+    return {'sgd_flops': sgd, 'rollout_bytes': int(chunk_bytes(split)),
+            'rollout': split}

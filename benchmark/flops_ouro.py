@@ -17,8 +17,8 @@ named (the shared sequence attention takes every key block of the sequence,
 masked).
 """
 
-from .flops_smallthinker import mean_rows_seen
-from .flops_trinity_mini import _BYTES, _dispatch
+from . import flops_smallthinker
+from .flops_trinity_mini import _BYTES, _dispatch, chunk_bytes, rollout_split
 
 
 def attention_parameters(model):
@@ -74,12 +74,28 @@ def train_window_flops(model, train_args):
                - 2 * forward_flops(model, bi))
 
 
+def rows_seen_at(model, kind, ply_index):
+    """The K (or V) rows of ONE (pass, layer) that a decode query must see at
+    the ply indices ``ply_index`` (0 at a game's first ply): the counter's
+    rows ``p + 1``, no window; every layer is of the one kind ``global``."""
+    return flops_smallthinker.rows_seen_at(model, kind, ply_index)
+
+
 def rows_written(model):
-    """The K (or V) rows of ONE (pass, layer) that a decode query sees, at
-    its mean over the plies of the games the env draws (lengths log-uniform
-    in [min_steps, max_steps]): the counter's rows, no window."""
-    return mean_rows_seen(dict(model, window_size=model['max_positions']),
-                          'global')
+    """``rows_seen_at`` at its mean over the plies of the games the env draws
+    (lengths log-uniform in [min_steps, max_steps])."""
+    return flops_smallthinker.mean_rows_seen(
+        dict(model, window_size=model['max_positions']), 'global')
+
+
+def _cache_split(model, train_args, ply_bytes):
+    """``ply_bytes`` a ply whatever the fill, and a row of every sequence in
+    every (pass, layer)."""
+    _fs, _windows, sequences, plies = _dispatch(train_args)
+    uses = model['passes'] * model['layers']
+    return rollout_split(
+        plies, ply_bytes, {'global': uses * sequences * _row_bytes(model)},
+        {'global': rows_written(model)})
 
 
 def _row_bytes(model):
@@ -94,26 +110,36 @@ def loop_attention_scope(model, train_args):
     and of attention over the causal pairs, every layer of every pass, and
     ``rollout_bytes``, what a chunk of decode plies must read: the actor's
     attention weights once a PASS and layer, and every sequence's K and V
-    rows written so far of every (pass, layer) (``rows_written``)."""
-    fs, windows, sequences, plies = _dispatch(train_args)
+    rows written so far of every (pass, layer). ``rollout`` is that count
+    split (``flops_trinity_mini.rollout_split``): ``chunk_bytes`` of it at a
+    chunk's own ply indices (``rows_seen_at``) is what that chunk required;
+    ``rollout_bytes`` is its value at the analytic mean (``rows_written``)."""
+    fs, windows, _sequences, _plies = _dispatch(train_args)
     uses = model['passes'] * model['layers']
     sgd = 3 * windows * (2 * fs * uses * attention_parameters(model)
                          + attention_flops(model, fs))
-    rollout = plies * uses * (
-        attention_parameters(model) * _BYTES[model['actor_param_dtype']]
-        + sequences * rows_written(model) * _row_bytes(model))
-    return {'sgd_flops': int(sgd), 'rollout_bytes': int(rollout)}
+    split = _cache_split(
+        model, train_args,
+        uses * attention_parameters(model) * _BYTES[model['actor_param_dtype']])
+    return {'sgd_flops': int(sgd), 'rollout_bytes': int(chunk_bytes(split)),
+            'rollout': split}
 
 
-def decode_ply_bytes(model, train_args):
-    """The bytes ONE decode ply must read: the actor's layer weights once a
-    PASS (a pass's ~310 MB outlast any fast memory), the head and the value
-    row once, and every sequence's K and V rows written so far of every
-    (pass, layer) at the games' mean fill."""
-    _fs, _windows, sequences, _plies = _dispatch(train_args)
+def decode_rollout(model, train_args):
+    """What the decode plies of ONE chunk must read, split
+    (``flops_trinity_mini.rollout_split``): a ply's fixed bytes are the
+    actor's layer weights once a PASS (a pass's ~310 MB outlast any fast
+    memory), the head and the value row once; a row is every sequence's K and
+    V of every (pass, layer)."""
     uses = model['passes'] * model['layers']
     weights = (uses * (attention_parameters(model) + mlp_parameters(model))
                + model['hidden_size'] * (model['vocab'] + 1)) \
         * _BYTES[model['actor_param_dtype']]
-    return int(weights + uses * sequences * rows_written(model)
-               * _row_bytes(model))
+    return _cache_split(model, train_args, weights)
+
+
+def decode_ply_bytes(model, train_args):
+    """The bytes ONE decode ply must read at the games' mean fill:
+    ``decode_rollout`` at the analytic mean, a ply."""
+    split = decode_rollout(model, train_args)
+    return int(chunk_bytes(split) / split['plies'])

@@ -17,8 +17,11 @@ sequence, masked). The router's products are counted forward only: it takes
 no gradient, and its input takes none through it.
 """
 
+import numpy as np
+
 from .flops_trinity_mini import (_BYTES, _dispatch,  # noqa: F401
-                                 expert_parameters, held_per_position)
+                                 chunk_bytes, expert_parameters,
+                                 held_per_position, rollout_split)
 
 KINDS = ('global', 'window')
 
@@ -98,12 +101,20 @@ def reglu_experts_scope(model, train_args):
     return {'sgd_flops': int(sgd), 'rollout_bytes': int(rollout)}
 
 
+def rows_seen_at(model, kind, ply_index):
+    """The K (or V) rows a decode query of a ``kind`` layer must see at the
+    ply indices ``ply_index`` (0 at a game's first ply): ``p + 1`` on a
+    global layer, as far as its buffer goes; ``min(p + 1, window)`` on a
+    window one."""
+    assert kind in KINDS, kind
+    cap = model['window_size' if kind == 'window' else 'max_positions']
+    return np.minimum(np.asarray(ply_index) + 1, cap)
+
+
 def mean_rows_seen(model, kind):
-    """The K (or V) rows a decode query sees, at its mean over the plies of
-    the games the env draws: lengths log-uniform in [min_steps, max_steps]
-    (weight 1 / L a length), a ply drawn uniformly from all plies played; at
-    position p a global layer's query sees p + 1 rows, a window layer's
-    ``min(p + 1, window)``."""
+    """``rows_seen_at`` at its mean over the plies of the games the env
+    draws: lengths log-uniform in [min_steps, max_steps] (weight 1 / L a
+    length), a ply drawn uniformly from all plies played."""
     W = model['window_size']
     rows = plies = 0.0
     for L in range(int(model['min_steps']), int(model['max_steps']) + 1):
@@ -122,7 +133,11 @@ def attention_scope(model, train_args, kind):
     backward of the four projections and of attention over the pairs the
     equations name, and ``rollout_bytes``, what a chunk of decode plies must
     read: the actor's attention weights once a ply and layer, and every
-    sequence's K and V rows that a query sees (``mean_rows_seen``)."""
+    sequence's K and V rows that a query sees. ``rollout`` is that count
+    split (``flops_trinity_mini.rollout_split``): ``chunk_bytes`` of it at a
+    chunk's own ply indices (``rows_seen_at``) is what that chunk required;
+    ``rollout_bytes`` is its value at the analytic mean
+    (``mean_rows_seen``)."""
     assert kind in KINDS, kind
     fs, windows, sequences, plies = _dispatch(train_args)
     n = layers_of(model, kind)
@@ -130,10 +145,12 @@ def attention_scope(model, train_args, kind):
                          + attention_flops(model, fs, kind))
     row = model['kv_heads_held'] * model['head_dim'] * 2 \
         * _BYTES[model['compute_dtype']]
-    rollout = plies * n * (attention_parameters(model)
-                           * _BYTES[model['actor_param_dtype']]
-                           + sequences * mean_rows_seen(model, kind) * row)
-    return {'sgd_flops': int(sgd), 'rollout_bytes': int(rollout)}
+    split = rollout_split(
+        plies,
+        n * attention_parameters(model) * _BYTES[model['actor_param_dtype']],
+        {kind: n * sequences * row}, {kind: mean_rows_seen(model, kind)})
+    return {'sgd_flops': int(sgd), 'rollout_bytes': int(chunk_bytes(split)),
+            'rollout': split}
 
 
 def window_attention_scope(model, train_args):

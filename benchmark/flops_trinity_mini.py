@@ -16,6 +16,8 @@ router's products are counted forward only where noted: it takes no
 gradient, but its input does not either, so its backward is nothing.
 """
 
+import numpy as np
+
 _BYTES = {'bfloat16': 2, 'float32': 4}
 
 
@@ -107,6 +109,42 @@ def _dispatch(train_args):
     return fs, windows, sequences, int(train_args['device_chunk_steps'])
 
 
+def rollout_split(plies, ply_bytes, row_bytes, analytic_rows):
+    """What a chunk of decode plies must read, split by what the fill moves:
+    ``ply_bytes``, the bytes a ply reads whatever the caches hold (the
+    actor's weights), and ``row_bytes``, a layer kind -> the bytes ONE more
+    row in every sequence's cache costs a ply over the layers of that kind.
+    ``analytic_rows`` are the rows a sequence that the counts took before PR
+    52 and ``chunk_bytes`` still takes where no ply index is given: the mean
+    over the plies of the games the env draws."""
+    return {'plies': int(plies), 'ply_bytes': int(ply_bytes),
+            'row_bytes': {k: int(v) for k, v in row_bytes.items()},
+            'analytic_rows': {k: float(v) for k, v in analytic_rows.items()}}
+
+
+def chunk_bytes(rollout, rows=None):
+    """The bytes the ``plies`` decode plies of ONE chunk must read.
+    ``rows``: a layer kind -> the rows a query of that kind sees, an array
+    over (ply, sequence or lane) of THIS chunk (``rows_seen_at`` of the ply
+    indices its counters had reached); without it, the analytic mean."""
+    if rows is None:
+        rows = rollout['analytic_rows']
+    plies = rollout['plies']
+    return plies * (rollout['ply_bytes'] + sum(
+        per_row * float(np.mean(rows[kind]))
+        for kind, per_row in rollout['row_bytes'].items()))
+
+
+def rows_seen_at(model, kind, ply_index):
+    """The K (or V) rows a decode query of a ``kind`` layer must see at the
+    ply indices ``ply_index`` (0 at a game's first ply): ``p + 1`` on a full
+    layer, as far as its buffer goes; ``min(p + 1, window)`` on a sliding
+    one."""
+    assert kind in ('full', 'sliding'), kind
+    cap = model['window_size' if kind == 'sliding' else 'max_positions']
+    return np.minimum(np.asarray(ply_index) + 1, cap)
+
+
 def moe_experts_scope(model, train_args):
     """What the named scope ``moe_experts`` requires in ONE fused dispatch:
     ``sgd_flops``, forward and backward of the routed experts' products over
@@ -130,8 +168,11 @@ def gqa_attention_scope(model, train_args):
     a chunk of decode plies must read: the actor's attention weights once a
     ply and layer, and every sequence's K and V rows that a query sees: the
     circle's ``window_size`` rows on a sliding layer, and on a full layer
-    the rows the counter has reached, taken at their mean over a game of
-    mean length (games are log-uniform in [min_steps, max_steps])."""
+    the rows the counter has reached. ``rollout`` is that count split
+    (``rollout_split``): ``chunk_bytes`` of it at a chunk's own ply indices
+    (``rows_seen_at``) is what that chunk required; ``rollout_bytes`` is its
+    value at the analytic mean, a ply drawn uniformly from a game of mean
+    length (games are log-uniform in [min_steps, max_steps])."""
     import math
     fs, windows, sequences, plies = _dispatch(train_args)
     kinds, _dense, _expert = _layers(model)
@@ -145,9 +186,13 @@ def gqa_attention_scope(model, train_args):
     mean_rows = mean_sq / (2 * mean_len)
     row = model['kv_heads_held'] * model['head_dim'] * 2 \
         * _BYTES[model['compute_dtype']]
-    cache = sum(min(model['window_size'], mean_rows) if kind == 'sliding'
-                else mean_rows for kind in kinds) * row
-    rollout = plies * (len(kinds) * attention_parameters(model)
-                       * _BYTES[model['actor_param_dtype']]
-                       + sequences * cache)
-    return {'sgd_flops': int(sgd), 'rollout_bytes': int(rollout)}
+    analytic = {'sliding': min(model['window_size'], mean_rows),
+                'full': mean_rows}
+    split = rollout_split(
+        plies,
+        len(kinds) * attention_parameters(model)
+        * _BYTES[model['actor_param_dtype']],
+        {kind: kinds.count(kind) * sequences * row for kind in analytic},
+        analytic)
+    return {'sgd_flops': int(sgd), 'rollout_bytes': int(chunk_bytes(split)),
+            'rollout': split}

@@ -12,13 +12,19 @@ it also opens a ``jax.profiler.TraceAnnotation`` named ``bench:<span>``, so
 the span is on the profiler's clock beside the device's operations.
 ``capture`` reads values after the call, each a dotted path rooted at
 ``self`` (the bound instance), ``ret`` (the return value) or ``argN``; a step
-is an attribute or, failing that, a key. Only numbers, strings and flat
-dicts of them are kept. A target that does not resolve fails by name.
+is an attribute or, failing that, a key. Only numbers, strings, flat dicts
+of them and numpy arrays (a chunk's ``done`` flags) are kept. Two hooks may
+wrap one target (``chunk_fetch`` and ``chunk_plies`` both wrap
+``FusedPipeline._parse``): the later one wraps the earlier one's shim, and
+they come off in the reverse order. A target that does not resolve fails by
+name.
 """
 
 import functools
 import importlib
 import time
+
+import numpy as np
 
 
 class HookError(RuntimeError):
@@ -61,6 +67,8 @@ def _plain(value):
         return {str(k): _plain(v) for k, v in value.items()}
     if isinstance(value, (bool, int, float, str)) or value is None:
         return value
+    if isinstance(value, np.ndarray) and value.ndim:
+        return value.copy()
     return float(value)
 
 
@@ -120,6 +128,6 @@ def install(specs, recorder):
         undo.append((owner, attr, fn))
 
     def uninstall():
-        for owner, attr, fn in undo:
+        for owner, attr, fn in reversed(undo):
             setattr(owner, attr, fn)
     return uninstall

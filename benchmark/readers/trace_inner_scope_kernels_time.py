@@ -22,10 +22,8 @@ the ``%``), ``stat`` (``median``). Without ``--trace 1``, or where no
 operation of the module is counted, there is nothing to read.
 """
 
-import bisect
 import os
 
-from .. import reduce_trace
 from ..record import quantile
 from . import trace_scope_time
 from .trace_inner_scope_time import self_times
@@ -40,23 +38,16 @@ def read(run, module, scope, kernels, stat='median'):
     loaded = trace_scope_time.load(path)
     if loaded is None:
         return None
-    modules, ops, names, paths = loaded
+    _modules, _ops, names, paths = loaded
     kernels = tuple(kernels)
 
     def counted(key):
         return (scope in (paths.get(key) or '').split('/')
                 or names.get(key, '').lstrip('%').startswith(kernels))
     lo, hi = run.trace['window']
-    ops.sort()
-    starts = [op[0] for op in ops]
     totals, by_kernel, seen = [], [], False
-    for start, end, key in modules:
-        if (reduce_trace._module_name(names.get(key, '')) != module
-                or start < lo or end > hi):
-            continue
-        inside = [op for op in ops[bisect.bisect_left(starts, start):
-                                   bisect.bisect_right(starts, end)]
-                  if op[1] <= end]
+    for _start, _end, inside in trace_scope_time.module_executions(
+            loaded, module, lo, hi):
         held = [(op_key, ns) for op_key, ns in self_times(inside)
                 if counted(op_key)]
         seen = seen or bool(held)

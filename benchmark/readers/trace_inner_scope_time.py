@@ -18,10 +18,8 @@ Decoding of the ``.xplane.pb`` is ``trace_scope_time``'s. Without
 program from before it was named), there is nothing to read.
 """
 
-import bisect
 import os
 
-from .. import reduce_trace
 from ..record import quantile
 from . import trace_scope_time
 
@@ -50,21 +48,14 @@ def read(run, module, scope, stat='median'):
     loaded = trace_scope_time.load(path)
     if loaded is None:
         return None
-    modules, ops, names, paths = loaded
+    paths = loaded[3]
 
     def counted(key):
         return scope in (paths.get(key) or '').split('/')
     lo, hi = run.trace['window']
-    ops.sort()
-    starts = [op[0] for op in ops]
     totals, seen = [], False
-    for start, end, key in modules:
-        if (reduce_trace._module_name(names.get(key, '')) != module
-                or start < lo or end > hi):
-            continue
-        inside = [op for op in ops[bisect.bisect_left(starts, start):
-                                   bisect.bisect_right(starts, end)]
-                  if op[1] <= end]
+    for _start, _end, inside in trace_scope_time.module_executions(
+            loaded, module, lo, hi):
         held = [ns for op_key, ns in self_times(inside) if counted(op_key)]
         seen = seen or bool(held)
         totals.append(sum(held) / 1e9)

@@ -225,6 +225,23 @@ def split_execution(ops, scope_by_id):
     return totals
 
 
+def module_executions(loaded, module, lo, hi):
+    """``(start ns, end ns, its operations)`` of every execution of
+    ``module`` wholly inside [lo, hi], in the order they ran; ``loaded`` is
+    ``load``'s tuple (its operations are sorted here, once)."""
+    modules, ops, names, _paths = loaded
+    ops.sort()
+    starts = [op[0] for op in ops]
+    for start, end, key in sorted(modules):
+        if (reduce_trace._module_name(names.get(key, '')) != module
+                or start < lo or end > hi):
+            continue
+        yield start, end, [
+            op for op in ops[bisect.bisect_left(starts, start):
+                             bisect.bisect_right(starts, end)]
+            if op[1] <= end]
+
+
 @functools.lru_cache(maxsize=4)
 def executions(path, module, scopes, lo, hi):
     """One ``{scope: seconds, 'module': seconds}`` per execution of
@@ -234,19 +251,10 @@ def executions(path, module, scopes, lo, hi):
     loaded = load(path)
     if loaded is None:
         return None
-    modules, ops, names, paths = loaded
     scope_by_id = {key: scope_of(path_, scopes)
-                   for key, path_ in paths.items()}
-    ops.sort()
-    starts = [op[0] for op in ops]
+                   for key, path_ in loaded[3].items()}
     out, labelled = [], False
-    for start, end, key in modules:
-        if (reduce_trace._module_name(names.get(key, '')) != module
-                or start < lo or end > hi):
-            continue
-        inside = [op for op in ops[bisect.bisect_left(starts, start):
-                                   bisect.bisect_right(starts, end)]
-                  if op[1] <= end]
+    for start, end, inside in module_executions(loaded, module, lo, hi):
         labelled = labelled or any(scope_by_id.get(op[2]) for op in inside)
         split = {k: v / 1e9 for k, v in
                  split_execution(inside, scope_by_id).items()}

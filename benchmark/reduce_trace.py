@@ -18,6 +18,7 @@ that matter here (checkpoint writes, tens of ms) can.
 
     {"path": <the file>, "devices": 1,
      "window": [t0_ns, t1_ns], "window_s": ..,   # the traced window
+     "marks": [[start_ns, end_ns], ...],         # its annotations, by end
      "busy_s": ..,               # union of op intervals, mean over chips
      "modules": {"jit_f": [seconds, ...]},        # per execution, chip 0
      "device_ops": [[name, self_seconds], ...],   # top 10, chip 0
@@ -158,10 +159,11 @@ def reduce(path, window_span='train_dispatch', gap_prefix=GAP_PREFIX):
             host = plane
     if not devices:
         return None
-    marks = sorted(end for _s, end, name in _host_spans(host, SPAN_PREFIX)
-                   if name == window_span)
+    marks = sorted(((start, end) for start, end, name
+                    in _host_spans(host, SPAN_PREFIX) if name == window_span),
+                   key=lambda mark: mark[1])
     if len(marks) >= 2:
-        lo, hi = marks[0], marks[-1]
+        lo, hi = marks[0][1], marks[-1][1]
     else:
         events = [iv for lines in devices.values()
                   for ivs in lines.values() for iv in ivs]
@@ -188,6 +190,7 @@ def reduce(path, window_span='train_dispatch', gap_prefix=GAP_PREFIX):
     return {
         'path': path, 'devices': len(devices),
         'window': [lo, hi], 'window_s': (hi - lo) / 1e9,
+        'marks': [list(mark) for mark in marks],
         'busy_s': sum(busy) / len(busy),
         'modules': modules,
         'device_ops': _top(_self_times(in_window)),

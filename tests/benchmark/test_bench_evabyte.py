@@ -23,7 +23,7 @@ from benchmark import checks_evabyte, flops_evabyte, rehearse
 from benchmark.manifest import Manifest, ROOT
 from benchmark.readers import trace_inner_scope_time
 
-from tests.benchmark import contracts
+from tests.benchmark import contracts, traced_fill
 
 CELL = 'evabyte.selfplay_4k'
 # EvaByte/EvaByte config.json, the numbers of it: what may not differ
@@ -131,7 +131,24 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
     model = config['model']
     scope = flops_evabyte.eva_attention_scope(model, args)
     assert model['eva_attention_sgd_flops'] == scope['sgd_flops']
-    assert model['eva_attention_rollout_bytes'] == scope['rollout_bytes']
+    # the cache's bytes are held SPLIT (PR 52): a ply's weights, and what
+    # one more row (a K and V of the window, or a summary pair) in every
+    # sequence costs a ply; with the WHOLE cache read, 2,560 rows, it is the
+    # number held until then
+    assert model['eva_attention_rollout'] == scope['rollout']
+    assert scope['rollout'] == {
+        'plies': 256, 'ply_bytes': 4 * 4 * 4096 * 1024 * 2,
+        'row_bytes': {'eva': 32 * 4 * 2 * 8 * 128 * 2},
+        'analytic_rows': {'eva': 2048 + 512}}
+    assert scope['rollout_bytes'] == 377957122048 \
+        == int(flops_evabyte.chunk_bytes(scope['rollout']))
+    # |L_n| + |R_n| of the equations: the window's rows up to the query and
+    # one summary a chunk of the windows before
+    at = [0, 9, 2047, 2048, 4100, 8191]
+    assert list(flops_evabyte.rows_seen_at(model, 'eva', at)) \
+        == [1, 10, 2048, 1 + 128, 5 + 256, 2048 + 384]
+    assert sum(flops_evabyte.rows_seen_at(model, 'eva', np.arange(5000))) \
+        == flops_evabyte.attention_pairs(model, 5000)
     window = flops_evabyte.train_window_flops(model, args)
     # 3 x (2 x positions x parameters) and a few percent of attention
     floor = 6 * 4096 * sum(flops_evabyte.matmul_parameters(model))
@@ -146,6 +163,40 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
     burn = dict(args, burn_in_steps=64)
     assert flops_evabyte.train_window_flops(model, burn) \
         > flops_evabyte.train_window_flops(model, args)
+
+
+@pytest.mark.parametrize('case, fill', traced_fill.CASES)
+def test_a_roofline_counts_the_rows_its_traced_chunk_had_reached(
+        cell, tmp_path, case, fill):
+    """As ``test_bench_ouro``'s, through ``eva_attention_roofline``'s own
+    file: 100% at 0.7, 1.0 and 1.3 times the games' mean ply index (2,559)
+    for a dispatch whose time is the chip's least for the cache rows its
+    queries must see (their window up to themselves and the summaries
+    reached), where the count of before PR 52, the WHOLE cache a ply, reads
+    far over 100%; the share of what is required for one that reads every
+    row; nothing for one whose chunk was never recorded."""
+    manifest, config, traffic, args = cell
+    spec = manifest.load_metric('eva_attention_roofline')
+    assert spec['reader'] == 'traced_fill_roofline'
+    assert spec['args'] == {
+        'module': 'jit_fused_pipeline_train', 'span': 'chunk_plies',
+        'scope': 'eva_attention',
+        'rows': 'benchmark.flops_evabyte:rows_seen_at',
+        'rollout': 'config.model.eva_attention_rollout',
+        'sgd_flops': 'config.model.eva_attention_sgd_flops'}
+    got, share, old = traced_fill.roofline_case(
+        tmp_path, manifest, manifest.cell(CELL), config, traffic, args,
+        'eva_attention_roofline', case, fill, mean_index=2559)
+    if case == 'unpaired':
+        assert got is None
+        return
+    assert got['value'] == pytest.approx(share, rel=1e-6)
+    assert got['analytic_mean_value'] == pytest.approx(old, rel=1e-6)
+    assert got['executions'][0]['fill_rows']['eva'] < 2560
+    if case == 'time_follows_fill':
+        assert share == pytest.approx(100.0) and old > 150
+    else:
+        assert 30 < got['value'] < 45
 
 
 def test_the_cells_own_metrics_are_pinned_by_name(cell):

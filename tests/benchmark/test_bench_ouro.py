@@ -27,7 +27,7 @@ from benchmark import checks_ouro as co
 from benchmark import flops_ouro, rehearse
 from benchmark.manifest import Manifest
 
-from tests.benchmark import contracts
+from tests.benchmark import contracts, traced_fill
 
 CELL = 'ouro.loop_selfplay_4k'
 SOURCE = 'https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json'
@@ -290,9 +290,26 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
     model = config['model']
     scope = flops_ouro.loop_attention_scope(model, args)
     assert model['loop_attention_sgd_flops'] == scope['sgd_flops']
-    assert model['loop_attention_rollout_bytes'] == scope['rollout_bytes']
-    assert model['decode_ply_bytes'] == flops_ouro.decode_ply_bytes(model,
-                                                                    args)
+    # the rollout's bytes are held SPLIT (PR 52): what a ply reads whatever
+    # the caches hold, and what one more row in every sequence costs a ply
+    assert model['loop_attention_rollout'] == scope['rollout']
+    decode = flops_ouro.decode_rollout(model, args)
+    assert model['decode_rollout'] == decode
+    assert decode == {'plies': 256, 'ply_bytes': 1291849728,
+                      'row_bytes': {'global': 32 * 32768},
+                      'analytic_rows': {'global': 1536.5}}
+    assert scope['rollout'] == dict(decode, ply_bytes=16 * 4 * 2048 * 512 * 2)
+    # at the analytic mean's rows the split is the number held until PR 52
+    assert scope['rollout_bytes'] == 446810816512 \
+        == int(flops_ouro.chunk_bytes(scope['rollout']))
+    assert flops_ouro.decode_ply_bytes(model, args) == 2902986752 \
+        == int(flops_ouro.chunk_bytes(decode) / 256)
+    at_mean = {'global': flops_ouro.rows_seen_at(
+        model, 'global', np.full((256, 16), 1535.5))}
+    assert flops_ouro.chunk_bytes(decode, at_mean) \
+        == flops_ouro.chunk_bytes(decode)
+    assert list(flops_ouro.rows_seen_at(model, 'global', [0, 7, 4095, 5000])) \
+        == [1, 8, 4096, 4096]
     attention, mlp, readout = flops_ouro.matmul_parameters(model)
     assert attention == 16 * 4 * 2048 * 512 and mlp == 16 * 3 * 2048 * 5632
     assert readout == 4 * 2048 * (12288 + 2)
@@ -310,7 +327,7 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
     seen = flops_ouro.rows_written(model)
     assert 2048 / 2 < seen < 4096 / 2 + 1
     sequences = 2 * args['generation_envs']
-    assert model['decode_ply_bytes'] == int(
+    assert flops_ouro.decode_ply_bytes(model, args) == int(
         weights + 2048 * 12289 * 2 + 16 * sequences * seen * 2048)
     assert scope['rollout_bytes'] == int(256 * 16 * (
         4 * 2048 * 512 * 2 + sequences * seen * 2048))
@@ -328,19 +345,22 @@ def test_each_new_metric_names_a_reader_and_the_cell(cell):
         assert spec['reader'] == 'trace_inner_scope_time'
         assert spec['args'] == {'module': 'jit_fused_pipeline_train',
                                 'scope': scope, 'stat': 'median'}
+    common = {'module': 'jit_fused_pipeline_train', 'span': 'chunk_plies',
+              'rows': 'benchmark.flops_ouro:rows_seen_at'}
     spec = manifest.load_metric('loop_attention_roofline')
-    assert spec['reader'] == 'derived'
-    for word in ('config.model.loop_attention_sgd_flops',
-                 'config.model.loop_attention_rollout_bytes',
-                 'loop_attention_ms'):
-        assert word in spec['args']['expr']
+    assert spec['reader'] == 'traced_fill_roofline'
+    assert spec['args'] == dict(
+        common, scope='loop_attention',
+        rollout='config.model.loop_attention_rollout',
+        sgd_flops='config.model.loop_attention_sgd_flops')
     assert 'UPPER bound' in spec['what']
     spec = manifest.load_metric('loop_decode_roofline')
-    assert spec['reader'] == 'derived'
-    for word in ('config.model.decode_ply_bytes', 'peak.hbm_bytes_per_s',
-                 'rollout_ms', 'train_args.device_chunk_steps'):
-        assert word in spec['args']['expr']
+    assert spec['reader'] == 'traced_fill_roofline'
+    assert spec['args'] == dict(
+        common, scope='rollout', scopes=['rollout', 'ingest', 'sgd', 'pack'],
+        rollout='config.model.decode_rollout')
     for name in ('loop_attention_roofline', 'loop_decode_roofline'):
+        assert 'TRACED plies' in manifest.load_metric(name)['what']
         assert manifest.metrics[name]['unit'] == '%'
         assert manifest.metrics[name]['layer'] == 'rollout'
     spec = manifest.load_metric('exit_entropy_share')
@@ -413,10 +433,11 @@ def test_the_scope_readers_read_each_of_the_three_scopes(cell, tmp_path):
 
 
 def test_the_rooflines_and_the_share_read_their_numbers(cell, monkeypatch):
-    """``derived`` over the configuration's counts and a scope's or the
-    rollout's time, and ``program_counter_ratio`` over two records of the
-    ``host_block`` span as ``FusedPipeline._parse`` sets it."""
-    from benchmark.readers import derived, program_counter_ratio
+    """``program_counter_ratio`` over two records of the ``host_block`` span
+    as ``FusedPipeline._parse`` sets it; the two rooflines read nothing
+    without a trace (with one:
+    ``test_a_roofline_counts_the_rows_its_traced_chunk_had_reached``)."""
+    from benchmark.readers import program_counter_ratio, traced_fill_roofline
     from benchmark.record import Run
     manifest, config, traffic, args = cell
     attrs = lambda k: {'window_positions_valid': 6000.0 * k,
@@ -433,22 +454,53 @@ def test_the_rooflines_and_the_share_read_their_numbers(cell, monkeypatch):
     share = manifest.load_metric('exit_entropy_share')
     assert program_counter_ratio.read(run, **share['args']) \
         == pytest.approx(90.0)
-    model = config['model']
-    spec = manifest.load_metric('loop_attention_roofline')
-    assert derived.read(run, **spec['args']) is None    # no time yet
-    least_ms = 1000 * (model['loop_attention_sgd_flops'] / 197e12
-                       + model['loop_attention_rollout_bytes'] / 819e9)
-    run.values['loop_attention_ms'] = 4 * least_ms
-    assert derived.read(run, **spec['args']) == pytest.approx(25.0)
-    spec = manifest.load_metric('loop_decode_roofline')
-    assert derived.read(run, **spec['args']) is None    # no rollout_ms yet
-    ply_ms = 1000 * model['decode_ply_bytes'] / 819e9
-    run.values['rollout_ms'] = 2 * ply_ms * args['device_chunk_steps']
-    assert derived.read(run, **spec['args']) == pytest.approx(50.0)
+    for name in ('loop_attention_roofline', 'loop_decode_roofline'):
+        spec = manifest.load_metric(name)
+        assert traced_fill_roofline.read(run, **spec['args']) is None
     # a program without the sums (the parent's): nothing to read, no error
     for record in ring:
         record['attrs'] = {'plies': 1}
     assert program_counter_ratio.read(run, **share['args']) is None
+
+
+@pytest.mark.parametrize('case, fill', traced_fill.CASES)
+@pytest.mark.parametrize('metric', ['loop_decode_roofline',
+                                    'loop_attention_roofline'])
+def test_a_roofline_counts_the_rows_its_traced_chunk_had_reached(
+        cell, tmp_path, metric, case, fill):
+    """A synthetic traced run (``traced_fill.py``) through the metric's own
+    file. A dispatch that takes exactly the HBM's time for the rows its own
+    counters reached reads 100% at 0.7, 1.0 and 1.3 times the games' mean
+    fill, where the expression of before PR 52 (the analytic mean's bytes
+    over whatever the traced dispatch took) reads 120%, 100% and 86%: the
+    105.25% that refused PR 49. A dispatch that reads all 4,096 rows of every
+    buffer at the 711 GB/s the tree's ply reaches reads the share the tree
+    reads (ledger, PR 51: 45.10-45.11%). A program whose chunk was never
+    recorded reads nothing: the analytic mean does not stand in."""
+    manifest, config, traffic, args = cell
+    got, share, old = traced_fill.roofline_case(
+        tmp_path, manifest, manifest.cell(CELL), config, traffic, args,
+        metric, case, fill, mean_index=1535.5, bandwidth=711e9)
+    if case == 'unpaired':
+        assert got is None
+        return
+    assert got['value'] == pytest.approx(share, rel=1e-6)
+    assert got['analytic_mean_value'] == pytest.approx(old, rel=1e-6)
+    assert got['samples'] == 1
+    assert got['executions'][0]['fill_rows']['global'] \
+        == pytest.approx(fill * 1535.5 + 1, abs=0.51)
+    if case == 'time_follows_fill':
+        assert share == pytest.approx(100.0)
+        if metric == 'loop_decode_roofline':
+            assert old == pytest.approx({0.7: 120.0, 1.0: 100.0,
+                                         1.3: 85.7}[fill], abs=0.05)
+    elif metric == 'loop_decode_roofline':
+        assert got['value'] == pytest.approx(45.1, abs=0.05)
+        # by hand, as the acceptance asks: the printed fill and milliseconds
+        (one,) = got['executions']
+        assert got['value'] == pytest.approx(
+            100 * (1.291849728e9 + 32 * 32768 * one['fill_rows']['global'])
+            / 819e9 / (one['ms'] / 1e3 / 256), rel=1e-9)
 
 
 # -- the checks and a planted fault for each, at the rehearsal's size ----------

@@ -27,7 +27,7 @@ from benchmark import checks_smallthinker as cs
 from benchmark import flops_smallthinker, rehearse
 from benchmark.manifest import Manifest
 
-from tests.benchmark import contracts
+from tests.benchmark import contracts, traced_fill
 
 CELL = 'smallthinker.moe_selfplay_8k'
 SOURCE = ('https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct/'
@@ -274,7 +274,10 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
                           ('window_attention', window),
                           ('global_attention', whole)):
         assert model[scope + '_sgd_flops'] == counts['sgd_flops']
-        assert model[scope + '_rollout_bytes'] == counts['rollout_bytes']
+        if scope == 'reglu_experts':    # the experts' bytes follow no fill
+            assert model[scope + '_rollout_bytes'] == counts['rollout_bytes']
+        else:                           # held SPLIT (PR 52)
+            assert model[scope + '_rollout'] == counts['rollout']
     # 2 windows of 8,192 positions, 1.5 held experts a position and layer
     assert experts['sgd_flops'] == int(
         3 * 2 * 2 * 8192 * 4 * 1.5 * 3 * 2560 * 768)
@@ -315,6 +318,24 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
     mean_len = 4096 / math.log(2)
     assert mean_len / 2 < deep < 8192 / 2 + 1
     assert whole['rollout_bytes'] == int(256 * (weights + 32 * deep * row))
+    # the split: a ply's weights, a row of every sequence over the kind's
+    # layers; at the analytic mean's rows it is the number held until PR 52
+    assert window['rollout'] == {
+        'plies': 256, 'ply_bytes': 3 * weights,
+        'row_bytes': {'window': 3 * 32 * row},
+        'analytic_rows': {'window': seen}}
+    assert whole['rollout'] == {
+        'plies': 256, 'ply_bytes': weights, 'row_bytes': {'global': 32 * row},
+        'analytic_rows': {'global': deep}}
+    assert window['rollout_bytes'] == 41734407697 \
+        == int(flops_smallthinker.chunk_bytes(window['rollout']))
+    assert whole['rollout_bytes'] == 15571353600 \
+        == int(flops_smallthinker.chunk_bytes(whole['rollout']))
+    at = [0, 9, 4095, 4096, 8191, 9000]
+    assert list(flops_smallthinker.rows_seen_at(model, 'window', at)) \
+        == [1, 10, 4096, 4096, 4096, 4096]
+    assert list(flops_smallthinker.rows_seen_at(model, 'global', at)) \
+        == [1, 10, 4096, 4097, 8192, 8192]
     burn = dict(args, burn_in_steps=64)
     assert flops_smallthinker.train_window_flops(model, burn) > step
 
@@ -348,13 +369,24 @@ def test_each_new_metric_names_a_reader_and_the_cell(cell):
     assert spec['reader'] == 'trace_inner_scope_kernels_time'
     assert (spec['args']['scope'], spec['args']['kernels']) \
         == ('reglu_experts', ['ragged-dot'])
+    spec = manifest.load_metric('reglu_experts_roofline')
+    assert spec['reader'] == 'derived'
+    for word in ('config.model.reglu_experts_sgd_flops',
+                 'config.model.reglu_experts_rollout_bytes',
+                 'reglu_experts_ms'):
+        assert word in spec['args']['expr']
+    for scope in ('window_attention', 'global_attention'):
+        spec = manifest.load_metric(scope + '_roofline')
+        assert spec['reader'] == 'traced_fill_roofline'
+        assert spec['args'] == {
+            'module': 'jit_fused_pipeline_train', 'span': 'chunk_plies',
+            'scope': scope,
+            'rows': 'benchmark.flops_smallthinker:rows_seen_at',
+            'rollout': 'config.model.%s_rollout' % scope,
+            'sgd_flops': 'config.model.%s_sgd_flops' % scope}
+        assert 'TRACED plies' in spec['what']
     for scope in ('reglu_experts', 'window_attention', 'global_attention'):
         spec = manifest.load_metric(scope + '_roofline')
-        assert spec['reader'] == 'derived'
-        for word in ('config.model.%s_sgd_flops' % scope,
-                     'config.model.%s_rollout_bytes' % scope,
-                     scope + '_ms'):
-            assert word in spec['args']['expr']
         assert 'UPPER bound' in spec['what']
         assert manifest.metrics[scope + '_roofline']['unit'] == '%'
     spec = manifest.load_metric('window_hidden_position_share')
@@ -440,10 +472,13 @@ def test_the_scope_readers_read_each_of_the_five_scopes(cell, tmp_path):
 
 
 def test_the_rooflines_and_the_share_read_their_numbers(cell, monkeypatch):
-    """``derived`` over the configuration's counts and a scope's time, and
+    """``derived`` over the experts' counts and their scope's time,
     ``program_counter_ratio`` over two records of the ``host_block`` span as
-    ``FusedPipeline._parse`` sets it."""
-    from benchmark.readers import derived, program_counter_ratio
+    ``FusedPipeline._parse`` sets it; the two attention rooflines read
+    nothing without a trace (with one:
+    ``test_a_roofline_counts_the_rows_its_traced_chunk_had_reached``)."""
+    from benchmark.readers import (derived, program_counter_ratio,
+                                   traced_fill_roofline)
     from benchmark.record import Run
     manifest, config, traffic, args = cell
     attrs = lambda k: {'window_positions_valid': 6000.0 * k,
@@ -472,18 +507,53 @@ def test_the_rooflines_and_the_share_read_their_numbers(cell, monkeypatch):
     assert shared('moe_load_max_over_mean') == 600.0 * 64 / 6000.0
     assert shared('expert_short_buffer_share') == 100.0
     model = config['model']
-    for scope in ('reglu_experts', 'window_attention', 'global_attention'):
+    spec = manifest.load_metric('reglu_experts_roofline')
+    assert derived.read(run, **spec['args']) is None    # no time yet
+    least_ms = 1000 * (model['reglu_experts_sgd_flops'] / 197e12
+                       + model['reglu_experts_rollout_bytes'] / 819e9)
+    run.values['reglu_experts_ms'] = 4 * least_ms
+    assert derived.read(run, **spec['args']) == pytest.approx(25.0)
+    for scope in ('window_attention', 'global_attention'):
         spec = manifest.load_metric(scope + '_roofline')
-        assert derived.read(run, **spec['args']) is None    # no time yet
-        least_ms = 1000 * (model[scope + '_sgd_flops'] / 197e12
-                           + model[scope + '_rollout_bytes'] / 819e9)
-        run.values[scope + '_ms'] = 4 * least_ms
-        assert derived.read(run, **spec['args']) == pytest.approx(25.0)
+        assert traced_fill_roofline.read(run, **spec['args']) is None
     # a program without the sums (the parent's): nothing to read, no error
     for record in ring:
         record['attrs'] = {'plies': 1}
     assert program_counter_ratio.read(run, **share['args']) is None
     assert shared('expert_short_buffer_share') is None
+
+
+@pytest.mark.parametrize('case, fill', traced_fill.CASES)
+@pytest.mark.parametrize('metric', ['window_attention_roofline',
+                                    'global_attention_roofline'])
+def test_a_roofline_counts_the_rows_its_traced_chunk_had_reached(
+        cell, tmp_path, metric, case, fill):
+    """As ``test_bench_ouro``'s: a dispatch whose time is the chip's least
+    for the rows its own counters reached reads 100% at 0.7, 1.0 and 1.3
+    times the games' mean ply index (3,071.5), whatever the expression of
+    before PR 52 reads there; one that reads every row of its buffers reads
+    what is required over that; one whose chunk was never recorded, nothing.
+    A window layer's rows stop at 4,096."""
+    manifest, config, traffic, args = cell
+    got, share, old = traced_fill.roofline_case(
+        tmp_path, manifest, manifest.cell(CELL), config, traffic, args,
+        metric, case, fill, mean_index=3071.5)
+    if case == 'unpaired':
+        assert got is None
+        return
+    assert got['value'] == pytest.approx(share, rel=1e-6)
+    assert got['analytic_mean_value'] == pytest.approx(old, rel=1e-6)
+    (kind, rows), = got['executions'][0]['fill_rows'].items()
+    assert kind == metric.split('_')[0]
+    if kind == 'global':
+        assert rows == pytest.approx(fill * 3071.5 + 1, abs=0.51)
+    else:
+        assert rows <= 4096 and (fill < 1.3 or rows > 3900)
+    if case == 'time_follows_fill':
+        assert share == pytest.approx(100.0)
+        assert (old > 105) == (fill == 0.7)
+    else:
+        assert 40 < got['value'] < 90
 
 
 # -- the checks and a planted fault for each, at the rehearsal's size ----------

@@ -29,7 +29,7 @@ from benchmark import checks_trinity_mini as ct
 from benchmark import flops_trinity_mini, rehearse
 from benchmark.manifest import Manifest
 
-from tests.benchmark import contracts
+from tests.benchmark import contracts, traced_fill
 
 CELL = 'trinity_mini.moe_selfplay_4k'
 # arcee-ai/Trinity-Mini config.json, the numbers of it: what may not differ
@@ -192,7 +192,22 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
     assert model['moe_experts_sgd_flops'] == experts['sgd_flops']
     assert model['moe_experts_rollout_bytes'] == experts['rollout_bytes']
     assert model['gqa_attention_sgd_flops'] == attention['sgd_flops']
-    assert model['gqa_attention_rollout_bytes'] == attention['rollout_bytes']
+    # the attention's bytes are held SPLIT (PR 52): a ply's weights, and
+    # what one more row in every sequence costs over the layers of a kind;
+    # at the rows the count took until then it is the number held until then
+    assert model['gqa_attention_rollout'] == attention['rollout']
+    assert attention['rollout'] == {
+        'plies': 256, 'ply_bytes': 5 * 6815744 * 2,
+        'row_bytes': {'sliding': 4 * 32 * 512, 'full': 32 * 512},
+        'analytic_rows': {'sliding': 2048.0,
+                          'full': pytest.approx(2560.0, rel=1e-12)}}
+    assert attention['rollout_bytes'] == 62545461248 \
+        == int(flops_trinity_mini.chunk_bytes(attention['rollout']))
+    at = [0, 9, 2047, 2048, 8191, 9000]
+    assert list(flops_trinity_mini.rows_seen_at(model, 'sliding', at)) \
+        == [1, 10, 2048, 2048, 2048, 2048]
+    assert list(flops_trinity_mini.rows_seen_at(model, 'full', at)) \
+        == [1, 10, 2048, 2049, 8192, 8192]
     # the even share: one held expert a position and layer
     assert flops_trinity_mini.held_per_position(model) == 1.0
     assert experts['sgd_flops'] == 3 * 4 * 2 * 4096 * 4 * 3 * 2048 * 1024
@@ -218,6 +233,43 @@ def test_the_counts_a_metric_reads_are_the_functions(cell):
         circles + 8192 * 128 * 2 * 2)
     burn = dict(args, burn_in_steps=64)
     assert flops_trinity_mini.train_window_flops(model, burn) > window
+
+
+@pytest.mark.parametrize('case, fill', traced_fill.CASES)
+def test_a_roofline_counts_the_rows_its_traced_chunk_had_reached(
+        cell, tmp_path, case, fill):
+    """As ``test_bench_ouro``'s, through ``gqa_attention_roofline``'s own
+    file: 100% at 0.7, 1.0 and 1.3 times the games' mean ply index (2,559)
+    for a dispatch whose time is the chip's least for the rows its own
+    counters reached, the sliding layers' rows ``min(p + 1, 2,048)`` and the
+    full layer's ``p + 1``; the share of what is required for one that reads
+    every row of its buffers; nothing for one whose chunk was never
+    recorded."""
+    manifest, config, traffic, args = cell
+    spec = manifest.load_metric('gqa_attention_roofline')
+    assert spec['reader'] == 'traced_fill_roofline'
+    assert spec['args'] == {
+        'module': 'jit_fused_pipeline_train', 'span': 'chunk_plies',
+        'scope': 'gqa_attention',
+        'rows': 'benchmark.flops_trinity_mini:rows_seen_at',
+        'rollout': 'config.model.gqa_attention_rollout',
+        'sgd_flops': 'config.model.gqa_attention_sgd_flops'}
+    got, share, old = traced_fill.roofline_case(
+        tmp_path, manifest, manifest.cell(CELL), config, traffic, args,
+        'gqa_attention_roofline', case, fill, mean_index=2559)
+    if case == 'unpaired':
+        assert got is None
+        return
+    assert got['value'] == pytest.approx(share, rel=1e-6)
+    assert got['analytic_mean_value'] == pytest.approx(old, rel=1e-6)
+    rows = got['executions'][0]['fill_rows']
+    assert rows['full'] == pytest.approx(fill * 2559 + 1, abs=0.51)
+    assert rows['sliding'] <= 2048
+    if case == 'time_follows_fill':
+        assert share == pytest.approx(100.0)
+        assert (old > 105) == (fill == 0.7)
+    else:
+        assert 60 < got['value'] < 80
 
 
 def test_each_new_metric_names_a_reader_and_the_cell(cell):
