@@ -10,10 +10,19 @@ of every (pass, layer): ``init_pass_cache``, ``pass_write``, ``pass_rows``.
 WITHOUT grouping the heads' queries side by side, by the shapes it is handed
 (``heads_side_by_side`` and the products over a set of rows, which
 ``models/evabyte.py`` composes over two sets under one soft-max).
+
+Which form runs where: the grouped form and ``evabyte``'s composition read
+every row of a buffer and mask those past a counter afterwards
+(``rows_seen``), on every backend. The side-by-side form over a looped net's
+buffers does so on the CPU alone (the tests, and what they hold the kernel
+to): where the program runs on a TPU it is the block kernel of
+``models/decode_kernel.py``, which reads only the row blocks a sequence's
+counter has reached, so rows past a counter are no longer read on the chip.
 """
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .trunk import NEG, f32
 
@@ -141,7 +150,25 @@ def rows_seen(n_rows, pos, circle):
     return seen | (pos[:, None] >= n_rows) if circle else seen
 
 
-def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):
+def _on_tpu():
+    """Whether the program being traced runs on a TPU."""
+    return jax.default_backend() == 'tpu'
+
+
+def _block_kernel(rows, width):
+    """``models/decode_kernel.py`` where its ``pass_attention`` reads a
+    pass's ``rows`` rows of ``width``: on a TPU, in whole blocks of whole
+    lanes; None elsewhere. Imported here, so that only a program that runs
+    the kernel pays for importing Pallas (0.85 s)."""
+    if not _on_tpu():
+        return None
+    from . import decode_kernel
+    fits = rows % decode_kernel.BLOCK == 0 and width % 128 == 0
+    return decode_kernel if fits else None
+
+
+def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype, t=None,
+                    rows=None):
     """The decode ply's attention, the one a layer's ``step`` calls: q (B,
     H, d) at each sequence's own position ``pos`` (B,) over the rows written
     so far of ck, cv (B, rows, kv_heads * d) -> (B, H * d); rows not
@@ -149,8 +176,21 @@ def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):
     no position). With one query head a KV head the grouped form is the
     slow one (``heads_side_by_side``), so the shapes say which to take:
     without groups the side-by-side product over ONE set of rows, read once
-    as they lie, with no relayout."""
+    as they lie, with no relayout.
+
+    A net that runs its layers several times hands over a layer's WHOLE
+    buffers (B, passes * rows, ...) with the pass ``t`` and its ``rows``.
+    Side by side and on a TPU that is the block kernel, which takes pass
+    t's row blocks up to each counter's own from the buffers as they lie and
+    reads nothing past them; elsewhere (the CPU's tests, a grouped layer,
+    shapes the kernel does not take) ``pass_rows`` of both and the all-rows
+    products below, the form the tests hold the kernel to."""
     B, H, d = q.shape
+    if t is not None:
+        kernel = H == kv_heads and not circle and _block_kernel(rows, H * d)
+        if kernel:
+            return kernel.pass_attention(q, ck, cv, pos, t, rows, dtype)
+        ck, cv = pass_rows(ck, t, rows), pass_rows(cv, t, rows)
     if H != kv_heads:
         return grouped_cache_attention(q, ck, cv, pos, circle, kv_heads,
                                        dtype)
@@ -196,8 +236,20 @@ def pass_write(ck, cv, k, v, pos, t, rows):
 
 def pass_rows(c, t, rows):
     """Pass ``t``'s rows of a layer's buffer (B, passes * rows, width): what
-    ``cache_attention`` reads in that pass, (B, rows, width)."""
+    ``cache_attention`` reads in that pass where the kernel does not run,
+    (B, rows, width)."""
     return jax.lax.dynamic_slice_in_dim(c, t * rows, rows, axis=1)
+
+
+def pass_rows_read(pos, rows, heads, kv_heads, head_dim):
+    """Of a pass's ``rows`` rows, how many ``cache_attention`` reads for a
+    sequence whose counter is ``pos`` (numpy, any shape): the kernel's whole
+    blocks up to the counter's own, every row where the products run."""
+    pos = np.asarray(pos)
+    kernel = heads == kv_heads and _block_kernel(rows, heads * head_dim)
+    if kernel:
+        return kernel.rows_read(np.minimum(pos, rows - 1))
+    return np.full_like(pos, rows)
 
 
 def heads_side_by_side(q):

@@ -40,7 +40,8 @@ once and a weight's gradient is the sum over its uses:
   head on the last alone. ``hidden`` holds K and V of every (pass, layer):
   a layer's buffer has the passes' ``max_positions`` rows one behind the
   other (``models/attention.py`` ``init_pass_cache``), keys stored already
-  turned, and ONE counter a sequence.
+  turned, and ONE counter a sequence. On a TPU a ply reads only the row
+  blocks a counter has reached (``models/decode_kernel.py``).
 """
 
 from __future__ import annotations
@@ -50,6 +51,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from . import attention, register
@@ -146,10 +148,8 @@ def _layer_step(spec, p, x, pos, t, rows, ck, cv):
         q, k, v = _qkv(spec, p, n, pos)                  # (B, H | KV, d)
         with jax.named_scope('state_update'):
             ck, cv = attention.pass_write(ck, cv, k, v, pos, t, rows)
-        y = attention.cache_attention(
-            q, attention.pass_rows(ck, t, rows),
-            attention.pass_rows(cv, t, rows), pos, False, spec.kv_heads,
-            spec.dtype)
+        y = attention.cache_attention(q, ck, cv, pos, False, spec.kv_heads,
+                                      spec.dtype, t=t, rows=rows)
         part = dot(y, p['wo'], spec.dtype, out=f32) * spec.inv
         a = x + rms_norm(part, p['norm_2'], spec.norm_eps, f32)
     return _mlp(spec, p, a), ck, cv
@@ -260,6 +260,17 @@ class OuroNet(ScaledTrunkNet):
             gate = self._row(features, self.gate) + self.gate_bias
         return {'policy_features': features, 'value': value,
                 'exit_gate': gate}
+
+    def decode_rows(self, pos):
+        """(read, held): the rows of K (as many of V) that ONE ply of
+        sequences at counters ``pos`` (numpy) reads, and those that their
+        buffers hold, over every (pass, layer)."""
+        pos = np.asarray(pos)
+        read = attention.pass_rows_read(
+            pos, self.max_positions, self.heads_held, self.kv_heads_held,
+            self.head_dim)
+        each = self.passes * self.layers
+        return each * int(read.sum()), each * self.max_positions * pos.size
 
     def attention_part(self, layer: int, x, positions, valid):
         return _attention_part(self.spec, self.blocks[layer].weights(), x,

@@ -41,6 +41,22 @@ from .train_step import (TrainState, _update_core, init_train_state,
                          make_optimizer)
 
 
+def ply_indices(done, at_start):
+    """Every lane's ply index at each ply of a chunk, (K, N), from the
+    chunk's ``done`` flags (K, N) and the indices ``at_start`` (N,) of its
+    first ply: one more a ply, 0 again behind a ``done``; and the indices
+    the next chunk starts at. A cache kept by ONE counter a sequence
+    (``models/attention.py`` ``reset_cache``) holds exactly these."""
+    K = done.shape[0]
+    ply = np.arange(K)[:, None]
+    # the ply behind the last ``done`` so far, 0 where none ended yet
+    begun = np.maximum.accumulate(np.where(done, ply + 1, 0), axis=0)
+    since = np.concatenate([np.zeros_like(begun[:1]), begun])
+    index = np.arange(K + 1)[:, None] - since + np.where(since == 0,
+                                                         at_start, 0)
+    return index[:K], index[K]
+
+
 class FusedPipeline:
     """Owns the device-resident loop state (env vector, recurrent hidden,
     windower history, HBM ring) and the two compiled programs (warmup /
@@ -271,6 +287,13 @@ class FusedPipeline:
         self.state_cache_bytes = sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.hidden))
         self.state_resets = hasattr(wrapper.module, 'reset_hidden')
+        # what the decode plies read of a cache kept by counters, where the
+        # net can say (``decode_rows``): every lane's ply index, which is its
+        # seats' counter, kept from the fetched ``done`` flags
+        self.decode_rows = getattr(wrapper.module, 'decode_rows', None)
+        self.lane_ply = np.zeros(n_envs, np.int64)
+        self.decode_rows_read_host = 0
+        self.decode_rows_held_host = 0
         # of all (query, key) pairs of a window, the share the update step's
         # attention multiplies, where the learner knows it (train.py
         # ``Trainer.attention_key_share``): the shapes fix it, and the
@@ -390,6 +413,13 @@ class FusedPipeline:
             if self.state_resets:
                 telemetry.counter('state_resets_total').inc(
                     int(done.sum()) * P)
+            if self.decode_rows is not None:
+                plies, self.lane_ply = ply_indices(done, self.lane_ply)
+                read, held = self.decode_rows(plies)
+                self.decode_rows_read_host += P * read
+                self.decode_rows_held_host += P * held
+                span.set(decode_rows_read=self.decode_rows_read_host,
+                         decode_rows_held=self.decode_rows_held_host)
             keys = self._metric_keys
             if has_metrics and 'data_count' in keys:
                 positions = (self.sgd_steps * self.batch_size

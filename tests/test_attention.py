@@ -392,3 +392,155 @@ def test_the_epoch_record_and_the_gauge_carry_what_the_learner_holds():
     assert record(want) == {'grad_norm': 3.0, 'attention_key_share': want}
     assert telemetry.gauge('attention_key_share').value == want
     assert record(None) == {'grad_norm': 3.0}
+
+
+# -- the block kernel of the looped trunk's decode ply (PR 53) -----------------
+from handyrl_tpu.models import decode_kernel                     # noqa: E402
+
+PASSES, PASS_ROWS = 3, 64
+
+
+def _pass_buffers(block, dtype=f32, heads=2, head_dim=16):
+    """q, the layer's whole K and V buffers, and one counter a sequence: 0,
+    ``block - 1``, ``block``, ``rows - 1`` and two in between, in ONE call."""
+    keys = jax.random.split(jax.random.PRNGKey(block), 3)
+    pos = jnp.asarray([0, block - 1, block, PASS_ROWS - 1, 5, block + 3])
+    B, W = pos.shape[0], heads * head_dim
+    return (jax.random.normal(keys[0], (B, heads, head_dim), dtype),
+            jax.random.normal(keys[1], (B, PASSES * PASS_ROWS, W), dtype),
+            jax.random.normal(keys[2], (B, PASSES * PASS_ROWS, W), dtype),
+            pos)
+
+
+def _spoiled(c, pos, t, value):
+    """``c`` with pass t's rows past each counter, and EVERY row of the other
+    passes, set to ``value``: what the kernel never reads or masks."""
+    row = jnp.arange(c.shape[1])[None, :, None]
+    keep = (row >= t * PASS_ROWS) & (row <= t * PASS_ROWS + pos[:, None, None])
+    return jnp.where(keep, c, value)
+
+
+@pytest.mark.parametrize('t', range(PASSES))
+@pytest.mark.parametrize('block', [8, 16, 32, 64])
+def test_the_block_kernel_is_the_all_rows_form_and_reads_nothing_past_a_counter(
+        block, t):
+    """``decode_kernel.pass_attention`` (interpreted here) against the
+    all-rows products at the same inputs, at every pass offset, for counters
+    at a block's first and last row, a buffer's first and last, and a mix of
+    them across the sequences of one call; the rows past each counter and
+    the other passes' rows hold NaN (K) and 1e30 (V) in what the kernel is
+    handed, and zeros in what the all-rows form is (its masked weights are
+    exact zeros, which a NaN would still spoil). Tolerance: both sides sum
+    the same float32 products of at most 64 rows; the online soft-max
+    rescales by ``exp(m_old - m_new)`` a block where the all-rows form
+    subtracts one maximum, a few float32 roundings of values of order 1:
+    2e-6 stands 8 x over the largest difference seen (2.4e-7)."""
+    q, ck, cv, pos = _pass_buffers(block)
+    want = attention.cache_attention(
+        q, _spoiled(ck, pos, t, 0.0), _spoiled(cv, pos, t, 0.0), pos, False,
+        2, f32, t=jnp.int32(t), rows=PASS_ROWS)
+    got = decode_kernel.pass_attention(
+        q, _spoiled(ck, pos, t, jnp.nan), _spoiled(cv, pos, t, 1e30), pos,
+        jnp.int32(t), PASS_ROWS, f32, block=block)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def test_the_block_kernel_in_bfloat16_stays_within_the_weights_rounding():
+    """In the cell's dtype the two forms round the soft-max's weights to
+    bfloat16 at different scales (the kernel before its division by the
+    sum, the all-rows form after), 2**-9 relative a weight: outputs of order
+    0.3 agree to 2**-7 with room; a masked row that leaked would move them
+    by their own size."""
+    q, ck, cv, pos = _pass_buffers(16, jnp.bfloat16)
+    want = attention.cache_attention(q, ck, cv, pos, False, 2, f32,
+                                     t=jnp.int32(1), rows=PASS_ROWS)
+    got = decode_kernel.pass_attention(q, ck, cv, pos, jnp.int32(1),
+                                       PASS_ROWS, f32, block=16)
+    np.testing.assert_allclose(got, want, atol=2 ** -7)
+
+
+def test_a_counter_past_the_buffer_is_held_to_its_last_row():
+    """No copy may start past a buffer's end (on the chip that is a fault,
+    not a masked row): a counter at or past ``rows`` reads what ``rows - 1``
+    reads, which is what the all-rows mask gives it too."""
+    q, ck, cv, _ = _pass_buffers(16)
+    pos = jnp.full((q.shape[0],), PASS_ROWS + 5)
+    got = decode_kernel.pass_attention(q, ck, cv, pos, jnp.int32(2),
+                                       PASS_ROWS, f32, block=16)
+    want = attention.cache_attention(q, ck, cv, pos, False, 2, f32,
+                                     t=jnp.int32(2), rows=PASS_ROWS)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize('on_tpu,heads,kv_heads,rows,kernel', [
+    (True, 2, 2, 64, True),       # whole blocks of whole lanes, on a TPU
+    (False, 2, 2, 64, False),     # the CPU: the all-rows products
+    (True, 4, 2, 64, False),      # a grouped layer
+    (True, 2, 2, 72, False),      # rows that are no whole blocks
+])
+def test_the_kernel_is_chosen_from_the_backend_and_the_shapes(
+        monkeypatch, on_tpu, heads, kv_heads, rows, kernel):
+    """``cache_attention`` over a looped net's whole buffers takes the block
+    kernel where the program runs on a TPU and the shapes are the kernel's,
+    and ``pass_rows`` with the all-rows products elsewhere: read from the
+    lowered text (a kernel interpreted here lowers to a loop with no product
+    over a pass's rows). ``pass_rows_read`` counts by the same choice."""
+    monkeypatch.setattr(attention, '_on_tpu', lambda: on_tpu)
+    monkeypatch.setattr(decode_kernel, 'BLOCK', 16)
+    shape = jax.ShapeDtypeStruct
+    buffers = shape((2, 3 * rows, kv_heads * 64), f32)
+    text = jax.jit(lambda q, ck, cv, pos, t: attention.cache_attention(
+        q, ck, cv, pos, False, kv_heads, f32, t=t, rows=rows)).lower(
+        shape((2, heads, 64), f32), buffers, buffers, shape((2,), jnp.int32),
+        shape((), jnp.int32)).as_text()
+    sliced = 'tensor<2x%dx%dxf32>' % (rows, kv_heads * 64) in text
+    assert sliced != kernel
+    read = attention.pass_rows_read(np.asarray([0, 15, 16, rows - 1]), rows,
+                                    heads, kv_heads, 64)
+    assert read.tolist() == ([16, 16, 32, 64] if kernel else [rows] * 4)
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    """A DESCRIBED v5e chip to compile for (no chip is attached and nothing
+    runs); the one fixture of the suite that loads the TPU's compiler, made
+    inside a test so that every worker collects the same tests."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:      # no compiler here, or another process has it
+        pytest.skip('no v5e:2x2 topology can be described here: %r' % (e,))
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_block_kernel_compiles_for_the_chip_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """What interpret mode cannot show: the chip's compiler takes the kernel
+    at ``ouro.loop_selfplay_4k``'s shapes (32 sequences, 4 heads of 128,
+    4 passes of 4,096 rows, bfloat16, the shipped block) as ONE custom call
+    with no temporary beside its two double buffers of fast memory: the
+    layer's buffers go in as they lie. A compile is not a measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                     sharding=one_chip)
+    buffers = shape((32, 4 * 4096, 512), jnp.bfloat16)
+    # compiled, not interpreted; an entry written for a described chip
+    # cannot be read back, so the persistent cache stays out of it
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda q, ck, cv, pos, t: decode_kernel.pass_attention(
+                q, ck, cv, pos, t, 4096, jnp.bfloat16)).lower(
+            shape((32, 4, 128), jnp.bfloat16), buffers, buffers,
+            shape((32,), jnp.int32), shape((), jnp.int32)).compile()
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count('tpu_custom_call') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -151,6 +151,49 @@ def test_host_block_counters_are_hand_countable(sharded):
         'windows_built': 7, 'windows_ingested': 7}
 
 
+def test_host_block_carries_the_rows_a_nets_decode_plies_read():
+    """A net that can say what a ply reads of its cache (``decode_rows``)
+    is asked once a fetched chunk with every lane's ply index at every ply,
+    rebuilt from the ``done`` flags across chunks (0 at the first ply, 0
+    again behind a ``done``), and the sums of both seats ride ``host_block``;
+    a net that cannot leaves the span without them."""
+    import time
+
+    from handyrl_tpu import telemetry
+    from handyrl_tpu.ops.fused_pipeline import ply_indices
+
+    K, N = 8, 16
+    fp, _params = _ttt_pipeline(None, fs=2, windows_cap=3, capacity=64)
+    t_start = time.perf_counter()
+    rng = np.random.RandomState(3)
+    chunks = [rng.rand(K, N) < 0.15 for _ in range(3)]
+
+    def parse(done):
+        fp._parse((np.concatenate([
+            done.reshape(-1).astype(np.float32),
+            np.zeros(K * N * 2 + 3, np.float32)]), False))
+        return telemetry.spans('host_block', since=t_start)[-1]['attrs']
+    assert 'decode_rows_read' not in parse(chunks[0])
+    # reads the rows up to its counter in blocks of 4, holds 12 a sequence
+    asked = []
+    fp.decode_rows = lambda pos: asked.append(pos) or (
+        int(((pos // 4 + 1) * 4).sum()), 12 * pos.size)
+    fp.lane_ply[:] = 0
+    index = np.zeros(N, int)
+    read = 0
+    for done in chunks:
+        attrs = parse(done)
+        for row in done:
+            read += 2 * int(((index // 4 + 1) * 4).sum())
+            index = np.where(row, 0, index + 1)
+        assert attrs['decode_rows_read'] == read
+    assert attrs['decode_rows_held'] == 2 * 12 * K * N * 3
+    assert fp.lane_ply.tolist() == index.tolist()
+    assert [a.shape for a in asked] == [(K, N)] * 3
+    plies, after = ply_indices(chunks[2], np.arange(N))
+    assert plies[0].tolist() == list(range(N)) and after.shape == (N,)
+
+
 @pytest.mark.timeout(560)
 @pytest.mark.parametrize('sharded', [False, True],
                          ids=['one_device', 'cpu_mesh'])

@@ -162,26 +162,34 @@ def test_decode_through_the_cache_matches_sequence():
     assert hidden['pos'].tolist() == [T] * 3
 
 
+RESETS = {1: 9, 2: 23}      # sequence -> the ply its second game begins
+
+
+def _played_across_resets(net, variables, ids):
+    """Three sequences' logits (3, T, A) ply by ply through the cache, two
+    of them reset at ``RESETS``."""
+    step = jax.jit(net.apply)
+    hidden = net.init_hidden((3,))
+    got = []
+    for t in range(T):
+        done = jnp.asarray([RESETS.get(b) == t for b in range(3)])
+        hidden = net.reset_hidden(hidden, done)
+        out = step(variables, ids[:, t], hidden)
+        hidden = out['hidden']
+        got.append(out['policy'])
+    assert hidden['pos'].tolist() == [T, T - 9, T - 23]
+    return jnp.stack(got, axis=1)
+
+
 def test_lanes_reset_at_different_counters_keep_their_buffers():
     """Three sequences, two of them reset at different plies: every ply of
     every game against the full forward over that game's ids; the buffers
     are never cleared, the counter alone masks what an earlier game left."""
     net, variables = _net_and_variables()
     ids = _ids(5, (3, T))
-    resets = {1: 9, 2: 23}      # sequence -> the ply its second game begins
-    step = jax.jit(net.apply)
-    hidden = net.init_hidden((3,))
-    got = []
-    for t in range(T):
-        done = jnp.asarray([resets.get(b) == t for b in range(3)])
-        hidden = net.reset_hidden(hidden, done)
-        out = step(variables, ids[:, t], hidden)
-        hidden = out['hidden']
-        got.append(out['policy'])
-    got = jnp.stack(got, axis=1)                         # (3, T, A)
-    assert hidden['pos'].tolist() == [T, T - 9, T - 23]
+    got = _played_across_resets(net, variables, ids)
     for b in range(3):
-        a = resets.get(b, 0)
+        a = RESETS.get(b, 0)
         for lo, hi in ((0, a), (a, T)):
             if lo == hi:
                 continue
@@ -191,6 +199,65 @@ def test_lanes_reset_at_different_counters_keep_their_buffers():
             np.testing.assert_allclose(got[b, lo:hi],
                                        want['logits'][-1][:hi - lo],
                                        atol=3e-4)
+
+
+@pytest.fixture
+def kernel_form(monkeypatch):
+    """``cache_attention`` as it chooses on a TPU, the kernel interpreted
+    here, in blocks of 16 rows (three a pass of the nets below); the count
+    of the kernel's calls while a program is traced."""
+    from handyrl_tpu.models import decode_kernel
+    calls = []
+    real = decode_kernel.pass_attention
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    monkeypatch.setattr(decode_kernel, 'BLOCK', 16)
+    monkeypatch.setattr(decode_kernel, 'pass_attention',
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    return calls
+
+
+@pytest.mark.parametrize('dtype,atol', [('float32', 3e-4),
+                                        ('bfloat16', 0.12)])
+def test_the_kernels_form_decodes_what_sequence_computes_across_a_games_end(
+        kernel_form, dtype, atol):
+    """``OuroNet`` played ply by ply through the cache with the block kernel
+    (a row of 128 lanes: 2 heads of 64) against ``sequence`` over the same
+    ids, to the tolerances of the two cases above: three sequences, two of
+    them reset at different plies, so a counter goes back to 0 over buffers
+    that still hold the old game's rows, and the counters pass every block
+    boundary of a 48-row pass."""
+    net, variables = _net_and_variables(dtype, head_dim=64)
+    ids = _ids(7, (3, T))
+    got = _played_across_resets(net, variables, ids)
+    # a call a layer each time the pass scan's body is traced
+    assert kernel_form and len(kernel_form) % net.layers == 0
+    for b in range(3):
+        a = RESETS.get(b, 0)
+        for lo, hi in ((0, a), (a, T)):
+            if lo == hi:
+                continue
+            game = jnp.zeros((1, T), jnp.int32).at[0, :hi - lo].set(
+                ids[b, lo:hi])
+            logits, _value, _gate = _program(
+                net, variables, game, jnp.zeros((1,), jnp.int32),
+                (jnp.arange(T) < hi - lo)[None])
+            np.testing.assert_allclose(got[b, lo:hi],
+                                       logits[-1][0, :hi - lo], atol=atol)
+
+
+def test_the_net_counts_the_rows_its_plies_read(kernel_form):
+    """``decode_rows``: over every (pass, layer), whole blocks up to each
+    counter's own where the kernel runs, every row elsewhere (a grouped
+    net, the CPU), beside the rows the buffers hold."""
+    pos = np.asarray([[0, 15], [16, 47]])
+    net, _ = _net_and_variables(head_dim=64)
+    each = net.passes * net.layers
+    assert net.decode_rows(pos) == (each * (16 + 16 + 32 + 48),
+                                    each * 48 * 4)
+    grouped, _ = _net_and_variables(head_dim=64, heads_held=4)
+    assert grouped.decode_rows(pos) == (each * 48 * 4, each * 48 * 4)
+    narrow, _ = _net_and_variables()         # 32 lanes: not the kernel's
+    assert narrow.decode_rows(pos)[0] == each * 48 * 4
 
 
 def test_a_pass_reads_its_own_rows_and_no_other_passes():
