@@ -7,18 +7,22 @@ the scopes these run under (``models/trinity.py``, ``models/smallthinker.py``).
 A net that runs its layers several times (``models/ouro.py``) keeps K and V
 of every (pass, layer): ``init_pass_cache``, ``pass_write``, ``pass_rows``.
 ``cache_attention`` is the ONE decode attention: grouped, or for a layer
-WITHOUT grouping the heads' queries side by side, by the shapes it is handed
-(``heads_side_by_side`` and the products over a set of rows, which
-``models/evabyte.py`` composes over two sets under one soft-max).
+WITHOUT grouping the heads' queries side by side (``span_attention``), by
+the shapes it is handed. What a side-by-side query sees is a short list of
+``Span``s of a layer's buffers under one soft-max: one for a looped net's
+pass, two for ``models/evabyte.py``'s step (its window's rows, then the
+summaries), which calls ``span_attention`` itself.
 
-Which form runs where: the grouped form and ``evabyte``'s composition read
-every row of a buffer and mask those past a counter afterwards
-(``rows_seen``), on every backend. The side-by-side form over a looped net's
-buffers does so on the CPU alone (the tests, and what they hold the kernel
-to): where the program runs on a TPU it is the block kernel of
-``models/decode_kernel.py``, which reads only the row blocks a sequence's
-counter has reached, so rows past a counter are no longer read on the chip.
+Which form runs where: the grouped form, and a side-by-side layer that hands
+no span (a plain cache, a circle), read every row of a buffer and mask those
+past a counter afterwards (``rows_seen``), on every backend. Over spans the
+side-by-side form does so on the CPU alone (``spans_seen``: the tests, and
+what they hold the kernel to): where the program runs on a TPU it is the
+block kernel of ``models/decode_kernel.py``, which walks the row blocks
+each span's count has reached, so rows past a count are not read on the chip.
 """
+
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -155,16 +159,83 @@ def _on_tpu():
     return jax.default_backend() == 'tpu'
 
 
-def _block_kernel(rows, width):
-    """``models/decode_kernel.py`` where its ``pass_attention`` reads a
-    pass's ``rows`` rows of ``width``: on a TPU, in whole blocks of whole
-    lanes; None elsewhere. Imported here, so that only a program that runs
-    the kernel pays for importing Pallas (0.85 s)."""
+class Span(NamedTuple):
+    """The first ``count`` (one a sequence) rows from row ``first`` (a
+    scalar) on, of a buffer that holds ``extent`` rows there: what a decode
+    query sees of one stretch of a layer's buffers. The mask of the all-rows
+    products (``spans_seen``), the block kernel's walk and the count of the
+    rows a ply reads (``spans_rows_read``) all derive from a query's spans."""
+    first: Any
+    count: Any
+    extent: int
+
+
+def pass_span(pos, t, rows):
+    """What a query at counter ``pos`` sees of a looped net's pass ``t``."""
+    return Span(t * rows, pos + 1, rows)
+
+
+def spans_seen(n_rows, spans):
+    """(B, n_rows): the rows of a buffer that lie in one of ``spans``."""
+    row = jnp.arange(n_rows)[None, :]
+    seen = False
+    for first, count, extent in spans:
+        seen = seen | ((row >= first)
+                       & (row - first < jnp.minimum(count, extent)[:, None]))
+    return seen
+
+
+def _block_kernel(spans, width, dtype):
+    """``models/decode_kernel.py`` where its walk takes ``spans`` of rows of
+    ``width``: on a TPU, in whole blocks of whole lanes; None elsewhere.
+    Imported here, so that only a program that runs the kernel pays for
+    importing Pallas (0.85 s)."""
     if not _on_tpu():
         return None
     from . import decode_kernel
-    fits = rows % decode_kernel.BLOCK == 0 and width % 128 == 0
-    return decode_kernel if fits else None
+    return decode_kernel if decode_kernel.takes(spans, width, dtype) else None
+
+
+def span_attention(q, ck, cv, spans, dtype):
+    """The side-by-side decode attention: q (B, H, d), one query head a KV
+    head, over each sequence's ``spans`` of ck, cv (B, rows, H * d), the
+    layer's WHOLE buffers as they lie, under ONE soft-max -> (B, H * d).
+    On a TPU that is the block kernel, which takes each span's row blocks up
+    to its count's own and reads nothing past them; elsewhere (the CPU's
+    tests, shapes the kernel does not take) the all-rows products under
+    ``spans_seen``, the form the tests hold the kernel to: over a lone
+    span's ``extent`` rows, over the whole buffers for more."""
+    _, H, d = q.shape
+    kernel = _block_kernel(spans, H * d, ck.dtype)
+    if kernel:
+        return kernel.span_attention(q, ck, cv, spans, dtype)
+    if len(spans) == 1 and spans[0].extent < ck.shape[1]:
+        first, count, extent = spans[0]
+        ck, cv = (jax.lax.dynamic_slice_in_dim(c, first, extent, axis=1)
+                  for c in (ck, cv))
+        spans = [Span(0, count, extent)]
+    return seen_attention(q, ck, cv, spans_seen(ck.shape[1], spans), dtype)
+
+
+def seen_attention(q, ck, cv, seen, dtype):
+    """The all-rows side-by-side products: q (B, H, d) over EVERY row of ck,
+    cv (B, rows, H * d), those not ``seen`` (B, rows) masked afterwards."""
+    B, H, d = q.shape
+    s = side_by_side_scores(heads_side_by_side(q), ck, d)
+    prob = jax.nn.softmax(jnp.where(seen[:, None], s, NEG),
+                          axis=-1).astype(cv.dtype)
+    out = side_by_side_values(prob, cv)
+    return own_blocks(out, H).astype(dtype).reshape(B, H * d)
+
+
+def spans_rows_read(spans, width, dtype):
+    """How many rows ``span_attention`` reads for sequences whose ``spans``
+    hold numpy counts (any shape): the kernel's whole blocks of each span up
+    to its count's own, every row of every span where the products run."""
+    kernel = _block_kernel(spans, width, dtype)
+    if kernel:
+        return kernel.rows_read(spans, width, dtype)
+    return sum(np.full_like(span.count, span.extent) for span in spans)
 
 
 def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype, t=None,
@@ -175,31 +246,25 @@ def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype, t=None,
     ``rows_seen`` are masked (keys are stored already turned, so a row needs
     no position). With one query head a KV head the grouped form is the
     slow one (``heads_side_by_side``), so the shapes say which to take:
-    without groups the side-by-side product over ONE set of rows, read once
-    as they lie, with no relayout.
+    without groups the side-by-side products over the rows, read once as
+    they lie, with no relayout.
 
     A net that runs its layers several times hands over a layer's WHOLE
-    buffers (B, passes * rows, ...) with the pass ``t`` and its ``rows``.
-    Side by side and on a TPU that is the block kernel, which takes pass
-    t's row blocks up to each counter's own from the buffers as they lie and
-    reads nothing past them; elsewhere (the CPU's tests, a grouped layer,
-    shapes the kernel does not take) ``pass_rows`` of both and the all-rows
-    products below, the form the tests hold the kernel to."""
-    B, H, d = q.shape
-    if t is not None:
-        kernel = H == kv_heads and not circle and _block_kernel(rows, H * d)
-        if kernel:
-            return kernel.pass_attention(q, ck, cv, pos, t, rows, dtype)
-        ck, cv = pass_rows(ck, t, rows), pass_rows(cv, t, rows)
+    buffers (B, passes * rows, ...) with the pass ``t`` and its ``rows``:
+    side by side that is ``span_attention`` over ``pass_span``, which the
+    block kernel walks from the buffers as they lie; a grouped layer takes
+    ``pass_rows`` of both. A layer that hands no pass (a plain cache, a
+    circle) keeps the all-rows products under ``rows_seen``."""
+    H = q.shape[1]
     if H != kv_heads:
+        if t is not None:
+            ck, cv = pass_rows(ck, t, rows), pass_rows(cv, t, rows)
         return grouped_cache_attention(q, ck, cv, pos, circle, kv_heads,
                                        dtype)
-    s = side_by_side_scores(heads_side_by_side(q), ck, d)
-    seen = rows_seen(ck.shape[1], pos, circle)
-    prob = jax.nn.softmax(jnp.where(seen[:, None], s, NEG),
-                          axis=-1).astype(cv.dtype)
-    out = side_by_side_values(prob, cv)
-    return own_blocks(out, H).astype(dtype).reshape(B, H * d)
+    if t is not None:
+        return span_attention(q, ck, cv, [pass_span(pos, t, rows)], dtype)
+    return seen_attention(q, ck, cv, rows_seen(ck.shape[1], pos, circle),
+                          dtype)
 
 
 def grouped_cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):
@@ -239,17 +304,6 @@ def pass_rows(c, t, rows):
     ``cache_attention`` reads in that pass where the kernel does not run,
     (B, rows, width)."""
     return jax.lax.dynamic_slice_in_dim(c, t * rows, rows, axis=1)
-
-
-def pass_rows_read(pos, rows, heads, kv_heads, head_dim):
-    """Of a pass's ``rows`` rows, how many ``cache_attention`` reads for a
-    sequence whose counter is ``pos`` (numpy, any shape): the kernel's whole
-    blocks up to the counter's own, every row where the products run."""
-    pos = np.asarray(pos)
-    kernel = heads == kv_heads and _block_kernel(rows, heads * head_dim)
-    if kernel:
-        return kernel.rows_read(np.minimum(pos, rows - 1))
-    return np.full_like(pos, rows)
 
 
 def heads_side_by_side(q):

@@ -24,8 +24,10 @@ Two entries over one set of parameters:
   the serving engine). ``hidden`` holds, a layer, ONE buffer of K and one of
   V (window + max_positions / chunk, heads * d): the current window's rows,
   then the summaries of the whole game; and ONE position counter a
-  sequence. A row is the heads side by side, so the step's two products
-  read a buffer as it lies (``models/attention.py`` ``heads_side_by_side``;
+  sequence. A row is the heads side by side, so the step's attention reads
+  a buffer as it lies (``models/attention.py`` ``span_attention`` over
+  ``eva_spans``: on a TPU the block kernel, which reads only the row blocks
+  the window's slot and the summaries' count have reached, PERF.md, PR 54;
   summaries in buffers of their own are fetched whole into fast memory and
   written back every ply, PERF.md, PR 50). A row is written at the
   sequence's own counter; nothing is ever cleared: what a counter does not
@@ -45,6 +47,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from . import attention, register
@@ -72,6 +75,17 @@ def _summarise(k, v, mu, phi, member):
         sv = jnp.einsum('hct,htd->hcd', weight.astype(v.dtype), v,
                         preferred_element_type=f32).astype(v.dtype)
         return sk, sv
+
+
+def eva_spans(pos, window, chunk, n_rows):
+    """What the query at position ``pos`` (one a sequence, jax or numpy)
+    sees of a layer's buffer of ``n_rows`` rows: its window's rows up to its
+    own slot, and a summary for every chunk of the windows BEFORE its own
+    (the running summary of the chunk being played, at row ``window + pos //
+    chunk``, lies past that count until its window is over)."""
+    return [attention.Span(0, pos % window + 1, window),
+            attention.Span(window, pos // window * (window // chunk),
+                           n_rows - window)]
 
 
 class EvaBlock(nn.Module):
@@ -181,7 +195,7 @@ class EvaBlock(nn.Module):
         """x (B, D) float32 at each sequence's own position ``pos`` (B,);
         cache = (k, v (B, window + chunks, H * d)): the window's rows, then
         the game's summaries; a row is the heads side by side, as the
-        products read it."""
+        decode attention reads it."""
         ck, cv = cache
         W, chunk = self.window_size, self.chunk_size
         H, d = self.heads_held, self.head_dim
@@ -209,20 +223,11 @@ class EvaBlock(nn.Module):
             with jax.named_scope('state_update'):
                 ck = ck.at[rows, W + pos // chunk].set(sk.reshape(B, H * d))
                 cv = cv.at[rows, W + pos // chunk].set(sv.reshape(B, H * d))
-            # a window row up to this position's own, a summary of the
-            # windows before this one
-            row = jnp.arange(ck.shape[1])[None, :]
-            seen = jnp.where(row < W, row <= slot[:, None],
-                             row - W < (pos // W * (W // chunk))[:, None])
             # one query row a head: the heads' queries side by side as ONE
             # matrix against the rows, one soft-max over rows and summaries
-            scores = attention.side_by_side_scores(
-                attention.heads_side_by_side(q), ck, d)
-            prob = jax.nn.softmax(jnp.where(seen[:, None], scores, NEG),
-                                  axis=-1).astype(cv.dtype)
-            y = attention.own_blocks(
-                attention.side_by_side_values(prob, cv), H)
-            x = x + dot(y.reshape(B, -1), self.wo, self.dtype, out=f32)
+            y = attention.span_attention(
+                q, ck, cv, eva_spans(pos, W, chunk, ck.shape[1]), self.dtype)
+            x = x + dot(y, self.wo, self.dtype, out=f32)
         return self.mlp(x), (ck, cv)
 
 
@@ -270,6 +275,17 @@ class EvaByteNet(TrunkNet):
         return attention.init_cache(
             batch_shape, [rows] * self.layers,
             self.heads_held * self.head_dim, self.dtype)
+
+    def decode_rows(self, pos):
+        """(read, held): the rows of K (as many of V) that ONE ply of
+        sequences at counters ``pos`` (numpy) reads, and those that their
+        buffers hold, over every layer."""
+        pos = np.asarray(pos)
+        held = self.window_size + self.max_positions // self.chunk_size
+        read = attention.spans_rows_read(
+            eva_spans(pos, self.window_size, self.chunk_size, held),
+            self.heads_held * self.head_dim, self.dtype)
+        return self.layers * int(read.sum()), self.layers * held * pos.size
 
     # -- inputs and outputs ----------------------------------------------------
     def _embed(self, ids):

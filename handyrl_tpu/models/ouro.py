@@ -266,9 +266,12 @@ class OuroNet(ScaledTrunkNet):
         sequences at counters ``pos`` (numpy) reads, and those that their
         buffers hold, over every (pass, layer)."""
         pos = np.asarray(pos)
-        read = attention.pass_rows_read(
-            pos, self.max_positions, self.heads_held, self.kv_heads_held,
-            self.head_dim)
+        if self.heads_held == self.kv_heads_held:
+            read = attention.spans_rows_read(
+                [attention.pass_span(pos, 0, self.max_positions)],
+                self.heads_held * self.head_dim, self.dtype)
+        else:       # the grouped form reads every row
+            read = np.full_like(pos, self.max_positions)
         each = self.passes * self.layers
         return each * int(read.sum()), each * self.max_positions * pos.size
 

@@ -394,7 +394,7 @@ def test_the_epoch_record_and_the_gauge_carry_what_the_learner_holds():
     assert record(None) == {'grad_norm': 3.0}
 
 
-# -- the block kernel of the looped trunk's decode ply (PR 53) -----------------
+# -- the block kernel of the decode ply: a looped trunk's pass (PR 53) ----------
 from handyrl_tpu.models import decode_kernel                     # noqa: E402
 
 PASSES, PASS_ROWS = 3, 64
@@ -412,6 +412,12 @@ def _pass_buffers(block, dtype=f32, heads=2, head_dim=16):
             pos)
 
 
+def _pass_kernel(q, ck, cv, pos, t, rows, dtype, block):
+    """The kernel over a looped net's one span a pass."""
+    return decode_kernel.span_attention(
+        q, ck, cv, [attention.pass_span(pos, t, rows)], dtype, block=block)
+
+
 def _spoiled(c, pos, t, value):
     """``c`` with pass t's rows past each counter, and EVERY row of the other
     passes, set to ``value``: what the kernel never reads or masks."""
@@ -424,7 +430,7 @@ def _spoiled(c, pos, t, value):
 @pytest.mark.parametrize('block', [8, 16, 32, 64])
 def test_the_block_kernel_is_the_all_rows_form_and_reads_nothing_past_a_counter(
         block, t):
-    """``decode_kernel.pass_attention`` (interpreted here) against the
+    """``decode_kernel.span_attention`` over one span (interpreted here) against the
     all-rows products at the same inputs, at every pass offset, for counters
     at a block's first and last row, a buffer's first and last, and a mix of
     them across the sequences of one call; the rows past each counter and
@@ -439,9 +445,9 @@ def test_the_block_kernel_is_the_all_rows_form_and_reads_nothing_past_a_counter(
     want = attention.cache_attention(
         q, _spoiled(ck, pos, t, 0.0), _spoiled(cv, pos, t, 0.0), pos, False,
         2, f32, t=jnp.int32(t), rows=PASS_ROWS)
-    got = decode_kernel.pass_attention(
+    got = _pass_kernel(
         q, _spoiled(ck, pos, t, jnp.nan), _spoiled(cv, pos, t, 1e30), pos,
-        jnp.int32(t), PASS_ROWS, f32, block=block)
+        jnp.int32(t), PASS_ROWS, f32, block)
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(got, want, atol=2e-6)
 
@@ -455,8 +461,7 @@ def test_the_block_kernel_in_bfloat16_stays_within_the_weights_rounding():
     q, ck, cv, pos = _pass_buffers(16, jnp.bfloat16)
     want = attention.cache_attention(q, ck, cv, pos, False, 2, f32,
                                      t=jnp.int32(1), rows=PASS_ROWS)
-    got = decode_kernel.pass_attention(q, ck, cv, pos, jnp.int32(1),
-                                       PASS_ROWS, f32, block=16)
+    got = _pass_kernel(q, ck, cv, pos, jnp.int32(1), PASS_ROWS, f32, 16)
     np.testing.assert_allclose(got, want, atol=2 ** -7)
 
 
@@ -466,8 +471,7 @@ def test_a_counter_past_the_buffer_is_held_to_its_last_row():
     reads, which is what the all-rows mask gives it too."""
     q, ck, cv, _ = _pass_buffers(16)
     pos = jnp.full((q.shape[0],), PASS_ROWS + 5)
-    got = decode_kernel.pass_attention(q, ck, cv, pos, jnp.int32(2),
-                                       PASS_ROWS, f32, block=16)
+    got = _pass_kernel(q, ck, cv, pos, jnp.int32(2), PASS_ROWS, f32, 16)
     want = attention.cache_attention(q, ck, cv, pos, False, 2, f32,
                                      t=jnp.int32(2), rows=PASS_ROWS)
     np.testing.assert_allclose(got, want, atol=2e-6)
@@ -485,9 +489,10 @@ def test_the_kernel_is_chosen_from_the_backend_and_the_shapes(
     kernel where the program runs on a TPU and the shapes are the kernel's,
     and ``pass_rows`` with the all-rows products elsewhere: read from the
     lowered text (a kernel interpreted here lowers to a loop with no product
-    over a pass's rows). ``pass_rows_read`` counts by the same choice."""
+    over a pass's rows). ``spans_rows_read`` counts the pass's span by the
+    same choice (a grouped layer hands no span: ``OuroNet.decode_rows``)."""
     monkeypatch.setattr(attention, '_on_tpu', lambda: on_tpu)
-    monkeypatch.setattr(decode_kernel, 'BLOCK', 16)
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
     shape = jax.ShapeDtypeStruct
     buffers = shape((2, 3 * rows, kv_heads * 64), f32)
     text = jax.jit(lambda q, ck, cv, pos, t: attention.cache_attention(
@@ -496,9 +501,138 @@ def test_the_kernel_is_chosen_from_the_backend_and_the_shapes(
         shape((), jnp.int32)).as_text()
     sliced = 'tensor<2x%dx%dxf32>' % (rows, kv_heads * 64) in text
     assert sliced != kernel
-    read = attention.pass_rows_read(np.asarray([0, 15, 16, rows - 1]), rows,
-                                    heads, kv_heads, 64)
-    assert read.tolist() == ([16, 16, 32, 64] if kernel else [rows] * 4)
+    if heads == kv_heads:
+        span = attention.pass_span(np.asarray([0, 15, 16, rows - 1]), 0, rows)
+        read = attention.spans_rows_read([span], heads * 64, f32)
+        assert read.tolist() == ([16, 16, 32, 64] if kernel else [rows] * 4)
+
+
+@pytest.mark.parametrize('circle', [False, True], ids=['buffer', 'circle'])
+def test_a_layer_that_hands_no_span_keeps_the_products_on_a_tpu(
+        monkeypatch, circle):
+    """The kernel walks the spans it is handed: a side-by-side layer with a
+    plain cache or a circle hands none, and keeps the all-rows products under
+    ``rows_seen`` on a TPU too, at shapes the kernel would take."""
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    monkeypatch.setattr(decode_kernel, 'takes', lambda *_: 1 / 0)
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(keys[0], (3, 2, 64), f32)
+    ck, cv = (jax.random.normal(key, (3, 64, 128), f32) for key in keys[1:])
+    pos = jnp.asarray([0, 63, 150] if circle else [0, 17, 63])
+    got = attention.cache_attention(q, ck, cv, pos, circle, 2, f32)
+    want = attention.grouped_cache_attention(q, ck, cv, pos, circle, 2, f32)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# -- two spans a sequence under one soft-max: a window and its summaries (PR 54)
+EVA_W, EVA_CHUNK, EVA_MAX = 64, 4, 256
+EVA_ROWS = EVA_W + EVA_MAX // EVA_CHUNK           # 64 window rows, 64 summaries
+
+
+def _eva_spans(pos):
+    from handyrl_tpu.models.evabyte import eva_spans
+    return eva_spans(pos, EVA_W, EVA_CHUNK, EVA_ROWS)
+
+
+def _eva_counters(case, block):
+    """The positions of one call: a slot at a block's first and last row and
+    the window's last, the first position of the second window (slot 0
+    beside a window's 16 summaries), that window's last, the game's last."""
+    cases = {'slot_0': 0, 'a_blocks_last_row': block - 1,
+             'a_blocks_first_row': block, 'the_windows_last_row': EVA_W - 1,
+             'slot_0_beside_summaries': EVA_W,
+             'the_second_windows_last_row': 2 * EVA_W - 1,
+             'the_games_last_position': EVA_MAX - 1}
+    if case == 'a_mix_in_one_call':
+        return jnp.asarray(sorted(cases.values()) + [5, EVA_W + block + 3])
+    return jnp.asarray([cases[case], cases[case]])
+
+
+def _eva_buffers(pos, dtype=f32, heads=2, head_dim=64):
+    keys = jax.random.split(jax.random.PRNGKey(int(pos.sum())), 3)
+    B, W = pos.shape[0], heads * head_dim
+    return (jax.random.normal(keys[0], (B, heads, head_dim), dtype),
+            jax.random.normal(keys[1], (B, EVA_ROWS, W), dtype),
+            jax.random.normal(keys[2], (B, EVA_ROWS, W), dtype))
+
+
+def _outside_spans(c, pos, value):
+    """``c`` with every row that lies in neither span (the window's rows
+    past the slot, the summaries from the running one at row ``W + pos //
+    chunk`` on) set to ``value``."""
+    seen = attention.spans_seen(EVA_ROWS, _eva_spans(pos))
+    assert not bool(seen[jnp.arange(pos.shape[0]),
+                         EVA_W + pos // EVA_CHUNK].any())
+    return jnp.where(seen[:, :, None], c, value)
+
+
+@pytest.mark.parametrize('case', [
+    'slot_0', 'a_blocks_last_row', 'a_blocks_first_row',
+    'the_windows_last_row', 'slot_0_beside_summaries',
+    'the_second_windows_last_row', 'the_games_last_position',
+    'a_mix_in_one_call'])
+@pytest.mark.parametrize('block', [8, 16, 32, 64])
+def test_the_walk_over_two_spans_is_the_all_rows_form_under_their_mask(
+        block, case):
+    """``decode_kernel.span_attention`` (interpreted here) over a window's
+    rows and the summaries of the windows before, ONE soft-max, against the
+    all-rows products under ``spans_seen`` (``attention.span_attention`` on
+    the CPU); what lies in neither span, the running summary's row among it,
+    holds NaN (K) and 1e30 (V) in what the kernel is handed and zeros in
+    what the products are. A sequence in its first window has an EMPTY
+    second span, which is not read. Tolerance as the one-span case's."""
+    pos = _eva_counters(case, block)
+    q, ck, cv = _eva_buffers(pos)
+    spans = _eva_spans(pos)
+    want = attention.span_attention(
+        q, _outside_spans(ck, pos, 0.0), _outside_spans(cv, pos, 0.0), spans,
+        f32)
+    got = decode_kernel.span_attention(
+        q, _outside_spans(ck, pos, jnp.nan), _outside_spans(cv, pos, 1e30),
+        spans, f32, block=block)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def test_the_walk_over_two_spans_in_bfloat16_stays_within_the_weights_rounding():
+    """The two-span walk in the cell's dtype, to the one-span case's 2**-7."""
+    pos = _eva_counters('a_mix_in_one_call', 16)
+    q, ck, cv = _eva_buffers(pos, jnp.bfloat16)
+    want = attention.span_attention(q, ck, cv, _eva_spans(pos), f32)
+    got = decode_kernel.span_attention(q, ck, cv, _eva_spans(pos), f32,
+                                       block=16)
+    np.testing.assert_allclose(got, want, atol=2 ** -7)
+
+
+@pytest.mark.parametrize('on_tpu,width,window,kernel', [
+    (True, 128, 64, True),        # whole blocks of whole lanes, on a TPU
+    (False, 128, 64, False),      # the CPU: the all-rows products
+    (True, 96, 64, False),        # rows that are no whole lanes
+    (True, 128, 72, False),       # a window that is no whole blocks
+])
+def test_two_spans_take_the_kernel_by_the_backend_and_the_shapes(
+        monkeypatch, on_tpu, width, window, kernel):
+    """``span_attention`` over two spans takes the block kernel where the
+    program runs on a TPU and both spans lie in whole blocks of whole lanes,
+    the all-rows products (one product over all the buffer's rows) where
+    not; ``spans_rows_read`` counts by the same choice, from the same
+    spans: whole blocks of each span, none of an empty one."""
+    from handyrl_tpu.models.evabyte import eva_spans
+    monkeypatch.setattr(attention, '_on_tpu', lambda: on_tpu)
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
+    rows = window + 64
+    spans = lambda pos: eva_spans(pos, window, 4, rows)
+    shape = jax.ShapeDtypeStruct
+    buffers = shape((2, rows, width), f32)
+    text = jax.jit(lambda q, ck, cv, pos: attention.span_attention(
+        q, ck, cv, spans(pos), f32)).lower(
+        shape((2, 2, width // 2), f32), buffers, buffers,
+        shape((2,), jnp.int32)).as_text()
+    assert ('tensor<2x8x%dxf32>' % rows in text) != kernel
+    pos = np.asarray([0, 15, 16, window - 1, window, 2 * window + 20])
+    read = attention.spans_rows_read(spans(pos), width, f32)
+    assert read.tolist() == ([16, 16, 32, 64, 16 + 16, 32 + 32] if kernel
+                             else [rows] * 6)
 
 
 @pytest.fixture(scope='module')
@@ -535,10 +669,43 @@ def test_the_block_kernel_compiles_for_the_chip_at_the_cells_shapes(
     compilation_cache.reset_cache()
     try:
         compiled = jax.jit(
-            lambda q, ck, cv, pos, t: decode_kernel.pass_attention(
-                q, ck, cv, pos, t, 4096, jnp.bfloat16)).lower(
+            lambda q, ck, cv, pos, t: decode_kernel.span_attention(
+                q, ck, cv, [attention.pass_span(pos, t, 4096)],
+                jnp.bfloat16)).lower(
             shape((32, 4, 128), jnp.bfloat16), buffers, buffers,
             shape((32,), jnp.int32), shape((), jnp.int32)).compile()
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count('tpu_custom_call') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize('sequences', [32, 8])
+def test_the_two_span_walk_compiles_for_the_chip_at_the_cells_shapes(
+        one_chip, monkeypatch, sequences):
+    """The chip's compiler takes the two-span walk at
+    ``evabyte.selfplay_4k``'s shapes (the rollout's 32 sequences and
+    evaluation's 8, 8 heads of 128, a window of 2,048 rows and 512
+    summaries, bfloat16, the shipped block) as ONE custom call with no
+    temporary beside its double buffers of fast memory. A compile is not a
+    measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from handyrl_tpu.models.evabyte import eva_spans
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                     sharding=one_chip)
+    buffers = shape((sequences, 2560, 1024), jnp.bfloat16)
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda q, ck, cv, pos: attention.span_attention(
+                q, ck, cv, eva_spans(pos, 2048, 16, 2560),
+                jnp.bfloat16)).lower(
+            shape((sequences, 8, 128), jnp.bfloat16), buffers, buffers,
+            shape((sequences,), jnp.int32)).compile()
     finally:
         jax.config.update('jax_enable_compilation_cache', cached)
         compilation_cache.reset_cache()

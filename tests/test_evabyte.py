@@ -230,6 +230,140 @@ def test_the_step_side_by_side_is_the_plain_step(case, heads):
             a, jnp.concatenate(b, axis=1).reshape(a.shape))
 
 
+# -- the step as it is chosen on a TPU: the block kernel over two spans --------
+def _play(net, variables, ids, resets=()):
+    """Every ply's policy and the last cache of ``ids`` (B, T) played through
+    ``__call__``; the sequences of ``resets[t]`` start a new game at ply t.
+    A program of its own each call: traced anew under what the caller has
+    patched."""
+    step = jax.jit(lambda v, i, h: net.apply(v, i, h))
+    hidden = net.init_hidden(ids.shape[:1])
+    policy = []
+    for t in range(ids.shape[1]):
+        if t in resets:
+            hidden = net.reset_hidden(hidden, jnp.asarray(resets[t]))
+        out = step(variables, ids[:, t], hidden)
+        hidden = out['hidden']
+        policy.append(out['policy'])
+    return np.stack(policy, 1), hidden
+
+
+@pytest.mark.parametrize('dtype,atol', [('float32', 2e-5), ('bfloat16', 0.12)])
+def test_the_kernels_step_is_the_products_step_ply_by_ply_across_a_windows_end(
+        monkeypatch, dtype, atol):
+    """``EvaByteNet`` (a row of 128 lanes: 2 heads of 64) played 40 plies
+    through its cache, 2.5 windows of 16, with the step as it is chosen on a
+    TPU (the two-span walk, interpreted) against the step of the all-rows
+    products: every ply's policy, and the caches row for row (both write the
+    same rows; what either reads is the spans'). One sequence starts a new
+    game at ply 21 over buffers that still hold the old game's rows and
+    summaries, so its second span is EMPTY again beside two that are not.
+    In bfloat16 the two round the soft-max's weights at different scales
+    (tests/test_attention.py), 2**-7 of outputs that the readout scales by
+    ~10."""
+    from handyrl_tpu.models import attention, decode_kernel
+    net = EvaByteNet(dtype=jnp.dtype(dtype), **dict(WIDTHS, head_dim=64))
+    variables = jax.tree_util.tree_map(
+        lambda x: x * 8 if x.ndim >= 2 else x,
+        net.init(jax.random.PRNGKey(0), jnp.zeros((1,), jnp.int32), None))
+    ids = _ids(3, seed=5)
+    resets = {21: [False, True, False]}
+    want, want_hidden = _play(net, variables, ids, resets)
+    calls = []
+    real = decode_kernel.span_attention
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 8)
+    monkeypatch.setattr(decode_kernel, 'span_attention',
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    got, got_hidden = _play(net, variables, ids, resets)
+    assert len(calls) == net.layers        # a call a layer, traced once
+    assert list(np.asarray(got_hidden['pos'])) == [T, T - 21, T]
+    np.testing.assert_allclose(got, want, atol=atol)
+    if dtype == 'float32':
+        for a, b in zip(got_hidden['k'] + got_hidden['v'],
+                        want_hidden['k'] + want_hidden['v']):
+            np.testing.assert_allclose(a, b, atol=atol)
+
+
+def test_the_kernels_step_traces_under_a_mesh_as_the_sharded_fused_path_runs_it(
+        monkeypatch):
+    """The sharded fused path runs the rollout inside ``jax.shard_map`` over
+    the lanes (``ops/fused_pipeline.py``): each shard's step sees its local
+    sequences, and so does the kernel's call (the walk is over the
+    sequences it is handed). Four sequences over two devices, the step as
+    chosen on a TPU (interpreted), against the unsharded products' step."""
+    from functools import partial
+    from jax.sharding import Mesh, PartitionSpec as P
+    from handyrl_tpu.models import attention, decode_kernel
+    net = EvaByteNet(dtype=jnp.float32, **dict(WIDTHS, head_dim=64))
+    variables = net.init(jax.random.PRNGKey(0), jnp.zeros((1,), jnp.int32),
+                         None)
+    ids = _ids(4, seed=9)
+    hidden = dict(net.init_hidden((4,)), pos=jnp.asarray([0, 7, 16, 39]))
+    hidden = jax.tree_util.tree_map(
+        lambda x: jax.random.normal(jax.random.PRNGKey(1), x.shape, x.dtype)
+        if x.dtype == jnp.float32 else x, hidden)
+    want = jax.jit(lambda v, i, h: net.apply(v, i, h))(
+        variables, ids[:, 0], hidden)
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 8)
+    calls, real = [], decode_kernel.span_attention
+    monkeypatch.setattr(
+        decode_kernel, 'span_attention',
+        lambda q, *a, **k: calls.append(q.shape[0]) or real(q, *a, **k))
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ('data',))
+    step = jax.jit(partial(jax.shard_map, check_vma=False)(
+        lambda v, i, h: net.apply(v, i, h), mesh=mesh,
+        in_specs=(P(), P('data'), P('data')), out_specs=P('data')))
+    got = step(variables, ids[:, 0], hidden)
+    assert calls == [2] * net.layers       # a shard's two sequences a call
+    np.testing.assert_allclose(got['policy'], want['policy'], atol=2e-5)
+    for a, b in zip(got['hidden']['k'], want['hidden']['k']):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_the_net_counts_the_rows_its_plies_read_from_the_steps_own_spans(
+        monkeypatch):
+    """``EvaByteNet.decode_rows`` at the published sizes, tied to the
+    kernel's own count and to the yardstick: at EVERY ply index of the
+    longest game a ply reads, where the kernel runs, whole blocks of 256
+    rows of the window up to its slot and of the summaries of the windows
+    before: at least what the query must see
+    (``benchmark.flops_evabyte.rows_seen_at``), never more than the 2,560
+    rows a buffer holds, and exactly 2,560 where the products run (the CPU;
+    heads too narrow for whole lanes)."""
+    from benchmark.flops_evabyte import rows_seen_at
+    from handyrl_tpu.models import attention, decode_kernel
+    from handyrl_tpu.models.evabyte import eva_spans
+    net = EvaByteNet()
+    model = {'window_size': net.window_size, 'chunk_size': net.chunk_size}
+    plies = np.arange(net.max_positions)
+    held = net.window_size + net.max_positions // net.chunk_size
+    assert held == 2560
+    assert net.decode_rows(plies) == (net.layers * held * plies.size,
+                                      net.layers * held * plies.size)
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    assert decode_kernel.block_rows(net.heads_held * net.head_dim,
+                                    net.dtype) == 256
+    read = attention.spans_rows_read(
+        eva_spans(plies, net.window_size, net.chunk_size, held),
+        net.heads_held * net.head_dim, net.dtype)
+    must = rows_seen_at(model, 'eva', plies)
+    assert (read >= must).all() and (read <= held).all()
+    assert (read - must < 2 * 256).all()      # a block's rounding a span
+    np.testing.assert_array_equal(
+        read, -(-(plies % 2048 + 1) // 256) * 256
+        + -(-(plies // 2048 * 128) // 256) * 256)
+    assert net.decode_rows(plies) == (net.layers * int(read.sum()),
+                                      net.layers * held * plies.size)
+    # ply by ply as the pipeline asks, any shape
+    for p in (0, 255, 256, 2047, 2048, 4095, 4096, 8191):
+        assert net.decode_rows(np.full((2, 3), p))[0] \
+            == net.layers * 6 * int(read[p])
+    narrow = EvaByteNet(head_dim=8)         # 64 lanes: not the kernel's
+    assert narrow.decode_rows(plies)[0] == narrow.layers * held * plies.size
+
+
 def test_the_four_head_shares_sum_to_the_uncut_layer():
     """The cut is tied to the model: an uncut reference layer of 8 heads,
     its weights dealt to four shares of 2 heads; the program's attention

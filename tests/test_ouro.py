@@ -208,10 +208,10 @@ def kernel_form(monkeypatch):
     of the kernel's calls while a program is traced."""
     from handyrl_tpu.models import decode_kernel
     calls = []
-    real = decode_kernel.pass_attention
+    real = decode_kernel.span_attention
     monkeypatch.setattr(attention, '_on_tpu', lambda: True)
-    monkeypatch.setattr(decode_kernel, 'BLOCK', 16)
-    monkeypatch.setattr(decode_kernel, 'pass_attention',
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
+    monkeypatch.setattr(decode_kernel, 'span_attention',
                         lambda *a, **k: calls.append(1) or real(*a, **k))
     return calls
 
