@@ -29,7 +29,12 @@ tests/test_models.py holds the four to these signatures.
 * ``post_update(before, after, aux) -> params``: runs on the parameter trees
   after the optimizer, ``aux`` the ``sequence``'s (``ops/train_step.py``).
 * ``attention_key_share(T) -> float``: the share of all (query, key) pairs a
-  window of ``T`` multiplies, in every epoch's record (``train.py``).
+  window of ``T`` multiplies, in every epoch's record (``train.py``) and on
+  the ``host_block`` span (``ops/fused_pipeline.py``). The nets whose blocks
+  of queries take a span of the keys have it: ``trinity`` and
+  ``smallthinker`` (``models/shell.py``, the mean over their layers) and
+  ``evabyte`` (its local keys; every block takes all the summaries).
+  [the key is not set]
 * ``decode_rows(pos) -> (read, held)``: the cache rows one ply of sequences
   at counters ``pos`` reads and those their buffers hold; the ``host_block``
   span carries the sums (``ops/fused_pipeline.py``). ``models/ouro.py`` and

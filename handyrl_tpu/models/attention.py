@@ -49,6 +49,25 @@ def key_share(T, windows, query_block):
                for window in windows) / (len(windows) * T)
 
 
+def key_positions(positions, valid):
+    """The keys' positions (T,) for a block that takes a span: a key that is
+    padding stands one past every query, so ``valid`` needs no slice of its
+    own: ONE array a key to slice beside K and V."""
+    return jnp.where(valid, positions, jnp.iinfo(positions.dtype).max)
+
+
+def block_span(k, v, pk, b, bq, n_keys):
+    """What block ``b`` of ``bq`` queries takes of k, v (heads, T, d) and of
+    the keys' positions ``pk`` (T,): the ``n_keys`` (``block_keys``) that
+    end with the block's own last query, from key 0 while fewer lie before
+    it. The arithmetic that this module's loop and ``models/evabyte.py``'s
+    (two key sets under one soft-max) share."""
+    start = jnp.maximum((b + 1) * bq - n_keys, 0)
+    return (jax.lax.dynamic_slice_in_dim(k, start, n_keys, 1),
+            jax.lax.dynamic_slice_in_dim(v, start, n_keys, 1),
+            jax.lax.dynamic_slice_in_dim(pk, start, n_keys, 0))
+
+
 def _sequence_attention(q, k, v, positions, valid, window, query_block):
     """``sequence_attention``, traced where it is called."""
     T, H, d = q.shape
@@ -60,18 +79,13 @@ def _sequence_attention(q, k, v, positions, valid, window, query_block):
     bq = min(query_block, T)
     n_keys = block_keys(T, window, query_block)
     if n_keys < T:
-        # a key that is padding stands one past every query: ONE array a
-        # key to slice beside K and V
-        pk = jnp.where(valid, positions, jnp.iinfo(positions.dtype).max)
+        pk = key_positions(positions, valid)
 
     @jax.checkpoint
     def block(args):
         qb, pq, *b = args                      # (KV, G, bq, d), (bq,), [()]
         if b:
-            start = jnp.maximum((b[0] + 1) * bq - n_keys, 0)
-            kb = jax.lax.dynamic_slice_in_dim(k, start, n_keys, 1)
-            vb = jax.lax.dynamic_slice_in_dim(v, start, n_keys, 1)
-            pb = jax.lax.dynamic_slice_in_dim(pk, start, n_keys, 0)
+            kb, vb, pb = block_span(k, v, pk, b[0], bq, n_keys)
             seen = pb[None, :] <= pq[:, None]
         else:
             kb, vb, pb = k, v, positions

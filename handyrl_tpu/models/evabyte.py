@@ -19,7 +19,14 @@ logits). With absolute position p, chunk c(p) = p // 16, window w(p) = p //
 Two entries over one set of parameters:
 
 * ``sequence(ids, first_position, valid)``: T positions of each sequence in
-  one causal forward (the learner's window, ops/losses.py; the checks);
+  one causal forward (the learner's window, ops/losses.py; the checks). A
+  block of ``query_block`` queries multiplies, of the ``T`` local keys, the
+  ``window + query_block`` that end with its own last query (a key further
+  back lies in an earlier window whatever the first position: 2,304 of
+  4,096 in the cell), masked by position inside them, and all ``T // chunk
+  + 1`` summaries, under the one soft-max (``sequence_attention``; the
+  span's arithmetic is ``models/attention.py``'s ``block_keys`` and
+  ``block_span``, PERF.md, PR 55);
 * ``__call__(id, hidden)``: one position through the cache (rollout, eval,
   the serving engine). ``hidden`` holds, a layer, ONE buffer of K and one of
   V (window + max_positions / chunk, heads * d): the current window's rows,
@@ -75,6 +82,93 @@ def _summarise(k, v, mu, phi, member):
         sv = jnp.einsum('hct,htd->hcd', weight.astype(v.dtype), v,
                         preferred_element_type=f32).astype(v.dtype)
         return sk, sv
+
+
+def _block_attention(q, k, v, sk, sv, positions, valid, chunk_window,
+                     present, window, query_block):
+    """``sequence_attention`` after the summaries, traced where it is called:
+    q, k, v (H, T, d) and the summaries sk, sv (H, C, d) of the chunks in
+    windows ``chunk_window`` (C,), those ``present`` that hold a valid
+    position -> (T, H * d)."""
+    H, T, d = q.shape
+    W = window
+    scale = d ** -0.5
+    bq = min(query_block, T)
+    n_keys = attention.block_keys(T, W, query_block)
+    if n_keys < T:
+        pk = attention.key_positions(positions, valid)
+
+    @jax.checkpoint
+    def block(args):
+        qb, pq, *b = args                          # (H, bq, d), (bq,), [()]
+        if b:
+            kb, vb, pb = attention.block_span(k, v, pk, b[0], bq, n_keys)
+        else:
+            kb, vb, pb = k, v, positions
+        local = ((pq[:, None] // W == pb[None, :] // W)
+                 & (pb[None, :] <= pq[:, None]))
+        if not b:
+            local = local & valid[None, :]
+        remote = ((chunk_window[None, :] < pq[:, None] // W)
+                  & present[None, :])
+        s_local = scale * jnp.einsum('hqd,hkd->hqk', qb, kb,
+                                     preferred_element_type=f32)
+        s_remote = scale * jnp.einsum('hqd,hcd->hqc', qb, sk,
+                                      preferred_element_type=f32)
+        scores = jnp.concatenate(
+            [jnp.where(local[None], s_local, NEG),
+             jnp.where(remote[None], s_remote, NEG)], axis=-1)
+        prob = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return (jnp.einsum('hqk,hkd->hqd', prob[..., :n_keys], vb,
+                           preferred_element_type=f32)
+                + jnp.einsum('hqc,hcd->hqd', prob[..., n_keys:], sv,
+                             preferred_element_type=f32)).astype(v.dtype)
+
+    qs = q.reshape(H, T // bq, bq, d).swapaxes(0, 1)           # (nb, H, bq, d)
+    # only a block that takes a span of the keys needs its index
+    index = (jnp.arange(T // bq),) if n_keys < T else ()
+    out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)) + index)
+    return out.transpose(0, 2, 1, 3).reshape(T, H * d)
+
+
+_shared_block_attention = jax.jit(_block_attention,
+                                  static_argnames=('window', 'query_block'))
+
+
+def sequence_attention(q, k, v, mu, phi, positions, valid, window, chunk,
+                       query_block):
+    """One sequence. q, k, v (T, H, d), mu, phi (H, d) -> (T, H * d): query
+    ``n`` under ONE soft-max over its exact set (the keys of its own window
+    of ``window`` positions up to itself) and the summaries of the chunks in
+    the windows before. ``positions`` must rise by one an index (the net
+    passes ``first_position + arange(T)``), so a key more than ``window - 1``
+    indices before a query is never local to it: a block of queries
+    multiplies only the ``attention.block_keys`` keys that end with its own
+    last query, and inside them the mask is by position, a window's boundary
+    wherever it falls. Every block multiplies all ``T // chunk + 1``
+    summaries. A query that sees nothing (padding past its window's last
+    valid key, with no window before) gets the mean of the values its block
+    was handed: nothing reads it.
+
+    The blocks of a layer that takes a span are one function however many
+    layers a net has: traced, differentiated and lowered ONCE a program, as
+    ``attention.sequence_attention``'s are and for its reason (a block's
+    slices cost the host more to trace than they save the chip otherwise:
+    PERF.md, PR 48). Where the span is every key (a window as long as the
+    sequence) the blocks are traced where they stand."""
+    T, H, d = q.shape
+    q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))          # (H, T, d)
+    n_chunks = T // chunk + 1
+    chunk_ids = positions[0] // chunk + jnp.arange(n_chunks)
+    member = ((positions[None, :] // chunk == chunk_ids[:, None])
+              & valid[None, :])                                   # (C, T)
+    sk, sv = _summarise(k, v, mu, phi, member)
+    chunk_window = chunk_ids * chunk // window
+    present = member.any(axis=1)
+    span = attention.block_keys(T, window, query_block) < T
+    return (_shared_block_attention if span else _block_attention)(
+        q, k, v, sk, sv, positions, valid, chunk_window, present,
+        window=window, query_block=query_block)
 
 
 def eva_spans(pos, window, chunk, n_rows):
@@ -143,48 +237,11 @@ class EvaBlock(nn.Module):
         with jax.named_scope('eva_attention'):
             q, k, v = self._qkv(x, positions)
             k, v = burn_in_as_state(k, v, no_grad_prefix)
-            y = jax.vmap(self._sequence_attention)(q, k, v, positions, valid)
+            y = jax.vmap(lambda q, k, v, positions, valid: sequence_attention(
+                q, k, v, self.mu, self.phi, positions, valid,
+                self.window_size, self.chunk_size, self.query_block))(
+                    q, k, v, positions, valid)
             return dot(y, self.wo, self.dtype, out=f32)
-
-    def _sequence_attention(self, q, k, v, positions, valid):
-        """One sequence. q, k, v (T, H, d) -> (T, H * d)."""
-        T, H, d = q.shape
-        W, chunk = self.window_size, self.chunk_size
-        q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))      # (H, T, d)
-        n_chunks = T // chunk + 1
-        chunk_ids = positions[0] // chunk + jnp.arange(n_chunks)
-        member = ((positions[None, :] // chunk == chunk_ids[:, None])
-                  & valid[None, :])                               # (C, T)
-        sk, sv = _summarise(k, v, self.mu, self.phi, member)
-        chunk_window = chunk_ids * chunk // W
-        present = member.any(axis=1)
-        scale = d ** -0.5
-        bq = min(self.query_block, T)
-        assert T % bq == 0, (T, bq)
-
-        @jax.checkpoint
-        def block(args):
-            qb, pq = args                                  # (H, bq, d), (bq,)
-            local = ((pq[:, None] // W == positions[None, :] // W)
-                     & (positions[None, :] <= pq[:, None]) & valid[None, :])
-            remote = ((chunk_window[None, :] < pq[:, None] // W)
-                      & present[None, :])
-            s_local = scale * jnp.einsum('hqd,hkd->hqk', qb, k,
-                                         preferred_element_type=f32)
-            s_remote = scale * jnp.einsum('hqd,hcd->hqc', qb, sk,
-                                          preferred_element_type=f32)
-            scores = jnp.concatenate(
-                [jnp.where(local[None], s_local, NEG),
-                 jnp.where(remote[None], s_remote, NEG)], axis=-1)
-            prob = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-            return (jnp.einsum('hqk,hkd->hqd', prob[..., :T], v,
-                               preferred_element_type=f32)
-                    + jnp.einsum('hqc,hcd->hqd', prob[..., T:], sv,
-                                 preferred_element_type=f32)).astype(v.dtype)
-
-        qs = q.reshape(H, T // bq, bq, d).swapaxes(0, 1)       # (nb, H, bq, d)
-        out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
-        return out.transpose(0, 2, 1, 3).reshape(T, H * d)
 
     def sequence(self, x, positions, valid, no_grad_prefix=0):
         x = x + self.attention_part(x, positions, valid, no_grad_prefix)
@@ -250,7 +307,12 @@ class EvaByteNet(TrunkNet):
     pred_heads: int = 8
     rope_theta: float = 1e5
     norm_eps: float = 1e-5
-    query_block: int = 512
+    # queries a block of the window's attention: a block takes ``window +
+    # query_block`` local keys, so a smaller one takes fewer (2,304 of 4,096
+    # at 256) in more and smaller products. One layer's attention, forward
+    # and backward at the cell's shapes: 12.9 ms at 512, 9.75 at 256, 13.9
+    # at 128 (PERF.md, PR 55)
+    query_block: int = 256
     dtype: jnp.dtype = jnp.bfloat16
 
     def setup(self):
@@ -286,6 +348,11 @@ class EvaByteNet(TrunkNet):
             eva_spans(pos, self.window_size, self.chunk_size, held),
             self.heads_held * self.head_dim, self.dtype)
         return self.layers * int(read.sum()), self.layers * held * pos.size
+
+    def attention_key_share(self, T):
+        """The local (query, key) pairs a window of ``T`` multiplies, as a
+        share of all ``T x T``: every layer's blocks take one span."""
+        return attention.key_share(T, [self.window_size], self.query_block)
 
     # -- inputs and outputs ----------------------------------------------------
     def _embed(self, ids):

@@ -20,7 +20,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import evabyte as reference          # noqa: E402
 from handyrl_tpu.environment import make_env, make_jax_env    # noqa: E402
+from handyrl_tpu.models import attention, evabyte              # noqa: E402
 from handyrl_tpu.models.evabyte import EvaByteNet             # noqa: E402
+
+f32 = jnp.float32
 
 WIDTHS = dict(hidden_size=64, layers=2, heads_held=2, heads_published=8,
               head_dim=16, mlp_size=96, chunk_size=4, window_size=16,
@@ -100,6 +103,206 @@ def test_the_reference_controls_differ_from_the_model(control):
         assert diff[:16].max() < 1e-5
         diff = diff[16:]
     assert diff.max() > 0.05
+
+
+# -- a block of queries takes its local keys as a span by index (PR 55) --------
+def all_keys_attention(q, k, v, mu, phi, positions, valid, window, chunk,
+                       query_block):
+    """``evabyte.sequence_attention`` before PR 55: every block of queries
+    against all ``T`` keys and all the summaries, most local columns masked
+    away. The summaries are the model's own (``_summarise``, untouched)."""
+    T, H, d = q.shape
+    W = window
+    q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))
+    n_chunks = T // chunk + 1
+    chunk_ids = positions[0] // chunk + jnp.arange(n_chunks)
+    member = ((positions[None, :] // chunk == chunk_ids[:, None])
+              & valid[None, :])
+    sk, sv = evabyte._summarise(k, v, mu, phi, member)
+    chunk_window = chunk_ids * chunk // W
+    present = member.any(axis=1)
+    scale = d ** -0.5
+    bq = min(query_block, T)
+    assert T % bq == 0, (T, bq)
+
+    @jax.checkpoint
+    def block(args):
+        qb, pq = args
+        local = ((pq[:, None] // W == positions[None, :] // W)
+                 & (positions[None, :] <= pq[:, None]) & valid[None, :])
+        remote = ((chunk_window[None, :] < pq[:, None] // W)
+                  & present[None, :])
+        s_local = scale * jnp.einsum('hqd,hkd->hqk', qb, k,
+                                     preferred_element_type=f32)
+        s_remote = scale * jnp.einsum('hqd,hcd->hqc', qb, sk,
+                                      preferred_element_type=f32)
+        scores = jnp.concatenate(
+            [jnp.where(local[None], s_local, evabyte.NEG),
+             jnp.where(remote[None], s_remote, evabyte.NEG)], axis=-1)
+        prob = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return (jnp.einsum('hqk,hkd->hqd', prob[..., :T], v,
+                           preferred_element_type=f32)
+                + jnp.einsum('hqc,hcd->hqd', prob[..., T:], sv,
+                             preferred_element_type=f32)).astype(v.dtype)
+
+    qs = q.reshape(H, T // bq, bq, d).swapaxes(0, 1)
+    out = jax.lax.map(block, (qs, positions.reshape(T // bq, bq)))
+    return out.transpose(0, 2, 1, 3).reshape(T, H * d)
+
+
+def _local(positions, valid, window):
+    """(T, T): the keys of each query's exact set, by position."""
+    return ((positions[:, None] // window == positions[None, :] // window)
+            & (positions[None, :] <= positions[:, None]) & valid[None, :])
+
+
+def _sees(positions, valid, window, chunk):
+    """(T,), (T,): the queries that see a key, and those that see a summary."""
+    chunks = positions[0] // chunk + np.arange(len(positions) // chunk + 1)
+    present = ((positions[None, :] // chunk == chunks[:, None])
+               & valid[None, :]).any(axis=1)
+    remote = ((chunks * chunk // window)[None, :]
+              < positions[:, None] // window) & present[None, :]
+    return _local(positions, valid, window).any(axis=1), remote.any(axis=1)
+
+
+# (T, window, chunk, query block): a span of ``window + bq`` keys shorter
+# than the sequence in all but the last two, where every block takes every
+# key (a window as long as the sequence; a sequence of one block)
+SPAN_SHAPES = [(64, 16, 4, 8), (96, 32, 8, 16), (48, 8, 4, 8), (64, 8, 4, 16),
+               (40, 16, 4, 8), (16, 16, 4, 8), (8, 4, 2, 512)]
+# first positions that put a window's boundary inside a block of queries, at
+# a block's edge, and (where the sequence is no longer than a window)
+# nowhere in the sequence; a whole window and one with a padded tail
+SPAN_CASES = [((T, W, chunk, bq), first, tail)
+              for T, W, chunk, bq in SPAN_SHAPES
+              for first in (W - 3, 5 * W + 1,           # inside a block
+                            0, 3 * W + bq % W)          # at a block's edge
+              for tail in (0, T // 4 + 1)]
+SPAN_CASES += [((16, 16, 4, 8), 32, 0), ((16, 16, 4, 8), 16, 7),
+               ((16, 32, 4, 8), 37, 0), ((16, 32, 4, 8), 5, 6)]   # nowhere
+
+
+@pytest.mark.parametrize(
+    'shape,first,tail', SPAN_CASES,
+    ids=lambda x: 'x'.join(map(str, x)) if isinstance(x, tuple) else str(x))
+def test_a_blocks_span_of_keys_is_the_all_keys_block(shape, first, tail):
+    """Value and gradient (q, k, v, mu, phi) of ``sequence_attention``, whose
+    blocks take ``window + query_block`` local keys by index, against every
+    block over all ``T`` keys: a column sliced away held ``NEG``, whose
+    ``exp`` is exactly 0, so the two differ by summation order alone."""
+    T, window, chunk, bq = shape
+    H, d = 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(T + first + tail), 6)
+    q, k, v = (jax.random.normal(ks[i], (T, H, d), f32) for i in range(3))
+    mu, phi = (jax.random.normal(ks[3 + i], (H, d), f32) for i in range(2))
+    positions = first + jnp.arange(T)
+    valid = jnp.arange(T) < T - tail
+    # a query that sees nothing (padding in a window with no valid key and
+    # none before it) averages what its block was handed, here as there;
+    # nothing reads it, so it carries no cotangent. Every valid one sees
+    # itself
+    near, far = _sees(np.asarray(positions), np.asarray(valid), window, chunk)
+    sees = near | far
+    assert near[:T - tail].all()
+    cot = jax.random.normal(ks[5], (T, H * d), f32) * sees[:, None]
+
+    def run(f):
+        out = lambda *a: f(*a, positions, valid, window, chunk, bq)
+        return jax.jit(out)(q, k, v, mu, phi), jax.jit(jax.grad(
+            lambda *a: (out(*a) * cot).sum(), (0, 1, 2, 3, 4)))(
+                q, k, v, mu, phi)
+    got, grads = run(evabyte.sequence_attention)
+    want, wants = run(all_keys_attention)
+    np.testing.assert_allclose(got[sees], want[sees], rtol=2e-5, atol=2e-5)
+    # mu shifts every summary's score alike: it moves only a query that
+    # sees keys AND summaries under its one soft-max
+    moved = [True] * 3 + [bool((near & far).any()), bool(far.any())]
+    for g, w, some in zip(grads, wants, moved):
+        assert (float(jnp.abs(w).max()) > 1e-3) == some
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize('first', [0, 1, 511, 1000, 1536, 2047, 6000])
+@pytest.mark.parametrize('T,window,bq', [
+    (4096, 2048, 256), (4096, 2048, 512), (64, 16, 8), (48, 8, 16),
+    (16, 16, 8), (16, 4, 512)])
+def test_no_local_key_lies_outside_a_blocks_span_and_the_share_counts_it(
+        T, window, bq, first):
+    """By brute force: every (query, key) pair the mask ``local`` lets
+    through lies in the columns its block is handed, wherever the window's
+    boundaries fall, and ``attention_key_share`` is the share handed."""
+    bq_ = min(bq, T)
+    n_keys = attention.block_keys(T, window, bq)
+    handed = np.zeros((T, T), bool)
+    for b in range(T // bq_):
+        start = max((b + 1) * bq_ - n_keys, 0)
+        handed[b * bq_:(b + 1) * bq_, start:start + n_keys] = True
+    local = _local(first + np.arange(T), np.ones(T, bool), window)
+    assert local.any() and not (local & ~handed).any()
+    net = EvaByteNet(**dict(WIDTHS, window_size=window, query_block=bq))
+    assert net.attention_key_share(T) == handed.mean() == n_keys / T
+
+
+def test_the_cells_share_of_the_local_columns():
+    """What the learner's gauge ``attention_key_share`` reads in the cell:
+    2,304 of a window's 4,096 local columns a block of 256 queries; 1.0
+    where the span is the whole sequence."""
+    assert EvaByteNet().attention_key_share(4096) == 2304 / 4096 == 0.5625
+    assert EvaByteNet().attention_key_share(2048) == 1.0
+    assert EvaByteNet(query_block=512).attention_key_share(4096) == 0.625
+
+
+def test_the_cells_window_multiplies_a_span_in_one_loop_traced_once():
+    """From the jaxpr of the net's ``sequence`` at the cell's sizes
+    (abstract: nothing is allocated or run): every product inside a block
+    loop takes 2,304 local keys or the 257 summaries, none all 4,096 (the
+    summaries' own products, outside the loops, contract over a chunk's
+    membership of all ``T`` as they did); and the four layers call ONE
+    traced function of the loop."""
+    net, T = EvaByteNet(), 4096
+    shape = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: net.init(
+        jax.random.PRNGKey(0), jnp.zeros((1,), jnp.int32),
+        net.init_hidden((1,))))
+    jaxpr = jax.make_jaxpr(
+        lambda p, i, f, m: net.apply(p, i, f, m, method=net.sequence))(
+            params, shape((2, T), jnp.int32), shape((2,), jnp.int32),
+            shape((2, T), jnp.bool_))
+    extents, loops, bodies = [], [], []
+
+    def walk(jaxpr, in_loop):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name == 'dot_general' and in_loop:
+                extents.append(eqn.invars[1].aval.shape[-2])
+            if eqn.params.get('name') == '_block_attention':
+                loops.append(id(eqn.params['jaxpr']))
+            if name == 'scan':
+                bodies.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, in_loop or name == 'scan')
+    walk(jaxpr.jaxpr, False)
+    assert attention.block_keys(T, net.window_size, net.query_block) == 2304
+    assert sorted(set(extents)) == [257, 2304], extents
+    assert len(extents) == 4 * net.layers and len(bodies) == net.layers
+    assert len(loops) == net.layers and len(set(loops)) == 1
+
+
+@pytest.mark.parametrize('T,window,chunk,bq', [
+    (16, 16, 4, 8), (64, 56, 8, 8), (8, 4, 2, 512)])
+def test_blocks_that_take_every_key_run_the_all_keys_program(T, window,
+                                                             chunk, bq):
+    """No slice, no index, the mask as it was: where ``window + bq`` reaches
+    the sequence the text the gradient lowers to is the all-keys
+    function's to the character."""
+    shape = jax.ShapeDtypeStruct
+    args = ([shape((T, 2, 8), jnp.bfloat16)] * 3 + [shape((2, 8), f32)] * 2
+            + [shape((T,), jnp.int32), shape((T,), jnp.bool_)])
+    text = lambda f: jax.jit(jax.grad(lambda *a: f(
+        *a, window, chunk, bq).astype(f32).sum(), (0, 1, 2, 3, 4))).lower(
+            *args).as_text()
+    assert text(evabyte.sequence_attention) == text(all_keys_attention)
 
 
 def test_step_through_three_windows_matches_sequence():

@@ -113,15 +113,21 @@ TRUNKS = {
                     head_dim=16, mlp_size=96, vocab=72, passes=4,
                     max_positions=48, query_block=8, param_scale=4.0),
 }
-# name -> (parameters, every trunk has it)
+# name -> (parameters, the trunks that have it, as models/__init__.py lists
+# them)
+EVERY = frozenset(TRUNKS)
+EXPERT = frozenset({'TrinityNet', 'SmallThinkerNet'})
 PROTOCOL = {
-    'init_hidden': (['batch_shape'], True),
-    'reset_hidden': (['hidden', 'done'], True),
-    'sequence': (['ids', 'first_position', 'valid', 'no_grad_prefix'], True),
-    'policy_logits': (['features'], False),
-    'post_update': (['before', 'after', 'aux'], False),
-    'attention_key_share': (['T'], False),
-    'epoch_dynamics': (['sums'], False),
+    'init_hidden': (['batch_shape'], EVERY),
+    'reset_hidden': (['hidden', 'done'], EVERY),
+    'sequence': (['ids', 'first_position', 'valid', 'no_grad_prefix'], EVERY),
+    'policy_logits': (['features'], EXPERT | {'OuroNet'}),
+    'post_update': (['before', 'after', 'aux'], EXPERT),
+    # a net whose blocks of queries take a span of the keys: the expert
+    # trunks' window layers, every layer of ``evabyte``; ``ouro``'s layers
+    # see everything
+    'attention_key_share': (['T'], EXPERT | {'EvaByteNet'}),
+    'epoch_dynamics': (['sums'], EXPERT | {'OuroNet'}),
 }
 PLIES = 40
 
@@ -146,8 +152,8 @@ def _trunk_ids(seed, n=3):
 @pytest.mark.parametrize('name', sorted(TRUNKS))
 def test_a_trunk_defines_the_protocol_as_documented(name):
     net, _variables = _trunk(name)
-    for method, (parameters, required) in PROTOCOL.items():
-        assert hasattr(net, method) or not required, method
+    for method, (parameters, trunks) in PROTOCOL.items():
+        assert hasattr(net, method) == (name in trunks), method
         if hasattr(net, method):
             got = list(inspect.signature(getattr(net, method)).parameters)
             assert got == parameters, (method, got)
