@@ -37,9 +37,12 @@ tests/test_models.py holds the four to these signatures.
   [the key is not set]
 * ``decode_rows(pos) -> (read, held)``: the cache rows one ply of sequences
   at counters ``pos`` reads and those their buffers hold; the ``host_block``
-  span carries the sums (``ops/fused_pipeline.py``). ``models/ouro.py`` and
-  ``models/evabyte.py`` have it, each from the ``attention.Span``s its step
-  hands the decode attention. [the counters are not set]
+  span carries the sums (``ops/fused_pipeline.py``). The four trunks have
+  it, each from the ``attention.Span``s its step hands the decode
+  attention: ``models/ouro.py`` (a pass's span), ``models/evabyte.py`` (its
+  window's rows and its summaries), ``trinity`` and ``smallthinker``
+  (``models/shell.py``: the one span of each layer's circle or buffer).
+  [the counters are not set]
 * ``epoch_dynamics(sums) -> dict``: record keys of the net's own from the
   epoch's ``diag_*`` sums (``train.py`` ``_epoch_dynamics``).
 * ``actor_param_dtype``: what the actor's copy of the parameters is cast to

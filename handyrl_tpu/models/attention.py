@@ -6,20 +6,23 @@ or the ``window`` keys up to and with the query's own. What comes before
 the scopes these run under (``models/trinity.py``, ``models/smallthinker.py``).
 A net that runs its layers several times (``models/ouro.py``) keeps K and V
 of every (pass, layer): ``init_pass_cache``, ``pass_write``, ``pass_rows``.
-``cache_attention`` is the ONE decode attention: grouped, or for a layer
-WITHOUT grouping the heads' queries side by side (``span_attention``), by
-the shapes it is handed. What a side-by-side query sees is a short list of
-``Span``s of a layer's buffers under one soft-max: one for a looped net's
-pass, two for ``models/evabyte.py``'s step (its window's rows, then the
-summaries), which calls ``span_attention`` itself.
+``cache_attention`` is the ONE decode attention. What a decode query sees is
+a short list of ``Span``s of a layer's buffers under one soft-max: ONE for a
+plain cache, a circle or a looped net's pass, two for ``models/evabyte.py``'s
+step (its window's rows, then the summaries), which calls ``span_attention``
+itself.
 
-Which form runs where: the grouped form, and a side-by-side layer that hands
-no span (a plain cache, a circle), read every row of a buffer and mask those
-past a counter afterwards (``rows_seen``), on every backend. Over spans the
-side-by-side form does so on the CPU alone (``spans_seen``: the tests, and
-what they hold the kernel to): where the program runs on a TPU it is the
-block kernel of ``models/decode_kernel.py``, which walks the row blocks
-each span's count has reached, so rows past a count are not read on the chip.
+Which form runs where: where the program runs on a TPU and the spans lie in
+whole blocks of whole lanes, the block kernel of ``models/decode_kernel.py``
+walks the row blocks each span's count has reached, from the buffers as they
+lie in HBM, with the ``H / KV`` query heads of a group as the rows of its
+matrix (one head a KV head is the group of one), so rows past a count are
+not read on the chip and no buffer is fetched whole into fast memory.
+Elsewhere (the CPU's tests, shapes the walk does not take) the all-rows
+products read every row and mask those past a counter afterwards, the forms
+the tests hold the kernel to: ``grouped_cache_attention`` under
+``rows_seen`` with groups, the heads' queries side by side
+(``seen_attention``) under ``spans_seen`` without.
 """
 
 from typing import Any, NamedTuple
@@ -201,7 +204,8 @@ def spans_seen(n_rows, spans):
 
 def _block_kernel(spans, width, dtype):
     """``models/decode_kernel.py`` where its walk takes ``spans`` of rows of
-    ``width``: on a TPU, in whole blocks of whole lanes; None elsewhere.
+    ``width`` (the KV heads side by side): on a TPU, in whole blocks of
+    whole lanes; None elsewhere.
     Imported here, so that only a program that runs the kernel pays for
     importing Pallas (0.85 s)."""
     if not _on_tpu():
@@ -243,9 +247,10 @@ def seen_attention(q, ck, cv, seen, dtype):
 
 
 def spans_rows_read(spans, width, dtype):
-    """How many rows ``span_attention`` reads for sequences whose ``spans``
-    hold numpy counts (any shape): the kernel's whole blocks of each span up
-    to its count's own, every row of every span where the products run."""
+    """How many rows the decode attention reads for sequences whose ``spans``
+    of rows of ``width`` hold numpy counts (any shape): the kernel's whole
+    blocks of each span up to its count's own, every row of every span
+    where the products run."""
     kernel = _block_kernel(spans, width, dtype)
     if kernel:
         return kernel.rows_read(spans, width, dtype)
@@ -258,27 +263,31 @@ def cache_attention(q, ck, cv, pos, circle, kv_heads, dtype, t=None,
     H, d) at each sequence's own position ``pos`` (B,) over the rows written
     so far of ck, cv (B, rows, kv_heads * d) -> (B, H * d); rows not
     ``rows_seen`` are masked (keys are stored already turned, so a row needs
-    no position). With one query head a KV head the grouped form is the
-    slow one (``heads_side_by_side``), so the shapes say which to take:
-    without groups the side-by-side products over the rows, read once as
-    they lie, with no relayout.
+    no position). A net that runs its layers several times hands over a
+    layer's WHOLE buffers (B, passes * rows, ...) with the pass ``t`` and
+    its ``rows``.
 
-    A net that runs its layers several times hands over a layer's WHOLE
-    buffers (B, passes * rows, ...) with the pass ``t`` and its ``rows``:
-    side by side that is ``span_attention`` over ``pass_span``, which the
-    block kernel walks from the buffers as they lie; a grouped layer takes
-    ``pass_rows`` of both. A layer that hands no pass (a plain cache, a
-    circle) keeps the all-rows products under ``rows_seen``."""
-    H = q.shape[1]
-    if H != kv_heads:
-        if t is not None:
-            ck, cv = pass_rows(ck, t, rows), pass_rows(cv, t, rows)
-        return grouped_cache_attention(q, ck, cv, pos, circle, kv_heads,
-                                       dtype)
+    Every layer hands ONE span of its buffers as they lie: ``pass_span``,
+    for a plain cache or a circle the one of a lone pass (a circle that has
+    gone round has reached all its rows, before that rows ``0..pos``, as
+    ``rows_seen`` says). On a TPU, at shapes its walk takes, the block
+    kernel reads it: a group's query heads as the rows of one matrix
+    against their KV head's rows, only the row blocks the counter has
+    reached. Elsewhere the shapes say which product to take: with one query
+    head a KV head the grouped form is the slow one (``heads_side_by_side``),
+    so without groups the side-by-side products over the rows, read once as
+    they lie, with no relayout; with groups ``grouped_cache_attention``
+    over ``pass_rows``, the form the kernel is held to."""
+    span = (pass_span(pos, 0, ck.shape[1]) if t is None
+            else pass_span(pos, t, rows))
+    if q.shape[1] == kv_heads:
+        return span_attention(q, ck, cv, [span], dtype)
+    kernel = _block_kernel([span], ck.shape[2], ck.dtype)
+    if kernel:
+        return kernel.span_attention(q, ck, cv, [span], dtype)
     if t is not None:
-        return span_attention(q, ck, cv, [pass_span(pos, t, rows)], dtype)
-    return seen_attention(q, ck, cv, rows_seen(ck.shape[1], pos, circle),
-                          dtype)
+        ck, cv = pass_rows(ck, t, rows), pass_rows(cv, t, rows)
+    return grouped_cache_attention(q, ck, cv, pos, circle, kv_heads, dtype)
 
 
 def grouped_cache_attention(q, ck, cv, pos, circle, kv_heads, dtype):

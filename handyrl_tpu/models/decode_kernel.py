@@ -20,10 +20,17 @@ span's first, or the next sequence's first) is on its way, so no copy waits
 at a span's or a sequence's end. The soft-max is the online one (a running
 maximum, sum and accumulator in float32). Rows past a count exist in a
 span's LAST block only: there the scores go to ``NEG`` AND the V rows to
-zero, since a row never written may hold anything. The heads' queries stand
-side by side (``attention.heads_side_by_side``: row h holds head h's query at
-head h's columns), so a block's scores are ONE matrix product on the rows as
-they lie.
+zero, since a row never written may hold anything. The queries are the rows
+of ONE matrix as wide as a buffer's row, head ``n`` of ``H / KV`` a KV head
+at the columns of KV head ``n // (H / KV)`` and zeros at the others', so a
+block's scores are ONE matrix product on the rows as they lie: without
+groups the heads side by side (``attention.heads_side_by_side``), with the
+expert nets' ONE held KV head simply their 8 or 7 query heads (PR 58).
+
+The buffers are HELD to HBM as the call's operands (``_in_hbm``): left to
+the compiler, a buffer that fits fast memory (the expert nets' 16.8 MB
+circles) may be fetched WHOLE into it for the call that reads it or the
+scatter that writes it, and copied back, every ply (PERF.md, PR 58).
 """
 
 import functools
@@ -36,7 +43,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .trunk import NEG, f32
 
-# bytes of K (as many of V) a copy: chosen on the chip (PERF.md, PRs 53, 54)
+# bytes of K (as many of V) a copy: chosen on the chip (PERF.md, PRs 53, 54,
+# 58: 512 rows of 512 in ``ouro``, 256 of 1,024 in ``evabyte``, 2,048 of 128
+# in the expert nets)
 COPY_BYTES = 512 * 1024
 
 
@@ -94,13 +103,16 @@ def _attend(wide, k, v, state, scale, left=None):
 
 
 def _kernel(first_ref, count_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
-            *, heads, block, spans):
-    B, _, W = q_ref.shape
-    d = W // heads
-    m = -(-heads // 8) * 8
+            *, kv_heads, block, spans):
+    B, G, W = q_ref.shape
+    d = W // kv_heads
+    m = -(-kv_heads * G // 8) * 8
     scale = d ** -0.5
-    own = (jax.lax.broadcasted_iota(jnp.int32, (m, W), 1) // d
-           == jax.lax.broadcasted_iota(jnp.int32, (m, W), 0))
+    row = jax.lax.broadcasted_iota(jnp.int32, (m, W), 0)
+    own = jax.lax.broadcasted_iota(jnp.int32, (m, W), 1) // d == row // G
+    # the rows of each group's g-th head: one a KV head, all of ``own``
+    # where a group is one head
+    gth = [own & (row % G == g) for g in range(G)] if G > 1 else [own]
 
     def copies(b, s, j, slot):
         r = pl.multiple_of(first_ref[s] + j * block, block)
@@ -130,7 +142,10 @@ def _kernel(first_ref, count_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
             functools.partial(start, b + 1, 0, 0, slot))
 
     def sequence(b, step):
-        wide = jnp.where(own, q_ref[b].astype(f32), 0.0).astype(q_ref.dtype)
+        q, wide = q_ref[b].astype(f32), 0.0
+        for g, rows in enumerate(gth):
+            wide = jnp.where(rows, q[g:g + 1], wide)
+        wide = wide.astype(q_ref.dtype)
 
         def span(s, count, step, state):
             last = (count - 1) // block
@@ -159,9 +174,11 @@ def _kernel(first_ref, count_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
                 count > 0, functools.partial(span, s, count),
                 lambda step, state: (step, state), step, state)
         _, l, acc = state
-        # head h's values are block h of row h; the rest is dropped
-        o_ref[b] = jnp.where(own, acc / l, 0.0).sum(
-            axis=0, keepdims=True).astype(o_ref.dtype)
+        # a head's values are its KV head's block of its row; the rest is
+        # dropped
+        for g, rows in enumerate(gth):
+            o_ref[b, g:g + 1] = jnp.where(rows, acc / l, 0.0).sum(
+                axis=0, keepdims=True).astype(o_ref.dtype)
         return step
 
     start(0, 0, 0, 0)
@@ -169,23 +186,34 @@ def _kernel(first_ref, count_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
 
 
 def span_attention(q, ck, cv, spans, dtype, block=None):
-    """q (B, H, d), one query head a KV head, over each sequence's ``spans``
-    of ck, cv (B, rows, H * d), the whole buffers -> (B, H * d) in ``dtype``.
-    A span is ``(first, count, extent)``: the first ``count`` (B,) rows from
-    row ``first`` (a scalar, traced or not) on, of at most ``extent``; first
-    and extent must be multiples of ``block`` (``block_rows`` unless a test
-    or a measurement says otherwise). Off the TPU the kernel is interpreted
-    (the tests)."""
+    """q (B, H, d), ``H / KV`` query heads a KV head (head ``n`` reads KV head
+    ``n // (H / KV)``), over each sequence's ``spans`` of ck, cv (B, rows,
+    KV * d), the whole buffers -> (B, H * d) in ``dtype``. A span is
+    ``(first, count, extent)``: the first ``count`` (B,) rows from row
+    ``first`` (a scalar, traced or not) on, of at most ``extent``; first and
+    extent must be multiples of ``block`` (``block_rows`` unless a test or a
+    measurement says otherwise). Off the TPU the kernel is interpreted (the
+    tests).
+
+    The kernel is handed the g-th head of every group side by side, (B, G,
+    KV * d), and hands its values back so; its wide query matrix has a row a
+    head, row ``kv * G + g`` head ``kv * G + g``'s query at KV head ``kv``'s
+    columns and zeros at the others' (``attention.heads_side_by_side`` is
+    the case G = 1; with ONE KV head the heads are simply the matrix's
+    rows)."""
     B, H, d = q.shape
-    W = H * d
+    W = ck.shape[2]
+    KV = W // d
+    G = H // KV
     block = block or block_rows(W, ck.dtype)
-    assert ck.shape[2] == W and all(
+    assert KV * G == H and KV * d == W and all(
         extent % block == 0 for _, _, extent in spans), (spans, block, ck.shape)
     first = jnp.stack([jnp.asarray(first, jnp.int32) for first, _, _ in spans])
     count = jnp.concatenate(
         [count.astype(jnp.int32) for count in _reached(spans, jnp.clip)])
     out = pl.pallas_call(
-        functools.partial(_kernel, heads=H, block=block, spans=len(spans)),
+        functools.partial(_kernel, kv_heads=KV, block=block,
+                          spans=len(spans)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(1,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -195,8 +223,27 @@ def span_attention(q, ck, cv, spans, dtype, block=None):
             scratch_shapes=[pltpu.VMEM((2, block, W), ck.dtype),
                             pltpu.VMEM((2, block, W), cv.dtype),
                             pltpu.SemaphoreType.DMA((2, 2))]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, W), dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, W), dtype),
         interpret=jax.default_backend() != 'tpu',
         name='span_attention',
-    )(first, count, q.reshape(B, 1, W), ck, cv)
-    return out.reshape(B, W)
+    )(first, count, _groups_side_by_side(q, KV), _in_hbm(ck), _in_hbm(cv))
+    return jnp.swapaxes(out.reshape(B, G, KV, d), 1, 2).reshape(B, H * d)
+
+
+def _in_hbm(buffer):
+    """``buffer`` as a kernel's operand that stays where it lies: without
+    the constraint the compiler may fetch a buffer that fits fast memory
+    WHOLE into it for the call, and copy it back after (PERF.md, PR 58)."""
+    if jax.default_backend() != 'tpu' or not isinstance(buffer,
+                                                        jax.core.Tracer):
+        # interpreted, or run eagerly (a net's ``init``): the call is a
+        # program of its own, with nothing around it to fetch ahead for
+        return buffer
+    return pltpu.with_memory_space_constraint(buffer, pltpu.HBM)
+
+
+def _groups_side_by_side(q, kv_heads):
+    """q (B, H, d) -> (B, G, KV * d): row g holds every group's g-th head."""
+    B, H, d = q.shape
+    return jnp.swapaxes(q.reshape(B, kv_heads, H // kv_heads, d), 1,
+                        2).reshape(B, H // kv_heads, kv_heads * d)

@@ -265,15 +265,11 @@ class OuroNet(ScaledTrunkNet):
         """(read, held): the rows of K (as many of V) that ONE ply of
         sequences at counters ``pos`` (numpy) reads, and those that their
         buffers hold, over every (pass, layer)."""
-        pos = np.asarray(pos)
-        if self.heads_held == self.kv_heads_held:
-            read = attention.spans_rows_read(
-                [attention.pass_span(pos, 0, self.max_positions)],
-                self.heads_held * self.head_dim, self.dtype)
-        else:       # the grouped form reads every row
-            read = np.full_like(pos, self.max_positions)
+        read = attention.spans_rows_read(
+            [attention.pass_span(np.asarray(pos), 0, self.max_positions)],
+            self.kv_heads_held * self.head_dim, self.dtype)
         each = self.passes * self.layers
-        return each * int(read.sum()), each * self.max_positions * pos.size
+        return each * int(read.sum()), each * self.max_positions * read.size
 
     def attention_part(self, layer: int, x, positions, valid):
         return _attention_part(self.spec, self.blocks[layer].weights(), x,

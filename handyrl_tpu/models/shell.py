@@ -10,6 +10,7 @@ Each class names what it expects of its subclass beside ``dtype`` and the
 """
 
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from . import attention, experts
@@ -101,12 +102,29 @@ class ExpertTrunkNet(ScaledTrunkNet):
         return [self.window_size if kind == self.windowed_kind else None
                 for kind in self.layer_types]
 
+    @property
+    def cache_rows(self):
+        """A layer's rows: a circle of ``window_size``, or the longest
+        game's."""
+        return [self.max_positions if window is None else window
+                for window in self.windows]
+
     def init_hidden(self, batch_shape=()):
-        """A circle of ``window_size`` rows, or the longest game's."""
         return attention.init_cache(
-            batch_shape, [self.max_positions if window is None else window
-                          for window in self.windows],
+            batch_shape, self.cache_rows,
             self.kv_heads_held * self.head_dim, self.dtype)
+
+    def decode_rows(self, pos):
+        """(read, held): the rows of K (as many of V) that ONE ply of
+        sequences at counters ``pos`` (numpy) reads, and those that their
+        buffers hold, over every layer: each layer hands the decode
+        attention the ONE span of its circle or buffer."""
+        pos = np.asarray(pos)
+        read = sum(attention.spans_rows_read(
+            [attention.pass_span(pos, 0, rows)],
+            self.kv_heads_held * self.head_dim, self.dtype).sum()
+            for rows in self.cache_rows)
+        return int(read), sum(self.cache_rows) * pos.size
 
     def _layers(self, ids, first_position, valid, no_grad_prefix):
         """The window through every layer: the features (B, T, D) in

@@ -46,7 +46,9 @@ Two entries over one set of parameters, as ``models/trinity.py``:
 ``sequence(ids, first_position, valid)`` and ``__call__(id, hidden)``;
 ``hidden`` holds K and V of two lengths side by side: a circle of
 ``window_size`` rows on a window layer (keys stored already turned),
-``max_positions`` rows on a global one, and ONE counter a sequence.
+``max_positions`` rows on a global one, and ONE counter a sequence. On a TPU
+a ply reads only the row blocks a counter has reached
+(``models/decode_kernel.py``).
 """
 
 from __future__ import annotations

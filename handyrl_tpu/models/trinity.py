@@ -57,7 +57,9 @@ block of positions at a time, ops/losses.py) and ``__call__(id, hidden)``
 side by side: a circle of ``window_size`` rows on a sliding layer (keys are
 stored already turned, so a row needs no position), ``max_positions`` rows
 on a full one, (sequence, row, KV heads x d), and ONE counter a sequence.
-Nothing is cleared: what the counter has not reached is masked.
+Nothing is cleared: what the counter has not reached is masked, or, on a
+TPU, not read (``models/decode_kernel.py``: the 8 query heads of the held
+KV head are the rows of the block kernel's matrix).
 """
 
 from __future__ import annotations

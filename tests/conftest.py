@@ -81,6 +81,22 @@ def _retrace_sentinel_ends_with_its_test():
     telemetry.configure_perf_plane(True, 'warn')
 
 
+@pytest.fixture
+def kernel_form(monkeypatch):
+    """``cache_attention`` as it chooses on a TPU, the kernel interpreted
+    here, in blocks of 16 rows (three a pass of tests/test_ouro.py's nets,
+    one a circle and four a full layer of the expert nets'); the count of
+    the kernel's calls while a program is traced."""
+    from handyrl_tpu.models import attention, decode_kernel
+    calls = []
+    real = decode_kernel.span_attention
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
+    monkeypatch.setattr(decode_kernel, 'span_attention',
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    return calls
+
+
 @pytest.hookimpl(wrapper=True)
 def pytest_runtest_call(item):
     mark = item.get_closest_marker('timeout')

@@ -4,6 +4,7 @@ every block against EVERY key gives (the function as it stood before, kept
 here as the plain reference), value and gradient, and the program it lowers
 to stays near that one's size (the set-up seconds of every run, PR 47)."""
 
+import contextlib
 import itertools
 import re
 
@@ -480,17 +481,19 @@ def test_a_counter_past_the_buffer_is_held_to_its_last_row():
 @pytest.mark.parametrize('on_tpu,heads,kv_heads,rows,kernel', [
     (True, 2, 2, 64, True),       # whole blocks of whole lanes, on a TPU
     (False, 2, 2, 64, False),     # the CPU: the all-rows products
-    (True, 4, 2, 64, False),      # a grouped layer
+    (True, 4, 2, 64, True),       # a grouped layer: the same walk
+    (False, 4, 2, 64, False),     # a grouped layer on the CPU: its products
     (True, 2, 2, 72, False),      # rows that are no whole blocks
+    (True, 4, 2, 72, False),      # the same, grouped
 ])
 def test_the_kernel_is_chosen_from_the_backend_and_the_shapes(
         monkeypatch, on_tpu, heads, kv_heads, rows, kernel):
     """``cache_attention`` over a looped net's whole buffers takes the block
     kernel where the program runs on a TPU and the shapes are the kernel's,
-    and ``pass_rows`` with the all-rows products elsewhere: read from the
-    lowered text (a kernel interpreted here lowers to a loop with no product
-    over a pass's rows). ``spans_rows_read`` counts the pass's span by the
-    same choice (a grouped layer hands no span: ``OuroNet.decode_rows``)."""
+    with groups or without, and ``pass_rows`` with the all-rows products
+    elsewhere: read from the lowered text (a kernel interpreted here lowers
+    to a loop with no product over a pass's rows). ``spans_rows_read``
+    counts the pass's span by the same choice, from the buffers' width."""
     monkeypatch.setattr(attention, '_on_tpu', lambda: on_tpu)
     monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
     shape = jax.ShapeDtypeStruct
@@ -501,27 +504,104 @@ def test_the_kernel_is_chosen_from_the_backend_and_the_shapes(
         shape((), jnp.int32)).as_text()
     sliced = 'tensor<2x%dx%dxf32>' % (rows, kv_heads * 64) in text
     assert sliced != kernel
-    if heads == kv_heads:
-        span = attention.pass_span(np.asarray([0, 15, 16, rows - 1]), 0, rows)
-        read = attention.spans_rows_read([span], heads * 64, f32)
-        assert read.tolist() == ([16, 16, 32, 64] if kernel else [rows] * 4)
+    span = attention.pass_span(np.asarray([0, 15, 16, rows - 1]), 0, rows)
+    read = attention.spans_rows_read([span], kv_heads * 64, f32)
+    assert read.tolist() == ([16, 16, 32, 64] if kernel else [rows] * 4)
 
 
 @pytest.mark.parametrize('circle', [False, True], ids=['buffer', 'circle'])
-def test_a_layer_that_hands_no_span_keeps_the_products_on_a_tpu(
+def test_a_plain_cache_or_a_circle_hands_one_span_and_takes_the_kernel_on_a_tpu(
         monkeypatch, circle):
-    """The kernel walks the spans it is handed: a side-by-side layer with a
-    plain cache or a circle hands none, and keeps the all-rows products under
-    ``rows_seen`` on a TPU too, at shapes the kernel would take."""
-    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
-    monkeypatch.setattr(decode_kernel, 'takes', lambda *_: 1 / 0)
+    """A side-by-side layer with a plain cache or a circle hands the ONE span
+    of its buffer: on a TPU, at shapes the walk takes, the kernel reads it
+    (the rows past a counter hold NaN and 1e30 here, which the all-rows
+    products would not survive); a circle that has gone round has reached
+    all its rows. On the CPU it keeps the products."""
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(keys[0], (3, 2, 64), f32)
     ck, cv = (jax.random.normal(key, (3, 64, 128), f32) for key in keys[1:])
     pos = jnp.asarray([0, 63, 150] if circle else [0, 17, 63])
-    got = attention.cache_attention(q, ck, cv, pos, circle, 2, f32)
     want = attention.grouped_cache_attention(q, ck, cv, pos, circle, 2, f32)
+    products = attention.cache_attention(q, ck, cv, pos, circle, 2, f32)
+    np.testing.assert_allclose(products, want, atol=1e-5)
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    past = ~attention.rows_seen(64, pos, circle)[:, :, None]
+    got = attention.cache_attention(q, jnp.where(past, jnp.nan, ck),
+                                    jnp.where(past, 1e30, cv), pos, circle, 2,
+                                    f32)
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# -- a group's query heads as the rows of the kernel's matrix (PR 58) -----------
+GROUPS = [(8, 1), (7, 1), (4, 2)]        # (query heads, KV heads)
+GROUP_ROWS = 64
+
+
+def _group_counters(cache, block):
+    """One call's counters: a block's first and last row, the buffer's, two
+    in between; on a circle that has gone round, counters past its rows
+    beside ones that are not."""
+    before = [0, block - 1, block, GROUP_ROWS - 1, 5, block + 3]
+    return jnp.asarray({
+        'buffer': before, 'circle_before_it_has_gone_round': before,
+        'circle_gone_round': [GROUP_ROWS, GROUP_ROWS + block + 3,
+                              3 * GROUP_ROWS - 1, 7 * GROUP_ROWS, 5,
+                              GROUP_ROWS - 1]}[cache])
+
+
+def _group_buffers(heads, kv_heads, pos, dtype=f32, head_dim=64):
+    keys = jax.random.split(jax.random.PRNGKey(heads), 3)
+    B = pos.shape[0]
+    return (jax.random.normal(keys[0], (B, heads, head_dim), dtype),
+            jax.random.normal(keys[1], (B, GROUP_ROWS, kv_heads * head_dim),
+                              dtype),
+            jax.random.normal(keys[2], (B, GROUP_ROWS, kv_heads * head_dim),
+                              dtype))
+
+
+@pytest.mark.parametrize('cache', ['buffer',
+                                   'circle_before_it_has_gone_round',
+                                   'circle_gone_round'])
+@pytest.mark.parametrize('heads,kv_heads', GROUPS)
+@pytest.mark.parametrize('block', [16, 32])
+def test_the_grouped_walk_is_the_grouped_product_and_reads_nothing_past_a_counter(
+        monkeypatch, block, heads, kv_heads, cache):
+    """``cache_attention`` as it chooses on a TPU (the kernel interpreted
+    here) with ``H / KV`` query heads a KV head, the expert cells' 8 and 7 on
+    ONE and 2 on each of two, against ``grouped_cache_attention``: the rows
+    past each counter hold NaN (K) and 1e30 (V) in what the kernel is
+    handed and zeros in what the products are; a circle that has gone round
+    has reached every row. Tolerance as the one-span case's."""
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: block)
+    circle = cache != 'buffer'
+    pos = _group_counters(cache, block)
+    q, ck, cv = _group_buffers(heads, kv_heads, pos)
+    past = ~attention.rows_seen(GROUP_ROWS, pos, circle)[:, :, None]
+    assert bool(past.any())
+    want = attention.grouped_cache_attention(
+        q, jnp.where(past, 0.0, ck), jnp.where(past, 0.0, cv), pos, circle,
+        kv_heads, f32)
+    got = attention.cache_attention(
+        q, jnp.where(past, jnp.nan, ck), jnp.where(past, 1e30, cv), pos,
+        circle, kv_heads, f32)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize('heads,kv_heads', GROUPS)
+def test_the_grouped_walk_in_bfloat16_stays_within_the_weights_rounding(
+        monkeypatch, heads, kv_heads):
+    """The grouped walk in the cells' dtype, to the one-span case's 2**-7."""
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
+    pos = _group_counters('circle_gone_round', 16)
+    q, ck, cv = _group_buffers(heads, kv_heads, pos, jnp.bfloat16)
+    want = attention.grouped_cache_attention(q, ck, cv, pos, True, kv_heads,
+                                             f32)
+    got = attention.cache_attention(q, ck, cv, pos, True, kv_heads, f32)
+    np.testing.assert_allclose(got, want, atol=2 ** -7)
 
 
 # -- two spans a sequence under one soft-max: a window and its summaries (PR 54)
@@ -650,6 +730,23 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _compiling_for_the_chip(monkeypatch):
+    """Inside, a program is compiled for the described chip, not interpreted;
+    an entry written for a described chip cannot be read back, so the
+    persistent cache stays out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+        compilation_cache.reset_cache()
+
+
 def test_the_block_kernel_compiles_for_the_chip_at_the_cells_shapes(
         one_chip, monkeypatch):
     """What interpret mode cannot show: the chip's compiler takes the kernel
@@ -657,26 +754,16 @@ def test_the_block_kernel_compiles_for_the_chip_at_the_cells_shapes(
     4 passes of 4,096 rows, bfloat16, the shipped block) as ONE custom call
     with no temporary beside its two double buffers of fast memory: the
     layer's buffers go in as they lie. A compile is not a measurement."""
-    from jax.experimental.compilation_cache import compilation_cache
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
                                                      sharding=one_chip)
     buffers = shape((32, 4 * 4096, 512), jnp.bfloat16)
-    # compiled, not interpreted; an entry written for a described chip
-    # cannot be read back, so the persistent cache stays out of it
-    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update('jax_enable_compilation_cache', False)
-    compilation_cache.reset_cache()
-    try:
+    with _compiling_for_the_chip(monkeypatch):
         compiled = jax.jit(
             lambda q, ck, cv, pos, t: decode_kernel.span_attention(
                 q, ck, cv, [attention.pass_span(pos, t, 4096)],
                 jnp.bfloat16)).lower(
             shape((32, 4, 128), jnp.bfloat16), buffers, buffers,
             shape((32,), jnp.int32), shape((), jnp.int32)).compile()
-    finally:
-        jax.config.update('jax_enable_compilation_cache', cached)
-        compilation_cache.reset_cache()
     assert compiled.as_text().count('tpu_custom_call') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
@@ -690,24 +777,91 @@ def test_the_two_span_walk_compiles_for_the_chip_at_the_cells_shapes(
     summaries, bfloat16, the shipped block) as ONE custom call with no
     temporary beside its double buffers of fast memory. A compile is not a
     measurement."""
-    from jax.experimental.compilation_cache import compilation_cache
     from handyrl_tpu.models.evabyte import eva_spans
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
                                                      sharding=one_chip)
     buffers = shape((sequences, 2560, 1024), jnp.bfloat16)
-    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update('jax_enable_compilation_cache', False)
-    compilation_cache.reset_cache()
-    try:
+    with _compiling_for_the_chip(monkeypatch):
         compiled = jax.jit(
             lambda q, ck, cv, pos: attention.span_attention(
                 q, ck, cv, eva_spans(pos, 2048, 16, 2560),
                 jnp.bfloat16)).lower(
             shape((sequences, 8, 128), jnp.bfloat16), buffers, buffers,
             shape((sequences,), jnp.int32)).compile()
-    finally:
-        jax.config.update('jax_enable_compilation_cache', cached)
-        compilation_cache.reset_cache()
     assert compiled.as_text().count('tpu_custom_call') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize('sequences', [32, 8])
+@pytest.mark.parametrize('config', ['trinity_mini', 'smallthinker'])
+def test_the_expert_nets_ply_compiles_with_its_caches_left_in_hbm(
+        one_chip, monkeypatch, config, sequences):
+    """The chip's compiler takes the decode ply of both expert cells' nets
+    (``benchmark/configs``: the rollout's 32 sequences and evaluation's 8, 8
+    or 7 query heads on ONE KV head of 128, circles of 2,048 or 4,096 rows
+    and a buffer of 8,192, bfloat16, the shipped block), scanned with the
+    cache in the carry as the rollout scans it, with ONE custom call a
+    layer, NO value of a cache's shape in fast memory (``S(1)`` in its
+    layout) and NO copy of that shape: the buffers are read as they lie and
+    written in place (``state_update``'s scatters). Without the kernel, and
+    with it but without its operands held to HBM, ``trinity_mini``'s buffers
+    were fetched whole into fast memory and copied back every ply (PERF.md,
+    PR 58). A compile is not a measurement."""
+    import json
+    import os
+    import re
+    from handyrl_tpu.models import build
+    path = os.path.join(os.path.dirname(__file__), '..', 'benchmark',
+                        'configs', config + '.json')
+    with open(path) as f:
+        env_args = json.load(f)['env_args']
+    net = build(env_args['net_name'], **env_args['net'])
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, jnp.bfloat16 if x.dtype == f32 else x.dtype,
+            sharding=one_chip), tree)
+    ids = jnp.zeros((sequences,), jnp.int32)
+    hidden = jax.eval_shape(lambda: net.init_hidden((sequences,)))
+    params = jax.eval_shape(
+        lambda: net.init(jax.random.PRNGKey(0), ids, None))
+
+    def plies(params, ids, hidden):
+        def ply(carry, _):
+            out = net.apply(params, *carry)
+            ids = jnp.argmax(out['policy'], axis=-1).astype(jnp.int32)
+            return (ids, net.reset_hidden(out['hidden'], ids == 7)), ()
+        return jax.lax.scan(ply, (ids, hidden), None, length=2)[0]
+
+    with _compiling_for_the_chip(monkeypatch):
+        text = jax.jit(plies, donate_argnums=(2,)).lower(
+            placed(params), placed(ids), placed(hidden)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == len(
+        net.layer_types)
+    caches = {'bf16[%d,%d,%d]' % k.shape for k in hidden['k']}
+    assert len(caches) == 2
+    values = re.findall(r'= (\w+\[[\d,]*\])(\{[^ ]*\})? ([\w\-]+)\(', text)
+    cache_values = [(layout, op) for shape, layout, op in values
+                    if shape in caches]
+    assert cache_values, 'the writes, at the least'
+    assert not [v for v in cache_values if 'S(1)' in v[0]]
+    assert not [v for v in cache_values if v[1].startswith('copy')]
+
+
+def test_the_kernels_buffers_are_held_to_hbm_where_a_program_is_traced_for_a_tpu(
+        monkeypatch):
+    """``decode_kernel._in_hbm``: inside a traced program for a TPU the
+    kernel's two buffers carry the constraint (twice in the jaxpr, K and V);
+    run eagerly there (a net's ``init``, which the constraint refuses) and
+    where the kernel is interpreted they go in as they are."""
+    shape = jax.ShapeDtypeStruct
+    buffers = shape((2, 64, 128), f32)
+    traced = lambda: str(jax.make_jaxpr(
+        lambda q, ck, cv, pos: decode_kernel.span_attention(
+            q, ck, cv, [attention.pass_span(pos, 0, 64)], f32, block=16))(
+        shape((2, 8, 128), f32), buffers, buffers, shape((2,), jnp.int32)))
+    assert 'memory_space_constraint' not in traced()
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert traced().count('with_memory_space_constraint') == 2
+    eager = jnp.zeros((2, 64, 128))
+    assert decode_kernel._in_hbm(eager) is eager

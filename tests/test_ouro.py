@@ -201,21 +201,6 @@ def test_lanes_reset_at_different_counters_keep_their_buffers():
                                        atol=3e-4)
 
 
-@pytest.fixture
-def kernel_form(monkeypatch):
-    """``cache_attention`` as it chooses on a TPU, the kernel interpreted
-    here, in blocks of 16 rows (three a pass of the nets below); the count
-    of the kernel's calls while a program is traced."""
-    from handyrl_tpu.models import decode_kernel
-    calls = []
-    real = decode_kernel.span_attention
-    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
-    monkeypatch.setattr(decode_kernel, 'block_rows', lambda *_: 16)
-    monkeypatch.setattr(decode_kernel, 'span_attention',
-                        lambda *a, **k: calls.append(1) or real(*a, **k))
-    return calls
-
-
 @pytest.mark.parametrize('dtype,atol', [('float32', 3e-4),
                                         ('bfloat16', 0.12)])
 def test_the_kernels_form_decodes_what_sequence_computes_across_a_games_end(
@@ -247,15 +232,16 @@ def test_the_kernels_form_decodes_what_sequence_computes_across_a_games_end(
 
 def test_the_net_counts_the_rows_its_plies_read(kernel_form):
     """``decode_rows``: over every (pass, layer), whole blocks up to each
-    counter's own where the kernel runs, every row elsewhere (a grouped
-    net, the CPU), beside the rows the buffers hold."""
+    counter's own where the kernel runs, with groups or without, every row
+    elsewhere (rows of no whole lanes, the CPU), beside the rows the buffers
+    hold."""
     pos = np.asarray([[0, 15], [16, 47]])
     net, _ = _net_and_variables(head_dim=64)
     each = net.passes * net.layers
     assert net.decode_rows(pos) == (each * (16 + 16 + 32 + 48),
                                     each * 48 * 4)
     grouped, _ = _net_and_variables(head_dim=64, heads_held=4)
-    assert grouped.decode_rows(pos) == (each * 48 * 4, each * 48 * 4)
+    assert grouped.decode_rows(pos) == net.decode_rows(pos)
     narrow, _ = _net_and_variables()         # 32 lanes: not the kernel's
     assert narrow.decode_rows(pos)[0] == each * 48 * 4
 

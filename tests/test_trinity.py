@@ -194,6 +194,48 @@ def test_decode_through_the_cache_matches_sequence():
         np.testing.assert_allclose(out['value'][:, 0], value[:, t], atol=atol)
 
 
+def test_the_kernels_form_decodes_what_sequence_computes(kernel_form):
+    """The same game through the cache as ``cache_attention`` chooses on a
+    TPU (the block kernel interpreted here, a row of 128 lanes: the one KV
+    head at the cell's head size, its four query heads the rows of the
+    kernel's matrix): a circle is ONE block, the full layer four, and the
+    counters pass every block boundary and go round the circles twice."""
+    net, variables = _net_and_variables(head_dim=128)
+    ids = _ids(3, 5)
+    logits, value, _aux = _sequence(
+        net, variables, ids, jnp.zeros((3,), jnp.int32), jnp.ones((3, T), bool))
+    step = jax.jit(net.apply)
+    hidden = net.init_hidden((3,))
+    for t in range(T):
+        out = step(variables, ids[:, t], hidden)
+        hidden = out['hidden']
+        np.testing.assert_allclose(out['policy'], logits[:, t], atol=3e-4)
+        np.testing.assert_allclose(out['value'][:, 0], value[:, t], atol=3e-4)
+    # a call a layer each time the ply is traced
+    assert kernel_form and len(kernel_form) % len(net.layer_types) == 0
+
+
+@pytest.mark.parametrize('case,pos,read', [
+    ('low_counters', [0, 15, 16], 3 * (16 + 16) + 16 + 16 + 32),
+    ('a_full_circle_beside_a_half_read_full_layer', [40, 63],
+     2 * (16 + 16) + 48 + 64),
+    ('the_full_layers_last_row', [63], 16 + 16 + 64)])
+def test_the_net_counts_the_rows_its_plies_read(kernel_form, case, pos, read):
+    """``decode_rows``: each layer hands ONE span, so where the kernel runs a
+    ply reads whole blocks up to each counter's own: a circle of one block
+    whole whatever the counter (read == held there) and, of the full layer,
+    less than it holds until the counter reaches its last block; every row
+    where the products run (the narrow nets' head size of 16 is no whole
+    lane; the CPU)."""
+    net, _ = _net_and_variables(head_dim=128)
+    held = len(pos) * (16 + 16 + 64)
+    assert net.decode_rows(np.asarray(pos)) == (read, held)
+    assert read % 16 == 0 and (read < held) == (case != 'the_full_layers_'
+                                                        'last_row')
+    narrow, _ = _net_and_variables()
+    assert narrow.decode_rows(np.asarray(pos)) == (held, held)
+
+
 def test_lanes_reset_at_different_counters_keep_their_buffers():
     """Three lanes, reset after 0, 9 and 21 plies: each plays its new game
     over the rows of the last one, at its own counter."""
